@@ -1,4 +1,4 @@
-.PHONY: test lint shard-baselines perf-baselines num-baselines tpu-smoke obs-smoke serve-smoke chaos-smoke wire-smoke thread-smoke blocking-smoke approx-smoke trace-smoke warmup-smoke drift-smoke perf-smoke tf-smoke scale-smoke fleet-smoke num-smoke bench bench-blocking all
+.PHONY: test lint shard-baselines perf-baselines num-baselines chip-smoke tpu-smoke obs-smoke serve-smoke chaos-smoke wire-smoke thread-smoke blocking-smoke approx-smoke trace-smoke warmup-smoke drift-smoke perf-smoke tf-smoke scale-smoke fleet-smoke num-smoke bench bench-blocking all
 
 # CPU oracle/golden tier: 8 virtual devices, runs anywhere.
 test:
@@ -48,12 +48,21 @@ num-baselines:
 	JAX_PLATFORMS=cpu \
 		python -m splink_tpu.analysis --num-audit --update-num-baselines
 
-# Hardware smoke tier: real TPU lowering of Pallas kernels + pipeline.
-# Separate invocation because tests/conftest.py pins its process to CPU.
-# Skips cleanly when no TPU backend is present; exits 5 (nothing collected)
-# when the accelerator backend is unreachable — treated as a skip.
+# Chip tier: both targets need a TPU and FAIL without one — run them
+# through the chip tool, in one call so they share the compile cache, e.g.
+#   chiprun -- sh -c 'python chip_smoke.py && python -m pytest tests_tpu/ -q'
+# chip-smoke drives the offline and the serve path once at the full width
+# of BASELINE config 4 and checks what comes out (a "summary:" JSON line,
+# "claim": null — a smoke, not a benchmark — then the verdict line
+# {"ok": true, "device": {...}}); tpu-smoke is its finer-grained
+# companion: real TPU lowering of the Pallas kernels + pipeline pieces.
+# Separate invocations because tests/conftest.py pins its process to CPU,
+# and one process at a time because a chip belongs to one process.
+chip-smoke:
+	python chip_smoke.py
+
 tpu-smoke:
-	python -m pytest tests_tpu/ -q || [ $$? -eq 5 ]
+	python -m pytest tests_tpu/ -q
 
 # Telemetry smoke: fixture linker run with the JSONL sink enabled (fault
 # injection included), then the summarize + export-trace CLI over the
@@ -199,4 +208,4 @@ bench:
 bench-blocking:
 	python benchmarks/blocking_bench.py
 
-all: lint test tpu-smoke blocking-smoke approx-smoke serve-smoke chaos-smoke wire-smoke thread-smoke trace-smoke warmup-smoke drift-smoke perf-smoke tf-smoke scale-smoke fleet-smoke num-smoke bench
+all: lint test blocking-smoke approx-smoke serve-smoke chaos-smoke wire-smoke thread-smoke trace-smoke warmup-smoke drift-smoke perf-smoke tf-smoke scale-smoke fleet-smoke num-smoke bench

@@ -127,18 +127,11 @@ def config_3(scale):
     return out
 
 
-def config_4(scale):
-    """10M-row dedupe. At full scale the dob blocking rule alone yields
-    ~3.3B candidate pairs, so output is consumed as a stream (the full
-    scored frame would not fit host memory as one DataFrame) and quality
-    metrics aggregate incrementally. EM runs pattern-compressed: one device
-    pass histograms the gamma vectors, iterations run on the tiny weighted
-    pattern matrix."""
-    from splink_tpu import Splink
-
-    n = max(int(10_000_000 * scale), 1000)
-    df = make_people(n, seed=4)
-    settings = {
+def config_4_settings() -> dict:
+    """BASELINE config 4's model: six comparison columns, three blocking
+    rules. The one definition — ``config_4`` and ``chip_smoke.py`` both
+    build on it."""
+    return {
         "link_type": "dedupe_only",
         "comparison_columns": [
             {"col_name": "first_name", "num_levels": 3},
@@ -159,10 +152,24 @@ def config_4(scale):
         "retain_matching_columns": False,
         "retain_intermediate_calculation_columns": False,
         "additional_columns_to_retain": ["cluster"],
-        "spill_dir": os.environ.get(
-            "SPLINK_TPU_SPILL_DIR", os.path.join(os.path.dirname(__file__), "spill")
-        ),
     }
+
+
+def config_4(scale):
+    """10M-row dedupe. At full scale the dob blocking rule alone yields
+    ~3.3B candidate pairs, so output is consumed as a stream (the full
+    scored frame would not fit host memory as one DataFrame) and quality
+    metrics aggregate incrementally. EM runs pattern-compressed: one device
+    pass histograms the gamma vectors, iterations run on the tiny weighted
+    pattern matrix."""
+    from splink_tpu import Splink
+
+    n = max(int(10_000_000 * scale), 1000)
+    df = make_people(n, seed=4)
+    settings = config_4_settings()
+    settings["spill_dir"] = os.environ.get(
+        "SPLINK_TPU_SPILL_DIR", os.path.join(os.path.dirname(__file__), "spill")
+    )
     if os.environ.get("SPLINK_TPU_BENCH_FORCE_VIRTUAL"):
         # sub-scale runs sit below the auto threshold (2^28 pairs); force
         # the device pair path so the CPU tier still exercises/benches it

@@ -21,12 +21,13 @@ batches, and checks invariants no AST rule can see:
              log-Bayes-factor direction: sweeping one column through its
              levels sorted by log(m/u) (null slotted at 0) while the
              other columns stay null must produce a non-decreasing
-             probability, for both the jnp.sum reduction and the
-             fold_logit order.
-    NA-ORD   the fold order is pinned: fold_logit must be BIT-IDENTICAL
-             to a host-side numpy f32 reference that accumulates the
-             per-column masked level lookups strictly left to right,
-             using the device's own log tables as data.
+             probability, for match_probability and for
+             sigmoid(fold_logit).
+    NA-ORD   the fold order is pinned: fold_logit and match_logit (the
+             offline score's logit) must be BIT-IDENTICAL to a host-side
+             numpy f32 reference that accumulates the per-column masked
+             level lookups strictly left to right, using the device's
+             own log tables as data.
     NA-BASE  bookkeeping: a registered kernel has no ulp budget for this
              tier (the committed baselines are stale).
     NA-ERROR a kernel or corner failed to execute at all.
@@ -363,12 +364,10 @@ def _measure_ulp(spec) -> float:
     """Run a kernel at f32 and at f64 (inputs upcast, x64 on) and return
     the divergence. Deterministic: same seed inputs, no timing."""
     import jax
-    from jax.experimental import disable_x64, enable_x64
-
     fn, args, kwargs = spec.built()
-    with disable_x64():
+    with jax.enable_x64(False):
         out32 = jax.block_until_ready(fn(*args, **kwargs))
-    with enable_x64():
+    with jax.enable_x64(True):
         out64 = jax.block_until_ready(fn(*_upcast_args(args), **kwargs))
     return _ulp_divergence(out32, out64)
 
@@ -443,18 +442,18 @@ def _check_monotone() -> list[Finding]:
 
 
 def _check_fold_order() -> list[Finding]:
-    """NA-ORD: fold_logit must match a host numpy f32 reference that
-    accumulates the per-column masked level lookups strictly left to
-    right, bit for bit. The reference consumes the DEVICE log tables as
-    data, so it pins only the association order, not libm log."""
+    """NA-ORD: fold_logit AND match_logit (the offline score's logit) must
+    match a host numpy f32 reference that accumulates the per-column
+    masked level lookups strictly left to right, bit for bit. The
+    reference consumes the DEVICE log tables as data, so it pins only the
+    association order, not libm log."""
     import numpy as np
 
-    from ..models.fellegi_sunter import _safe_log, fold_logit
+    from ..models.fellegi_sunter import _safe_log, fold_logit, match_logit
     from .trace_audit import shared_fs_inputs
 
     G, _ = shared_fs_inputs()
     params = _mono_params()
-    device = np.asarray(fold_logit(G, params))
 
     Gn = np.asarray(G)
     log_m = np.asarray(_safe_log(params.m))
@@ -476,24 +475,29 @@ def _check_fold_order() -> list[Finding]:
         )
     reference = (prior + log_bf).astype(np.float32)
 
-    if not np.array_equal(device, reference):
+    findings = []
+    for name, fn in (("fold_logit", fold_logit), ("match_logit", match_logit)):
+        device = np.asarray(fn(G, params))
+        if np.array_equal(device, reference):
+            continue
         n_diff = int((device != reference).sum())
         worst = float(np.max(np.abs(device.astype(np.float64) - reference)))
-        return [
+        findings.append(
             Finding(
                 rule="NA-ORD",
-                path="fold_logit",
+                path=name,
                 line=0,
                 message=(
-                    f"fold_logit differs from the left-to-right reference "
+                    f"{name} differs from the left-to-right reference "
                     f"fold at {n_diff}/{device.size} rows (max abs diff "
                     f"{worst:.3e}) — the contracted fold order moved"
                 ),
-                hint="every TF-anchored path assumes fold_logit's column "
-                "order; see docs/numerics notes before changing it",
+                hint="serve/offline score parity and every TF-anchored path "
+                "assume this column order; see docs/numerics notes before "
+                "changing it",
             )
-        ]
-    return []
+        )
+    return findings
 
 
 # ---------------------------------------------------------------------------
@@ -532,8 +536,6 @@ def audit_kernel_numerics(spec, base: dict | None) -> list[Finding]:
     registered inputs and every applicable corner, NA-ULP against the
     committed budget (NA-BASE when the budget is missing)."""
     import jax
-    from jax.experimental import disable_x64
-
     findings: list[Finding] = []
     fn, args, kwargs = spec.built()
     check_fin = _FIN_CHECKERS.get(spec.name, _finite_leaves)
@@ -541,7 +543,7 @@ def audit_kernel_numerics(spec, base: dict | None) -> list[Finding]:
     batches = [("registered", args)] + _kernel_corners(spec.name, args)
     for cname, batch in batches:
         try:
-            with disable_x64():
+            with jax.enable_x64(False):
                 out = jax.block_until_ready(fn(*batch, **kwargs))
         except Exception as exc:  # noqa: BLE001 - surfaced as a finding
             findings.append(

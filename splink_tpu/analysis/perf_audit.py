@@ -273,9 +273,9 @@ def measure_cell(
     compile/execute wall, deterministic memory_analysis bytes, peak device
     delta (null without memory_stats). Forces x64 OFF — the production
     program width — regardless of ambient config."""
-    from jax.experimental import disable_x64
+    import jax
 
-    with disable_x64():
+    with jax.enable_x64(False):
         peak0 = _peak_device_bytes()
         compiled, args, kwargs, compile_ms = _compile_cell(
             cell.kernel, cell.factor
@@ -309,9 +309,9 @@ def _remeasure_execute(cell: PerfShape, k: int, best_of: int) -> float:
     """Median of K fresh best-of-N execute measurements (the PA-TIME noise
     guard). Re-uses one compile; the K re-runs interleave real time so a
     transient CPU spike cannot dominate every sample."""
-    from jax.experimental import disable_x64
+    import jax
 
-    with disable_x64():
+    with jax.enable_x64(False):
         compiled, args, kwargs, _ = _compile_cell(cell.kernel, cell.factor)
         samples = [
             _execute_best_of(compiled, args, kwargs, best_of)
@@ -323,10 +323,10 @@ def _remeasure_execute(cell: PerfShape, k: int, best_of: int) -> float:
 def _remeasure_compile(cell: PerfShape, k: int) -> float:
     """Median of K fresh compile measurements (the PA-TIME noise guard on
     the compile metric)."""
-    from jax.experimental import disable_x64
+    import jax
 
     samples = []
-    with disable_x64():
+    with jax.enable_x64(False):
         for _ in range(max(k, 1)):
             *_rest, compile_ms = _compile_cell(cell.kernel, cell.factor)
             samples.append(compile_ms)

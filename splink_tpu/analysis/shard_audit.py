@@ -234,10 +234,8 @@ def _lowered(spec: ShardKernelSpec):
     the identical executable (the mirror image of trace_audit forcing x64
     ON to catch dtype leaks)."""
     import jax
-    from jax.experimental import disable_x64
-
     if "lowered" not in spec.cache:
-        with disable_x64():
+        with jax.enable_x64(False):
             fn, args, kwargs = spec.build()
             jfn = fn if hasattr(fn, "lower") else jax.jit(fn)
             compiled = jfn.lower(*args, **kwargs).compile()
@@ -348,17 +346,17 @@ def _pad_reaches_all_outputs(closed, weights_leaf: int):
     higher-order eqns (while/scan/cond) are conservative — any tainted
     input taints every output — which is exact enough to catch the real
     failure mode: a weights argument that never enters the dataflow."""
-    import jax.core
+    import jax.extend.core as jex_core
 
     def hit(v, tainted):  # Literal atoms are unhashable and never tainted
-        return not isinstance(v, jax.core.Literal) and v in tainted
+        return not isinstance(v, jex_core.Literal) and v in tainted
 
     def walk(jaxpr, tainted: set):
         for eqn in jaxpr.eqns:
             sub = None
             if eqn.primitive.name == "pjit":
                 sub = eqn.params.get("jaxpr")
-            if sub is not None and isinstance(sub, jax.core.ClosedJaxpr):
+            if sub is not None and isinstance(sub, jex_core.ClosedJaxpr):
                 inner_taint = {
                     sub.jaxpr.invars[i]
                     for i, v in enumerate(eqn.invars)
@@ -432,9 +430,7 @@ def audit_shard_kernel(
         _check_leaf_sharding(
             spec, fail, "input", idx, tuple(leaf.shape), sharding
         )
-    from jax.experimental import disable_x64
-
-    with disable_x64():
+    with jax.enable_x64(False):
         out_struct = jax.eval_shape(
             fn if not hasattr(fn, "lower") else (lambda *a, **k: fn(*a, **k)),
             *args,
@@ -481,7 +477,7 @@ def audit_shard_kernel(
     # SA-PAD: padding weights must reach every output
     if spec.pad_weights_argnum is not None:
         try:
-            with disable_x64():
+            with jax.enable_x64(False):
                 closed = jax.make_jaxpr(lambda *a, **k: fn(*a, **k))(
                     *args, **kwargs
                 )

@@ -87,20 +87,28 @@ def gamma_log_probs(G, probs):
 
 
 def log_bayes_factor(G, params: FSParams):
-    """(n,) summed per-column log(m/u) evidence."""
-    return jnp.sum(
-        gamma_log_probs(G, params.m) - gamma_log_probs(G, params.u), axis=-1
-    )
+    """(n,) per-column log(m/u) evidence, accumulated COLUMN BY COLUMN,
+    left to right — :func:`fold_logit`'s order, pinned here too.
+
+    A ``jnp.sum`` over the column axis leaves the association order to the
+    backend's reduce lowering: XLA CPU happens to walk six columns in
+    order, the TPU's reduce associates differently, and the offline score
+    then sits a few float32 ulps from the fused serve kernel's running
+    accumulator (first seen on the chip, PR 21: 27 of 1197 pairs, worst
+    21 ulps). The column count is static and small, so the explicit chain
+    costs nothing a reduce would save."""
+    evidence = gamma_log_probs(G, params.m) - gamma_log_probs(G, params.u)
+    log_bf = jnp.zeros(evidence.shape[:-1], evidence.dtype)
+    for ci in range(evidence.shape[-1]):
+        log_bf = log_bf + evidence[..., ci]
+    return log_bf
 
 
 def match_logit(G, params: FSParams):
-    """(n,) pre-sigmoid match evidence: logit(lambda) + log Bayes factor.
-
-    The quantity the term-frequency fold adds its per-pair delta to
-    (term_frequencies.make_tf_fold_fn): serve and offline both compute
-    ``sigmoid(match_logit + tf_sum)`` with the same association order,
-    which is what keeps the TF-adjusted scores bit-identical across
-    paths."""
+    """(n,) pre-sigmoid match evidence: logit(lambda) + log Bayes factor,
+    bit for bit :func:`fold_logit` on every backend (same per-column
+    values, same left-to-right accumulation): the served score of a pair
+    and its offline score are the same float."""
     lam = params.lam
     prior_logit = _safe_log(lam) - _safe_log(1.0 - lam)
     return prior_logit + log_bayes_factor(G, params)
@@ -117,14 +125,13 @@ def fold_logit(G, params: FSParams):
     megakernel (serve/engine.make_score_fused_fn), per-column masked
     level lookups included.
 
-    Mathematically identical to ``match_logit``; bitwise it can differ in
-    the last ulp past ~2 comparison columns, because ``jnp.sum``'s
-    reduction tree is not the sequential order the fused kernel's running
-    accumulator uses. The TF fold therefore anchors on THIS logit on
-    every path (fused serve, unfused serve oracle, offline fold kernel) —
-    that shared order is what makes the TF-adjusted scores bit-identical
-    across all of them at any column count. The unadjusted score keeps
-    ``match_probability`` unchanged."""
+    Bit-identical to ``match_logit``, which pins the same accumulation
+    order over (n, C)-shaped intermediates (:func:`log_bayes_factor`); the
+    numerics audit's NA-ORD rule holds both to a host left-to-right
+    reference. The TF fold anchors on THIS logit on every path (fused
+    serve, unfused serve oracle, offline fold kernel), which is what makes
+    the TF-adjusted scores bit-identical across all of them at any column
+    count."""
     lam = params.lam
     prior_logit = _safe_log(lam) - _safe_log(1.0 - lam)
     log_m = _safe_log(params.m)

@@ -1,14 +1,19 @@
 """ctypes loader for the native host kernels, with pure-numpy fallback.
 
-The library is built on first use (``make`` + g++, a one-second compile) and
-cached next to the sources. Every entry point has a Python fallback so the
-package works on machines without a toolchain — ``available()`` reports which
-path is active.
+The library is built on first use (g++, a one-second compile) next to the
+sources, under a name that carries a hash of the source text, the compiler
+flags and the host CPU's feature set — ``-march=native`` code from another
+machine, or from an older source revision, has another name and is never
+loaded. Every entry point has a Python fallback so the package works on
+machines without a toolchain — ``available()`` reports which path is
+active, and the loader says so once at warning level.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -16,69 +21,80 @@ import threading
 
 import numpy as np
 
+from ..utils.envfp import cpu_target_fingerprint
+
 logger = logging.getLogger("splink_tpu")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_DIR, "libsplink_host.so")
+SOURCE = os.path.join(_DIR, "src", "host_kernels.cpp")
+_CXX = os.environ.get("CXX", "g++")
+_CXXFLAGS = ("-O3", "-march=native", "-fPIC", "-shared", "-std=c++17", "-Wall")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+_built_here = False
 
 _i64p = ctypes.POINTER(ctypes.c_int64)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 
 
-def _build() -> bool:
+def library_path() -> str:
+    """Where the library for THIS source, these flags and this CPU lives."""
+    h = hashlib.sha256()
+    with open(SOURCE, "rb") as fh:
+        h.update(fh.read())
+    h.update(" ".join((_CXX, *_CXXFLAGS, cpu_target_fingerprint())).encode())
+    return os.path.join(_DIR, f"libsplink_host-{h.hexdigest()[:16]}.so")
+
+
+def _build(path: str) -> None:
+    """Compile SOURCE to ``path`` (temp file + rename: concurrent first
+    uses in several processes never load a half-written library), then
+    sweep libraries built from other sources, flags or machines."""
+    tmp = f"{path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["make", "-C", _DIR, "-s"],
-            check=True,
-            capture_output=True,
-            timeout=120,
+            [_CXX, *_CXXFLAGS, "-o", tmp, SOURCE],
+            check=True, capture_output=True, timeout=120,
         )
-        return os.path.exists(_LIB_PATH)
-    except Exception as e:  # pragma: no cover - depends on toolchain
-        logger.debug("native build failed (%s); using numpy fallbacks", e)
-        return False
+        os.replace(tmp, path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libsplink_host*.so")):
+        if stale != path:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, _tried
+    global _lib, _tried, _built_here
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) and not _build():
-            return None
         try:
-            lib = _bind(ctypes.CDLL(_LIB_PATH))
-        except AttributeError:
-            # Stale cached .so from an older source revision (missing a newer
-            # symbol): rebuild once, then retry; numpy fallback if that fails.
-            logger.debug("native lib stale; rebuilding")
-            try:
-                os.remove(_LIB_PATH)
-            except OSError:
-                pass
-            if _build():
-                try:
-                    _lib = _bind(ctypes.CDLL(_LIB_PATH))
-                except (OSError, AttributeError) as e:  # pragma: no cover
-                    logger.debug("native rebuild failed (%s); numpy fallbacks", e)
-                    _lib = None
-            else:
-                _lib = None
-        except OSError as e:  # pragma: no cover
-            logger.debug("native load failed (%s); using numpy fallbacks", e)
+            path = library_path()
+            if not os.path.exists(path):
+                _build(path)
+                _built_here = True
+            _lib = _bind(ctypes.CDLL(path))
+        except (OSError, subprocess.SubprocessError, AttributeError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            logger.warning(
+                "native host kernels unavailable (%s: %s %s); host encode "
+                "and the host join run on the numpy fallbacks",
+                type(e).__name__, e, detail.decode(errors="replace")[-300:],
+            )
             _lib = None
-        else:
-            _lib = lib
         return _lib
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
-    """Declare signatures; raises AttributeError if the .so is stale."""
+    """Declare signatures; raises AttributeError on a missing symbol."""
     lib.encode_fixed_width.argtypes = [
         _u8p, _i64p, ctypes.c_int64, ctypes.c_int64, _u8p, _i32p,
     ]
@@ -100,6 +116,17 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def available() -> bool:
     return _load() is not None
+
+
+def build_info() -> dict:
+    """Which host path is active, and where the library came from."""
+    lib = _load()
+    return {
+        "available": lib is not None,
+        "library": os.path.basename(lib._name) if lib is not None else None,
+        "built_in_this_process": _built_here,
+        "source": os.path.relpath(SOURCE, os.path.dirname(os.path.dirname(_DIR))),
+    }
 
 
 def _ptr(a: np.ndarray, ctype):

@@ -30,19 +30,9 @@ logger = logging.getLogger("splink_tpu")
 
 
 def distributed_is_initialized() -> bool:
-    """Whether the multi-controller runtime is up. jax < 0.5 has no
-    ``jax.distributed.is_initialized``; fall back to the client object the
-    initialize call installs (reading it does NOT initialise the XLA
-    backend, unlike jax.process_count())."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:  # noqa: BLE001 - conservative: assume not initialised
-        return False
+    """Whether the multi-controller runtime is up (does NOT initialise
+    the XLA backend, unlike jax.process_count())."""
+    return bool(jax.distributed.is_initialized())
 
 
 def initialize_multihost(

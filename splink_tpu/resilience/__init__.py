@@ -12,12 +12,13 @@ histories, iteration counter):
     checkpoints are rejected rather than silently loaded.
   * :mod:`retry` — bounded exponential backoff around streamed batch fetch
     and device put/execute, classifying transient failures (RESOURCE_EXHAUSTED,
-    tunnel/RPC drops) from deterministic ones.
+    RPC drops) from deterministic ones.
   * :mod:`faults` — deterministic fault injection (env/settings-driven), so
     every recovery path has a test that actually exercises it.
 
-Degradation order when a regime fails outright: resident EM -> streamed EM
--> CPU backend (docs/resilience.md).
+Degradation when a regime fails outright: resident EM -> streamed EM
+(docs/resilience.md). An accelerator that does not come up raises; nothing
+falls back to the CPU backend.
 """
 
 from .checkpoint import (  # noqa: F401
@@ -34,7 +35,6 @@ from .retry import (  # noqa: F401
     RetryError,
     RetryPolicy,
     classify_error,
-    ensure_devices,
     is_oom,
     retry_call,
 )
@@ -53,7 +53,6 @@ __all__ = [
     "RetryError",
     "RetryPolicy",
     "classify_error",
-    "ensure_devices",
     "is_oom",
     "retry_call",
 ]

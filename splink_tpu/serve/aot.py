@@ -78,8 +78,16 @@ def deserialize_executable(blob: bytes):
     first (this is a pickle load)."""
     from jax.experimental import serialize_executable as se
 
+    import jax
+
     payload, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    # the serve programs are single-device; without execution_devices the
+    # loader binds the executable to EVERY local device and a multi-chip
+    # host then refuses the one-shard arguments
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=jax.local_devices()[:1],
+    )
 
 
 class AotStore:

@@ -48,6 +48,7 @@ from ..analysis import lockwatch
 
 import numpy as np
 
+from ..utils.compile_cache import enable_compilation_cache
 from ..utils.logging_utils import warn_degraded
 
 logger = logging.getLogger("splink_tpu")
@@ -151,6 +152,29 @@ def _top_k_rowwise(scores, k: int):
     return jnp.stack(vals, axis=1), jnp.stack(idxs, axis=1)
 
 
+def _take_slots(x, slots):
+    """``x[q, slots[q, j]]`` — ``jnp.take_along_axis(x, slots, axis=1)``
+    with the int32 slot indices kept int32 (take_along_axis widens them to
+    the canonical index dtype, int64 under x64, which the trace audit
+    rejects). Slots come from :func:`_top_k_rowwise`, which clamps them
+    into range."""
+    from jax import lax
+
+    return lax.gather(
+        x,
+        slots[..., None],
+        lax.GatherDimensionNumbers(
+            offset_dims=(),
+            collapsed_slice_dims=(1,),
+            start_index_map=(1,),
+            operand_batching_dims=(0,),
+            start_indices_batching_dims=(0,),
+        ),
+        slice_sizes=(1, 1),
+        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
 def _finish_topk(p, cand, valid, k: int):
     """Shared tail of both scoring paths: mask invalid slots to an
     impossible -1, run the partition-safe row-wise top-k, and map the
@@ -164,8 +188,8 @@ def _finish_topk(p, cand, valid, k: int):
         valid.reshape(-1), p, jnp.asarray(-1.0, p.dtype)
     ).reshape(q_n, capacity)
     top_p, top_i = _top_k_rowwise(scores, k)
-    top_rows = jnp.take_along_axis(cand, top_i, axis=1)
-    top_valid = jnp.take_along_axis(valid, top_i, axis=1)
+    top_rows = _take_slots(cand, top_i)
+    top_valid = _take_slots(valid, top_i)
     # a row with fewer than k valid candidates re-picks slot 0 with the
     # -2 mask sentinel once real entries are exhausted; the score guard
     # keeps such duplicates from reading slot 0's valid flag (real
@@ -257,14 +281,14 @@ def make_score_fused_fn(layout: dict, comparison_columns, k: int,
     compare-and-mask lookup in the same level order, the same null
     (gamma = -1) masking. ACROSS comparisons the accumulation order is
     the pinned left-to-right fold of
-    :func:`~..models.fellegi_sunter.fold_logit` (the NA-ORD audit
-    invariant, docs/static_analysis.md#layer-6); ``match_probability``'s
-    ``jnp.sum`` reduction tree is not contractually that order past ~2
-    comparison columns, so fused-vs-unfused parity is bit-identical
-    UNDER the fold order and ulp-budgeted otherwise — the parity tests
-    and the ``make warmup-smoke`` oracle comparison gate bit-identity on
-    the tiers where the lowered reduction coincides, and the layer-6
-    numerics audit pins the fold order itself.
+    :func:`~..models.fellegi_sunter.fold_logit`, which
+    ``match_probability`` shares (``log_bayes_factor`` accumulates its
+    columns in the same order instead of leaving it to a ``jnp.sum``
+    lowering), so fused, unfused and offline scores are the same float on
+    every backend. The layer-6 numerics audit (NA-ORD,
+    docs/static_analysis.md#layer-6) holds both logits to a host
+    left-to-right reference; the parity tests, ``make warmup-smoke`` and
+    — on the TPU — ``tests_tpu`` and ``chip_smoke.py`` gate bit-identity.
 
     With ``tf_spec`` the term-frequency u-probability fold rides the same
     fusion: per TF column ONE extra device gather (the reference token ids
@@ -346,15 +370,20 @@ def _exec_name(kind: str, q_pad: int, capacity: int) -> str:
 @contextlib.contextmanager
 def _persistent_cache_disabled():
     """Force a REAL backend compile (no persistent-cache read) — the only
-    kind of executable that serializes into a loadable sidecar blob."""
+    kind of executable that serializes into a loadable sidecar blob. jax
+    decides once per process whether the cache is in use and remembers
+    it, so the switch only takes effect with that memo dropped."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
     try:
         yield
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
 
 
 def _params_structs(mu_shape, dt):
@@ -388,6 +417,9 @@ class QueryEngine:
 
         self.index = index
         settings = index.settings
+        # a serve-only process never builds a linker: enable the persistent
+        # compile cache here too (first caller in the process wins)
+        enable_compilation_cache(settings.get("compilation_cache_dir"))
         # Fused scoring (make_score_fused_fn) is the default hot path; the
         # unfused program is the retained parity oracle (serve_fused=False
         # or fused=False selects it).
@@ -508,7 +540,7 @@ class QueryEngine:
         if index.dtype == "float64":
             import jax
 
-            if jax.default_backend() == "tpu":  # pragma: no cover - no TPU CI
+            if jax.default_backend() == "tpu":
                 raise ValueError(
                     "index was built for float64 but the TPU backend has no "
                     "float64 support; rebuild with float64 off"
@@ -735,6 +767,7 @@ class QueryEngine:
             )
         executables = {}
         recompiled = 0
+        fresh_jits: dict = {}
         for (kind, q_pad, capacity), ex in self._execs.items():
             if self._exec_source.get((kind, q_pad, capacity)) != "compiled":
                 # only an executable ACTUALLY backend-compiled in this
@@ -745,9 +778,16 @@ class QueryEngine:
                 # "Symbols not found" — writing it would overwrite a
                 # valid sidecar with a poisoned one. Re-compile a fresh
                 # twin with the persistent cache bypassed; the existing
-                # executable keeps serving.
+                # executable keeps serving. The twin comes from a NEW jit
+                # wrapper: jax memoises trace -> lowering -> executable per
+                # function object, so re-lowering the engine's own wrapper
+                # hands back the very executable being replaced.
+                if kind not in fresh_jits:
+                    fresh_jits[kind] = self._build_kernel(
+                        self.top_k if kind == "full" else self.brownout_top_k
+                    )
                 with _persistent_cache_disabled():
-                    ex = self._jit_kernel(kind).lower(
+                    ex = fresh_jits[kind].lower(
                         capacity, *self._arg_structs(q_pad)
                     ).compile()
                 recompiled += 1

@@ -69,13 +69,7 @@ _NON_COLUMN_DEFAULT_KEYS = [
     "profile_dir",
     "telemetry_dir",
     "telemetry_memory",
-    # NOTE: compilation_cache_dir is deliberately NOT auto-filled:
-    # completion mutates the caller's dict in place, so auto-filling
-    # would make a reused settings dict look explicitly configured on
-    # the second Splink() construction. The linker resolves the schema
-    # default lazily instead (the cache is on for every backend; the
-    # CPU tier keys entries by target-feature fingerprint — see
-    # linker._enable_compilation_cache).
+    "compilation_cache_dir",
     "float64",
     "checkpoint_dir",
     "checkpoint_interval",

@@ -126,6 +126,27 @@ def test_ord_gate_is_falsifiable(monkeypatch):
     assert "left-to-right" in findings[0].message
 
 
+def test_ord_gate_covers_the_offline_logit(monkeypatch):
+    # match_logit (the offline score) is held to the same reference: a
+    # right-to-left accumulation — what a backend's reduce tree may do —
+    # rounds differently and must fire, naming the function
+    import jax.numpy as jnp
+
+    import splink_tpu.models.fellegi_sunter as fs
+
+    def right_to_left(G, p):
+        ev = fs.gamma_log_probs(G, p.m) - fs.gamma_log_probs(G, p.u)
+        acc = jnp.zeros(ev.shape[:-1], ev.dtype)
+        for ci in reversed(range(ev.shape[-1])):
+            acc = acc + ev[..., ci]
+        return fs._safe_log(p.lam) - fs._safe_log(1.0 - p.lam) + acc
+
+    assert na._check_fold_order() == []
+    monkeypatch.setattr(fs, "match_logit", right_to_left)
+    findings = na._check_fold_order()
+    assert [(f.rule, f.path) for f in findings] == [("NA-ORD", "match_logit")]
+
+
 def test_corner_transforms_only_touch_their_leaves():
     import jax.numpy as jnp
 

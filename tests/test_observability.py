@@ -304,104 +304,128 @@ def test_profile_dir_captures_traces(tmp_path):
         set_trace_dir(None)  # process-wide flag: do not leak into other tests
 
 
-def test_cpu_cache_keyed_by_target_fingerprint(tmp_path, monkeypatch):
-    """On the CPU backend the persistent compilation cache is ON (no more
-    accelerator-only gate) and its directory is keyed by the host's
-    target-feature fingerprint: XLA:CPU executables embed exact machine
-    features, so the ``cpu-<fp16>`` subdirectory is what keeps a shared
-    cache volume from serving SIGILL-prone foreign code. Completion still
-    never auto-fills the settings key."""
+@pytest.fixture
+def fresh_cache_state(monkeypatch):
+    """The compile-cache module as a fresh process sees it (no directory
+    applied yet), with jax's own cache config restored afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    from splink_tpu.utils import compile_cache
+
+    saved = {
+        name: getattr(jax.config, name)
+        for name in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+        )
+    }
+    monkeypatch.setattr(compile_cache, "_applied", None)
+    yield compile_cache
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    jcc.reset_cache()
+
+
+def _expected_default_dir():
     import os
 
+    import splink_tpu
+    from splink_tpu.utils.envfp import cpu_target_fingerprint
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(splink_tpu.__file__)))
+    return os.path.join(
+        checkout, ".jax_cache", f"cpu-{cpu_target_fingerprint()[:16]}"
+    )
+
+
+def test_cache_dir_env_var_wins_and_nothing_else_is_set(
+    fresh_cache_state, monkeypatch, tmp_path
+):
+    """JAX_COMPILATION_CACHE_DIR set: that directory and no other — the
+    code sets nothing but the two thresholds that make small programs
+    cacheable."""
+    import jax
+
+    cc = fresh_cache_state
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 9.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 99)
+    got = cc.enable_compilation_cache(str(tmp_path / "from_settings"))
+    assert got == str(tmp_path / "outside")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert cc._applied is None
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+
+
+def test_cache_dir_unset_is_the_fixed_in_checkout_path(
+    fresh_cache_state, monkeypatch, tmp_path
+):
+    """Variable unset: one fixed directory inside the checkout, resolved
+    from the package's location (CPU entries under the target-fingerprint
+    subdirectory — XLA:CPU executables pin exact machine features); the
+    thresholds apply this way too; the first caller wins for the process;
+    the linker and a serve-only engine land in the same place."""
     import jax
     import pandas as pd
 
-    import splink_tpu.linker as linker_mod
     from splink_tpu import Splink
-    from splink_tpu.settings import complete_settings_dict
-    from splink_tpu.utils.envfp import cpu_target_fingerprint
+    from splink_tpu.serve import QueryEngine
 
-    if jax.default_backend() != "cpu":
-        pytest.skip("CPU-only keying: not exercisable on an accelerator")
-    # the conftest-pinned env var must not short-circuit the settings path
+    cc = fresh_cache_state
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    prev_applied = linker_mod._compilation_cache_applied
-    prev_dir = jax.config.jax_compilation_cache_dir
-    try:
-        linker_mod._compilation_cache_applied = None
-        base = tmp_path / "xla"
-        linker_mod._enable_compilation_cache(str(base), explicit=False)
-        applied = linker_mod._compilation_cache_applied
-        expect = os.path.join(
-            str(base), f"cpu-{cpu_target_fingerprint()[:16]}"
-        )
-        assert applied == expect
-        assert jax.config.jax_compilation_cache_dir == expect
-        # two hosts with different feature sets never share entries: the
-        # fingerprint is a pure function of machine + flags
-        assert cpu_target_fingerprint() == cpu_target_fingerprint()
-        # completion never fills the key (the linker resolves the schema
-        # default lazily; a reused dict must not look explicitly set)
-        s = complete_settings_dict(
-            {
-                "link_type": "dedupe_only",
-                "comparison_columns": [
-                    {"col_name": "name", "num_levels": 2}
-                ],
-                "blocking_rules": ["l.name = r.name"],
-            }
-        )
-        assert "compilation_cache_dir" not in s
-        # first linker wins holds for the fingerprinted path too
-        linker_mod._enable_compilation_cache(
-            str(tmp_path / "other"), explicit=False
-        )
-        assert linker_mod._compilation_cache_applied == expect
-        # and a default-config linker construction leaves it untouched
-        df = pd.DataFrame({"unique_id": [0, 1], "name": ["a", "b"]})
-        Splink(s, df=df)
-        assert linker_mod._compilation_cache_applied == expect
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        linker_mod._compilation_cache_applied = prev_applied
-        from jax._src import compilation_cache as _cc
+    expect = _expected_default_dir()
+    assert ".cache" not in expect and "tmp" not in expect
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 9.0)
+    assert cc.enable_compilation_cache() == expect
+    assert jax.config.jax_compilation_cache_dir == expect
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
+    # first caller wins: a later explicit setting never moves the cache
+    assert cc.enable_compilation_cache(str(tmp_path / "late")) == expect
 
-        _cc.reset_cache()
-
-
-def test_compilation_cache_dir_applies(tmp_path, monkeypatch):
-    """settings["compilation_cache_dir"] -> jax persistent compilation
-    cache enabled at that path (process-wide, first linker wins; on the
-    CPU backend under the target-fingerprint subdirectory); entries
-    actually land once a compile exceeds the time threshold (forced to 0
-    here so the CPU tier's sub-second compiles qualify)."""
-    import os
-
-    import jax
-    import numpy as np
-    import pandas as pd
-
-    import splink_tpu.linker as linker_mod
-    from splink_tpu import Splink
-    from splink_tpu.utils.envfp import cpu_target_fingerprint
-
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    expect = str(tmp_path / "xla")
-    if jax.default_backend() == "cpu":
-        expect = os.path.join(
-            expect, f"cpu-{cpu_target_fingerprint()[:16]}"
-        )
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_applied = linker_mod._compilation_cache_applied
-    prev_min_time = jax.config.jax_persistent_cache_min_compile_time_secs
-    prev_min_size = jax.config.jax_persistent_cache_min_entry_size_bytes
-    cache = tmp_path / "xla"
     df = pd.DataFrame(
-        {
-            "unique_id": range(100),
-            "name": ["ann", "bob"] * 50,
-            "dob": [f"d{k % 7}" for k in range(100)],
-        }
+        {"unique_id": range(40), "name": ["ann", "bob"] * 20,
+         "dob": [f"d{k % 5}" for k in range(40)]}
+    )
+    s = {
+        "link_type": "dedupe_only",
+        "comparison_columns": [{"col_name": "name", "num_levels": 2}],
+        "blocking_rules": ["l.dob = r.dob"],
+        "max_iterations": 1,
+    }
+    for build in (
+        lambda: Splink(dict(s), df=df),
+        lambda: QueryEngine(Splink(dict(s), df=df).export_index()),
+    ):
+        monkeypatch.setattr(cc, "_applied", None)
+        jax.config.update("jax_compilation_cache_dir", None)
+        build()
+        assert jax.config.jax_compilation_cache_dir == expect
+
+
+def test_cache_dir_setting_applies_when_variable_unset(
+    fresh_cache_state, monkeypatch, tmp_path
+):
+    """settings["compilation_cache_dir"] places the cache when the
+    variable is unset, and entries actually land there."""
+    import os
+
+    import jax
+    import pandas as pd
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    from splink_tpu import Splink
+    from splink_tpu.utils.envfp import cpu_target_fingerprint
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cache = tmp_path / "xla"
+    expect = os.path.join(str(cache), f"cpu-{cpu_target_fingerprint()[:16]}")
+    df = pd.DataFrame(
+        {"unique_id": range(100), "name": ["ann", "bob"] * 50,
+         "dob": [f"d{k % 7}" for k in range(100)]}
     )
     s = {
         "link_type": "dedupe_only",
@@ -410,44 +434,17 @@ def test_compilation_cache_dir_applies(tmp_path, monkeypatch):
         "max_iterations": 1,
         "compilation_cache_dir": str(cache),
     }
-    try:
-        linker_mod._compilation_cache_applied = None
-        Splink(s, df=df)
-        assert jax.config.jax_compilation_cache_dir == expect
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # drop in-process executable caches: earlier tests may have
-        # compiled these same shapes, and only a real compile persists.
-        # jax also binds its persistent-cache object to the FIRST dir it
-        # initialised with (an earlier linker in this process), so reset
-        # it to pick up this test's dir
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-        jax.clear_caches()
-        Splink(s, df=df).get_scored_comparisons()
-        entries = [
-            f for _root, _dirs, files in os.walk(cache) for f in files
-        ]
-        assert entries, "no compiled executables persisted"
-        # empty value disables for a fresh process but must NOT clear the
-        # already-applied process-wide dir (first linker wins)
-        Splink({**s, "compilation_cache_dir": ""}, df=df)
-        assert jax.config.jax_compilation_cache_dir == expect
-        # a later linker with a DIFFERENT dir must also be ignored
-        Splink({**s, "compilation_cache_dir": str(tmp_path / "b")}, df=df)
-        assert jax.config.jax_compilation_cache_dir == expect
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_min_time
-        )
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", prev_min_size
-        )
-        linker_mod._compilation_cache_applied = prev_applied
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
+    linker = Splink(s, df=df)
+    assert jax.config.jax_compilation_cache_dir == expect
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # drop in-process executable caches (earlier tests may have compiled
+    # these shapes; only a real compile persists) and jax's cache object,
+    # bound to the first directory this process initialised with
+    jcc.reset_cache()
+    jax.clear_caches()
+    linker.get_scored_comparisons()
+    entries = [f for _root, _dirs, files in os.walk(cache) for f in files]
+    assert entries, "no compiled executables persisted"
 
 
 # ----------------------------------------------------------------------

@@ -138,6 +138,37 @@ def test_save_after_restore_writes_a_valid_sidecar(saved, tmp_path):
     _assert_bit_identical(answers, third.query_arrays(df))
 
 
+def test_save_after_cache_warm_warmup_writes_a_valid_sidecar(
+    saved, tmp_path, monkeypatch
+):
+    """The same hazard through the PERSISTENT COMPILE CACHE: a menu the
+    cache served was itself deserialized, so save_aot must re-compile it
+    for real — from a fresh jit wrapper (jax memoises trace -> lowering ->
+    executable per function object) with the cache genuinely bypassed (jax
+    memoises "is the cache in use" per process). The second run of any
+    process pair hits this: warm cache, warmup, save."""
+    import jax
+
+    df, index_dir, _aot_dir, answers = saved
+    prev = jax.config.jax_persistent_cache_min_compile_time_secs
+    # the user's own tuning, which enable_compilation_cache leaves alone
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        _fresh_engine(index_dir, None).warmup()  # fills the cache
+        cached = _fresh_engine(index_dir, None)
+        warm = cached.warmup()
+        assert warm["cache_hits"] == warm["combinations"] == 2, warm
+        resaved = str(tmp_path / "aot_from_cache")
+        cached.save_aot(resaved)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", prev)
+    third = _fresh_engine(index_dir, resaved)
+    warm3 = third.warmup()
+    assert warm3["aot_restored"] == warm3["combinations"] == 2, warm3
+    _assert_bit_identical(answers, third.query_arrays(df))
+
+
 def test_missing_sidecar_is_a_plain_cold_start(saved, tmp_path):
     """No sidecar at the path: NOT a degradation (no warning) — the
     engine compiles the menu exactly as an unconfigured one would."""

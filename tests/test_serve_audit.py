@@ -94,10 +94,14 @@ def test_bad_serve_gather_trips_ta_dtype():
 
 
 def test_bad_serve_shard_twin_trips_the_gate():
-    """The serving shard gate is falsifiable: a lax.top_k-based twin (the
-    unpartitionable op the production kernel deliberately avoids) brings
-    back the all-gather and the replicated outputs — SA-COLL and SA-SPEC
-    both fire."""
+    """The serving shard gate is falsifiable: a twin that takes
+    lax.top_k (the unpartitionable op the production kernel deliberately
+    avoids) and then reorders the query rows by their best score — a
+    computed-index gather of the sharded operand, what the production
+    kernel's static repeat avoids — brings back the all-gather and hands
+    the (query, k) outputs back replicated: SA-COLL and SA-SPEC both fire.
+    (lax.top_k alone trips only SA-COLL under the installed XLA, which
+    reshards its outputs after the gathered sort.)"""
     registry: dict = {}
 
     @register_shard_kernel(
@@ -105,6 +109,7 @@ def test_bad_serve_shard_twin_trips_the_gate():
     )
     def _build():
         import jax
+        import jax.numpy as jnp
 
         from splink_tpu.analysis.shard_audit import audit_mesh
         from splink_tpu.parallel.mesh import pair_sharding
@@ -115,7 +120,9 @@ def test_bad_serve_shard_twin_trips_the_gate():
         )
 
         def bad(scores):
-            return jax.lax.top_k(scores, 4)
+            top_p, top_i = jax.lax.top_k(scores, 4)
+            order = jnp.argsort(-top_p[:, 0])
+            return top_p[order], top_i[order]
 
         return bad, (scores,), {}
 

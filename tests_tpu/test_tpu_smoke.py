@@ -451,3 +451,66 @@ class TestMaskedQgramOnHardware:
         )
         _, counts_h, _ = lk_host._ensure_pattern_ids()
         np.testing.assert_array_equal(np.asarray(counts_v), np.asarray(counts_h))
+
+
+class TestServeOfflineParityOnHardware:
+    """Serve <-> offline score parity is BIT-identity on the TPU too
+    (docs/serving.md): both logits accumulate the comparison columns left
+    to right (fellegi_sunter.log_bayes_factor / fold_logit). A jnp.sum
+    over the column axis associates differently here than XLA CPU's
+    in-order walk and left the two a few float32 ulps apart at six
+    columns — a case the CPU tier cannot see."""
+
+    def test_match_logit_is_the_fold_on_device(self, rng):
+        import jax
+
+        from splink_tpu.models.fellegi_sunter import (
+            FSParams,
+            fold_logit,
+            match_logit,
+        )
+
+        for n_cols in (6, 8):
+            params = FSParams(
+                lam=jnp.float32(0.11),
+                m=jnp.asarray(rng.dirichlet(np.ones(3), n_cols), jnp.float32),
+                u=jnp.asarray(rng.dirichlet(np.ones(3), n_cols), jnp.float32),
+            )
+            G = jnp.asarray(rng.integers(-1, 3, (1 << 16, n_cols)), jnp.int8)
+            fold = np.asarray(jax.jit(fold_logit)(G, params))
+            offline = np.asarray(jax.jit(match_logit)(G, params))
+            np.testing.assert_array_equal(fold, offline)
+
+    def test_six_column_serve_scores_are_the_offline_floats(self):
+        from benchmarks.datagen import make_people
+        from benchmarks.run import config_4_settings
+        from splink_tpu import Splink
+        from splink_tpu.serve import BucketPolicy, QueryEngine
+
+        df = make_people(3000, seed=4)
+        linker = Splink({**config_4_settings(), "max_iterations": 5}, df=df)
+        df_e = linker.get_scored_comparisons()
+        offline = dict(
+            zip(
+                zip(df_e.unique_id_l.to_numpy(), df_e.unique_id_r.to_numpy()),
+                df_e.match_probability.to_numpy(),
+            )
+        )
+        index = linker.export_index()
+        engine = QueryEngine(
+            index, top_k=64, policy=BucketPolicy((128,), (64, 256))
+        )
+        queries = df.iloc[:512]
+        top_p, top_rows, top_valid, _ = engine.query_arrays(queries)
+        uid_q = queries.unique_id.to_numpy()
+        checked = differ = 0
+        for q in range(len(queries)):
+            for r in np.flatnonzero(top_valid[q]):
+                m = int(index.unique_id[top_rows[q, r]])
+                a = int(uid_q[q])
+                if m == a:
+                    continue
+                checked += 1
+                differ += np.float32(offline[(min(a, m), max(a, m))]) != top_p[q, r]
+        assert checked > 300
+        assert differ == 0, f"{differ} of {checked} served scores off"
