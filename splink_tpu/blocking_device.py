@@ -129,7 +129,7 @@ def make_segment_sort_fn():
     from jax import lax
 
     @jax.jit
-    def fn(codes, side, rank, row):
+    def block_segment_sort(codes, side, rank, row):
         m = codes.shape[0]
         imax = jnp.int32(_IMAX)
         key = jnp.where(codes < 0, imax, codes)
@@ -158,7 +158,7 @@ def make_segment_sort_fn():
         )
         return row_s, seg_start, l_cnt, r_cnt, n_seg, n_valid
 
-    return fn
+    return block_segment_sort
 
 
 @functools.lru_cache(maxsize=1)
@@ -173,7 +173,7 @@ def make_bucket_csr_fn():
     from jax import lax
 
     @jax.jit
-    def fn(codes):
+    def block_bucket_csr(codes):
         m = codes.shape[0]
         imax = jnp.int32(_IMAX)
         key = jnp.where(codes < 0, imax, codes)
@@ -195,7 +195,7 @@ def make_bucket_csr_fn():
         )
         return row_s, starts, sizes, row_bucket, n_seg, n_valid
 
-    return fn
+    return block_bucket_csr
 
 
 def make_pair_emit_fn(batch_size: int, n_prev: int, has_uid_mask: bool,
@@ -251,8 +251,8 @@ def make_pair_emit_fn(batch_size: int, n_prev: int, has_uid_mask: bool,
     )
 
     @functools.partial(jax.jit, **jit_kwargs)
-    def fn(pos, order, ua, la, ub, lb, ranks, prev_l, prev_r, uid_codes,
-           res_ops, meta):
+    def block_pair_emit(pos, order, ua, la, ub, lb, ranks, prev_l, prev_r,
+                        uid_codes, res_ops, meta):
         i, j, valid = unit_decode(
             pos, order, ua, la, ub, lb, meta, mesh_ladder=mesh is not None
         )
@@ -285,7 +285,7 @@ def make_pair_emit_fn(batch_size: int, n_prev: int, has_uid_mask: bool,
         out_i = jnp.concatenate([out_i, kcum[-1:]])
         return out_i, out_j, keep
 
-    return fn
+    return block_pair_emit
 
 
 # --------------------------------------------------------------------------
@@ -844,7 +844,7 @@ def make_chunk_digest_fn(mesh=None):
         jit_kwargs = {"out_shardings": replicated(mesh)}
 
     @functools.partial(jax.jit, **jit_kwargs)
-    def fn(i, j, keep):
+    def block_chunk_digest(i, j, keep):
         mixed = (i.astype(jnp.uint32) * jnp.uint32(DIGEST_MUL)) ^ (
             j.astype(jnp.uint32) + jnp.uint32(DIGEST_ADD)
         )
@@ -853,7 +853,7 @@ def make_chunk_digest_fn(mesh=None):
             jnp.where(keep, mixed, jnp.uint32(0)), dtype=jnp.uint32
         )
 
-    return fn
+    return block_chunk_digest
 
 
 @functools.lru_cache(maxsize=1)
@@ -872,7 +872,7 @@ def make_chunk_digest_compact_fn():
     from .spill import DIGEST_ADD, DIGEST_MUL
 
     @jax.jit
-    def fn(i_ext, j, pos):
+    def block_chunk_digest_compact(i_ext, j, pos):
         # static python index, NOT i_ext[-1]: a traced negative index
         # lowers through an int64 dynamic_slice under x64 (TA-DTYPE — the
         # same hazard the segment-sort kernel documents)
@@ -887,7 +887,7 @@ def make_chunk_digest_compact_fn():
             jnp.where(keep, mixed, jnp.uint32(0)), dtype=jnp.uint32
         )
 
-    return fn
+    return block_chunk_digest_compact
 
 
 def _shard_unit_ranges(pc: np.ndarray, n_shards: int) -> list[tuple[int, int]]:

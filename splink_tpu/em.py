@@ -148,9 +148,10 @@ def run_em(
         return (state.it < max_iterations) & (~state.converged)
 
     def body(state: _LoopState):
-        p = match_probability(G, state.params)
-        stats = sufficient_stats(G, p, max_levels, weights)
-        new = update_params(stats)
+        with jax.named_scope("em_step"):
+            p = match_probability(G, state.params)
+            stats = sufficient_stats(G, p, max_levels, weights)
+            new = update_params(stats)
         delta = jnp.maximum(
             jnp.max(jnp.abs(new.m - state.params.m)),
             jnp.max(jnp.abs(new.u - state.params.u)),
@@ -551,7 +552,8 @@ def trimmed_trajectory(result: EMResult) -> dict:
 @jax.jit
 def score_pairs(G, params: FSParams):
     """Final E-step scoring: match probability for every pair."""
-    return match_probability(G, params)
+    with jax.named_scope("score"):
+        return match_probability(G, params)
 
 
 @jax.jit
@@ -561,10 +563,11 @@ def score_pairs_with_intermediates(G, params: FSParams):
     (/root/reference/splink/expectation_step.py:196-221)."""
     from .models.fellegi_sunter import gamma_prob_lookup
 
-    p = match_probability(G, params)
-    prob_m = gamma_prob_lookup(G, params.m)
-    prob_u = gamma_prob_lookup(G, params.u)
-    return p, prob_m, prob_u
+    with jax.named_scope("score"):
+        p = match_probability(G, params)
+        prob_m = gamma_prob_lookup(G, params.m)
+        prob_u = gamma_prob_lookup(G, params.u)
+        return p, prob_m, prob_u
 
 
 @jax.jit
@@ -577,7 +580,8 @@ def score_pairs_with_logits(G, params: FSParams):
     (fellegi_sunter.fold_logit docstring)."""
     from .models.fellegi_sunter import fold_logit
 
-    return match_probability(G, params), fold_logit(G, params)
+    with jax.named_scope("score"):
+        return match_probability(G, params), fold_logit(G, params)
 
 
 @jax.jit
@@ -586,7 +590,8 @@ def score_pairs_with_intermediates_logits(G, params: FSParams):
     that also retain intermediate columns)."""
     from .models.fellegi_sunter import fold_logit, gamma_prob_lookup
 
-    p = match_probability(G, params)
-    prob_m = gamma_prob_lookup(G, params.m)
-    prob_u = gamma_prob_lookup(G, params.u)
-    return p, prob_m, prob_u, fold_logit(G, params)
+    with jax.named_scope("score"):
+        p = match_probability(G, params)
+        prob_m = gamma_prob_lookup(G, params.m)
+        prob_u = gamma_prob_lookup(G, params.u)
+        return p, prob_m, prob_u, fold_logit(G, params)

@@ -40,6 +40,7 @@ from .ops.gamma import (
 )
 from .settings import comparison_column_name
 from .utils.logging_utils import log_jaxpr
+from .utils.profiling import count, fetch, span
 
 logger = logging.getLogger("splink_tpu")
 
@@ -187,7 +188,8 @@ def int32_histogram(ids, length: int):
     on in-range input. The single histogram used by every pattern kernel
     (gamma pattern batch, host-G batch, pairgen's virtual twin) so the
     dtype discipline cannot drift between them."""
-    return jnp.zeros(length, jnp.int32).at[ids].add(1, mode="drop")
+    with jax.named_scope("pattern_hist"):
+        return jnp.zeros(length, jnp.int32).at[ids].add(1, mode="drop")
 
 
 def pattern_ids_fit_uint16(n_patterns: int) -> bool:
@@ -770,15 +772,17 @@ class GammaProgram:
 
         # Pack the compared columns into one uint32 matrix and push it to
         # device once: each pair batch then costs exactly two row gathers.
-        packed, layout = pack_table(
-            table,
-            float_dtype,
-            include=comparison_columns_used(settings),
-            qgram_specs=qgram_specs_for(settings),
-            charset_specs=charset_specs_for(settings),
-            jw_specs=jw_specs_for(settings) if self.two_phase_div else (),
-        )
-        self._packed = jnp.asarray(packed)
+        with span("pack_table", rows=int(table.n_rows)):
+            packed, layout = pack_table(
+                table,
+                float_dtype,
+                include=comparison_columns_used(settings),
+                qgram_specs=qgram_specs_for(settings),
+                charset_specs=charset_specs_for(settings),
+                jw_specs=jw_specs_for(settings) if self.two_phase_div else (),
+            )
+        with span("h2d_put", bytes=packed.nbytes):
+            self._packed = jnp.asarray(packed)
         self._layout = layout
 
         cols = settings["comparison_columns"]
@@ -790,10 +794,16 @@ class GammaProgram:
         # them bit-identical on the gamma output.
         def _make_gamma_body(two_phase_div):
             def _gamma_body(packed, idx_l, idx_r):
-                rows_l = packed[idx_l]
-                rows_r = packed[idx_r]
+                # named scopes: how the device trace's ops say which part
+                # of the program they belong to (docs/observability.md)
+                with jax.named_scope("row_gather"):
+                    rows_l = packed[idx_l]
+                    rows_r = packed[idx_r]
                 ctx = PairContext(layout, rows_l, rows_r, two_phase_div)
-                gammas = [_spec_gamma(c, ctx) for c in cols]
+                gammas = []
+                for c in cols:
+                    with jax.named_scope(f"cmp/{comparison_column_name(c)}"):
+                        gammas.append(_spec_gamma(c, ctx))
                 return jnp.stack(gammas, axis=1), ctx.overflow_count()
 
             return _gamma_body
@@ -840,6 +850,9 @@ class GammaProgram:
         # Host-batched G paths read back one array per batch; the overflow
         # flag rides as one extra G row (int8 flag at [-1, 0]) so detecting
         # it costs no second device fetch.
+        # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches
+        # the XLA module ``jit_fn(`` (see pairgen.make_virtual_pattern_fn,
+        # the only other program of that name).
         def _flagged(body):
             def fn(packed, idx_l, idx_r):
                 G, ovf = body(packed, idx_l, idx_r)
@@ -1093,7 +1106,7 @@ class GammaProgram:
             run_batch, zero_acc = self._mesh_pattern_context(mesh)
         else:
             run_batch = lambda bl, br, valid, acc: self._pattern_batch(  # noqa: E731
-                jnp.asarray(bl), jnp.asarray(br), valid, acc
+                *_put_pair_batch(bl, br), valid, acc
             )
             zero_acc = lambda: jnp.zeros(self.n_patterns + 1, jnp.int32)  # noqa: E731
         flush_every = max(min(_HIST_FLUSH_BATCHES, (1 << 30) // batch_size), 1)
@@ -1109,12 +1122,12 @@ class GammaProgram:
             the flagged batch skipped the histogram, so the late redo's
             acc addition commutes into an identical total."""
             ps, pe, prev, pbl, pbr = pending
-            arr = np.asarray(prev)
+            arr = fetch(prev)
             if has_flag and arr[-1]:
                 pid2, acc = self._pattern_batch_exact(
                     jnp.asarray(pbl), jnp.asarray(pbr), pe - ps, acc
                 )
-                arr = np.asarray(pid2)
+                arr = fetch(pid2)
             pids[ps:pe] = arr[: pe - ps].astype(id_dtype)
             return acc
 
@@ -1127,6 +1140,7 @@ class GammaProgram:
                 bl = np.concatenate([bl, np.zeros(pad, bl.dtype)])
                 br = np.concatenate([br, np.zeros(pad, br.dtype)])
             pid, acc = run_batch(bl, br, stop - start, acc)
+            count(batches=1)
             if pending is not None:
                 acc = read_pending(pending, acc)
             pending = (start, stop, pid, bl, br)
@@ -1134,13 +1148,13 @@ class GammaProgram:
             if in_acc >= flush_every:
                 acc = read_pending(pending, acc)
                 pending = None
-                total += np.asarray(acc[:-1], np.int64)
+                total += fetch(acc)[:-1]
                 acc = zero_acc()
                 in_acc = 0
         if pending is not None:
             acc = read_pending(pending, acc)
         if in_acc:
-            total += np.asarray(acc[:-1], np.int64)
+            total += fetch(acc)[:-1]
         return pids, total
 
     def patterns_matrix(self) -> np.ndarray:
@@ -1219,12 +1233,12 @@ class GammaProgram:
 
         def read_pending(pending):
             valid, pG, pbl, pbr = pending
-            arr = np.asarray(pG)
+            arr = fetch(pG)
             if arr[-1, 0]:
                 pG = self._gamma_batch_flagged_exact(
                     jnp.asarray(pbl), jnp.asarray(pbr)
                 )
-                arr = np.asarray(pG)
+                arr = fetch(pG)
             return arr[:valid], pG, valid
 
         for start in range(0, n, batch_size):
@@ -1235,7 +1249,8 @@ class GammaProgram:
                 pad = batch_size - (stop - start)
                 bl = np.concatenate([bl, np.zeros(pad, bl.dtype)])
                 br = np.concatenate([br, np.zeros(pad, br.dtype)])
-            G = self._gamma_batch_flagged(jnp.asarray(bl), jnp.asarray(br))
+            G = self._gamma_batch_flagged(*_put_pair_batch(bl, br))
+            count(batches=1)
             if pending is not None:
                 yield read_pending(pending)
             pending = (stop - start, G, bl, br)
@@ -1263,6 +1278,12 @@ class GammaProgram:
             idx_l, idx_r, batch_size
         ):
             yield arr
+
+
+def _put_pair_batch(bl, br):
+    """One batch's pair indices on the device, under an ``h2d_put`` span."""
+    with span("h2d_put", bytes=bl.nbytes + br.nbytes):
+        return jnp.asarray(bl), jnp.asarray(br)
 
 
 class _StreamBatcher:
@@ -1351,19 +1372,19 @@ class GammaStream(_StreamBatcher):
 
     def _read_pending(self):
         v, prev, pbl, pbr = self._pending
-        arr = np.asarray(prev)
+        arr = fetch(prev)
         if arr[-1, 0]:  # two-phase overflow: redo through the exact twin
             prev = self.program._gamma_batch_flagged_exact(
                 jnp.asarray(pbl), jnp.asarray(pbr)
             )
-            arr = np.asarray(prev)
+            arr = fetch(prev)
         self._out_parts.append(arr[:v])
         if self._device_batches is not None:
             self._device_batches.append(prev[:v])
         self._pending = None
 
     def _emit(self, bl, br, valid):
-        G = self.program._gamma_batch_flagged(jnp.asarray(bl), jnp.asarray(br))
+        G = self.program._gamma_batch_flagged(*_put_pair_batch(bl, br))
         if self._device_batches is not None and self.total > self.keep_limit:
             self._device_batches = None  # too big: free HBM
         # double buffer: read back the PREVIOUS batch (it has finished by
@@ -1438,7 +1459,7 @@ class PatternStream(_StreamBatcher):
 
     def _read_pending(self):
         v, prev, pbl, pbr = self._pending
-        arr = np.asarray(prev)
+        arr = fetch(prev)
         if self.mesh is None and arr[-1]:
             # two-phase overflow: the flagged batch skipped the histogram;
             # redo through the exact twin (any acc generation works — the
@@ -1446,7 +1467,7 @@ class PatternStream(_StreamBatcher):
             pid2, self._acc = self.program._pattern_batch_exact(
                 jnp.asarray(pbl), jnp.asarray(pbr), v, self._acc
             )
-            arr = np.asarray(pid2)
+            arr = fetch(pid2)
             self._acc_dirty = True  # a redo may land after the last flush
         self._parts.append(arr[:v].astype(self.id_dtype))
         self._pending = None
@@ -1456,14 +1477,14 @@ class PatternStream(_StreamBatcher):
             pid, self._acc = self._run_batch(bl, br, valid, self._acc)
         else:
             pid, self._acc = self.program._pattern_batch(
-                jnp.asarray(bl), jnp.asarray(br), valid, self._acc
+                *_put_pair_batch(bl, br), valid, self._acc
             )
         if self._pending is not None:
             self._read_pending()
         self._pending = (valid, pid, bl, br)
         self._in_acc += 1
         if self._in_acc >= self._flush_every:
-            self._total_counts += np.asarray(self._acc[:-1], np.int64)
+            self._total_counts += fetch(self._acc)[:-1]
             self._acc = self._zero_acc()
             self._in_acc = 0
 
@@ -1472,7 +1493,7 @@ class PatternStream(_StreamBatcher):
         if self._pending is not None:
             self._read_pending()
         if self._in_acc or self._acc_dirty:
-            self._total_counts += np.asarray(self._acc[:-1], np.int64)
+            self._total_counts += fetch(self._acc)[:-1]
             self._in_acc = 0
             self._acc_dirty = False
         pids = np.empty(self.total, self.id_dtype)

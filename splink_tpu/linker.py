@@ -38,7 +38,7 @@ from .params import Params, load_params_from_json
 from .parallel.mesh import mesh_from_settings, shard_pairs
 from .settings import comparison_column_name, complete_settings_dict
 from .utils.compile_cache import enable_compilation_cache
-from .utils.profiling import StageTimer
+from .utils.profiling import StageTimer, count, fetch, span
 
 logger = logging.getLogger("splink_tpu")
 
@@ -65,15 +65,16 @@ def _gamma_histograms(settings, G, weights=None, chunk: int = 1 << 22) -> dict:
     multiply that path's host footprint."""
     cols = settings["comparison_columns"]
     acc = [np.zeros(int(col["num_levels"]) + 1, np.float64) for col in cols]
-    for s in range(0, len(G), chunk):
-        Gc = G[s : s + chunk]
-        w = weights[s : s + chunk] if weights is not None else None
-        for c, col in enumerate(cols):
-            levels = int(col["num_levels"])
-            g = np.asarray(Gc[:, c], np.int64) + 1  # -1 (null) -> bin 0
-            acc[c] += np.bincount(
-                np.clip(g, 0, levels), weights=w, minlength=levels + 1
-            )[: levels + 1]
+    with span("gamma_histogram", rows=len(G)):
+        for s in range(0, len(G), chunk):
+            Gc = G[s : s + chunk]
+            w = weights[s : s + chunk] if weights is not None else None
+            for c, col in enumerate(cols):
+                levels = int(col["num_levels"])
+                g = np.asarray(Gc[:, c], np.int64) + 1  # -1 (null) -> bin 0
+                acc[c] += np.bincount(
+                    np.clip(g, 0, levels), weights=w, minlength=levels + 1
+                )[: levels + 1]
     return {
         comparison_column_name(col): [int(v) for v in acc[c]]
         for c, col in enumerate(cols)
@@ -103,6 +104,24 @@ class Splink:
                 (/root/reference/splink/iterate.py:54-55).
             spark: ignored (the reference's SparkSession slot).
         """
+        # Per-run observability scope, opened first so the constructor is
+        # itself a span: the run's span table is keyed by this run's id (a
+        # later linker neither clears nor pollutes an earlier one's), and
+        # the telemetry context is live iff settings["telemetry_dir"] is
+        # set — disabled, it adds no host callbacks and compiled programs
+        # are unchanged.
+        from .obs.runtime import RunContext
+        from .utils.profiling import begin_run
+
+        self._obs = RunContext.from_settings(settings)
+        begin_run(self._obs.run_id)
+        with self._call("init") as call:
+            self._init(settings, df, df_l, df_r, save_state_fn)
+            call.count(rows=sum(
+                len(x) for x in (df, df_l, df_r) if x is not None
+            ))
+
+    def _init(self, settings, df, df_l, df_r, save_state_fn):
         self.settings = complete_settings_dict(settings)
         backend = self.settings["backend"]
         if backend != "jax":  # schema enum also rejects; double-checked here
@@ -119,16 +138,6 @@ class Splink:
         self._n_left_released: int | None = None
         self.save_state_fn = save_state_fn
         self._check_args()
-        # Per-run observability scope: stage timings and the profiler-trace
-        # target are keyed by this run's id (a later linker no longer
-        # clears or pollutes an earlier one's), and the telemetry context
-        # is live iff settings["telemetry_dir"] is set — disabled, it adds
-        # no host callbacks and compiled programs are unchanged.
-        from .obs.runtime import RunContext
-        from .utils.profiling import begin_run
-
-        self._obs = RunContext.from_settings(self.settings)
-        begin_run(self._obs.run_id, self.settings.get("profile_dir") or None)
         enable_compilation_cache(self.settings["compilation_cache_dir"])
 
         self._table: EncodedTable | None = None
@@ -167,11 +176,18 @@ class Splink:
         return self._obs.run_id
 
     def _stage(self, name: str) -> StageTimer:
-        """A StageTimer bound to this linker's run scope: records wall
-        time under this run id, resolves this run's profile_dir, and (when
-        telemetry is enabled) emits the stage span with its
-        compile-vs-execute split and a device-memory snapshot."""
+        """A StageTimer bound to this linker's run scope: records the stage
+        span under this run id and (when telemetry is enabled) emits it
+        with its compile-vs-execute split and a device-memory snapshot."""
         return StageTimer(name, run=self._obs.run_id, telemetry=self._obs)
+
+    def _call(self, name: str) -> StageTimer:
+        """The root span of one public call (``kind="call"``, so
+        ``stage_timings()`` keeps the stage names): its self time is what
+        the facade spends outside every stage and sub-span."""
+        return StageTimer(
+            name, run=self._obs.run_id, telemetry=self._obs, kind="call"
+        )
 
     def _check_args(self):
         link_type = self.settings["link_type"]
@@ -307,12 +323,12 @@ class Splink:
 
     def _ensure_encoded(self) -> EncodedTable:
         if self._table is None:
-            with self._stage("encode"):
+            with self._stage("encode") as st:
                 if self.settings["link_type"] == "dedupe_only":
                     self._table = encode_table(self.df, self.settings)
                 else:
                     self._table = concat_tables(self.df_l, self.df_r, self.settings)
-            self._obs.count("rows_encoded", int(self._table.n_rows))
+                st.count(rows=int(self._table.n_rows))
         return self._table
 
     def _ensure_pairs(self) -> PairIndex:
@@ -346,18 +362,19 @@ class Splink:
                 from .blocking_device import spill_block_rules
                 from .parallel.distributed import spill_shard_dir
 
-                with self._stage("blocking"):
+                with self._stage("blocking") as st:
                     pairs = spill_block_rules(
                         self.settings, table, self._n_left,
                         spill_shard_dir(build_dir),
                     )
+                    if pairs is not None:
+                        st.count(pairs=int(pairs.n_pairs))
                 if pairs is not None:
                     self._pairs = pairs
                     logger.info(
                         "blocking produced %d candidate pairs (spill store)",
                         pairs.n_pairs,
                     )
-                    self._obs.count("pairs_blocked", int(pairs.n_pairs))
                     from .blocking import clear_key_code_cache
 
                     clear_key_code_cache(table)
@@ -369,15 +386,15 @@ class Splink:
                     "rule shapes unsupported by the device emission plan",
                 )
             stream = self._overlap_stream(table)
-            with self._stage("blocking"):
+            with self._stage("blocking") as st:
                 self._pairs = block_using_rules(
                     self.settings,
                     table,
                     self._n_left,
                     pair_consumer=stream.feed if stream is not None else None,
                 )
+                st.count(pairs=int(self._pairs.n_pairs))
             logger.info("blocking produced %d candidate pairs", self._pairs.n_pairs)
-            self._obs.count("pairs_blocked", int(self._pairs.n_pairs))
             if self._obs.enabled:
                 # block-size skew telemetry rides the still-warm key-code
                 # cache; freed with it just below
@@ -439,12 +456,16 @@ class Splink:
     def _finish_overlap(self, stream) -> None:
         from .gammas import PatternStream
 
-        if isinstance(stream, PatternStream):
-            with self._stage("gammas_patterns"):
+        # the stream's batches were dispatched while blocking ran; this
+        # stage is the tail flush, and carries the whole pass's counts
+        stage = "gammas_patterns" if isinstance(stream, PatternStream) else "gammas"
+        with self._stage(stage) as st:
+            if isinstance(stream, PatternStream):
                 self._P, self._pattern_counts = stream.finish()
-        else:
-            with self._stage("gammas"):
+            else:
                 self._G, self._G_dev = stream.finish()
+            st.count(pairs=stream.total,
+                     batches=-(-stream.total // stream.batch_size))
 
     def _maybe_spill_pairs(self) -> None:
         """Note the blocking-created spill dir (streamed regime): blocking's
@@ -483,9 +504,10 @@ class Splink:
                 # enough for the resident regime: decode the gamma matrix
                 # from the pattern LUT (bit-identical to recomputation —
                 # the pattern id IS the gamma vector in mixed radix)
-                with self._stage("gammas"):
+                with self._stage("gammas") as st:
                     PM = self._pattern_program.patterns_matrix()
                     self._G = PM[self._P]  # fancy-index accepts uint16/int32
+                    st.count(pairs=len(self._G))
                 return self._G
             # In the resident regime (and without a mesh, which shards its
             # own upload), keep the device-side gamma batches so EM doesn't
@@ -494,7 +516,7 @@ class Splink:
                 pairs.n_pairs <= int(self.settings["max_resident_pairs"])
                 and mesh_from_settings(self.settings) is None
             )
-            with self._stage("gammas"):
+            with self._stage("gammas") as st:
                 program = GammaProgram(
                     self.settings, table, float_dtype=self._float_dtype
                 )
@@ -504,6 +526,7 @@ class Splink:
                     batch_size=self.settings["pair_batch_size"],
                     keep_device=keep,
                 )
+                st.count(pairs=len(self._G))
         return self._G
 
     def _pattern_capable(self) -> bool:
@@ -546,9 +569,10 @@ class Splink:
         if self._pair_bound is None:
             from .blocking import estimate_pair_upper_bound
 
-            self._pair_bound = estimate_pair_upper_bound(
-                self.settings, table, self._n_left
-            )
+            with span("pair_bound", rows=int(table.n_rows)):
+                self._pair_bound = estimate_pair_upper_bound(
+                    self.settings, table, self._n_left
+                )
         return self._pair_bound
 
     def _virtual_plan(self):
@@ -717,7 +741,7 @@ class Splink:
                     return None, self._pattern_counts, self._pattern_program
                 from .pairgen import compute_virtual_pattern_ids
 
-                with self._stage("gammas_patterns"):
+                with self._stage("gammas_patterns") as st:
                     self._ensure_pattern_program()
                     want_ids = self._virtual_ids_policy()
                     pids, self._pattern_counts, n_real = (
@@ -731,6 +755,7 @@ class Splink:
                     )
                     if want_ids:
                         self._P_virtual = pids
+                    st.count(pairs=n_real)
                 logger.info(
                     "device pair generation scored %d pairs (%d candidate "
                     "positions)", n_real, self._virtual.n_candidates,
@@ -740,7 +765,7 @@ class Splink:
             if self._P is not None:
                 # the overlap PatternStream already computed them
                 return self._P, self._pattern_counts, self._pattern_program
-            with self._stage("gammas_patterns"):
+            with self._stage("gammas_patterns") as st:
                 self._pattern_program = GammaProgram(
                     self.settings, table, float_dtype=self._float_dtype
                 )
@@ -752,6 +777,7 @@ class Splink:
                         mesh=self._pattern_mesh(),
                     )
                 )
+                st.count(pairs=len(self._P))
         return self._P, self._pattern_counts, self._pattern_program
 
     def _tf_fold_ctx(self):
@@ -804,11 +830,12 @@ class Splink:
         out = np.empty(n, dtype)
         for s in range(0, n, batch):
             e = min(s + batch, n)
-            args = [jnp.asarray(tid[il[s:e]]) for tid in tids]
-            args += [jnp.asarray(tid[ir[s:e]]) for tid in tids]
-            out[s:e] = np.asarray(
-                fold(jnp.asarray(z[s:e]), u_dev, *args, *logs_dev)
-            )
+            host = [z[s:e]]
+            host += [tid[il[s:e]] for tid in tids]
+            host += [tid[ir[s:e]] for tid in tids]
+            with span("h2d_put", bytes=sum(a.nbytes for a in host)):
+                args = [jnp.asarray(a) for a in host]
+            out[s:e] = fetch(fold(*args[:1], u_dev, *args[1:], *logs_dev))
         return out
 
     def _pattern_score_luts(self):
@@ -837,17 +864,27 @@ class Splink:
         (stored virtual ids / virtual recompute / materialised pairs) is
         _iter_pattern_triples — the single definition of the pair stream."""
         PM, p_lut, pm_lut, pu_lut, z_lut = self._pattern_score_luts()
-        with self._stage("score_patterns"):
+        with self._stage("score_patterns") as st:
             for il, ir, Pk in self._iter_pattern_triples():
+                st.count(pairs=len(Pk), batches=1)
                 yield self._assemble_df_e(
-                    PM[Pk],
-                    il,
-                    ir,
-                    p_lut[Pk],
-                    pm_lut[Pk] if pm_lut is not None else None,
-                    pu_lut[Pk] if pu_lut is not None else None,
-                    z=z_lut[Pk] if z_lut is not None else None,
+                    *self._lut_gather(PM, il, ir, Pk, p_lut, pm_lut, pu_lut, z_lut)
                 )
+
+    @staticmethod
+    def _lut_gather(PM, il, ir, Pk, p_lut, pm_lut, pu_lut, z_lut):
+        """One chunk's per-pair arrays from the per-pattern tables, in
+        ``_assemble_df_e``'s argument order."""
+        with span("lut_gather", rows=len(Pk)):
+            return (
+                PM[Pk],
+                il,
+                ir,
+                p_lut[Pk],
+                pm_lut[Pk] if pm_lut is not None else None,
+                pu_lut[Pk] if pu_lut is not None else None,
+                z_lut[Pk] if z_lut is not None else None,
+            )
 
     def _iter_pattern_triples(self):
         """Yield (idx_l, idx_r, pattern_ids) per chunk across the pattern
@@ -867,14 +904,15 @@ class Splink:
             sentinel = program.n_patterns
 
             def decode(Pc, r, p0):
-                keep = Pc != sentinel
-                if not keep.any():
-                    return None
-                qs = p0 + np.flatnonzero(keep).astype(np.int64)
-                il, ir, _ = decode_positions(
-                    plan, r, qs, compute_masked=False
-                )
-                return il, ir, Pc[keep]
+                with span("decode_pairs", rows=len(Pc)):
+                    keep = Pc != sentinel
+                    if not keep.any():
+                        return None
+                    qs = p0 + np.flatnonzero(keep).astype(np.int64)
+                    il, ir, _ = decode_positions(
+                        plan, r, qs, compute_masked=False
+                    )
+                    return il, ir, Pc[keep]
 
             P = self._P_virtual  # local: immune to concurrent release
             if P is not None:
@@ -991,13 +1029,7 @@ class Splink:
             with self._stage("score_tf_patterns"):
                 for il, ir, Pk in self._iter_pattern_triples():
                     df = self._assemble_df_e(
-                        PM[Pk],
-                        il,
-                        ir,
-                        p_lut[Pk],
-                        pm_lut[Pk] if pm_lut is not None else None,
-                        pu_lut[Pk] if pu_lut is not None else None,
-                        z=z_lut[Pk] if z_lut is not None else None,
+                        *self._lut_gather(PM, il, ir, Pk, p_lut, pm_lut, pu_lut, z_lut)
                     )
                     adj_arrays = []
                     for name, (tid, _nt) in cols.items():
@@ -1035,7 +1067,6 @@ class Splink:
             int(counts.sum()),
             int(seen.sum()),
         )
-        self._obs.count("pairs_gamma_scored", int(counts.sum()))
         self._obs.gauge("gamma_patterns_distinct", int(seen.sum()))
         self._last_em_result = None  # same staleness guard as _run_em
         # always cheap here (the pattern matrix is small by construction);
@@ -1056,7 +1087,10 @@ class Splink:
         chunks = list(chunks)
         if not chunks:
             return self._empty_df_e()
-        return pd.concat(chunks, ignore_index=True)
+        with span("concat_frame", chunks=len(chunks)) as sp:
+            df_e = pd.concat(chunks, ignore_index=True)
+            sp.count(rows=len(df_e))
+        return df_e
 
     def _empty_df_e(self) -> "pd.DataFrame":
         n_cols = len(self.settings["comparison_columns"])
@@ -1074,13 +1108,14 @@ class Splink:
     def manually_apply_fellegi_sunter_weights(self):
         """Score using the m/u values in the settings, without running EM
         (/root/reference/splink/__init__.py:111-119)."""
-        if self._use_pattern_pipeline():
-            df_e = self._concat_chunks(self._stream_pattern_chunks())
-        else:
-            G = self._ensure_gammas()
-            df_e = self._build_df_e(G)
-            self._G_dev = None  # release the HBM copy once scoring is done
-        self._obs.count("pairs_scored_output", len(df_e))
+        with self._call("manually_apply_fellegi_sunter_weights") as call:
+            if self._use_pattern_pipeline():
+                df_e = self._concat_chunks(self._stream_pattern_chunks())
+            else:
+                G = self._ensure_gammas()
+                df_e = self._build_df_e(G)
+                self._G_dev = None  # release the HBM copy once scoring is done
+            call.count(pairs=len(df_e))
         self._obs.finish()
         return df_e
 
@@ -1123,39 +1158,41 @@ class Splink:
                 "checkpoint_dir= or set the checkpoint_dir settings key."
             )
         try:
-            if self._use_pattern_pipeline():
-                self._run_em_patterns(compute_ll)
-            else:
-                pairs = self._ensure_pairs()
-                store = getattr(pairs, "spill_store", None)
-                # A store written under multi-controller emission holds
-                # only THIS process's shard subset, so the manifest-fed
-                # driver (whose cross-process stats reduction forms the
-                # global aggregate) is the ONLY correct EM path for it —
-                # and the branch must not depend on the LOCAL pair count,
-                # which differs per process and would split controllers
-                # across collective/non-collective regimes (deadlock) or
-                # train each on its own subset without reduction.
-                # process_count is identical in every per-process store's
-                # meta, so this decision is globally consistent.
-                if store is not None and (
-                    self._multihost_spill_store(pairs) is not None
-                    or pairs.n_pairs
-                    > int(self.settings["max_resident_pairs"])
-                ):
-                    # spill-store-backed pairs past the resident cap: EM
-                    # consumes the manifest directly — gammas per chunk on
-                    # device, never rematerialised host-side
-                    self._run_em_streamed_spill(pairs, compute_ll)
-                else:
-                    G = self._ensure_gammas()
-                    self._run_em(G, compute_ll)
-                    self._G_dev = None
+            with self._call("estimate_parameters"):
+                self._estimate_parameters(compute_ll)
         finally:
             self._ckpt_dir_arg = None
             self._ckpt_resume = False
             self._obs.finish()
         return self.params
+
+    def _estimate_parameters(self, compute_ll: bool) -> None:
+        if self._use_pattern_pipeline():
+            self._run_em_patterns(compute_ll)
+            return
+        pairs = self._ensure_pairs()
+        store = getattr(pairs, "spill_store", None)
+        # A store written under multi-controller emission holds only THIS
+        # process's shard subset, so the manifest-fed driver (whose
+        # cross-process stats reduction forms the global aggregate) is the
+        # ONLY correct EM path for it — and the branch must not depend on
+        # the LOCAL pair count, which differs per process and would split
+        # controllers across collective/non-collective regimes (deadlock)
+        # or train each on its own subset without reduction. process_count
+        # is identical in every per-process store's meta, so this decision
+        # is globally consistent.
+        if store is not None and (
+            self._multihost_spill_store(pairs) is not None
+            or pairs.n_pairs > int(self.settings["max_resident_pairs"])
+        ):
+            # spill-store-backed pairs past the resident cap: EM consumes
+            # the manifest directly — gammas per chunk on device, never
+            # rematerialised host-side
+            self._run_em_streamed_spill(pairs, compute_ll)
+        else:
+            G = self._ensure_gammas()
+            self._run_em(G, compute_ll)
+            self._G_dev = None
 
     def get_scored_comparisons(self, compute_ll: bool = False):
         """Estimate parameters by EM and return scored comparisons
@@ -1167,23 +1204,24 @@ class Splink:
         them, EM runs on the weighted pattern matrix, and scoring is a host
         LUT gather — pair data crosses the host<->device link exactly once.
         """
-        if self._use_pattern_pipeline():
-            # scoring follows EM here, so the virtual pass may keep its
-            # per-candidate ids and make the stream LUT-only (one kernel
-            # pass instead of two)
-            self._virtual_want_ids = True
-            self._run_em_patterns(compute_ll)
-            df_e = self._concat_chunks(self._stream_pattern_chunks())
-            # the single-frame output is materialised — release the ids
-            # (same convention as _G_dev below); a later re-stream simply
-            # recomputes them chunk-wise
-            self._P_virtual = None
-        else:
-            G = self._ensure_gammas()
-            self._run_em(G, compute_ll)
-            df_e = self._build_df_e(G)
-            self._G_dev = None  # release the HBM copy once EM + scoring are done
-        self._obs.count("pairs_scored_output", len(df_e))
+        with self._call("scored_comparisons") as call:
+            if self._use_pattern_pipeline():
+                # scoring follows EM here, so the virtual pass may keep its
+                # per-candidate ids and make the stream LUT-only (one kernel
+                # pass instead of two)
+                self._virtual_want_ids = True
+                self._run_em_patterns(compute_ll)
+                df_e = self._concat_chunks(self._stream_pattern_chunks())
+                # the single-frame output is materialised — release the ids
+                # (same convention as _G_dev below); a later re-stream simply
+                # recomputes them chunk-wise
+                self._P_virtual = None
+            else:
+                G = self._ensure_gammas()
+                self._run_em(G, compute_ll)
+                df_e = self._build_df_e(G)
+                self._G_dev = None  # release the HBM copy once EM + scoring are done
+            call.count(pairs=len(df_e))
         self._obs.finish()
         return df_e
 
@@ -1197,7 +1235,6 @@ class Splink:
         from .resilience import active_plan, is_oom
         from .utils.logging_utils import warn_degraded
 
-        self._obs.count("pairs_gamma_scored", len(G))
         # a stale result from an earlier call must not attach its
         # trajectory to this run's diagnostics (the streamed/checkpointed
         # paths replay history without going through _replay_history)
@@ -1247,10 +1284,13 @@ class Splink:
             G_dev, weights = shard_pairs(mesh, G)
             weights = weights.astype(dtype)
         else:
-            G_dev = self._G_dev if self._G_dev is not None else jnp.asarray(G)
-        self._run_em_fused(G_dev, weights, compute_ll)
+            G_dev = self._G_dev
+            if G_dev is None:
+                with span("h2d_put", bytes=G.nbytes):
+                    G_dev = jnp.asarray(G)
+        self._run_em_fused(G_dev, weights, compute_ll, pairs=len(G))
 
-    def _run_em_fused(self, G_dev, weights, compute_ll: bool) -> None:
+    def _run_em_fused(self, G_dev, weights, compute_ll: bool, pairs: int) -> None:
         """Shared fused-EM driver: whole-run while_loop normally, stepped one
         update at a time when a save_state_fn checkpoint hook must run
         between iterations (the restart semantics of
@@ -1268,7 +1308,8 @@ class Splink:
 
         ckpt_dir, resume, interval = self._checkpoint_config()
         tel = self._obs if self._obs.enabled else None
-        with self._stage("em"):
+        with self._stage("em") as st:
+            updates_before = len(self.params.param_history)
             # inside the stage span so em_begin captures it as the parent
             # of every em_iteration span
             if tel is not None:
@@ -1313,6 +1354,12 @@ class Splink:
                     if bool(result.converged):
                         converged = True
                         break
+            # counted where the stage ends well: an OOM'd resident attempt
+            # that falls back to the streamed regime counts its pairs once
+            st.count(
+                pairs=pairs, patterns=int(G_dev.shape[0]),
+                iterations=len(self.params.param_history) - updates_before,
+            )
         if converged:
             logger.info("EM algorithm has converged")
 
@@ -1403,7 +1450,8 @@ class Splink:
         """Fused EM on a weighted pattern matrix (counts as weights)."""
         dtype = self._float_dtype
         self._run_em_fused(
-            jnp.asarray(G_pat), jnp.asarray(weights.astype(dtype)), compute_ll
+            jnp.asarray(G_pat), jnp.asarray(weights.astype(dtype)), compute_ll,
+            pairs=int(weights.sum()),
         )
 
     def _run_em_streamed_stats(self, G: np.ndarray, compute_ll: bool) -> None:
@@ -1422,6 +1470,7 @@ class Splink:
 
         from .parallel.distributed import global_pair_slice
 
+        pairs = len(G)
         if jax.process_count() > 1:
             G = G[global_pair_slice(len(G))]
         batch = int(self.settings["pair_batch_size"])
@@ -1430,7 +1479,7 @@ class Splink:
             for s in range(0, len(G), batch):
                 yield G[s : s + batch]
 
-        self._run_em_streamed_driver(batches, compute_ll)
+        self._run_em_streamed_driver(batches, compute_ll, pairs)
 
     def _run_em_streamed_spill(self, pairs: PairIndex, compute_ll: bool) -> None:
         """Manifest-fed streamed EM: the spill store IS the pair stream.
@@ -1473,16 +1522,15 @@ class Splink:
                 store, program, batch, pair_range=pair_range
             )
 
-        self._obs.count("pairs_gamma_scored", int(store.total_pairs))
         self._last_em_result = None
         logger.info(
             "spill-fed streamed EM over %d pairs (%d manifest segments)",
             store.total_pairs, len(store.segments),
         )
-        self._run_em_streamed_driver(batches, compute_ll)
+        self._run_em_streamed_driver(batches, compute_ll, int(store.total_pairs))
         self._emit_em_diagnostics(None)
 
-    def _run_em_streamed_driver(self, batches, compute_ll: bool) -> None:
+    def _run_em_streamed_driver(self, batches, compute_ll: bool, pairs: int) -> None:
         """The shared streamed-EM driver: checkpoint/resume plumbing,
         telemetry and the run_em_streamed call over any re-iterable batch
         factory — the materialised G path and the spill-manifest path
@@ -1573,7 +1621,8 @@ class Splink:
             if self.save_state_fn is not None:
                 self.save_state_fn(self.params, self.settings)
 
-        with self._stage("em_streamed"):
+        with self._stage("em_streamed") as st:
+            updates_before = len(self.params.param_history)
             # inside the stage span so em_begin captures it as the parent
             # of every em_iteration span
             if tel is not None:
@@ -1598,6 +1647,10 @@ class Splink:
                 retry_policy=RetryPolicy(),
                 fault_plan=active_plan(self.settings),
                 telemetry=tel,
+            )
+            st.count(
+                pairs=pairs,
+                iterations=len(self.params.param_history) - updates_before,
             )
         if checkpointer is not None:
             checkpointer.finish(converged)
@@ -1718,26 +1771,32 @@ class Splink:
             term_frequency_columns,
         )
 
-        pair_token_ids = None
-        if self._pairs is not None and self._df_e_aligned_with_pairs(df_e):
-            table = self._ensure_encoded()
-            pair_token_ids = {}
-            for name in term_frequency_columns(self.settings):
-                if name in table.strings:
-                    tid = table.strings[name].token_ids
-                    pair_token_ids[name] = (
-                        tid[self._pairs.idx_l],
-                        tid[self._pairs.idx_r],
-                        table.strings[name].n_tokens,
-                    )
-
-        return make_adjustment_for_term_frequencies(
-            df_e,
-            self.params,
-            self.settings,
-            retain_adjustment_columns=True,
-            pair_token_ids=pair_token_ids,
-        )
+        with self._call("tf") as call:
+            pair_token_ids = None
+            if self._pairs is not None:
+                with span("tf_align_check", rows=len(df_e)):
+                    aligned = self._df_e_aligned_with_pairs(df_e)
+                if aligned:
+                    table = self._ensure_encoded()
+                    pair_token_ids = {}
+                    with span("tf_token_ids", rows=len(df_e)):
+                        for name in term_frequency_columns(self.settings):
+                            if name in table.strings:
+                                tid = table.strings[name].token_ids
+                                pair_token_ids[name] = (
+                                    tid[self._pairs.idx_l],
+                                    tid[self._pairs.idx_r],
+                                    table.strings[name].n_tokens,
+                                )
+            out = make_adjustment_for_term_frequencies(
+                df_e,
+                self.params,
+                self.settings,
+                retain_adjustment_columns=True,
+                pair_token_ids=pair_token_ids,
+            )
+            call.count(rows=len(out))
+        return out
 
     def _df_e_aligned_with_pairs(self, df_e) -> bool:
         """Whether df_e still corresponds row-for-row to the pair index (the
@@ -1823,7 +1882,12 @@ class Splink:
         pending = None  # (start, stop, device results)
         for s in range(0, n, batch):
             stop = min(s + batch, n)
-            Gb = src_dev[s:stop] if src_dev is not None else jnp.asarray(G[s:stop])
+            count(batches=1)
+            if src_dev is not None:
+                Gb = src_dev[s:stop]
+            else:
+                with span("h2d_put", bytes=G[s:stop].nbytes):
+                    Gb = jnp.asarray(G[s:stop])
             if stop - s < batch:
                 Gb = jnp.concatenate(
                     [Gb, jnp.zeros((batch - (stop - s), n_cols), Gb.dtype)]
@@ -1847,13 +1911,13 @@ class Splink:
     @staticmethod
     def _drain_score_batch(pending, p, prob_m, prob_u, z):
         s, stop, res = pending
-        p[s:stop] = np.asarray(res[0])
+        p[s:stop] = fetch(res[0])
         if prob_m is not None:
-            prob_m[s:stop] = np.asarray(res[1])
-            prob_u[s:stop] = np.asarray(res[2])
+            prob_m[s:stop] = fetch(res[1])
+            prob_u[s:stop] = fetch(res[2])
         if z is not None:
             # the logit rides last in every variant that computes it
-            z[s:stop] = np.asarray(res[-1])
+            z[s:stop] = fetch(res[-1])
 
     def _build_df_e(self, G: np.ndarray, rows: slice | None = None):
         """Assemble the scored comparisons DataFrame with the reference's
@@ -1870,10 +1934,11 @@ class Splink:
         params_dev = FSParams(
             lam=jnp.asarray(lam), m=jnp.asarray(m), u=jnp.asarray(u)
         )
-        with self._stage("score"):
+        with self._stage("score") as st:
             p, prob_m, prob_u, z = self._score_batched(
                 G, params_dev, want_z=self._tf_fold_ctx() is not None
             )
+            st.count(pairs=len(G))
         return self._assemble_df_e(G, il, ir, p, prob_m, prob_u, z=z)
 
     def _assemble_df_e(self, G, il, ir, p, prob_m, prob_u, z=None):
@@ -1884,6 +1949,15 @@ class Splink:
         ``tf_match_probability`` column — the first-class TF-adjusted
         score, bit-identical to what the serve megakernel returns for the
         same pairs."""
+        with span("assemble_frame", rows=len(p)) as sp:
+            cols = self._assemble_columns(G, il, ir, p, prob_m, prob_u, z)
+            sp.count(
+                columns=len(cols),
+                string_columns=sum(v.dtype == object for v in cols.values()),
+            )
+            return pd.DataFrame(cols)
+
+    def _assemble_columns(self, G, il, ir, p, prob_m, prob_u, z) -> dict:
         table = self._ensure_encoded()
         settings = self.settings
         uid = settings["unique_id_column_name"]
@@ -1923,8 +1997,7 @@ class Splink:
             add_lr("_source_table", src)
         for extra in settings["additional_columns_to_retain"]:
             add_lr(extra, table.column_values(extra))
-
-        return pd.DataFrame(cols)
+        return cols
 
 
 @check_types

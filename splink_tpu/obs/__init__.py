@@ -11,8 +11,9 @@ Layers (each importable on its own, none imports jax at module scope):
   * :mod:`.events`  — thread-safe JSONL event sink + the ambient ``publish``
     hook the resilience stack emits through (zero-cost no-op when no sink
     is registered).
-  * :mod:`.tracer`  — nested run -> stage -> EM-iteration spans with
-    monotonic timestamps and chrome-trace (Perfetto-loadable) export.
+  * :mod:`.tracer`  — the record's span events (run -> call -> stage ->
+    sub-span / EM iteration; ``utils.profiling`` keeps the one span table
+    and times them) and chrome-trace (Perfetto-loadable) export.
   * :mod:`.metrics` — counters/gauges/histograms, the process-wide jit
     compile monitor (``jax.monitoring`` duration listeners) and device
     memory snapshots.

@@ -8,11 +8,12 @@ Python — nothing here touches the jax dataflow.
 The compile monitor hangs one process-global listener on
 ``jax.monitoring``'s duration stream (``/jax/core/compile/*``: jaxpr trace,
 MLIR lowering, backend compile). jax offers registration only — listeners
-cannot be removed individually — so it is installed once, lazily, the first
-time a telemetry-enabled run needs it, and accumulates process totals;
-run/stage attribution is done by snapshot deltas (``compile_totals`` before
-and after). This is what splits stage wall time into compile vs execute —
-the cold-start number the Spark UI showed as query-planning time.
+cannot be removed individually — so it is installed once, by the first
+linker's ``begin_run``, and accumulates process totals. Each duration also
+lands in the run's span table as a ``build`` span under the span that was
+open on the compiling thread (utils/profiling.py): that is what splits a
+stage's wall time into compile vs execute — the cold-start number the Spark
+UI showed as query-planning time.
 """
 
 from __future__ import annotations
@@ -91,23 +92,58 @@ _COMPILE = {
 _MONITOR_INSTALLED = False
 
 
+# jax.monitoring duration event -> the build span it becomes in the run's
+# span table (utils/profiling.py)
+_BUILD_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    "/jax/core/compile/backend_compile_duration": "jax_backend_compile",
+}
+# Every jnp function traced INSIDE a kernel's trace fires its own trace
+# duration, by the hundred and each well under a millisecond; the kernel's own
+# event covers them. Below this a trace is no span (granularity rule).
+_MIN_TRACE_S = 1e-3
+# a persistent-cache hit is announced (event, then its retrieval seconds)
+# BEFORE the backend_compile_duration that wraps it, on the same thread
+_PENDING_HIT = threading.local()
+
+
 def install_compile_monitor() -> None:
-    """Install the process-global jax compile listeners (idempotent)."""
+    """Install the process-global jax compile listeners (idempotent). They
+    fire on compile events only; each duration also becomes a closed
+    ``build`` span under whatever span is open on the compiling thread."""
     global _MONITOR_INSTALLED
     if _MONITOR_INSTALLED:
         return
     import jax
 
-    def _on_duration(name: str, secs: float, **_kw) -> None:
+    from ..utils.profiling import add_closed
+
+    def _on_duration(name: str, secs: float, **kw) -> None:
+        if name == "/jax/compilation_cache/cache_retrieval_time_sec":
+            _PENDING_HIT.read_s = secs
+            return
         if not name.startswith("/jax/core/compile"):
             return
+        backend = name.endswith("backend_compile_duration")
         with _COMPILE_LOCK:
             _COMPILE["seconds"] += secs
-            if name.endswith("backend_compile_duration"):
+            if backend:
                 _COMPILE["requests"] += 1
+        span = _BUILD_SPANS.get(name)
+        if span is None or (span == "jax_trace" and secs < _MIN_TRACE_S):
+            return
+        counts = {"fun": str(kw.get("fun_name", ""))}
+        if backend:
+            counts["cache_hit"] = int(getattr(_PENDING_HIT, "hit", 0))
+            if counts["cache_hit"]:
+                counts["cache_read_s"] = getattr(_PENDING_HIT, "read_s", 0.0)
+            _PENDING_HIT.hit, _PENDING_HIT.read_s = 0, 0.0
+        add_closed(span, "build", secs, **counts)
 
     def _on_event(name: str, **_kw) -> None:
         if name == "/jax/compilation_cache/cache_hits":
+            _PENDING_HIT.hit = 1
             with _COMPILE_LOCK:
                 _COMPILE["cache_hits"] += 1
 

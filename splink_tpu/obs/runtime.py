@@ -9,8 +9,12 @@ registry pins the jaxprs). When enabled it owns:
   * an :class:`~.events.EventSink` writing
     ``<telemetry_dir>/run_<run_id>.jsonl`` (registered as an ambient sink
     so resilience events land in the same file);
-  * a :class:`~.tracer.Tracer` for run/stage/EM-iteration spans, with the
-    per-stage compile-vs-execute split from the compile monitor;
+  * the JSONL form of the run's span table: ``utils.profiling`` keeps the
+    one table (and the one stack of open spans) and hands each closed span
+    to :meth:`RunContext.stage_exit`, which emits it with the compile-vs-
+    execute split from the build spans below it; EM-iteration and run
+    spans, which exist only in the record, come through
+    :class:`~.tracer.Tracer`;
   * a :class:`~.metrics.MetricsRegistry` snapshotted into the record at
     the end of each public linker call.
 
@@ -54,6 +58,30 @@ def _never_raise(fn):
     return wrapper
 
 
+# (span name, count) -> the run counter it feeds: the counts made at the
+# spans' boundaries are the one source of these
+_COUNTERS = {
+    ("encode", "rows"): "rows_encoded",
+    ("blocking", "pairs"): "pairs_blocked",
+    ("em", "pairs"): "pairs_gamma_scored",
+    ("em_streamed", "pairs"): "pairs_gamma_scored",
+}
+
+
+def _union_seconds(intervals) -> float:
+    """Length of the union of (t0, t1) intervals: build spans overlap (a
+    jit traced inside another reports its own trace time)."""
+    total, end = 0.0, None
+    for t0, t1 in sorted(intervals):
+        if end is None or t0 > end:
+            total += t1 - t0
+            end = t1
+        elif t1 > end:
+            total += t1 - end
+            end = t1
+    return total
+
+
 class RunContext:
     """Telemetry scope for one linker run (see module docstring)."""
 
@@ -86,7 +114,7 @@ class RunContext:
         self._em_prev = None
         self._em_last_mono: float | None = None
         if sink is not None:
-            install_compile_monitor()
+            install_compile_monitor()  # RunContext.span's compile split
             register_ambient(sink)
             sink.emit("run_start", config_hash=config_hash)
             # The ambient registry holds a strong reference to the sink, so
@@ -127,54 +155,87 @@ class RunContext:
         )
         return ctx
 
-    # -- stage spans (driven by utils.profiling.StageTimer) ---------------
+    # -- spans (closed by utils.profiling.StageTimer) ----------------------
 
     @_never_raise
-    def stage_enter(self, stage: str):
+    def stage_exit(self, span: dict, failed: bool = False):
+        """Emit one closed span of the run's span table (utils/profiling.py
+        hands over every span that closes under this context: ``id``,
+        ``name``, ``kind``, ``t0``, ``t1``, ``parent``, ``counts`` and
+        ``build``, the build spans below it)."""
         if not self.enabled:
-            return None
-        sid = self.tracer.begin(stage, kind="stage")
-        return (sid, compile_totals())
-
-    @_never_raise
-    def stage_exit(self, token, stage: str, elapsed: float, failed: bool = False):
-        if not self.enabled or token is None:
             return
-        sid, (c0_count, c0_secs) = token
-        c1_count, c1_secs = compile_totals()
-        compile_s = max(c1_secs - c0_secs, 0.0)
-        span = self.tracer.end(
-            sid,
-            compile_count=c1_count - c0_count,
-            compile_s=compile_s,
-            execute_s=max(elapsed - compile_s, 0.0),
+        builds = span.get("build", ())
+        self._emit_span(
+            span["id"], span["name"], span["kind"], span["t0"], span["t1"],
+            span["parent"], span["counts"],
+            compiles=sum(
+                1 for b in builds
+                if b["name"] == "jax_backend_compile"
+                and not b["counts"].get("cache_hit")
+            ),
+            compile_s=_union_seconds((b["t0"], b["t1"]) for b in builds),
             failed=failed,
         )
-        self.sink.emit("span", **span)
-        self.metrics.observe(f"stage_s.{stage}", elapsed)
-        self.metrics.count("compile_count", c1_count - c0_count)
+
+    @_never_raise
+    def _emit_span(self, span_id, name, kind, t0, t1, parent, counts,
+                   compiles, compile_s, failed):
+        """One span into the record. Stages also feed the compile/execute
+        split, the stage metrics and a device-memory snapshot; a span's
+        counts are THE source of the run's counters."""
+        elapsed = t1 - t0
+        execute_s = max(elapsed - compile_s, 0.0)
+        attrs = dict(counts)
+        if kind != "build":
+            attrs.update(compile_count=compiles, compile_s=compile_s,
+                         execute_s=execute_s, failed=failed)
+        self.sink.emit("span", **self.tracer.emit_closed(
+            name, kind, t0, t1, parent=parent, span_id=span_id, **attrs
+        ))
+        for key, n in counts.items():
+            counter = "pairs_scored_output" if (
+                kind == "call" and key == "pairs"
+            ) else _COUNTERS.get((name, key))
+            if counter:
+                self.metrics.count(counter, n)
+        if kind != "stage":
+            return
+        self.metrics.observe(f"stage_s.{name}", elapsed)
+        self.metrics.count("compile_count", compiles)
         self.metrics.count("compile_s", compile_s)
-        self.metrics.count("execute_s", max(elapsed - compile_s, 0.0))
-        self.kernelwatch.observe(stage, max(elapsed - compile_s, 0.0))
+        self.metrics.count("execute_s", execute_s)
+        self.kernelwatch.observe(name, execute_s)
         if self.memory_snapshots:
             devices = device_memory_snapshot()
             if devices:
-                self.sink.emit("memory", stage=stage, devices=devices)
+                self.sink.emit("memory", stage=name, devices=devices)
                 peak = max(d.get("peak_bytes_in_use") or 0 for d in devices)
                 if peak:
                     self.metrics.gauge("peak_bytes_in_use", peak)
 
     @contextmanager
     def span(self, name: str, **attrs):
-        """Standalone span context (bench.py and non-StageTimer callers)."""
-        token = self.stage_enter(name)
+        """Standalone stage-kind span for callers outside a linker run (the
+        serve loop's batches, bench.py): emitted to the record and kept in
+        no span table, so a long-lived service does not grow one."""
+        if not self.enabled:
+            yield
+            return
         t0 = time.perf_counter()
+        c0, s0 = compile_totals()
+        failed = False
         try:
             yield
         except BaseException:
-            self.stage_exit(token, name, time.perf_counter() - t0, failed=True)
+            failed = True
             raise
-        self.stage_exit(token, name, time.perf_counter() - t0)
+        finally:
+            c1, s1 = compile_totals()
+            self._emit_span(
+                None, name, "stage", t0, time.perf_counter(), None, attrs,
+                c1 - c0, max(s1 - s0, 0.0), failed,
+            )
 
     # -- EM convergence stream --------------------------------------------
 
@@ -184,7 +245,9 @@ class RunContext:
             return
         import numpy as np
 
-        self._em_parent = self.tracer.current_id()
+        from ..utils.profiling import current_span_id
+
+        self._em_parent = current_span_id()
         self._em_prev = (np.asarray(m0, float), np.asarray(u0, float))
         self._em_last_mono = time.monotonic()
         self.sink.emit(

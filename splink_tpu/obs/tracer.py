@@ -1,71 +1,35 @@
-"""Span tracer: nested run -> stage -> EM-iteration spans.
+"""Span events: run -> call -> stage -> sub-span / EM-iteration.
 
 Spans carry a monotonic [t0, t1) interval, a kind, parent linkage and free
 attributes, and are emitted to the run's event sink as ``type: "span"``
-events when they close. :func:`chrome_trace_from_events` converts a run's
+events when they close (``utils.profiling`` times them; this module only
+shapes the event). :func:`chrome_trace_from_events` converts a run's
 JSONL events into the Chrome trace-event format that ui.perfetto.dev and
 chrome://tracing load directly.
 """
 
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
-
 
 class Tracer:
-    """Open/close nested spans; completed spans are kept in order.
+    """Shapes closed intervals into the record's span events. The open-span
+    stack lives in ``utils.profiling`` (the run's one span table); spans of
+    that table keep their table id (>= 0), spans that exist only in the
+    record (EM iterations, the run span, standalone spans) count down from
+    -1, so the two never collide."""
 
-    The open-span stack is a plain list, not thread-local: the pipeline is
-    one host thread, and the EM host-callback thread never opens stage
-    spans (iteration spans record their parent explicitly — see
-    ``RunContext.em_begin``).
-    """
-
-    def __init__(self, clock=time.monotonic):
-        self._clock = clock
-        self._next_id = 1
-        self._stack: list[dict] = []
-        self.completed: list[dict] = []
-
-    def current_id(self) -> int | None:
-        return self._stack[-1]["span_id"] if self._stack else None
-
-    def begin(self, name: str, kind: str = "stage", parent: int | None = None, **attrs) -> int:
-        span = {
-            "span_id": self._next_id,
-            "parent_id": parent if parent is not None else self.current_id(),
-            "name": name,
-            "kind": kind,
-            "t0": self._clock(),
-            "attrs": dict(attrs),
-        }
-        self._next_id += 1
-        self._stack.append(span)
-        return span["span_id"]
-
-    def end(self, span_id: int, **attrs) -> dict:
-        """Close ``span_id`` (and, defensively, anything opened after it
-        that was left dangling by an exception) and return the span dict."""
-        while self._stack:
-            span = self._stack.pop()
-            if span["span_id"] == span_id or not self._stack:
-                break
-        else:  # pragma: no cover - end() without begin()
-            span = {"span_id": span_id, "parent_id": None, "name": "?",
-                    "kind": "stage", "t0": self._clock(), "attrs": {}}
-        span["t1"] = self._clock()
-        span["dur_s"] = span["t1"] - span["t0"]
-        span["attrs"].update(attrs)
-        self.completed.append(span)
-        return span
+    def __init__(self):
+        self._next_id = -1
 
     def emit_closed(self, name: str, kind: str, t0: float, t1: float,
-                    parent: int | None = None, **attrs) -> dict:
-        """Record an already-timed interval as a span (used for EM
-        iteration spans, whose boundaries are host-callback arrivals)."""
-        span = {
-            "span_id": self._next_id,
+                    parent: int | None = None, span_id: int | None = None,
+                    **attrs) -> dict:
+        """An already-timed interval as a span event."""
+        if span_id is None:
+            span_id = self._next_id
+            self._next_id -= 1
+        return {
+            "span_id": span_id,
             "parent_id": parent,
             "name": name,
             "kind": kind,
@@ -74,25 +38,14 @@ class Tracer:
             "dur_s": t1 - t0,
             "attrs": dict(attrs),
         }
-        self._next_id += 1
-        self.completed.append(span)
-        return span
-
-    @contextmanager
-    def span(self, name: str, kind: str = "stage", **attrs):
-        sid = self.begin(name, kind=kind, **attrs)
-        try:
-            yield sid
-        finally:
-            self.end(sid)
 
 
 # Track rows in the chrome trace, one per span kind. Row 4 renders the
 # grafted REMOTE half of stitched cross-host traces (obs/fleet.py): the
 # far server's span tree, rebased onto this host's clock by the wire
 # client's offset estimate, directly under the local attempt row.
-_KIND_TID = {"run": 0, "stage": 1, "em_iteration": 2, "request": 3,
-             "remote": 4}
+_KIND_TID = {"run": 0, "call": 0, "stage": 1, "em_iteration": 2,
+             "request": 3, "remote": 4, "span": 5, "build": 6}
 
 
 def chrome_trace_from_events(events: list[dict]) -> dict:
@@ -207,7 +160,9 @@ def chrome_trace_from_events(events: list[dict]) -> dict:
         {"name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
          "args": {"name": row}}
         for pid in sorted(pids)
-        for row, tid in (("run", 0), ("stages", 1), ("em / events", 2),
-                         ("requests", 3), ("remote (stitched)", 4))
+        for row, tid in (("run / calls", 0), ("stages", 1),
+                         ("em / events", 2), ("requests", 3),
+                         ("remote (stitched)", 4), ("sub-spans", 5),
+                         ("jax build", 6))
     ]
     return {"traceEvents": meta + trace_events, "displayTimeUnit": "ms"}

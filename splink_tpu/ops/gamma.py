@@ -9,6 +9,7 @@ to gamma = -1 (the "uninformative" pseudo-level) exactly as in the reference.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 GAMMA_DTYPE = jnp.int8
@@ -20,10 +21,11 @@ def bucket_similarity(sim, thresholds, null_mask):
     thresholds[0] gates the top level: gamma = #\\{i : sim > thresholds[i]\\}.
     E.g. thresholds (0.94, 0.88): sim > 0.94 -> 2, sim in (0.88, 0.94] -> 1.
     """
-    gamma = jnp.zeros(sim.shape, dtype=GAMMA_DTYPE)
-    for t in thresholds:
-        gamma = gamma + (sim > t).astype(GAMMA_DTYPE)
-    return apply_null(gamma, null_mask)
+    with jax.named_scope("levels"):
+        gamma = jnp.zeros(sim.shape, dtype=GAMMA_DTYPE)
+        for t in thresholds:
+            gamma = gamma + (sim > t).astype(GAMMA_DTYPE)
+        return apply_null(gamma, null_mask)
 
 
 def bucket_difference(diff, thresholds, null_mask):
@@ -32,21 +34,23 @@ def bucket_difference(diff, thresholds, null_mask):
     thresholds[0] gates the top level: gamma = #\\{i : diff < thresholds[i]\\}.
     E.g. thresholds (1e-4, 0.05): diff < 1e-4 -> 2, diff in [1e-4, 0.05) -> 1.
     """
-    gamma = jnp.zeros(diff.shape, dtype=GAMMA_DTYPE)
-    for t in thresholds:
-        gamma = gamma + (diff < t).astype(GAMMA_DTYPE)
-    return apply_null(gamma, null_mask)
+    with jax.named_scope("levels"):
+        gamma = jnp.zeros(diff.shape, dtype=GAMMA_DTYPE)
+        for t in thresholds:
+            gamma = gamma + (diff < t).astype(GAMMA_DTYPE)
+        return apply_null(gamma, null_mask)
 
 
 def bucket_difference_le(diff, thresholds, null_mask, equal, top_level):
     """Levenshtein-style levels: exact equality takes the top level, then
     ascending ``<=`` thresholds fill the middle levels
     (cf. /root/reference/splink/case_statements.py:117-141)."""
-    gamma = jnp.zeros(diff.shape, dtype=GAMMA_DTYPE)
-    for t in thresholds:
-        gamma = gamma + (diff <= t).astype(GAMMA_DTYPE)
-    gamma = jnp.where(equal, jnp.asarray(top_level, GAMMA_DTYPE), gamma)
-    return apply_null(gamma, null_mask)
+    with jax.named_scope("levels"):
+        gamma = jnp.zeros(diff.shape, dtype=GAMMA_DTYPE)
+        for t in thresholds:
+            gamma = gamma + (diff <= t).astype(GAMMA_DTYPE)
+        gamma = jnp.where(equal, jnp.asarray(top_level, GAMMA_DTYPE), gamma)
+        return apply_null(gamma, null_mask)
 
 
 def apply_null(gamma, null_mask):

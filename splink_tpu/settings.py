@@ -66,7 +66,6 @@ _NON_COLUMN_DEFAULT_KEYS = [
     "build_spill_dir",
     "build_spill_chunk_rows",
     "emit_shard_chunks",
-    "profile_dir",
     "telemetry_dir",
     "telemetry_memory",
     "compilation_cache_dir",

@@ -30,6 +30,7 @@ import numpy as np
 
 from .params import Params
 from .check_types import check_types
+from .utils.profiling import fetch, span
 
 
 def bayes_combine(probs: list[np.ndarray]) -> np.ndarray:
@@ -149,7 +150,7 @@ def _device_token_stats_fn(num_segments: int):
     import jax.numpy as jnp
 
     @jax.jit
-    def fn(tid_l, tid_r, p, sums, counts):
+    def tf_token_stats(tid_l, tid_r, p, sums, counts):
         agree = (tid_l == tid_r) & (tid_l >= 0)
         af = agree.astype(p.dtype)
         # disagreeing (and padded, tid=-1) pairs go to the overflow bucket
@@ -158,7 +159,7 @@ def _device_token_stats_fn(num_segments: int):
         counts = counts + jax.ops.segment_sum(af, seg, num_segments=num_segments)
         return sums, counts
 
-    return fn
+    return tf_token_stats
 
 
 @functools.lru_cache(maxsize=None)
@@ -168,13 +169,13 @@ def _device_token_gather_fn(num_segments: int):
     import jax.numpy as jnp
 
     @jax.jit
-    def fn(tid_l, tid_r, adjusted):
+    def tf_token_gather(tid_l, tid_r, adjusted):
         agree = (tid_l == tid_r) & (tid_l >= 0)
         return jnp.where(
             agree, adjusted[jnp.minimum(tid_l, num_segments - 1)], 0.5
         )
 
-    return fn
+    return tf_token_gather
 
 
 def compute_token_adjustment_device(
@@ -221,9 +222,9 @@ def compute_token_adjustment_device(
         pc = p_host[s : s + chunk]
         if len(pc) < chunk:
             pc = np.concatenate([pc, np.zeros(chunk - len(pc), pc.dtype)])
-        sums, counts = stats_fn(
-            jnp.asarray(cl), jnp.asarray(cr), jnp.asarray(pc, dtype), sums, counts
-        )
+        with span("h2d_put", bytes=cl.nbytes + cr.nbytes + pc.nbytes):
+            chunk_dev = jnp.asarray(cl), jnp.asarray(cr), jnp.asarray(pc, dtype)
+        sums, counts = stats_fn(*chunk_dev, sums, counts)
 
     tok_lambda = sums / jnp.maximum(counts, 1.0)
     # Bayes-combine each token lambda with (1 - base lambda)
@@ -246,14 +247,16 @@ def compute_token_adjustment_device(
     for (s, cl), (_, cr) in zip(
         chunks_of(np.asarray(tid_l), -1), chunks_of(np.asarray(tid_r), -1)
     ):
-        out = gather_fn(jnp.asarray(cl), jnp.asarray(cr), adjusted)
+        with span("h2d_put", bytes=cl.nbytes + cr.nbytes):
+            chunk_dev = jnp.asarray(cl), jnp.asarray(cr)
+        out = gather_fn(*chunk_dev, adjusted)
         if pending is not None:
             ps, pout = pending
-            adj[ps : ps + chunk] = np.asarray(pout)[: max(0, min(chunk, n - ps))]
+            adj[ps : ps + chunk] = fetch(pout)[: max(0, min(chunk, n - ps))]
         pending = (s, out)
     ps, pout = pending
-    adj[ps : ps + chunk] = np.asarray(pout)[: max(0, min(chunk, n - ps))]
-    return adj, np.asarray(tok_lambda), np.asarray(counts)
+    adj[ps : ps + chunk] = fetch(pout)[: max(0, min(chunk, n - ps))]
+    return adj, fetch(tok_lambda), fetch(counts)
 
 
 # ---------------------------------------------------------------------------
@@ -346,7 +349,7 @@ def make_tf_fold_fn(spec: tuple):
     n_tf = len(spec)
 
     @jax.jit
-    def fold(z, u, *arrs):
+    def tf_fold(z, u, *arrs):
         tid_l = arrs[:n_tf]
         tid_r = arrs[n_tf : 2 * n_tf]
         log_tf = arrs[2 * n_tf :]
@@ -358,7 +361,7 @@ def make_tf_fold_fn(spec: tuple):
             )
         return jax.nn.sigmoid(z + tf_sum)
 
-    return fold
+    return tf_fold
 
 
 @check_types
@@ -384,37 +387,41 @@ def make_adjustment_for_term_frequencies(
         )
         return df_e
 
-    df = df_e.copy()
-    base_lambda = params.params["λ"]
-    adj_arrays = []
-    for col in tf_cols:
-        if pair_token_ids is not None and col in pair_token_ids:
-            tid_l, tid_r, n_tokens = pair_token_ids[col]
-            adj, _, _ = compute_token_adjustment_device(
-                tid_l,
-                tid_r,
-                df["match_probability"].to_numpy(),
-                base_lambda,
-                n_tokens,
-            )
-        else:
-            adj, _ = compute_token_adjustment(
-                df[f"{col}_l"].to_numpy(dtype=object),
-                df[f"{col}_r"].to_numpy(dtype=object),
-                df["match_probability"].to_numpy(),
-                base_lambda,
-            )
-        df[f"{col}_adj"] = adj
-        adj_arrays.append(adj)
+    # tf_frame: the pandas work (copy, column writes, reorder); its self
+    # time excludes the tf_device spans below it
+    with span("tf_frame", rows=len(df_e)):
+        df = df_e.copy()
+        base_lambda = params.params["λ"]
+        adj_arrays = []
+        for col in tf_cols:
+            if pair_token_ids is not None and col in pair_token_ids:
+                tid_l, tid_r, n_tokens = pair_token_ids[col]
+                with span("tf_device", rows=len(df)):
+                    adj, _, _ = compute_token_adjustment_device(
+                        tid_l,
+                        tid_r,
+                        df["match_probability"].to_numpy(),
+                        base_lambda,
+                        n_tokens,
+                    )
+            else:
+                adj, _ = compute_token_adjustment(
+                    df[f"{col}_l"].to_numpy(dtype=object),
+                    df[f"{col}_r"].to_numpy(dtype=object),
+                    df["match_probability"].to_numpy(),
+                    base_lambda,
+                )
+            df[f"{col}_adj"] = adj
+            adj_arrays.append(adj)
 
-    df["tf_adjusted_match_prob"] = bayes_combine(
-        [df["match_probability"].to_numpy()] + adj_arrays
-    )
-    if not retain_adjustment_columns:
-        df = df.drop(columns=[f"{c}_adj" for c in tf_cols])
+        df["tf_adjusted_match_prob"] = bayes_combine(
+            [df["match_probability"].to_numpy()] + adj_arrays
+        )
+        if not retain_adjustment_columns:
+            df = df.drop(columns=[f"{c}_adj" for c in tf_cols])
 
-    # Column order: tf_adjusted_match_prob leads, as in the reference
-    # (/root/reference/splink/term_frequencies.py:108-115).
-    lead = ["tf_adjusted_match_prob", "match_probability"]
-    rest = [c for c in df.columns if c not in lead]
-    return df[lead + rest]
+        # Column order: tf_adjusted_match_prob leads, as in the reference
+        # (/root/reference/splink/term_frequencies.py:108-115).
+        lead = ["tf_adjusted_match_prob", "match_probability"]
+        rest = [c for c in df.columns if c not in lead]
+        return df[lead + rest]

@@ -197,31 +197,6 @@ def test_stage_timings_recorded_through_pipeline():
         assert stage in t and t[stage][0] >= 0, (stage, t.keys())
 
 
-def test_stage_timer_trace_hook_writes_profile(tmp_path):
-    """StageTimer(trace_dir=...) wraps the stage in a jax.profiler.trace and
-    leaves a TensorBoard-format profile artifact behind — the observability
-    hook is exercised, not just wired."""
-    import os
-
-    import jax.numpy as jnp
-
-    from splink_tpu.utils.profiling import StageTimer, stage_timings
-
-    trace_dir = str(tmp_path / "trace")
-    with StageTimer("traced_stage", trace_dir=trace_dir):
-        jnp.dot(jnp.ones((32, 32)), jnp.ones((32, 32))).block_until_ready()
-
-    produced = [
-        os.path.join(root, f)
-        for root, _, files in os.walk(trace_dir)
-        for f in files
-    ]
-    assert any("xplane" in f or f.endswith(".json.gz") for f in produced), (
-        f"no profile artifact under {trace_dir}: {produced}"
-    )
-    assert "traced_stage" in stage_timings()
-
-
 def test_spill_sweep_reclaims_recycled_pid_dirs(tmp_path):
     """A stale splink_pairs_* dir whose recorded pid was recycled by an
     unrelated live process is reclaimed (the start-time token detects the
@@ -264,44 +239,6 @@ def test_spill_sweep_reclaims_recycled_pid_dirs(tmp_path):
     assert not recycled.exists(), "recycled-pid orphan not reclaimed"
     assert kept.exists(), "live owner's dir must not be touched"
     assert not dead.exists(), "dead-pid orphan not reclaimed"
-
-
-def test_profile_dir_captures_traces(tmp_path):
-    """settings["profile_dir"] -> device-heavy stages emit jax profiler
-    traces (one flag turns an EM pass into utilisation data)."""
-    import os
-
-    import numpy as np
-    import pandas as pd
-
-    from splink_tpu import Splink
-    from splink_tpu.utils.profiling import set_trace_dir
-
-    rng = np.random.default_rng(0)
-    df = pd.DataFrame(
-        {
-            "unique_id": range(200),
-            "name": rng.choice(["ann", "bob", "cat"], 200),
-            "dob": rng.choice([f"d{k}" for k in range(10)], 200),
-        }
-    )
-    s = {
-        "link_type": "dedupe_only",
-        "comparison_columns": [{"col_name": "name", "num_levels": 2}],
-        "blocking_rules": ["l.dob = r.dob"],
-        "max_iterations": 2,
-        "profile_dir": str(tmp_path),
-    }
-    try:
-        Splink(s, df=df).get_scored_comparisons()
-        found = [
-            os.path.join(root, f)
-            for root, _dirs, files in os.walk(tmp_path)
-            for f in files
-        ]
-        assert found, "no trace files captured"
-    finally:
-        set_trace_dir(None)  # process-wide flag: do not leak into other tests
 
 
 @pytest.fixture
@@ -448,9 +385,9 @@ def test_cache_dir_setting_applies_when_variable_unset(
 
 
 # ----------------------------------------------------------------------
-# Run-scoped profiling (utils/profiling.py): timings and trace dirs are
-# keyed by run id — two linkers in one process no longer interleave
-# timings or clobber each other's profile_dir.
+# Run-scoped profiling (utils/profiling.py): span tables are keyed by run
+# id — two linkers in one process do not interleave their timings
+# (tests/test_spans.py covers the span table itself).
 # ----------------------------------------------------------------------
 
 
@@ -511,73 +448,3 @@ def test_timings_scoped_per_linker_run():
     assert len(stage_timings(run=b2.run_id)["em"]) == 1
 
 
-def test_later_linker_does_not_clear_earlier_trace_dir(tmp_path):
-    """A later linker WITHOUT profile_dir must not disable an earlier
-    linker's trace capture (the old process-global _TRACE_DIR did:
-    linker.py cleared it unconditionally on every construction)."""
-    import os
-
-    from splink_tpu import Splink
-
-    a = Splink(_tiny_settings(profile_dir=str(tmp_path)), df=_tiny_df(seed=5))
-    Splink(_tiny_settings(), df=_tiny_df(seed=6))  # no profile_dir
-    a.get_scored_comparisons()
-    found = [
-        os.path.join(root, f)
-        for root, _dirs, files in os.walk(tmp_path)
-        for f in files
-    ]
-    assert found, "later linker clobbered the first linker's profile_dir"
-
-
-def test_stage_timer_does_not_nest_profiler_traces(tmp_path):
-    """jax.profiler.trace cannot nest: an inner StageTimer with a trace
-    dir must skip tracing while an outer trace is active (and trace again
-    once it is released)."""
-    from splink_tpu.utils import profiling
-    from splink_tpu.utils.profiling import StageTimer
-
-    outer_dir = str(tmp_path / "outer")
-    inner_dir = str(tmp_path / "inner")
-    with StageTimer("outer", trace_dir=outer_dir) as outer:
-        assert outer._trace is not None and profiling._TRACE_ACTIVE
-        with StageTimer("inner", trace_dir=inner_dir) as inner:
-            assert inner._trace is None  # skipped: a trace is active
-        assert profiling._TRACE_ACTIVE  # inner exit didn't release the flag
-    assert not profiling._TRACE_ACTIVE
-    with StageTimer("after", trace_dir=str(tmp_path / "after")) as after:
-        assert after._trace is not None
-    assert not profiling._TRACE_ACTIVE
-
-
-def test_stage_timer_trace_active_exception_safety(tmp_path):
-    """_TRACE_ACTIVE is released when the stage body raises, and even when
-    the profiler's own __exit__ raises — otherwise no later stage could
-    ever trace again."""
-    import pytest
-
-    from splink_tpu.utils import profiling
-    from splink_tpu.utils.profiling import StageTimer
-
-    with pytest.raises(RuntimeError, match="boom"):
-        with StageTimer("failing", trace_dir=str(tmp_path / "t1")):
-            raise RuntimeError("boom")
-    assert not profiling._TRACE_ACTIVE
-
-    class _ExplodingTrace:
-        def __exit__(self, *exc):
-            raise OSError("profiler write failed")
-
-    # simulate a profiler whose own __exit__ raises WITHOUT opening a real
-    # jax trace (overwriting a live trace object would leak the singleton
-    # profiler session into later tests)
-    timer = StageTimer("bad_exit")
-    with pytest.raises(OSError, match="profiler write failed"):
-        with timer:
-            profiling._TRACE_ACTIVE = True
-            timer._trace = _ExplodingTrace()
-    assert not profiling._TRACE_ACTIVE
-    # timing was still recorded for the failing stage
-    from splink_tpu.utils.profiling import stage_timings
-
-    assert "bad_exit" in stage_timings()

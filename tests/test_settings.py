@@ -162,19 +162,21 @@ def test_backend_key_is_read_and_checked():
 
 
 def test_observability_defaults_filled():
-    """profile_dir and the telemetry keys complete from the schema (the
-    schema is the single source of truth for their defaults)."""
+    """The telemetry keys complete from the schema (the schema is the
+    single source of truth for their defaults); the removed profile_dir
+    hook left no key behind."""
     s = complete_settings_dict(_minimal())
-    assert s["profile_dir"] == ""
+    assert "profile_dir" not in s
     assert s["telemetry_dir"] == ""
     assert s["telemetry_memory"] is True
 
 
 def test_observability_keys_validate_types():
-    """Schema validation rejects wrongly-typed observability keys and
-    accepts correctly-typed ones."""
+    """Schema validation rejects wrongly-typed observability keys (and
+    profile_dir, now an unknown key: wrap the call in jax.profiler.trace
+    instead) and accepts correctly-typed ones."""
     for bad in (
-        {"profile_dir": 5},
+        {"profile_dir": "/tmp/prof"},
         {"telemetry_dir": 5},
         {"telemetry_dir": ["x"]},
         {"telemetry_memory": "yes"},
@@ -183,7 +185,6 @@ def test_observability_keys_validate_types():
             validate_settings(_minimal(**bad))
     validate_settings(
         _minimal(
-            profile_dir="/tmp/prof",
             telemetry_dir="/tmp/tel",
             telemetry_memory=False,
         )

@@ -1,0 +1,576 @@
+"""The run's one span table (utils/profiling.py): parent linkage and self
+time, thread isolation, the ``stage_timings()`` guarantee, the spans and
+counts each job closes, build spans from jax.monitoring, the JSONL form of
+the table, the profiler's view of it, and the names of the jitted programs.
+"""
+
+import json
+import os
+import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+import pandas as pd
+import pytest
+
+from splink_tpu import Splink
+from splink_tpu.utils import profiling
+from splink_tpu.utils.profiling import (
+    StageTimer,
+    add_closed,
+    begin_run,
+    count,
+    discard_run,
+    runs,
+    span,
+    spans,
+    stage_timings,
+)
+
+# ---------------------------------------------------------------------------
+# helpers
+# ---------------------------------------------------------------------------
+
+
+def _self_seconds(table: list[dict]) -> dict[int, float]:
+    """Self time by span id: duration minus the union of the children's
+    intervals, clipped to the span."""
+    by_parent: dict[int, list[dict]] = {}
+    for s in table:
+        by_parent.setdefault(s["parent"], []).append(s)
+    out = {}
+    for s in table:
+        covered, end = 0.0, s["t0"]
+        for c in sorted(by_parent.get(s["id"], ()), key=lambda c: c["t0"]):
+            lo, hi = max(c["t0"], end), min(c["t1"], s["t1"])
+            if hi > lo:
+                covered += hi - lo
+                end = hi
+        out[s["id"]] = (s["t1"] - s["t0"]) - covered
+    return out
+
+
+@pytest.fixture
+def scope():
+    """A fresh run scope, dropped afterwards."""
+    run = begin_run(f"test-{time.perf_counter_ns()}")
+    yield run
+    discard_run(run)
+
+
+def _people(n: int, seed: int) -> pd.DataFrame:
+    rng = np.random.default_rng(seed)
+    return pd.DataFrame(
+        {
+            "unique_id": np.arange(n),
+            "first_name": rng.choice(["ann", "bob", "cat", "dan", "eve"], n),
+            "surname": rng.choice(["smith", "jones", "taylor", "brown"], n),
+            "city": rng.choice(["x", "y", "z"], n),
+        }
+    )
+
+
+def _dedupe_job():
+    """A tiny dedupe on the virtual pair index (the c4 cell's path)."""
+    settings = {
+        "link_type": "dedupe_only",
+        "comparison_columns": [
+            {"col_name": "first_name"},
+            {"col_name": "surname"},
+        ],
+        "blocking_rules": ["l.city = r.city", "l.surname = r.surname"],
+        "max_iterations": 3,
+        "device_pair_generation": "on",
+        "max_resident_pairs": 1024,
+        "pair_batch_size": 1 << 16,
+    }
+    linker = Splink(settings, df=_people(900, 1))
+    linker.get_scored_comparisons()
+    return linker
+
+
+def _link_tf_job():
+    """A tiny two-frame link plus the TF pass (the c3 cell's path)."""
+    settings = {
+        "link_type": "link_only",
+        "comparison_columns": [
+            {"col_name": "first_name", "term_frequency_adjustments": True},
+            {"col_name": "surname"},
+        ],
+        "blocking_rules": ["l.city = r.city"],
+        "max_iterations": 3,
+    }
+    df = _people(900, 2)
+    linker = Splink(settings, df_l=df.iloc[:600], df_r=df.iloc[600:])
+    linker.make_term_frequency_adjustments(linker.get_scored_comparisons())
+    return linker
+
+
+_JOBS = {"dedupe": _dedupe_job, "link_tf": _link_tf_job}
+# the stage names each job's stage_timings() has always had
+_STAGES = {
+    "dedupe": {"encode", "pairgen_plan", "gammas_patterns", "em",
+               "score_patterns"},
+    "link_tf": {"encode", "blocking", "gammas", "em", "score"},
+}
+# the span names of ISSUE 27 §2 that each job has to close, and the four
+# that the first chip trace asked for (what was left in the roots' self time)
+_SPANS = {
+    "dedupe": {"init", "scored_comparisons", "assemble_frame", "lut_gather",
+               "concat_frame", "d2h_wait", "h2d_put", "pack_table",
+               "gamma_histogram", "decode_pairs"},
+    "link_tf": {"init", "scored_comparisons", "tf", "assemble_frame",
+                "d2h_wait", "h2d_put", "tf_align_check", "tf_token_ids",
+                "tf_device", "tf_frame", "pair_bound", "pack_table",
+                "gamma_histogram"},
+}
+
+
+@pytest.fixture(scope="module", params=sorted(_JOBS))
+def job(request):
+    linker = _JOBS[request.param]()
+    return request.param, linker, spans(run=linker.run_id)
+
+
+# ---------------------------------------------------------------------------
+# the table itself
+# ---------------------------------------------------------------------------
+
+
+def test_parent_linkage_and_self_time(scope):
+    with StageTimer("outer") as outer:
+        time.sleep(0.02)
+        with span("a", rows=3) as a:
+            time.sleep(0.03)
+            with span("leaf"):
+                time.sleep(0.01)
+        with span("b"):
+            time.sleep(0.02)
+    table = spans()
+    by_name = {s["name"]: s for s in table}
+    assert [s["name"] for s in table] == ["outer", "a", "leaf", "b"]
+    assert [s["id"] for s in table] == [0, 1, 2, 3]
+    assert by_name["outer"]["parent"] is None
+    assert by_name["a"]["parent"] == by_name["b"]["parent"] == 0
+    assert by_name["leaf"]["parent"] == by_name["a"]["id"]
+    assert by_name["outer"]["kind"] == "stage" and by_name["a"]["kind"] == "span"
+    assert by_name["a"]["counts"] == {"rows": 3}
+    own = _self_seconds(table)
+    # outer slept 0.02 s itself; a 0.03 s; the rest is its children's
+    assert 0.015 < own[0] < 0.04
+    assert 0.025 < own[1] < 0.05
+    assert outer.elapsed == pytest.approx(
+        by_name["outer"]["t1"] - by_name["outer"]["t0"]
+    )
+    assert outer.elapsed > a.elapsed > 0.04
+    # only the stage is a stage timing
+    assert list(stage_timings()) == ["outer"]
+
+
+def test_span_closes_and_records_when_body_raises(scope):
+    with pytest.raises(RuntimeError, match="boom"):
+        with StageTimer("failing"):
+            with span("inner"):
+                raise RuntimeError("boom")
+    table = spans()
+    assert [s["name"] for s in table] == ["failing", "inner"]
+    assert all(s["t1"] >= s["t0"] for s in table)
+    assert profiling.current_span_id() is None  # the stack unwound
+    with span("after"):
+        pass
+    assert spans()[-1]["parent"] is None
+
+
+def test_pool_thread_spans_leave_the_driver_stack_alone(scope):
+    """Spans opened on D2H-pool-like worker threads nest under nothing of
+    the driver's and never disturb its stack, also under contention."""
+    barrier = threading.Barrier(8, timeout=10)
+
+    def worker(k):
+        barrier.wait()
+        for _ in range(50):
+            with span("pool_span", k=k):
+                with span("pool_leaf"):
+                    pass
+        return threading.get_ident()
+
+    with StageTimer("driver") as driver:
+        with ThreadPoolExecutor(max_workers=8) as pool:
+            idents = [f.result(timeout=30) for f in
+                      [pool.submit(worker, k) for k in range(8)]]
+        assert profiling.current_span_id() == driver.span["id"]
+        with span("driver_child"):
+            pass
+    table = spans()
+    assert [s["id"] for s in table] == list(range(len(table)))  # no id lost
+    assert len(table) == 2 + 8 * 50 * 2
+    mine = threading.get_ident()
+    assert mine not in idents
+    by_id = {s["id"]: s for s in table}
+    for s in table:
+        if s["name"] == "pool_span":
+            assert s["parent"] is None and s["thread"] != mine
+        elif s["name"] == "pool_leaf":
+            assert by_id[s["parent"]]["name"] == "pool_span"
+            assert by_id[s["parent"]]["thread"] == s["thread"]
+        elif s["name"] == "driver_child":
+            assert s["parent"] == driver.span["id"] and s["thread"] == mine
+
+
+def test_generator_stage_closed_out_of_order_keeps_the_stack(scope):
+    """A stage wrapped round a generator stays open while the consumer
+    works; closing the generator late must not pop someone else's span."""
+
+    def chunks():
+        with StageTimer("stream"):
+            yield 1
+            yield 2
+
+    gen = chunks()
+    next(gen)
+    with StageTimer("consumer") as consumer:
+        gen.close()  # "stream" closes while "consumer" is innermost
+        assert profiling.current_span_id() == consumer.span["id"]
+    assert profiling.current_span_id() is None
+    assert set(stage_timings()) == {"stream", "consumer"}
+
+
+def test_count_targets_the_innermost_stage(scope):
+    count(batches=1)  # nothing open: nothing to count into
+    with StageTimer("stage") as st:
+        with span("sub"):
+            count(batches=1)
+            count(batches=1, pairs=7)
+    assert st.counts == {"batches": 2, "pairs": 7}
+    by_name = {s["name"]: s for s in spans()}
+    assert by_name["stage"]["counts"] == {"batches": 2, "pairs": 7}
+    assert by_name["sub"]["counts"] == {}
+
+
+def test_add_closed_lands_under_the_open_span_or_nowhere(scope):
+    add_closed("jax_lower", "build", 0.5, fun="f")  # nothing open: dropped
+    assert spans() == []
+    with StageTimer("stage") as st:
+        add_closed("jax_lower", "build", 0.25, fun="f")
+    build = spans()[-1]
+    assert build["kind"] == "build" and build["parent"] == st.span["id"]
+    assert build["t1"] - build["t0"] == pytest.approx(0.25)
+    assert build["counts"] == {"fun": "f"}
+    assert list(stage_timings()) == ["stage"]  # a build span is no stage
+
+
+def test_runs_lists_scopes_in_begin_order_and_spans_are_copies():
+    a, b = begin_run("order-a"), begin_run("order-b")
+    try:
+        assert runs()[-2:] == [a, b]
+        begin_run(a)  # re-begun: moves to the end, table emptied
+        assert runs()[-2:] == [b, a]
+        with StageTimer("x", run=b):
+            pass
+        got = spans(run=b)
+        got[0]["counts"]["tampered"] = 1
+        got[0]["name"] = "y"
+        assert spans(run=b)[0]["name"] == "x"
+        assert spans(run=b)[0]["counts"] == {}
+        assert spans(run=a) == [] and spans(run="never-begun") == []
+    finally:
+        discard_run(a)
+        discard_run(b)
+    assert a not in runs() and b not in runs()
+
+
+def test_retained_runs_are_bounded():
+    ids = [begin_run(f"bound-{k}") for k in range(profiling._MAX_RETAINED_RUNS + 5)]
+    try:
+        kept = runs()
+        assert len(kept) <= profiling._MAX_RETAINED_RUNS
+        assert ids[0] not in kept and ids[-1] in kept
+    finally:
+        for run in ids:
+            discard_run(run)
+
+
+def test_one_span_stack_and_no_profiler_session_code():
+    """ISSUE 27 acceptance: the second stack and the profile_dir hook are
+    gone, everywhere."""
+    from splink_tpu.obs.tracer import Tracer
+
+    for gone in ("begin", "end", "_stack", "span", "current_id"):
+        assert not hasattr(Tracer(), gone), gone
+    for gone in ("_TRACE_DIRS", "_TRACED_STAGES", "_TRACE_ACTIVE",
+                 "set_trace_dir", "_TIMINGS"):
+        assert not hasattr(profiling, gone), gone
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    hits = []
+    for top in ("splink_tpu", "docs"):
+        for folder, _dirs, files in os.walk(os.path.join(root, top)):
+            for name in files:
+                if name.endswith((".py", ".md", ".json")):
+                    path = os.path.join(folder, name)
+                    with open(path, encoding="utf-8") as f:
+                        if "profile_dir" in f.read():
+                            hits.append(path)
+    assert hits == []
+    with open(os.path.join(root, "splink_tpu", "utils", "profiling.py")) as f:
+        source = f.read()
+    code = source.split('"""', 2)[2]  # past the module docstring
+    for session in ("start_trace", "stop_trace", "profiler.trace("):
+        assert session not in code, session
+
+
+# ---------------------------------------------------------------------------
+# the two jobs
+# ---------------------------------------------------------------------------
+
+
+def test_stage_timings_hold_only_the_stage_names(job):
+    name, linker, table = job
+    timings = stage_timings(run=linker.run_id)
+    assert set(timings) == _STAGES[name]
+    stages = sorted(
+        (s for s in table if s["kind"] == "stage"), key=lambda s: s["t1"]
+    )
+    expect: dict[str, list[float]] = {}
+    for s in stages:
+        expect.setdefault(s["name"], []).append(s["t1"] - s["t0"])
+    assert timings == expect
+    assert list(timings) == list(expect)  # same order: as the stages closed
+
+
+def test_every_named_span_appears_in_its_job(job):
+    name, _linker, table = job
+    seen = {s["name"] for s in table}
+    assert _SPANS[name] <= seen, _SPANS[name] - seen
+    kinds = {s["name"]: s["kind"] for s in table}
+    for root in ("init", "scored_comparisons"):
+        assert kinds[root] == "call"
+    for sub in _SPANS[name] - {"init", "scored_comparisons", "tf"}:
+        assert kinds[sub] == "span", sub
+    # the granularity rule: a job closes on the order of 10^2 spans
+    assert len(table) < 400, len(table)
+
+
+def test_each_public_call_is_one_root_whose_self_time_is_small(job):
+    name, _linker, table = job
+    roots = [s for s in table if s["parent"] is None]
+    calls = ["init", "scored_comparisons"] + (["tf"] if name == "link_tf" else [])
+    assert [s["name"] for s in roots] == calls
+    assert all(s["kind"] == "call" for s in roots)
+    own = _self_seconds(table)
+    for s in roots[1:]:  # init has no children: all of it is self time
+        dur = s["t1"] - s["t0"]
+        assert own[s["id"]] < 0.10 * dur, (s["name"], own[s["id"]], dur)
+    # and everything else hangs below one of them
+    by_id = {s["id"]: s for s in table}
+    for s in table:
+        top = s
+        while top["parent"] is not None:
+            top = by_id[top["parent"]]
+        assert top["kind"] == "call"
+
+
+def test_counts_at_the_stage_boundaries(job):
+    name, linker, table = job
+    by_name: dict[str, list[dict]] = {}
+    for s in table:
+        by_name.setdefault(s["name"], []).append(s)
+    [scored] = by_name["scored_comparisons"]
+    pairs = scored["counts"]["pairs"]
+    assert pairs > 0
+    assert by_name["init"][0]["counts"] == {"rows": 900}
+    assert by_name["encode"][0]["counts"] == {"rows": 900}
+    [em] = by_name["em"]
+    assert em["counts"]["pairs"] == pairs
+    assert 1 <= em["counts"]["iterations"] <= 3
+    assert em["counts"]["patterns"] >= 1
+    if name == "dedupe":
+        [gp] = by_name["gammas_patterns"]
+        assert gp["counts"]["pairs"] == pairs and gp["counts"]["batches"] >= 2
+        [sp] = by_name["score_patterns"]
+        assert sp["counts"]["pairs"] == pairs
+        assert sp["counts"]["batches"] == len(by_name["assemble_frame"])
+        assert sum(s["counts"]["rows"] for s in by_name["lut_gather"]) == pairs
+        [concat] = by_name["concat_frame"]
+        assert concat["counts"] == {
+            "chunks": sp["counts"]["batches"], "rows": pairs
+        }
+    else:
+        assert by_name["blocking"][0]["counts"] == {"pairs": pairs}
+        assert by_name["gammas"][0]["counts"] == {"pairs": pairs, "batches": 1}
+        assert by_name["score"][0]["counts"] == {"pairs": pairs, "batches": 1}
+        [frame] = by_name["assemble_frame"]
+        assert frame["counts"]["rows"] == pairs
+        assert frame["counts"]["string_columns"] >= 2
+        assert frame["counts"]["columns"] > frame["counts"]["string_columns"]
+        assert by_name["tf"][0]["counts"] == {"rows": pairs}
+    assert all(s["counts"]["bytes"] > 0 for s in by_name["d2h_wait"])
+    assert all(s["counts"]["bytes"] > 0 for s in by_name["h2d_put"])
+
+
+def test_build_spans_under_a_fresh_linker_and_none_on_the_second_call():
+    """Every linker traces, lowers and compiles (or reads back) its own
+    pattern kernels — ROADMAP A2; a second call on the same linker reuses
+    them, so its gamma pass records no build span."""
+    linker = _dedupe_job()
+    table = spans(run=linker.run_id)
+    by_id = {s["id"]: s for s in table}
+    under = [
+        s for s in table
+        if s["kind"] == "build" and by_id[s["parent"]]["name"] == "gammas_patterns"
+    ]
+    names = {s["name"] for s in under}
+    assert {"jax_lower", "jax_backend_compile"} <= names, names
+    assert any(s["counts"]["fun"] == "jit(fn)" for s in under)
+    assert all(
+        s["counts"]["cache_hit"] in (0, 1)
+        for s in under if s["name"] == "jax_backend_compile"
+    )
+    assert all(s["t1"] - s["t0"] >= 1e-3 for s in under if s["name"] == "jax_trace")
+    # second pass over the same linker: the kernels are cached on its plan
+    before = len(table)
+    linker._pattern_counts = None
+    linker._ensure_pattern_ids()
+    again = spans(run=linker.run_id)[before:]
+    assert [s["name"] for s in again if s["kind"] == "stage"] == ["gammas_patterns"]
+    assert [s for s in again if s["kind"] == "build"] == []
+
+
+def test_telemetry_record_carries_the_sub_spans(tmp_path):
+    settings = {
+        "link_type": "dedupe_only",
+        "comparison_columns": [{"col_name": "first_name"}],
+        "blocking_rules": ["l.city = r.city"],
+        "max_iterations": 2,
+        "telemetry_dir": str(tmp_path),
+    }
+    linker = Splink(settings, df=_people(200, 3))
+    df_e = linker.get_scored_comparisons()
+    linker.close_telemetry()
+    [path] = [p for p in os.listdir(tmp_path) if p.endswith(".jsonl")]
+    with open(tmp_path / path) as f:
+        events = [json.loads(line) for line in f]
+    emitted = {e["span_id"]: e for e in events if e["type"] == "span"}
+    table = {s["id"]: s for s in spans(run=linker.run_id)}
+    assert set(table) <= set(emitted)  # every table span is in the record
+    for sid, s in table.items():
+        e = emitted[sid]
+        assert (e["name"], e["kind"], e["parent_id"]) == (
+            s["name"], s["kind"], s["parent"]
+        )
+        assert e["dur_s"] == pytest.approx(s["t1"] - s["t0"])
+    subs = [e for e in emitted.values() if e["kind"] == "span"]
+    assert {"assemble_frame", "d2h_wait", "h2d_put"} <= {e["name"] for e in subs}
+    assert all(e["parent_id"] in emitted for e in subs)
+    [frame] = [e for e in subs if e["name"] == "assemble_frame"]
+    assert frame["attrs"]["rows"] == len(df_e)
+    assert emitted[frame["parent_id"]]["name"] == "scored_comparisons"
+    # record-only spans (run, em iterations) never collide with table ids
+    assert all(sid < 0 for sid, e in emitted.items()
+               if e["kind"] in ("run", "em_iteration"))
+    counters = [e for e in events if e["type"] == "metrics"][-1]["counters"]
+    assert counters["rows_encoded"] == 200
+    assert counters["pairs_blocked"] == len(df_e)
+    assert counters["pairs_gamma_scored"] == len(df_e)
+    assert counters["pairs_scored_output"] == len(df_e)
+
+
+def test_standalone_runcontext_span_grows_no_table(tmp_path):
+    from splink_tpu.obs.events import read_events
+    from splink_tpu.obs.runtime import RunContext
+
+    ctx = RunContext.from_settings({"telemetry_dir": str(tmp_path)})
+    before = {run: len(spans(run=run)) for run in runs()}
+    default_before = len(spans(run=""))
+    for k in range(3):
+        with ctx.span("serve_batch", batch=k):
+            pass
+    ctx.close()
+    assert {run: len(spans(run=run)) for run in runs()} == before
+    assert len(spans(run="")) == default_before
+    got = [e for e in read_events(ctx.sink.path) if e["type"] == "span"]
+    assert [e["attrs"]["batch"] for e in got] == [0, 1, 2]
+    assert len({e["span_id"] for e in got}) == 3 and all(e["span_id"] < 0 for e in got)
+
+
+def test_spans_lie_in_a_profiler_trace_by_name(tmp_path, scope):
+    """With a profiler session active the spans are TraceAnnotations in the
+    host plane, on the trace's clock: no profile_dir, no second session."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with StageTimer("span_test_stage"):
+            with span("span_test_sub"):
+                jnp.dot(jnp.ones((64, 64)), jnp.ones((64, 64))).block_until_ready()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("span_test_stage", "span_test_sub"):
+                    found[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert set(found) == {"span_test_stage", "span_test_sub"}
+    (s0, sd), (c0, cd) = found["span_test_stage"], found["span_test_sub"]
+    assert s0 <= c0 and c0 + cd <= s0 + sd  # nested on the trace's clock
+
+
+# ---------------------------------------------------------------------------
+# device names
+# ---------------------------------------------------------------------------
+
+
+def test_jitted_programs_have_names_of_their_own():
+    """Every jitted program on the two cells' paths names its XLA module;
+    only the two gamma programs are ``fn`` (what the benchmark's
+    gamma_hbm_roofline matches as ``jit_fn(``)."""
+    import jax.numpy as jnp
+
+    from splink_tpu import blocking_device, em, term_frequencies
+    from splink_tpu.data import encode_table
+    from splink_tpu.gammas import GammaProgram, _pattern_counts_batch
+    from splink_tpu.pairgen import make_virtual_pattern_fn
+    from splink_tpu.settings import complete_settings_dict
+
+    settings = complete_settings_dict({
+        "link_type": "dedupe_only",
+        "comparison_columns": [{"col_name": "first_name"}],
+        "blocking_rules": ["l.city = r.city"],
+    })
+    program = GammaProgram(settings, encode_table(_people(50, 4), settings),
+                           float_dtype=jnp.float32)
+    gamma = [
+        make_virtual_pattern_fn(program, 64, n_prev=0, has_uid_mask=False),
+        program._flagged_factory(program._exact_gamma_body()),
+    ]
+    assert [f.__name__ for f in gamma] == ["fn", "fn"]
+    others = [
+        blocking_device.make_segment_sort_fn(),
+        blocking_device.make_bucket_csr_fn(),
+        blocking_device.make_pair_emit_fn(64, 0, False, False),
+        blocking_device.make_chunk_digest_fn(),
+        blocking_device.make_chunk_digest_compact_fn(),
+        term_frequencies._device_token_stats_fn(8),
+        term_frequencies._device_token_gather_fn(8),
+        term_frequencies.make_tf_fold_fn(((0, "first_name", 1),)),
+        program._gamma_batch_fn,
+        _pattern_counts_batch,
+        em.run_em,
+        em.score_pairs,
+        em.score_pairs_with_intermediates,
+        em.score_pairs_with_logits,
+        em.score_pairs_with_intermediates_logits,
+    ]
+    names = [f.__name__ for f in others]
+    assert "fn" not in names and "fold" not in names
+    assert len(set(names)) == len(names), names
+    assert {n for n in names if n.startswith("block_")} == {
+        "block_segment_sort", "block_bucket_csr", "block_pair_emit",
+        "block_chunk_digest", "block_chunk_digest_compact",
+    }
+    assert {n for n in names if n.startswith("tf_")} == {
+        "tf_token_stats", "tf_token_gather", "tf_fold",
+    }
