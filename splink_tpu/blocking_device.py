@@ -76,8 +76,11 @@ from .pairgen import (
     _units_for_cross_join,
     _units_for_self_join,
     compile_residual_device,
+    residual_signatures,
     unit_decode,
 )
+from .utils import kernel_registry
+from .utils.kernel_registry import mesh_key
 
 logger = logging.getLogger("splink_tpu")
 
@@ -226,7 +229,31 @@ def make_pair_emit_fn(batch_size: int, n_prev: int, has_uid_mask: bool,
     host-side with vectorised numpy instead — on accelerator backends the
     on-device compaction stands: it halves the D2H bytes (whether D2H is
     the scarce resource there is not measured on this machine).
+
+    The kernel closes over its arguments and nothing else, so it is the
+    PROCESS's (utils/kernel_registry), keyed by them — the mesh by value,
+    each residual by its signature (pairgen.compile_residual_device): a
+    second linker's plan gets the same jitted function and builds nothing.
+    A residual without a signature cannot be keyed; that kernel is built
+    for its caller alone.
     """
+    prev_res = tuple(prev_res)
+    signed = residual_signatures((own_res, *prev_res))
+    key = None if signed is None else (
+        "pair_emit", batch_size, n_prev, bool(has_uid_mask),
+        bool(rank_filter), mesh_key(mesh), bool(compact), signed,
+    )
+    return kernel_registry.lookup(
+        "pair_emit", key,
+        functools.partial(
+            _build_pair_emit_fn, batch_size, n_prev, has_uid_mask,
+            rank_filter, own_res, prev_res, mesh, compact,
+        ),
+    )
+
+
+def _build_pair_emit_fn(batch_size, n_prev, has_uid_mask, rank_filter,
+                        own_res, prev_res, mesh, compact):
     import jax
     import jax.numpy as jnp
 
@@ -322,8 +349,8 @@ class DeviceBlockPlan:
     uid_codes: np.ndarray | None  # (n,) int32 when duplicate uids exist
     res_ops: list[np.ndarray] = field(default_factory=list)
     chunk: int = CHUNK  # unit extent bound (int32/f32-exactness margin)
-    # jitted emission kernels keyed by (rule, batch, mesh): reusing the
-    # closure is what makes a warmup emission actually warm the next one
+    # this plan's emission kernels by (rule, batch, mesh, compaction): its
+    # view of the process's registry (make_pair_emit_fn), asked once a key
     kernel_cache: dict = field(default_factory=dict)
 
     @property
@@ -580,9 +607,7 @@ def _rule_emit_setup(plan, r, rp, ctx, mesh, pos_cache):
     put = ctx["put"]
     order_dev = put(rp.order)
     units_dev = tuple(put(a) for a in (rp.ua, rp.la, rp.ub, rp.lb))
-    kkey = (
-        r, rule_bs, None if mesh is None else id(mesh), ctx["compact_dev"],
-    )
+    kkey = (r, rule_bs, mesh_key(mesh), ctx["compact_dev"])
     fn = plan.kernel_cache.get(kkey)
     if fn is None:
         fn = plan.kernel_cache[kkey] = make_pair_emit_fn(
@@ -1099,9 +1124,7 @@ def emit_pairs_sharded(
                 pos_cache[rule_bs] = pos_rule
             order_dev = put(rp.order)
             units_dev = tuple(put(a) for a in (rp.ua, rp.la, rp.ub, rp.lb))
-            kkey = (
-                r, rule_bs, None if mesh is None else id(mesh), compact_dev,
-            )
+            kkey = (r, rule_bs, mesh_key(mesh), compact_dev)
             fn = plan.kernel_cache.get(kkey)
             if fn is None:
                 fn = plan.kernel_cache[kkey] = make_pair_emit_fn(

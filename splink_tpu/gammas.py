@@ -19,9 +19,12 @@ which is free VPU work compared to extra HBM gather passes.
 
 from __future__ import annotations
 
+import copy
 import functools
+import json
 import logging
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +42,7 @@ from .ops.gamma import (
     bucket_similarity,
 )
 from .settings import comparison_column_name
+from .utils import kernel_registry
 from .utils.logging_utils import log_jaxpr
 from .utils.profiling import count, fetch, span
 
@@ -752,8 +756,227 @@ def _spec_gamma(col_settings: dict, ctx: PairContext) -> jnp.ndarray:
     raise ValueError(f"Unknown comparison kind {kind!r}")
 
 
+class _Parts(NamedTuple):
+    """Everything the gamma kernels close over that is not an argument. A
+    kernel is built from these and nothing else — never from a
+    :class:`GammaProgram`, whose packed device table and encoded table a
+    registered closure would pin for the life of the process."""
+
+    cols: tuple  # the comparison columns' settings (private copies)
+    layout: dict  # packed-table field layout: lane metadata, no data
+    two_phase_div: int | None
+    strides: tuple  # mixed-radix pattern strides
+    n_patterns: int
+
+
+def _layout_signature(layout: dict) -> tuple:
+    """The packed-table layout as a hashable value: per field its kind and
+    every lane index / lane slice. Metadata only — table contents and row
+    counts are arguments, and shapes are jax's business."""
+
+    def plain(v):
+        return (v.start, v.stop) if isinstance(v, slice) else v
+
+    return tuple(sorted(
+        (name, type(f).__name__, tuple(plain(getattr(f, a)) for a in f.__slots__))
+        for name, f in layout.items()
+    ))
+
+
+def _signature(cols, layout, two_phase_div, float_dtype):
+    """The registry key of a gamma program — the comparison columns'
+    settings by CONTENT (a caller may deep-copy its settings per job), the
+    layout, the two-phase divisor and the float dtype; level counts, strides
+    and the pattern count follow from the columns. None when it cannot be
+    signed: a ``custom`` column runs whatever callable is registered under
+    its name right now, and settings json cannot write have no canonical
+    form. Such a program is built per linker."""
+    if any((c.get("comparison") or {}).get("kind") == "custom" for c in cols):
+        return None
+    try:
+        cols_json = json.dumps(cols, sort_keys=True)
+    except (TypeError, ValueError):
+        return None
+    return (
+        cols_json,
+        _layout_signature(layout),
+        two_phase_div,
+        jnp.dtype(float_dtype).name,
+    )
+
+
+# ONE body template, instantiated twice: the two-phase body (primary on a
+# single device) and the exact body (mesh sharding — survivor compaction
+# does not partition trivially — and the overflow-redo twin). Both return
+# (G, overflow_count); the property tests pin them bit-identical on the
+# gamma output.
+def _make_gamma_body(parts: _Parts, two_phase_div):
+    cols, layout = parts.cols, parts.layout
+
+    # The packed table is an explicit argument, NOT a closure capture: a
+    # captured device array becomes a jaxpr constant, and at millions of
+    # rows that constant is serialised into the compiled program
+    # (trace_audit TA-CONST pins this).
+    def _gamma_body(packed, idx_l, idx_r):
+        # named scopes: how the device trace's ops say which part
+        # of the program they belong to (docs/observability.md)
+        with jax.named_scope("row_gather"):
+            rows_l = packed[idx_l]
+            rows_r = packed[idx_r]
+        ctx = PairContext(layout, rows_l, rows_r, two_phase_div)
+        gammas = []
+        for c in cols:
+            with jax.named_scope(f"cmp/{comparison_column_name(c)}"):
+                gammas.append(_spec_gamma(c, ctx))
+        return jnp.stack(gammas, axis=1), ctx.overflow_count()
+
+    return _gamma_body
+
+
+def _mesh_gamma_body(parts: _Parts, mesh):
+    """The exact gamma body as a per-shard program over the mesh's
+    data axis (packed table replicated, pair indices and G split).
+
+    ``jit(out_shardings=...)`` alone cannot do this on a TPU: the
+    string kernels are Pallas (Mosaic) custom calls there, which XLA's
+    partitioner does not split — it would gather every pair onto every
+    chip in front of the call. Every op in the body is per-pair, so
+    under shard_map each chip runs the single-device program on its
+    own ``batch / N`` slice. Two-phase survivor compaction
+    (jnp.nonzero along the sharded pair axis) would need a
+    cross-device prefix sum, so the pruning stays a single-device
+    optimisation; tests/test_jw_two_phase.py pins the two bodies
+    bit-identical."""
+    from jax.sharding import PartitionSpec as P
+
+    from .parallel.mesh import DATA_AXIS
+
+    return jax.shard_map(
+        _make_gamma_body(parts, None),
+        mesh=mesh,
+        in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
+        out_specs=(P(DATA_AXIS), P()),
+        # the kernels' scans start from unvarying constants (a carry
+        # type mismatch under the check); the overflow count the exact
+        # body returns is the constant 0 on every shard
+        check_vma=False,
+    )
+
+
+# ONE pattern-kernel template over a gamma body. The returned pid array
+# carries one extra trailing element: the batch's overflow flag (0/1), so
+# the per-batch host read that fetches the ids anyway also learns whether
+# the two-phase survivor capacity blew. An overflowed batch contributes
+# NOTHING to the histogram — the driver redoes it through the exact twin,
+# and int32 addition commuting makes the late redo bit-identical.
+def _make_pattern_kernel(parts: _Parts, gamma_body, append_flag=True):
+    strides_dev = jnp.asarray(parts.strides, jnp.int32)
+    n_patterns = parts.n_patterns
+
+    def _pattern_kernel(packed, idx_l, idx_r, valid, acc):
+        G, ovf = gamma_body(packed, idx_l, idx_r)
+        G = G.astype(jnp.int32)
+        pid = jnp.sum(
+            (G + 1) * strides_dev[None, :], axis=1, dtype=jnp.int32
+        )
+        masked = jnp.where(
+            jnp.arange(pid.shape[0], dtype=jnp.int32) < valid,
+            pid,
+            n_patterns,
+        )
+        ovf_flag = (ovf > 0).astype(jnp.int32)
+        acc = acc + int32_histogram(
+            masked, n_patterns + 1
+        ) * (1 - ovf_flag)
+        if pattern_ids_fit_uint16(n_patterns):
+            # narrow on device: halves the per-batch D2H (all
+            # real ids < n_patterns <= 65535; padding-tail pids
+            # are sliced off host-side before use)
+            pid = pid.astype(jnp.uint16)
+        if append_flag:
+            # overflow flag rides as pid[-1]; mesh kernels skip
+            # it (a B+1 output cannot shard evenly, and the
+            # exact body they compose never overflows)
+            pid = jnp.concatenate(
+                [pid, ovf_flag.astype(pid.dtype)[None]]
+            )
+        return pid, acc
+
+    return _pattern_kernel
+
+
+# The jitted programs, each built from a _Parts alone: what
+# GammaProgram._kernel hands the registry as ``build``.
+
+
+def _jit_gamma_body(parts: _Parts):
+    return jax.jit(_make_gamma_body(parts, parts.two_phase_div))
+
+
+def _jit_gamma_safe(parts: _Parts):
+    """Two-phase body with the overflow redo ON DEVICE (lax.cond —
+    jit-composable, so no caller can drop the overflow flag the
+    tuple-returning fns carry)."""
+    body = _make_gamma_body(parts, parts.two_phase_div)
+    exact_body = _make_gamma_body(parts, None)
+
+    def _safe_body(packed, idx_l, idx_r):
+        G, ovf = body(packed, idx_l, idx_r)
+        return jax.lax.cond(
+            ovf > 0,
+            lambda ops: exact_body(*ops)[0],
+            lambda ops: G,
+            (packed, idx_l, idx_r),
+        )
+
+    return jax.jit(_safe_body)
+
+
+def _jit_gamma_flagged(parts: _Parts, exact: bool):
+    """Host-batched G paths read back one array per batch; the overflow
+    flag rides as one extra G row (int8 flag at [-1, 0]) so detecting it
+    costs no second device fetch."""
+    body = _make_gamma_body(parts, None if exact else parts.two_phase_div)
+
+    # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches
+    # the XLA module ``jit_fn(`` (see pairgen.make_virtual_pattern_fn,
+    # the only other program of that name).
+    def fn(packed, idx_l, idx_r):
+        G, ovf = body(packed, idx_l, idx_r)
+        flag_row = (
+            jnp.zeros((1, G.shape[1]), G.dtype)
+            .at[0, 0]
+            .set((ovf > 0).astype(G.dtype))
+        )
+        return jnp.concatenate([G, flag_row])
+
+    return jax.jit(fn)
+
+
+def _jit_pattern_batch(parts: _Parts, exact: bool):
+    body = _make_gamma_body(parts, None if exact else parts.two_phase_div)
+    return jax.jit(_make_pattern_kernel(parts, body))
+
+
+def _jit_pattern_batch_mesh(parts: _Parts, mesh):
+    from .parallel.mesh import pair_sharding, replicated
+
+    return jax.jit(
+        _make_pattern_kernel(
+            parts, _mesh_gamma_body(parts, mesh), append_flag=False
+        ),
+        out_shardings=(pair_sharding(mesh), replicated(mesh)),
+    )
+
+
 class GammaProgram:
-    """Compiled gamma computation bound to one encoded table."""
+    """One encoded table packed on the device, and the gamma kernels of its
+    settings. The table (``_packed``) belongs to this program; the jitted
+    kernels take it as an argument and are the PROCESS's — shared through
+    ``utils.kernel_registry`` with every program whose comparison columns,
+    packed layout, two-phase divisor and float dtype are equal, so a second
+    linker on the same model traces, lowers and reads back nothing. A
+    program that cannot be signed (:func:`_signature`) builds its own."""
 
     def __init__(self, settings: dict, table: EncodedTable, float_dtype=jnp.float32):
         self.settings = settings
@@ -785,232 +1008,129 @@ class GammaProgram:
             self._packed = jnp.asarray(packed)
         self._layout = layout
 
-        cols = settings["comparison_columns"]
-
-        # ONE body template, instantiated twice: the two-phase body (primary
-        # on a single device) and the exact body (mesh sharding — survivor
-        # compaction does not partition trivially — and the overflow-redo
-        # twin). Both return (G, overflow_count); the property tests pin
-        # them bit-identical on the gamma output.
-        def _make_gamma_body(two_phase_div):
-            def _gamma_body(packed, idx_l, idx_r):
-                # named scopes: how the device trace's ops say which part
-                # of the program they belong to (docs/observability.md)
-                with jax.named_scope("row_gather"):
-                    rows_l = packed[idx_l]
-                    rows_r = packed[idx_r]
-                ctx = PairContext(layout, rows_l, rows_r, two_phase_div)
-                gammas = []
-                for c in cols:
-                    with jax.named_scope(f"cmp/{comparison_column_name(c)}"):
-                        gammas.append(_spec_gamma(c, ctx))
-                return jnp.stack(gammas, axis=1), ctx.overflow_count()
-
-            return _gamma_body
-
-        self._make_gamma_body = _make_gamma_body
-        _gamma_body = _make_gamma_body(self.two_phase_div)
-
-        # The packed table is an explicit argument, NOT a closure capture: a
-        # captured device array becomes a jaxpr constant, and at millions of
-        # rows that constant is serialised into the compiled program
-        # (trace_audit TA-CONST pins this).
-        _gamma_batch_p = jax.jit(_gamma_body)
-
-        # _gamma_batch is the convenience path (bench.py's jitted score
-        # loop, ad-hoc scoring) and it must be IMPOSSIBLE to misuse: when
-        # the two-phase survivor capacity blows, it redoes the batch
-        # through the exact body ON DEVICE (lax.cond — jit-composable, so
-        # no caller can drop the overflow flag the tuple-returning fns
-        # carry). The double-buffered host paths keep using the flagged
-        # variants below, whose host-side redo overlaps transfers.
-        if self.two_phase_div:
-            _exact_body = self._exact_gamma_body()
-
-            def _safe_body(packed, idx_l, idx_r):
-                G, ovf = _gamma_body(packed, idx_l, idx_r)
-                return jax.lax.cond(
-                    ovf > 0,
-                    lambda ops: _exact_body(*ops)[0],
-                    lambda ops: G,
-                    (packed, idx_l, idx_r),
-                )
-
-            _gamma_safe_p = jax.jit(_safe_body)
-        else:
-            _gamma_safe_p = lambda packed, il, ir: _gamma_batch_p(  # noqa: E731
-                packed, il, ir
-            )[0]
-        self._gamma_batch = lambda il, ir: _gamma_safe_p(self._packed, il, ir)
-        # the pure (packed-explicit) jitted fn, for composition into larger
-        # jitted programs (pairgen's virtual pair kernels) without turning
-        # the packed table into a jaxpr constant; returns (G, overflow)
-        self._gamma_batch_fn = _gamma_batch_p
-
-        # Host-batched G paths read back one array per batch; the overflow
-        # flag rides as one extra G row (int8 flag at [-1, 0]) so detecting
-        # it costs no second device fetch.
-        # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches
-        # the XLA module ``jit_fn(`` (see pairgen.make_virtual_pattern_fn,
-        # the only other program of that name).
-        def _flagged(body):
-            def fn(packed, idx_l, idx_r):
-                G, ovf = body(packed, idx_l, idx_r)
-                flag_row = (
-                    jnp.zeros((1, G.shape[1]), G.dtype)
-                    .at[0, 0]
-                    .set((ovf > 0).astype(G.dtype))
-                )
-                return jnp.concatenate([G, flag_row])
-
-            return jax.jit(fn)
-
-        _gamma_flagged_p = _flagged(_gamma_body)
-        self._gamma_batch_flagged = lambda il, ir: _gamma_flagged_p(
-            self._packed, il, ir
-        )
-        self._flagged_factory = _flagged
-        self._gamma_flagged_exact_p = None
-
-        # The compiled-artifact analogue of the reference logging its
-        # generated SQL at debug level (/root/reference/splink/gammas.py:120).
-        probe = jnp.zeros(8, jnp.int32)
-        log_jaxpr("gamma_program", self._gamma_batch, probe, probe)
-
         # Pattern-id pipeline: gamma vectors mixed-radix-encode into a single
         # pattern id (strides over levels_c + 1), the complete sufficient
         # statistic per pair. One device pass then yields BOTH the per-pair
         # ids (int16/int32 host array, 3x smaller than the gamma matrix) and
         # their histogram (EM's input); scoring afterwards is a host LUT
         # gather with no further device traffic.
+        cols = settings["comparison_columns"]
         self.level_counts = [int(c["num_levels"]) for c in cols]
         strides, self.n_patterns = pattern_strides_for(self.level_counts)
         self._pattern_strides = strides
-        if self.n_patterns <= MAX_PATTERNS:
-            strides_dev = jnp.asarray(strides, jnp.int32)
-            n_patterns = self.n_patterns
 
-            # ONE kernel template over a gamma body. The returned pid array
-            # carries one extra trailing element: the batch's overflow flag
-            # (0/1), so the per-batch host read that fetches the ids anyway
-            # also learns whether the two-phase survivor capacity blew. An
-            # overflowed batch contributes NOTHING to the histogram — the
-            # driver redoes it through the exact twin, and int32 addition
-            # commuting makes the late redo bit-identical.
-            def _make_pattern_kernel(gamma_body, append_flag=True):
-                def _pattern_kernel(packed, idx_l, idx_r, valid, acc):
-                    G, ovf = gamma_body(packed, idx_l, idx_r)
-                    G = G.astype(jnp.int32)
-                    pid = jnp.sum(
-                        (G + 1) * strides_dev[None, :], axis=1, dtype=jnp.int32
-                    )
-                    masked = jnp.where(
-                        jnp.arange(pid.shape[0], dtype=jnp.int32) < valid,
-                        pid,
-                        n_patterns,
-                    )
-                    ovf_flag = (ovf > 0).astype(jnp.int32)
-                    acc = acc + int32_histogram(
-                        masked, n_patterns + 1
-                    ) * (1 - ovf_flag)
-                    if pattern_ids_fit_uint16(n_patterns):
-                        # narrow on device: halves the per-batch D2H (all
-                        # real ids < n_patterns <= 65535; padding-tail pids
-                        # are sliced off host-side before use)
-                        pid = pid.astype(jnp.uint16)
-                    if append_flag:
-                        # overflow flag rides as pid[-1]; mesh kernels skip
-                        # it (a B+1 output cannot shard evenly, and the
-                        # exact body they compose never overflows)
-                        pid = jnp.concatenate(
-                            [pid, ovf_flag.astype(pid.dtype)[None]]
-                        )
-                    return pid, acc
+        self._sig = _signature(cols, layout, self.two_phase_div, float_dtype)
+        self._parts = _Parts(
+            # a shared kernel may retrace for new shapes long after this
+            # linker mutated or dropped its settings: it reads its own copy
+            cols=tuple(cols if self._sig is None else copy.deepcopy(cols)),
+            layout=dict(layout),
+            two_phase_div=self.two_phase_div,
+            strides=tuple(strides),
+            n_patterns=self.n_patterns,
+        )
+        # this program's kernels by (fun, variant): the registry is asked
+        # once per kernel, and an eviction cannot take a kernel in use
+        self._kernels: dict = {}
 
-                return _pattern_kernel
+        # The compiled-artifact analogue of the reference logging its
+        # generated SQL at debug level (/root/reference/splink/gammas.py:120).
+        probe = jnp.zeros(8, jnp.int32)
+        log_jaxpr("gamma_program", self._gamma_batch, probe, probe)
 
-            self._make_pattern_kernel = _make_pattern_kernel
-            self._pattern_kernel = _make_pattern_kernel(_gamma_body)
-            # overflow-redo twin: exact body, flagged like the primary so
-            # the host read path is uniform; with two-phase off the primary
-            # IS exact and nothing builds twice
-            if self.two_phase_div:
-                self._pattern_kernel_exact = _make_pattern_kernel(
-                    self._exact_gamma_body()
-                )
-            else:
-                self._pattern_kernel_exact = self._pattern_kernel
-            _pattern_batch = jax.jit(self._pattern_kernel)
-            self._pattern_batch = lambda il, ir, v, acc: _pattern_batch(
-                self._packed, il, ir, v, acc
+    def _kernel(self, fun: str, variant: tuple, build, shareable: bool = True):
+        """The jitted program ``build(parts)`` makes, from the process's
+        registry when this program has a signature and the caller could
+        sign ``variant`` (the rest of what ``build`` closes over); else
+        this program's own. ``build`` must not capture the program."""
+        fn = self._kernels.get((fun, variant))
+        if fn is None:
+            key = (
+                (fun, self._sig, variant)
+                if self._sig is not None and shareable else None
             )
-            self._pattern_batch_exact_jit = None
-        else:
-            # pattern space too large (strides overflow int32 well before the
-            # dense histogram would OOM); callers must use the gamma-matrix
-            # paths
-            self._pattern_batch = None
-            self._pattern_kernel = None
-            self._pattern_kernel_exact = None
-        self._pattern_batch_mesh_cache: dict = {}
+            fn = self._kernels[(fun, variant)] = kernel_registry.lookup(
+                fun, key, functools.partial(build, self._parts)
+            )
+        return fn
 
-    def _exact_gamma_body(self):
-        """The exact (no two-phase) gamma body — what mesh-sharded kernels
-        compose and what the overflow redo runs. (G, overflow) signature,
-        overflow always 0. One cached instance so every exact consumer
-        shares jit caches keyed on it."""
-        body = getattr(self, "_exact_body_cache", None)
-        if body is None:
-            body = self._exact_body_cache = self._make_gamma_body(None)
-        return body
+    @property
+    def _gamma_batch_fn(self):
+        """The pure (packed-explicit) jitted body, for composition into
+        larger jitted programs (pairgen's virtual pair kernels) without
+        turning the packed table into a jaxpr constant; returns
+        (G, overflow)."""
+        return self._kernel("gamma_body", (), _jit_gamma_body)
 
-    def _mesh_gamma_body(self, mesh):
-        """The exact gamma body as a per-shard program over the mesh's
-        data axis (packed table replicated, pair indices and G split).
+    def _gamma_batch(self, il, ir):
+        """The convenience path (bench.py's jitted score loop, ad-hoc
+        scoring): IMPOSSIBLE to misuse — when the two-phase survivor
+        capacity blows it redoes the batch through the exact body on
+        device. The double-buffered host paths use the flagged variants,
+        whose host-side redo overlaps transfers."""
+        if self.two_phase_div:
+            safe = self._kernel("gamma_safe", (), _jit_gamma_safe)
+            return safe(self._packed, il, ir)
+        return self._gamma_batch_fn(self._packed, il, ir)[0]
 
-        ``jit(out_shardings=...)`` alone cannot do this on a TPU: the
-        string kernels are Pallas (Mosaic) custom calls there, which XLA's
-        partitioner does not split — it would gather every pair onto every
-        chip in front of the call. Every op in the body is per-pair, so
-        under shard_map each chip runs the single-device program on its
-        own ``batch / N`` slice. Two-phase survivor compaction
-        (jnp.nonzero along the sharded pair axis) would need a
-        cross-device prefix sum, so the pruning stays a single-device
-        optimisation; tests/test_jw_two_phase.py pins the two bodies
-        bit-identical."""
-        from jax.sharding import PartitionSpec as P
-
-        from .parallel.mesh import DATA_AXIS
-
-        return jax.shard_map(
-            self._exact_gamma_body(),
-            mesh=mesh,
-            in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
-            out_specs=(P(DATA_AXIS), P()),
-            # the kernels' scans start from unvarying constants (a carry
-            # type mismatch under the check); the overflow count the exact
-            # body returns is the constant 0 on every shard
-            check_vma=False,
+    def _gamma_flagged_fn(self, exact: bool = False):
+        """The jitted flagged G kernel (packed-explicit); ``exact`` is the
+        overflow-redo twin. With two-phase off the primary IS exact and
+        nothing builds twice."""
+        exact = bool(exact and self.two_phase_div)
+        return self._kernel(
+            "gamma_flagged", (exact,),
+            functools.partial(_jit_gamma_flagged, exact=exact),
         )
 
+    def _gamma_batch_flagged(self, il, ir):
+        return self._gamma_flagged_fn()(self._packed, il, ir)
+
     def _gamma_batch_flagged_exact(self, il, ir):
-        """Exact-twin flagged batch (for redoing an overflowed G batch)."""
-        if self.two_phase_div is None:
-            return self._gamma_batch_flagged(il, ir)
-        if self._gamma_flagged_exact_p is None:
-            self._gamma_flagged_exact_p = self._flagged_factory(
-                self._exact_gamma_body()
-            )
-        return self._gamma_flagged_exact_p(self._packed, il, ir)
+        """Exact-twin flagged batch (for redoing an overflowed G batch);
+        it only compiles if a two-phase batch ever overflows."""
+        return self._gamma_flagged_fn(exact=True)(self._packed, il, ir)
+
+    @property
+    def _pattern_kernel(self):
+        """The un-jitted pattern kernel over the primary body (audits)."""
+        return _make_pattern_kernel(
+            self._parts, _make_gamma_body(self._parts, self.two_phase_div)
+        )
+
+    def _pattern_batch_fn(self, exact: bool = False):
+        exact = bool(exact and self.two_phase_div)
+        return self._kernel(
+            "pattern_batch", (exact,),
+            functools.partial(_jit_pattern_batch, exact=exact),
+        )
+
+    def _run_pattern_batch(self, il, ir, valid, acc):
+        return self._pattern_batch_fn()(self._packed, il, ir, valid, acc)
+
+    @property
+    def _pattern_batch(self):
+        """The pattern-batch call, or None when the pattern space is too
+        large (strides overflow int32 well before the dense histogram would
+        OOM) and callers must use the gamma-matrix paths. A property, not an
+        attribute: a bound method stored on the program would be a reference
+        cycle, and the packed table would stay on the device until the
+        cyclic collector happened to run."""
+        return self._run_pattern_batch if self.n_patterns <= MAX_PATTERNS else None
 
     def _pattern_batch_exact(self, il, ir, valid, acc):
-        """Exact-twin pattern batch (overflow redo). Jitted lazily: it only
-        compiles if a two-phase batch ever overflows."""
-        if self.two_phase_div is None:
-            return self._pattern_batch(il, ir, valid, acc)
-        if self._pattern_batch_exact_jit is None:
-            self._pattern_batch_exact_jit = jax.jit(self._pattern_kernel_exact)
-        return self._pattern_batch_exact_jit(self._packed, il, ir, valid, acc)
+        """Exact-twin pattern batch (overflow redo): flagged like the
+        primary so the host read path is uniform; it only compiles if a
+        two-phase batch ever overflows."""
+        return self._pattern_batch_fn(exact=True)(
+            self._packed, il, ir, valid, acc
+        )
+
+    def _exact_gamma_body(self):
+        """The exact (no two-phase) gamma body, un-jitted — what the
+        overflow redo runs and the mesh kernels compose. (G, overflow)
+        signature, overflow always 0."""
+        return _make_gamma_body(self._parts, None)
 
     def _pattern_batch_for_mesh(self, mesh):
         """Mesh-sharded twin of the pattern-batch kernel (same
@@ -1021,22 +1141,12 @@ class GammaProgram:
         pairs and inserts the histogram psum. Mirrors
         pairgen.make_virtual_pattern_fn's sharding layout so materialised
         pattern jobs compose with multi-chip EM the same way virtual ones
-        do. Cached per Mesh VALUE (Mesh is hashable), so equal meshes from
-        repeated mesh_from_settings calls share one compile."""
-        if mesh not in self._pattern_batch_mesh_cache:
-            import functools
-
-            from .parallel.mesh import pair_sharding, replicated
-
-            self._pattern_batch_mesh_cache[mesh] = functools.partial(
-                jax.jit,
-                out_shardings=(pair_sharding(mesh), replicated(mesh)),
-            )(
-                self._make_pattern_kernel(
-                    self._mesh_gamma_body(mesh), append_flag=False
-                )
-            )
-        return self._pattern_batch_mesh_cache[mesh]
+        do. Keyed by the mesh's VALUE (kernel_registry.mesh_key), so equal
+        meshes from repeated mesh_from_settings calls share one compile."""
+        return self._kernel(
+            "pattern_batch_mesh", (kernel_registry.mesh_key(mesh),),
+            functools.partial(_jit_pattern_batch_mesh, mesh=mesh),
+        )
 
     def _mesh_pattern_context(self, mesh):
         """(run_batch, zero_acc) for a mesh pattern pass — the shared

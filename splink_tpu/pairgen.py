@@ -69,6 +69,7 @@ from .blocking import (
 )
 from .data import EncodedTable
 from .gammas import int32_histogram, pattern_ids_fit_uint16
+from .utils.kernel_registry import mesh_key
 
 # Unit extent bound. 2048 keeps the triangle discriminant (2s-1)^2 < 2^24
 # (f32-exact) and a rectangle's pair count at 2048^2 ~ 4.2M (int32-safe);
@@ -101,13 +102,13 @@ class RulePlan:
     pc: np.ndarray  # (U+1,) int64 cumulative pair counts over units
     residual: str | None = None  # translated residual predicate source
     residual_fn: object = None  # compiled device closure (see _ResCompiler)
-    # jitted kernels keyed by (id(program), batch_size): jax.jit caches on
-    # function identity, so rebuilding the closure per pass would recompile
-    # — reusing it makes a warmup pass actually warm the timed pass
-    kernel_cache: dict = field(default_factory=dict)
-    # same keys -> the abstract arguments (shape, dtype, sharding) of each
-    # kernel's first call, for compiled_kernel_texts
-    kernel_args: dict = field(default_factory=dict)
+    # (batch size, mesh key, two_phase) -> (kernel, the abstract arguments
+    # — shape, dtype, sharding — of its first call): the pattern kernels
+    # this rule has RUN, for compiled_kernel_texts. The kernels themselves
+    # are the process's (utils/kernel_registry via GammaProgram._kernel):
+    # jax.jit caches on function identity, so every pass and every linker
+    # on the same model has to call the SAME jitted function.
+    kernels_run: dict = field(default_factory=dict)
 
     @property
     def total(self) -> int:
@@ -142,6 +143,20 @@ class _ResUnsupported(Exception):
     the plan falls back to host blocking."""
 
 
+def _cmp_apply(opname, x, y):
+    """One comparison. A module function and not a method: the closures the
+    compiler returns must not hold the compiler, whose table they would
+    keep alive for as long as a kernel that traced them is registered."""
+    return {
+        "eq": lambda: x == y,
+        "ne": lambda: x != y,
+        "lt": lambda: x < y,
+        "le": lambda: x <= y,
+        "gt": lambda: x > y,
+        "ge": lambda: x >= y,
+    }[opname]()
+
+
 class _ResCompiler:
     """Compile a translated residual predicate (the same python-expression
     surface residual_eval interprets) into a jax-traceable closure
@@ -168,11 +183,16 @@ class _ResCompiler:
         self.ops = ops  # shared across rules; uploaded once
         self.op_index = op_index  # key -> position in ops
         self.aux = aux  # vocab arrays for literal binding (host-only)
+        # everything the compile took from the TABLE, in order — operand
+        # slots, literal ranks, column kinds: with the source it determines
+        # the closure (compile_residual_device signs the closure with it)
+        self.trail: list = []
 
     def _register(self, key, build) -> int:
         if key not in self.op_index:
             self.op_index[key] = len(self.ops)
             self.ops.append(build())
+        self.trail.append(("slot", key, self.op_index[key]))
         return self.op_index[key]
 
     def _col_values_null(self, col):
@@ -302,9 +322,10 @@ class _ResCompiler:
                 f"literal {lit!r} vs column {col!r} type mismatch"
             )
         pos = int(np.searchsorted(vocab, lit))
-        if pos < len(vocab) and vocab[pos] == lit:
-            return 2 * pos
-        return 2 * pos - 1  # odd: orders correctly, equals nothing
+        # odd: orders correctly, equals nothing
+        rank = 2 * pos if pos < len(vocab) and vocab[pos] == lit else 2 * pos - 1
+        self.trail.append(("literal", col, lit, rank))
+        return rank
 
     # -- value level: returns ("str", col, op_idx, side) |
     #    ("num", fn(i,j,ops)->float array) | ("lit_s", s) | ("lit_n", x)
@@ -319,6 +340,7 @@ class _ResCompiler:
                 raise _ResUnsupported("subscript shape")
             col = node.slice.value
             side = node.value.id
+            self.trail.append(("numeric", col, col in self.table.numerics))
             if col in self.table.numerics:
                 idx = self._numeric_vals(col)
                 return ("num", self._gather_num(idx, side))
@@ -413,6 +435,7 @@ class _ResCompiler:
             kind, vals, null = evaluate_key(self.table, canon)
         except DerivedKeyError as e:
             raise _ResUnsupported(str(e)) from None
+        self.trail.append(("derived", canon, kind))
         if kind == "num":
 
             def build(vals=vals, null=null):
@@ -459,18 +482,6 @@ class _ResCompiler:
         raise _ResUnsupported("non-numeric operand in numeric context")
 
     # -- comparisons -> (val, unk) closures
-    def _cmp_apply(self, opname, x, y):
-        import jax.numpy as jnp
-
-        return {
-            "eq": lambda: x == y,
-            "ne": lambda: x != y,
-            "lt": lambda: x < y,
-            "le": lambda: x <= y,
-            "gt": lambda: x > y,
-            "ge": lambda: x >= y,
-        }[opname]()
-
     def compare_pair(self, opname, lv, rv):
         if lv[0] == "str" and rv[0] == "str":
             if lv[1] == rv[1]:
@@ -484,7 +495,7 @@ class _ResCompiler:
                 a = ops[li][i if ls == "l" else j]
                 b = ops[ri][i if rs == "l" else j]
                 unk = (a < 0) | (b < 0)
-                return self._cmp_apply(opname, a, b) & ~unk, unk
+                return _cmp_apply(opname, a, b) & ~unk, unk
 
             return f
         if lv[0] == "str" and rv[0] == "lit_s":
@@ -494,7 +505,7 @@ class _ResCompiler:
             def f(i, j, ops, li=li, ls=ls, k=k, opname=opname):
                 a = ops[li][i if ls == "l" else j]
                 unk = a < 0
-                return self._cmp_apply(opname, a, k) & ~unk, unk
+                return _cmp_apply(opname, a, k) & ~unk, unk
 
             return f
         if rv[0] == "str" and lv[0] == "lit_s":
@@ -504,7 +515,7 @@ class _ResCompiler:
             def f(i, j, ops, ri=ri, rs=rs, k=k, opname=opname):
                 b = ops[ri][i if rs == "l" else j]
                 unk = b < 0
-                return self._cmp_apply(opname, k, b) & ~unk, unk
+                return _cmp_apply(opname, k, b) & ~unk, unk
 
             return f
         # numeric comparison — a BARE string column here is a type
@@ -522,7 +533,7 @@ class _ResCompiler:
 
             x, y = a(i, j, ops), b(i, j, ops)
             unk = jnp.isnan(x) | jnp.isnan(y)
-            return self._cmp_apply(opname, x, y) & ~unk, unk
+            return _cmp_apply(opname, x, y) & ~unk, unk
 
         return f
 
@@ -617,15 +628,33 @@ def compile_residual_device(table, residual_src: str,
                             ops: list[np.ndarray], op_index: dict,
                             aux: dict):
     """-> fn(i, j, ops) -> (val, unk), or None when the predicate needs
-    host-only machinery (the caller then rejects the whole plan)."""
+    host-only machinery (the caller then rejects the whole plan).
+
+    ``fn.signature`` is the closure BY VALUE: the source and, in order,
+    everything the compile took from the table (operand slots, literal
+    ranks, column kinds). Two closures with equal signatures trace to the
+    same program, so the kernels that compose them can be shared across
+    linkers (make_virtual_pattern_fn); the closure holds no table."""
     try:
         tree = ast.parse(residual_src, mode="eval")
     except SyntaxError:
         return None
+    compiler = _ResCompiler(table, ops, op_index, aux)
     try:
-        return _ResCompiler(table, ops, op_index, aux).boolean(tree.body)
+        fn = compiler.boolean(tree.body)
     except _ResUnsupported:
         return None
+    fn.signature = (residual_src, tuple(compiler.trail))
+    return fn
+
+
+def residual_signatures(residuals) -> tuple | None:
+    """The signatures of compiled residual closures (None entries: no
+    residual), as part of a kernel's registry key — or None when one of them
+    carries no signature, so a kernel that traces it cannot be keyed."""
+    if all(r is None or hasattr(r, "signature") for r in residuals):
+        return tuple(None if r is None else r.signature for r in residuals)
+    return None
 
 
 def _split_extents(n: int, chunk: int) -> np.ndarray:
@@ -1091,20 +1120,58 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
     run. own_res / prev_res are compiled residual closures (traced into
     this jit; the ops arrays arrive as the res_ops argument).
 
+    The kernel is the PROCESS's, not the caller's: it comes from the
+    kernel registry under the program's signature plus everything else it
+    closes over — ``n_prev``, ``has_uid_mask``, ``two_phase``, the mesh by
+    value and the residuals' signatures (compile_residual_device) — so a
+    second linker on the same model gets the same jitted function and
+    builds nothing. A residual that carries no signature cannot be keyed:
+    that kernel is the program's own.
+
     With ``mesh``, the batch SHARDS over the mesh's data axis: ``pos``
     arrives as a sharded iota (the only sharded input — plan arrays, table
     data and codes are replicated), every per-position op partitions
     trivially along it, the gamma body runs per shard
-    (GammaProgram._mesh_gamma_body), and XLA inserts one psum for the
+    (gammas._mesh_gamma_body), and XLA inserts one psum for the
     histogram accumulator. This is how the virtual pair index composes with
     multi-chip EM: each chip decodes and scores its own slice of every
     unit, the way the reference's Spark join distributed its shuffle
     partitions (/root/reference/splink/blocking.py:210)."""
+    prev_res = tuple(prev_res)
+    # the exact twin of a program without two-phase IS its primary, and a
+    # mesh kernel is exact whatever it is asked
+    two_phase = bool(two_phase and mesh is None and program.two_phase_div)
+    residuals = (own_res, *prev_res)
+    signed = residual_signatures(residuals)
+    variant = (
+        n_prev, bool(has_uid_mask), two_phase, mesh_key(mesh),
+        # unsigned: the closures themselves, for the program's own memo
+        residuals if signed is None else signed,
+    )
+    return program._kernel(
+        "virtual_pattern", variant,
+        functools.partial(
+            _build_virtual_pattern_fn,
+            gamma_batch_fn=program._gamma_batch_fn, n_prev=n_prev,
+            has_uid_mask=has_uid_mask, own_res=own_res, prev_res=prev_res,
+            mesh=mesh, two_phase=two_phase,
+        ),
+        shareable=signed is not None,
+    )
+
+
+def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
+                              own_res, prev_res, mesh, two_phase):
+    """The virtual pattern kernel from a gamma program's parts
+    (gammas._Parts) and its jitted body — nothing of a linker, a plan or a
+    table, which a registered kernel would pin."""
     import jax
     import jax.numpy as jnp
 
-    n_patterns = program.n_patterns
-    strides_dev = jnp.asarray(program._pattern_strides, jnp.int32)
+    from .gammas import _make_gamma_body, _mesh_gamma_body
+
+    n_patterns = parts.n_patterns
+    strides_dev = jnp.asarray(parts.strides, jnp.int32)
     # Mesh kernels and the overflow-redo twin compose the EXACT gamma body
     # (two-phase survivor compaction does not partition along a sharded
     # pair axis); the single-device primary composes the two-phase body.
@@ -1113,15 +1180,11 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
     # and bumps the overflow slot instead; non-mesh kernels also append
     # the flag to pid so the ids path can redo per batch.
     if mesh is not None:
-        gamma_fn = program._mesh_gamma_body(mesh)
-    elif not two_phase:
-        gamma_fn = (
-            program._exact_gamma_body()
-            if program.two_phase_div
-            else program._gamma_batch_fn
-        )
+        gamma_fn = _mesh_gamma_body(parts, mesh)
+    elif not two_phase and parts.two_phase_div:
+        gamma_fn = _make_gamma_body(parts, None)
     else:
-        gamma_fn = program._gamma_batch_fn
+        gamma_fn = gamma_batch_fn
 
     jit_kwargs = {}
     if mesh is not None:
@@ -1135,8 +1198,8 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
 
     # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches the
     # XLA module ``jit_fn(``, and its files are not this code's to edit. This
-    # and gammas._flagged are the only two programs of that name, so
-    # ``jit_fn(`` means the gamma body and nothing else.
+    # and gammas._jit_gamma_flagged are the only two programs of that name,
+    # so ``jit_fn(`` means the gamma body and nothing else.
     @functools.partial(jax.jit, **jit_kwargs)
     def fn(pos, packed, order, ua, la, ub, lb, prev_codes, uid_codes,
            res_ops, meta, acc):
@@ -1204,9 +1267,9 @@ def compiled_kernel_texts(plan: VirtualPlan) -> list[tuple[int, str]]:
     the kernels of a run (collectives, what each device feeds the string
     kernels) — chip_smoke.py's mesh leg reads it."""
     return [
-        (key[1], rp.kernel_cache[key].lower(*args).compile().as_text())
+        (key[0], fn.lower(*args).compile().as_text())
         for rp in plan.rules
-        for key, args in rp.kernel_args.items()
+        for key, (fn, args) in rp.kernels_run.items()
     ]
 
 
@@ -1342,37 +1405,28 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                       + sum(a.nbytes for a in units)):
                 order_dev = put(rp.order)
                 units_dev = tuple(put(a) for a in units)
-            kkey = (
-                id(program), rule_bs,
-                None if mesh is None else id(mesh), two_phase,
+            # the program hands out the process's kernel for this rule
+            # (make_virtual_pattern_fn): the same jitted function in every
+            # pass and every linker on this model
+            res = {
+                "has_uid_mask": plan.uid_codes is not None,
+                "own_res": rp.residual_fn,
+                "prev_res": tuple(p.residual_fn for p in plan.rules[:r]),
+            }
+            fn = make_virtual_pattern_fn(
+                program, rule_bs, n_prev=r, mesh=mesh, two_phase=two_phase,
+                **res,
             )
-            fn = rp.kernel_cache.get(kkey)
-            if fn is None:
-                fn = rp.kernel_cache[kkey] = make_virtual_pattern_fn(
-                    program, rule_bs, n_prev=r,
-                    has_uid_mask=plan.uid_codes is not None,
-                    own_res=rp.residual_fn,
-                    prev_res=tuple(p.residual_fn for p in plan.rules[:r]),
-                    mesh=mesh, two_phase=two_phase,
-                )
+            kkey = (rule_bs, mesh_key(mesh), two_phase)
 
-            def exact_fn(r=r, rp=rp, rule_bs=rule_bs):
+            def exact_fn(r=r, rule_bs=rule_bs, res=res):
                 """The rule's exact-twin kernel for overflow redos, built
                 on first use (it only ever compiles if a batch overflows
                 the two-phase survivor capacity)."""
-                ekey = (id(program), rule_bs, None, False)
-                efn = rp.kernel_cache.get(ekey)
-                if efn is None:
-                    efn = rp.kernel_cache[ekey] = make_virtual_pattern_fn(
-                        program, rule_bs, n_prev=r,
-                        has_uid_mask=plan.uid_codes is not None,
-                        own_res=rp.residual_fn,
-                        prev_res=tuple(
-                            p.residual_fn for p in plan.rules[:r]
-                        ),
-                        mesh=None, two_phase=False,
-                    )
-                return efn
+                return make_virtual_pattern_fn(
+                    program, rule_bs, n_prev=r, mesh=None, two_phase=False,
+                    **res,
+                )
             # One metadata row per batch (_unit_batch_meta), uploaded per
             # batch with device_put — uploads are ASYNC, where an EAGER
             # device-side op like meta_dev[b] is a blocking dispatch;
@@ -1383,8 +1437,8 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                     pos_rule, packed, order_dev, *units_dev, codes_dev,
                     uid_dev, res_ops_dev, meta_dev, acc,
                 )
-                if kkey not in rp.kernel_args:
-                    rp.kernel_args[kkey] = _abstract_args(args)
+                if kkey not in rp.kernels_run:
+                    rp.kernels_run[kkey] = (fn, _abstract_args(args))
                 pid, acc = fn(*args)
                 if want_ids:
                     redo_args = (

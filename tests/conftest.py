@@ -44,6 +44,16 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def empty_kernel_registry():
+    """Every test starts with no jitted gamma program registered, so a test
+    that counts compiles or build spans sees its own linker build, whatever
+    ran before it in the process (utils/kernel_registry.py)."""
+    from splink_tpu.utils import kernel_registry
+
+    kernel_registry.clear()
+
+
 @pytest.fixture
 def basic_settings():
     """A small two-column dedupe settings dict used across tests."""

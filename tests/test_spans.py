@@ -408,32 +408,45 @@ def test_counts_at_the_stage_boundaries(job):
     assert all(s["counts"]["bytes"] > 0 for s in by_name["h2d_put"])
 
 
-def test_build_spans_under_a_fresh_linker_and_none_on_the_second_call():
-    """Every linker traces, lowers and compiles (or reads back) its own
-    pattern kernels — ROADMAP A2; a second call on the same linker reuses
-    them, so its gamma pass records no build span."""
+def _gamma_pass_builds(table: list[dict]) -> dict[str, list[dict]]:
+    """The build spans directly under the job's gamma pass, by name."""
+    by_id = {s["id"]: s for s in table}
+    out: dict[str, list[dict]] = {}
+    for s in table:
+        if s["kind"] == "build" and by_id[s["parent"]]["name"] == "gammas_patterns":
+            out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def test_build_spans_under_the_first_linker_of_a_key_and_none_after():
+    """The first linker of a registry key traces, lowers and compiles (or
+    reads back) the pattern kernels; a second call on it, and a second
+    fresh linker on the same settings and frame, get the same jitted
+    programs from the registry and build nothing — ROADMAP A2."""
     linker = _dedupe_job()
     table = spans(run=linker.run_id)
-    by_id = {s["id"]: s for s in table}
-    under = [
-        s for s in table
-        if s["kind"] == "build" and by_id[s["parent"]]["name"] == "gammas_patterns"
-    ]
-    names = {s["name"] for s in under}
-    assert {"jax_lower", "jax_backend_compile"} <= names, names
-    assert any(s["counts"]["fun"] == "jit(fn)" for s in under)
-    assert all(
-        s["counts"]["cache_hit"] in (0, 1)
-        for s in under if s["name"] == "jax_backend_compile"
-    )
-    assert all(s["t1"] - s["t0"] >= 1e-3 for s in under if s["name"] == "jax_trace")
-    # second pass over the same linker: the kernels are cached on its plan
+    under = _gamma_pass_builds(table)
+    assert {"jax_lower", "jax_backend_compile", "kernel_lookup"} <= set(under)
+    assert any(s["counts"]["fun"] == "jit(fn)" for s in under["jax_lower"])
+    assert all(s["counts"]["cache_hit"] in (0, 1)
+               for s in under["jax_backend_compile"])
+    assert all(s["t1"] - s["t0"] >= 1e-3 for s in under.get("jax_trace", ()))
+    lookups = [s["counts"] for s in under["kernel_lookup"]]
+    assert {c["fun"] for c in lookups} == {"gamma_body", "virtual_pattern"}
+    assert all(c == {"fun": c["fun"], "hit": 0, "shared": 1} for c in lookups)
+    # second pass over the same linker: the program holds its kernels
     before = len(table)
     linker._pattern_counts = None
     linker._ensure_pattern_ids()
     again = spans(run=linker.run_id)[before:]
     assert [s["name"] for s in again if s["kind"] == "stage"] == ["gammas_patterns"]
     assert [s for s in again if s["kind"] == "build"] == []
+    # a second linker, same key: every lookup a hit, nothing built
+    second = _gamma_pass_builds(spans(run=_dedupe_job().run_id))
+    assert set(second) == {"kernel_lookup"}, set(second)
+    assert [s["counts"] for s in second["kernel_lookup"]] == [
+        dict(c, hit=1) for c in lookups
+    ]
 
 
 def test_telemetry_record_carries_the_sub_spans(tmp_path):
@@ -544,9 +557,10 @@ def test_jitted_programs_have_names_of_their_own():
                            float_dtype=jnp.float32)
     gamma = [
         make_virtual_pattern_fn(program, 64, n_prev=0, has_uid_mask=False),
-        program._flagged_factory(program._exact_gamma_body()),
+        program._gamma_flagged_fn(),
+        program._gamma_flagged_fn(exact=True),
     ]
-    assert [f.__name__ for f in gamma] == ["fn", "fn"]
+    assert [f.__name__ for f in gamma] == ["fn", "fn", "fn"]
     others = [
         blocking_device.make_segment_sort_fn(),
         blocking_device.make_bucket_csr_fn(),
