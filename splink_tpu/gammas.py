@@ -1038,11 +1038,14 @@ class GammaProgram:
         probe = jnp.zeros(8, jnp.int32)
         log_jaxpr("gamma_program", self._gamma_batch, probe, probe)
 
-    def _kernel(self, fun: str, variant: tuple, build, shareable: bool = True):
+    def _kernel(self, fun: str, variant: tuple, build, shareable: bool = True,
+                mesh=None):
         """The jitted program ``build(parts)`` makes, from the process's
         registry when this program has a signature and the caller could
         sign ``variant`` (the rest of what ``build`` closes over); else
-        this program's own. ``build`` must not capture the program."""
+        this program's own. ``build`` must not capture the program.
+        ``mesh``: the mesh the program shards over (its key is in
+        ``variant``), for the lookup span's ``devices``."""
         fn = self._kernels.get((fun, variant))
         if fn is None:
             key = (
@@ -1050,7 +1053,8 @@ class GammaProgram:
                 if self._sig is not None and shareable else None
             )
             fn = self._kernels[(fun, variant)] = kernel_registry.lookup(
-                fun, key, functools.partial(build, self._parts)
+                fun, key, functools.partial(build, self._parts),
+                devices=1 if mesh is None else mesh.devices.size,
             )
         return fn
 
@@ -1146,6 +1150,7 @@ class GammaProgram:
         return self._kernel(
             "pattern_batch_mesh", (kernel_registry.mesh_key(mesh),),
             functools.partial(_jit_pattern_batch_mesh, mesh=mesh),
+            mesh=mesh,
         )
 
     def _mesh_pattern_context(self, mesh):
@@ -1154,11 +1159,11 @@ class GammaProgram:
         packed table, sharded index uploads, replicated accumulator."""
         import jax
 
-        from .parallel.mesh import pair_sharding, replicated
+        from .parallel.mesh import pair_sharding, put_on_mesh, replicated
 
         shard = pair_sharding(mesh)
         repl = replicated(mesh)
-        packed_dev = jax.device_put(self._packed, repl)
+        (packed_dev,) = put_on_mesh(repl, self._packed)
         fn = self._pattern_batch_for_mesh(mesh)
 
         def run_batch(bl, br, valid, acc):
@@ -1210,11 +1215,13 @@ class GammaProgram:
             return pids, total
         batch_size = min(batch_size, max(n, 1))
         if mesh is not None:
-            from .parallel.mesh import pad_to_multiple
+            from .parallel.mesh import gather_from_mesh, pad_to_multiple
 
             batch_size = pad_to_multiple(batch_size, mesh.devices.size)
             run_batch, zero_acc = self._mesh_pattern_context(mesh)
+            ids_home = gather_from_mesh  # sharded ids: span mesh_gather
         else:
+            ids_home = np.asarray
             run_batch = lambda bl, br, valid, acc: self._pattern_batch(  # noqa: E731
                 *_put_pair_batch(bl, br), valid, acc
             )
@@ -1232,7 +1239,7 @@ class GammaProgram:
             the flagged batch skipped the histogram, so the late redo's
             acc addition commutes into an identical total."""
             ps, pe, prev, pbl, pbr = pending
-            arr = fetch(prev)
+            arr = fetch(prev, via=ids_home)
             if has_flag and arr[-1]:
                 pid2, acc = self._pattern_batch_exact(
                     jnp.asarray(pbl), jnp.asarray(pbr), pe - ps, acc
@@ -1539,13 +1546,15 @@ class PatternStream(_StreamBatcher):
                 f"({MAX_PATTERNS}); use GammaStream"
             )
         self.mesh = mesh
+        self._ids_home = np.asarray
         if mesh is not None:
-            from .parallel.mesh import pad_to_multiple
+            from .parallel.mesh import gather_from_mesh, pad_to_multiple
 
             batch_size = pad_to_multiple(batch_size, mesh.devices.size)
             self._run_batch, self._zero_acc = program._mesh_pattern_context(
                 mesh
             )
+            self._ids_home = gather_from_mesh  # sharded ids: span mesh_gather
         else:
             self._zero_acc = lambda: jnp.zeros(
                 program.n_patterns + 1, jnp.int32
@@ -1569,7 +1578,7 @@ class PatternStream(_StreamBatcher):
 
     def _read_pending(self):
         v, prev, pbl, pbr = self._pending
-        arr = fetch(prev)
+        arr = fetch(prev, via=self._ids_home)
         if self.mesh is None and arr[-1]:
             # two-phase overflow: the flagged batch skipped the histogram;
             # redo through the exact twin (any acc generation works — the

@@ -466,6 +466,8 @@ class Splink:
                 self._G, self._G_dev = stream.finish()
             st.count(pairs=stream.total,
                      batches=-(-stream.total // stream.batch_size))
+            if isinstance(stream, PatternStream):
+                self._count_mesh(st, stream.total)
 
     def _maybe_spill_pairs(self) -> None:
         """Note the blocking-created spill dir (streamed regime): blocking's
@@ -676,6 +678,15 @@ class Splink:
 
         return mesh if jax.process_count() == 1 else None
 
+    def _count_mesh(self, st, positions: int) -> None:
+        """On a pattern pass's stage: over how many chips it was sharded
+        (``devices``) and each chip's share of its pair positions
+        (``pairs_per_device``). Without a mesh the stage carries neither."""
+        mesh = self._pattern_mesh()
+        if mesh is not None:
+            n = mesh.devices.size
+            st.count(devices=n, pairs_per_device=-(-positions // n))
+
     def _ensure_pattern_program(self) -> "GammaProgram":
         """The pattern-capable GammaProgram, built lazily. Scoring-only
         consumers (manual FS weights, the virtual score stream) need just
@@ -756,6 +767,7 @@ class Splink:
                     if want_ids:
                         self._P_virtual = pids
                     st.count(pairs=n_real)
+                    self._count_mesh(st, self._virtual.n_candidates)
                 logger.info(
                     "device pair generation scored %d pairs (%d candidate "
                     "positions)", n_real, self._virtual.n_candidates,
@@ -778,6 +790,7 @@ class Splink:
                     )
                 )
                 st.count(pairs=len(self._P))
+                self._count_mesh(st, len(self._P))
         return self._P, self._pattern_counts, self._pattern_program
 
     def _tf_fold_ctx(self):

@@ -1156,7 +1156,7 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
             has_uid_mask=has_uid_mask, own_res=own_res, prev_res=prev_res,
             mesh=mesh, two_phase=two_phase,
         ),
-        shareable=signed is not None,
+        shareable=signed is not None, mesh=mesh,
     )
 
 
@@ -1319,8 +1319,10 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     batch_size = min(batch_size, max(total, 1), safe)
     if mesh is not None:
         from .parallel.mesh import (
+            gather_from_mesh,
             pad_to_multiple,
             pair_sharding,
+            put_on_mesh,
             replicated,
         )
 
@@ -1334,9 +1336,18 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
             batch_size = max(safe // msz, 1) * msz
         shard = pair_sharding(mesh)
         repl = replicated(mesh)
-        put = lambda a: jax.device_put(jnp.asarray(a), repl)  # noqa: E731
+        put = lambda a: jax.device_put(a, repl)  # noqa: E731
+        place = functools.partial(put_on_mesh, repl)
+        download = gather_from_mesh
     else:
         put = jnp.asarray
+        download = np.asarray
+
+        def place(*arrays):
+            """The pass's table and plan arrays onto the device; under a
+            mesh, parallel.mesh.put_on_mesh (span ``mesh_put``)."""
+            with span("h2d_put", bytes=sum(a.nbytes for a in arrays)):
+                return tuple(jnp.asarray(a) for a in arrays)
     # per-bucket iota cache: rules sharing a rule_bs bucket share one array
     pos_cache: dict = {}
     flush_every = max(min(_HIST_FLUSH_BATCHES, (1 << 30) // batch_size), 1)
@@ -1370,13 +1381,12 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
         # kernel's static n_prev bounds how many code rows it reads); per-rule
         # plan arrays + kernel are built per rule (shapes differ, so each rule
         # is its own jit specialisation)
-        with span("h2d_put", bytes=uid_codes.nbytes + plan.codes.nbytes
-                  + sum(a.nbytes for a in plan.res_ops)):
-            if mesh is not None:
-                packed = jax.device_put(packed, repl)
-            uid_dev = put(uid_codes)
-            codes_dev = put(plan.codes)
-            res_ops_dev = tuple(put(a) for a in plan.res_ops)
+        if mesh is not None:
+            (packed,) = place(packed)  # from the program's device to all
+        uid_dev, codes_dev, *res_ops_dev = place(
+            uid_codes, plan.codes, *plan.res_ops
+        )
+        res_ops_dev = tuple(res_ops_dev)
         out_pos = 0
         for r, rp in enumerate(plan.rules):
             if rp.total == 0:
@@ -1394,17 +1404,14 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
             pos_rule = pos_cache.get(rule_bs)
             if pos_rule is None:
                 if mesh is not None:
-                    pos_rule = jax.device_put(
-                        np.arange(rule_bs, dtype=np.int32), shard
+                    (pos_rule,) = put_on_mesh(
+                        shard, np.arange(rule_bs, dtype=np.int32)
                     )
                 else:
                     pos_rule = jnp.arange(rule_bs, dtype=jnp.int32)
                 pos_cache[rule_bs] = pos_rule
-            units = (rp.ua, rp.la, rp.ub, rp.lb)
-            with span("h2d_put", bytes=rp.order.nbytes
-                      + sum(a.nbytes for a in units)):
-                order_dev = put(rp.order)
-                units_dev = tuple(put(a) for a in units)
+            order_dev, *units_dev = place(rp.order, rp.ua, rp.la, rp.ub, rp.lb)
+            units_dev = tuple(units_dev)
             # the program hands out the process's kernel for this rule
             # (make_virtual_pattern_fn): the same jitted function in every
             # pass and every linker on this model
@@ -1446,7 +1453,7 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                     ) if mesh is None else None
                     inflight.append(
                         (r, p0, out_pos, p1 - p0,
-                         pool.submit(np.asarray, pid), redo_args)
+                         pool.submit(download, pid), redo_args)
                     )
                     while len(inflight) > _D2H_DEPTH:
                         pr, pp0, ps, n_valid, fut, rd = inflight.popleft()

@@ -13,6 +13,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..utils.profiling import span
+
 DATA_AXIS = "data"
 
 
@@ -63,6 +65,35 @@ def pair_sharding(mesh: Mesh) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec())
+
+
+def put_on_mesh(sharding: NamedSharding, *arrays) -> tuple:
+    """Place ``arrays`` on the mesh with ``sharding`` under one ``mesh_put``
+    span (``bytes``: what the host hands over, ``devices``: how many chips
+    take it) and WAIT for them: the span then reads the placement and not
+    its dispatch. The wait costs a pass nothing — these are the table and
+    plan arrays its first program needs before it can start. Per-batch
+    uploads (a metadata row, an accumulator) stay plain async
+    ``device_put`` calls outside any span."""
+    with span("mesh_put", bytes=sum(int(a.nbytes) for a in arrays),
+              devices=sharding.mesh.devices.size):
+        return jax.block_until_ready(
+            tuple(jax.device_put(a, sharding) for a in arrays)
+        )
+
+
+def gather_from_mesh(x) -> np.ndarray:
+    """``np.asarray(x)`` for an array sharded over the mesh, under a
+    ``mesh_gather`` span (``bytes``, ``shards``). It waits for the program
+    that makes ``x`` BEFORE the span opens, so the span is the copies from
+    the chips alone; the driver thread's ``d2h_wait`` is the wait for both
+    (``profiling.fetch(x, via=gather_from_mesh)``, or the pooled download
+    the virtual pass waits on)."""
+    x.block_until_ready()
+    with span("mesh_gather", shards=len(x.sharding.device_set)) as sp:
+        arr = np.asarray(x)
+        sp.count(bytes=arr.nbytes)
+    return arr
 
 
 def pad_to_multiple(n: int, multiple: int) -> int:

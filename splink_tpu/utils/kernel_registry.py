@@ -24,7 +24,8 @@ The contract of a registered program:
 The registry is bounded: past ``MAX_ENTRIES`` the least recently used entry
 goes (a linker still running keeps its own reference to the callable, so an
 eviction can only cost a later linker a rebuild). Every lookup closes a
-``kernel_lookup`` build span with counts ``fun``, ``hit`` and ``shared``.
+``kernel_lookup`` build span with counts ``fun``, ``hit``, ``shared`` and
+``devices``.
 """
 
 from __future__ import annotations
@@ -48,11 +49,12 @@ _LOCK = threading.Lock()
 _ENTRIES: OrderedDict = OrderedDict()
 
 
-def lookup(fun: str, key, build):
+def lookup(fun: str, key, build, devices: int = 1):
     """The jitted program ``build()`` makes — the registry's when an equal
     ``key`` was seen before, else built now and kept. ``key=None``: the
     caller could not sign its closure; the program is built and NOT kept.
-    ``fun`` names the program in the ``kernel_lookup`` span."""
+    ``fun`` names the program in the ``kernel_lookup`` span and ``devices``
+    says over how many chips it shards (the size of the mesh in its key)."""
     t0 = time.perf_counter()
     fn = None
     if key is not None:
@@ -72,7 +74,8 @@ def lookup(fun: str, key, build):
                 while len(_ENTRIES) > MAX_ENTRIES:
                     _ENTRIES.popitem(last=False)
     add_closed("kernel_lookup", "build", time.perf_counter() - t0,
-               fun=fun, hit=int(hit), shared=int(key is not None))
+               fun=fun, hit=int(hit), shared=int(key is not None),
+               devices=devices)
     return fn
 
 

@@ -206,11 +206,13 @@ def count(**counts) -> None:
             return
 
 
-def fetch(x):
+def fetch(x, via=np.asarray):
     """``np.asarray(x)`` for a device array, under a ``d2h_wait`` span: the
-    one place the driver thread blocks on a device-to-host copy."""
+    one place the driver thread blocks on a device-to-host copy. ``via`` is
+    the copy itself — ``parallel.mesh.gather_from_mesh`` for an array
+    sharded over a mesh, whose ``mesh_gather`` span then lies inside."""
     with span("d2h_wait") as sp:
-        arr = np.asarray(x)
+        arr = via(x)
         sp.count(bytes=arr.nbytes)
     return arr
 

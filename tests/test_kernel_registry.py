@@ -213,7 +213,7 @@ def test_a_registered_comparison_is_built_per_linker():
     for _ in range(2):
         linker, frame = _run(settings, df)
         looked = _lookups(linker)
-        assert looked and all(c == {"fun": c["fun"], "hit": 0, "shared": 0}
+        assert looked and all(c == {"fun": c["fun"], "hit": 0, "shared": 0, "devices": 1}
                               for c in looked)
         assert {"jax_lower", "jax_backend_compile"} <= _gamma_pass_builds(linker)
         assert set(frame["gamma_initial"]) <= {0, 1}

@@ -433,7 +433,7 @@ def test_build_spans_under_the_first_linker_of_a_key_and_none_after():
     assert all(s["t1"] - s["t0"] >= 1e-3 for s in under.get("jax_trace", ()))
     lookups = [s["counts"] for s in under["kernel_lookup"]]
     assert {c["fun"] for c in lookups} == {"gamma_body", "virtual_pattern"}
-    assert all(c == {"fun": c["fun"], "hit": 0, "shared": 1} for c in lookups)
+    assert all(c == {"fun": c["fun"], "hit": 0, "shared": 1, "devices": 1} for c in lookups)
     # second pass over the same linker: the program holds its kernels
     before = len(table)
     linker._pattern_counts = None
