@@ -42,12 +42,11 @@ from .utils.profiling import StageTimer, count, fetch, span
 
 logger = logging.getLogger("splink_tpu")
 
-# RAM caps (candidate counts) for keeping the virtual pass's per-candidate
-# pattern ids for a later score stream: 2^32 uint16 ids = 8.6 GB, 2^31
-# int32 ids = 8.6 GB. Above these the stream recomputes ids chunk-wise
-# instead (virtual_materialise_ids="on" overrides).
-_MAX_RESIDENT_IDS_U16 = 1 << 32
-_MAX_RESIDENT_IDS_I32 = 1 << 31
+# RAM cap for keeping the virtual pass's per-candidate pattern ids and row
+# pairs (pairgen.VirtualIds: 10 bytes a candidate with uint16 ids, 12 with
+# int32) for a later score stream: 8.6 GB. Above it the stream recomputes
+# them chunk-wise instead (virtual_materialise_ids="on" overrides).
+_MAX_RESIDENT_ID_BYTES = 1 << 33
 
 try:  # pandas is required for the linker facade (not for the kernels)
     import pandas as pd
@@ -151,8 +150,9 @@ class Splink:
         self._virtual_checked = False
         # per-candidate pattern ids from the virtual pass (sentinel kept),
         # materialised when a score stream is known to follow — one kernel
-        # pass instead of two (see _virtual_ids_policy)
-        self._P_virtual: np.ndarray | None = None
+        # pass instead of two (see _virtual_ids_policy): a pairgen.VirtualIds,
+        # the ids with the row pairs the kernel decoded
+        self._P_virtual = None
         self._virtual_want_ids = False
         self._pair_bound: int | None = None  # estimate_pair_upper_bound memo
         # last EMResult replayed into Params (EM diagnostics attach its
@@ -702,14 +702,16 @@ class Splink:
         return self._pattern_program
 
     def _virtual_ids_policy(self) -> bool:
-        """Should the virtual pattern pass ALSO materialise per-candidate
-        ids? One pass (ids + histogram together) beats two (histogram-only
-        EM pass, then an ids recompute inside the score stream) whenever a
-        score stream is going to happen and the ids fit host RAM: the
-        kernels run once instead of twice, and the downloads overlap the
-        kernels either way. EM-only jobs keep the histogram-only pass —
-        no per-pair bytes ever cross the link (cost not measured on this
-        machine; scripts/virtual_breakdown.py)."""
+        """Should the virtual pattern pass ALSO bring home what it knows of
+        every candidate — its pattern id and the row pair the kernel decoded
+        (pairgen.VirtualIds: 2 or 4 bytes of id and 8 of pair)? One pass
+        (ids + pairs + histogram together) beats two (histogram-only EM
+        pass, then a recompute inside the score stream) whenever a score
+        stream is going to happen and they fit host RAM: the kernels run
+        once instead of twice, and the downloads overlap the kernels either
+        way. EM-only jobs keep the histogram-only pass — no per-pair bytes
+        ever cross the link (cost not measured on this machine;
+        scripts/virtual_breakdown.py)."""
         mode = self.settings.get("virtual_materialise_ids", "auto")
         if mode == "on":
             return True
@@ -717,12 +719,11 @@ class Splink:
             return False
         if not self._virtual_want_ids:
             return False
-        n = self._virtual.n_candidates
         from .gammas import pattern_ids_fit_uint16
 
         small = pattern_ids_fit_uint16(self._ensure_pattern_program().n_patterns)
-        cap = _MAX_RESIDENT_IDS_U16 if small else _MAX_RESIDENT_IDS_I32
-        if n > cap:
+        need = self._virtual.n_candidates * (10 if small else 12)
+        if need > _MAX_RESIDENT_ID_BYTES:
             return False
         # "fits host RAM" means the RAM actually free right now, not just
         # the hard cap: claim at most half of it, else stream chunk-wise
@@ -730,7 +731,7 @@ class Splink:
             avail = os.sysconf("SC_AVPHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
         except (ValueError, OSError, AttributeError):
             return True  # no probe on this platform; the cap still bounds
-        return n * (2 if small else 4) <= avail // 2
+        return need <= avail // 2
 
     def _ensure_pattern_ids(self):
         """(pattern_ids, counts, program): ONE device pass over the pair
@@ -747,7 +748,8 @@ class Splink:
                 # transferred per pair. Default is a histogram-ONLY pass
                 # (EM needs nothing else); when a score stream is known to
                 # follow, _virtual_ids_policy keeps the per-candidate ids
-                # from this same pass so the stream is LUT-only.
+                # and row pairs from this same pass so the stream is
+                # LUT-only.
                 if self._pattern_counts is not None:
                     return None, self._pattern_counts, self._pattern_program
                 from .pairgen import compute_virtual_pattern_ids
@@ -755,7 +757,7 @@ class Splink:
                 with self._stage("gammas_patterns") as st:
                     self._ensure_pattern_program()
                     want_ids = self._virtual_ids_policy()
-                    pids, self._pattern_counts, n_real = (
+                    ids, self._pattern_counts, n_real = (
                         compute_virtual_pattern_ids(
                             self._pattern_program,
                             self._virtual,
@@ -765,7 +767,7 @@ class Splink:
                         )
                     )
                     if want_ids:
-                        self._P_virtual = pids
+                        self._P_virtual = ids
                     st.count(pairs=n_real)
                     self._count_mesh(st, self._virtual.n_candidates)
                 logger.info(
@@ -907,47 +909,55 @@ class Splink:
         score stream assembles frames from it and the streaming TF
         adjustment drives it twice. (The virtual branch deliberately
         avoids _ensure_pattern_ids: scoring needs no histogram pass, e.g.
-        under manual FS weights.)"""
+        under manual FS weights.)
+
+        A virtual position is decoded to its row pair ONCE, by the kernel
+        that computed its pattern id (pairgen.unit_decode); both virtual
+        branches take the kernel's pairs — kept beside the ids, or home
+        with them from the recompute pass — and nothing here decodes a
+        position again. What is left under the ``decode_pairs`` span is
+        dropping the masked positions."""
         batch = int(self.settings["pair_batch_size"])
         if self._virtual_plan() is not None:
-            from .pairgen import _virtual_pass_iter, decode_positions
+            from .pairgen import _virtual_pass_iter
 
             plan = self._virtual
             program = self._ensure_pattern_program()
             sentinel = program.n_patterns
 
-            def decode(Pc, r, p0):
-                with span("decode_pairs", rows=len(Pc)):
+            def unmasked(Pc, il, ir):
+                """One chunk of the stream without its masked positions;
+                ``device_decoded`` counts the positions whose row pair the
+                kernel decoded, which is all of them."""
+                with span(
+                    "decode_pairs", rows=len(Pc), device_decoded=len(Pc)
+                ) as sp:
                     keep = Pc != sentinel
-                    if not keep.any():
+                    n_kept = int(np.count_nonzero(keep))
+                    sp.count(kept=n_kept)
+                    if not n_kept:
                         return None
-                    qs = p0 + np.flatnonzero(keep).astype(np.int64)
-                    il, ir, _ = decode_positions(
-                        plan, r, qs, compute_masked=False
+                    return (
+                        il[keep],
+                        ir[keep],
+                        Pc[keep].astype(np.int32, copy=False),
                     )
-                    return il, ir, Pc[keep]
 
-            P = self._P_virtual  # local: immune to concurrent release
-            if P is not None:
+            kept = self._P_virtual  # local: immune to concurrent release
+            if kept is not None:
                 out_base = 0
-                for r, rp in enumerate(plan.rules):
-                    for p0 in range(0, rp.total, batch):
-                        p1 = min(p0 + batch, rp.total)
-                        t = decode(
-                            P[out_base + p0 : out_base + p1].astype(
-                                np.int32, copy=False
-                            ),
-                            r,
-                            p0,
-                        )
+                for rp in plan.rules:
+                    for p0 in range(out_base, out_base + rp.total, batch):
+                        rows = slice(p0, min(p0 + batch, out_base + rp.total))
+                        t = unmasked(*(a[rows] for a in kept))
                         if t is not None:
                             yield t
                     out_base += rp.total
                 return
-            for r, p0, _, _n, chunk in _virtual_pass_iter(
+            for _, _, _, _, *chunk in _virtual_pass_iter(
                 program, plan, batch, mesh=self._pattern_mesh()
             ):
-                t = decode(chunk.astype(np.int32, copy=False), r, p0)
+                t = unmasked(*chunk)
                 if t is not None:
                     yield t
             return

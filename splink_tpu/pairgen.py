@@ -34,7 +34,8 @@ keys collide (duplicate-uid inputs) or for which an EARLIER rule's
 predicate holds (the reference's ``AND NOT ifnull(prev, false)``,
 /root/reference/splink/blocking.py:59-68) gets the sentinel pattern id
 ``n_patterns`` and falls out of the histogram's overflow bucket; the
-output stream filters the sentinel when decoding chunks host-side.
+output stream filters on the sentinel. The kernel hands the row pairs it
+decoded back beside the ids, so the stream never decodes a position again.
 
 Supported: all three link types on a single device — link_and_dedupe
 self-joins the concatenated table ordered by (source, uid), link_only
@@ -57,6 +58,7 @@ from __future__ import annotations
 import ast
 import functools
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -1045,7 +1047,7 @@ def build_virtual_plan(
 
 
 # --------------------------------------------------------------------------
-# Host-side decode (output streaming + test oracle)
+# Host-side decode (the test oracle)
 # --------------------------------------------------------------------------
 
 
@@ -1053,12 +1055,13 @@ def decode_positions(plan: VirtualPlan, rule: int, q: np.ndarray,
                      compute_masked: bool = True):
     """(i, j, masked) for rule-relative pair positions q (int64, numpy).
 
-    The host mirror of the device kernel — used to rebuild (idx_l, idx_r)
-    for output chunks (f64 sqrt is exact here) and as the oracle the
-    device kernel is tested against. The streaming caller already filtered
-    masked positions by the kernel's sentinel pattern id and passes
-    ``compute_masked=False`` (masked comes back None) — re-running the
-    residual predicates on the host per chunk would be pure waste.
+    The host mirror of the device kernel (f64 sqrt is exact here) and the
+    oracle it is tested against: the row pairs the virtual pattern kernel
+    hands back and its masked sentinels must equal these, position for
+    position. Nothing of the program's pair stream calls it — the stream
+    takes the kernel's own pairs (linker._iter_pattern_triples) — so its
+    cost is the tests'. ``compute_masked=False`` skips the masks (masked
+    comes back None).
     """
     rp = plan.rules[rule]
     u = np.searchsorted(rp.pc, q, side="right") - 1
@@ -1114,8 +1117,10 @@ def decode_positions(plan: VirtualPlan, rule: int, q: np.ndarray,
 def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
                             has_uid_mask: bool, own_res=None,
                             prev_res=(), mesh=None, two_phase=True):
-    """Jitted (pid, acc) kernel decoding + scoring one batch of virtual
-    pair positions. Shapes of the plan arrays vary per rule, so XLA
+    """Jitted (pid, i, j, acc) kernel decoding + scoring one batch of virtual
+    pair positions; ``i`` / ``j`` are the row pairs it decoded (int32, one per
+    position), always there and downloaded only by a pass that wants the
+    ids. Shapes of the plan arrays vary per rule, so XLA
     compiles one executable per (rule shape, kpad bucket) — a handful per
     run. own_res / prev_res are compiled residual closures (traced into
     this jit; the ops arrays arrive as the res_ops argument).
@@ -1190,10 +1195,11 @@ def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
     if mesh is not None:
         from .parallel.mesh import pair_sharding, replicated
 
-        # pid comes back sharded along the pair axis; the histogram is the
-        # cross-shard psum and replicates
+        # pid and the decoded row pairs come back sharded along the pair
+        # axis; the histogram is the cross-shard psum and replicates
+        pairs = pair_sharding(mesh)
         jit_kwargs = {
-            "out_shardings": (pair_sharding(mesh), replicated(mesh)),
+            "out_shardings": (pairs, pairs, pairs, replicated(mesh)),
         }
 
     # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches the
@@ -1245,7 +1251,9 @@ def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
             # overflow flag rides as pid[-1] (a B+1 output cannot shard
             # evenly, and mesh kernels are exact anyway)
             pid = jnp.concatenate([pid, ovf_flag.astype(pid.dtype)[None]])
-        return pid, acc
+        # the row pairs the ids were computed from go out beside them, so
+        # that no caller decodes the positions a second time
+        return pid, i, j, acc
 
     return fn
 
@@ -1277,12 +1285,15 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                        mesh=None, want_ids: bool = True, counts_out=None,
                        two_phase: bool = True, overflow_out=None):
     """Drive one device pass over the virtual pair stream, yielding
-    ``(rule, rule_p0, out_pos, n_valid, pid_host)`` per batch.
-    With ``want_ids``, pattern-id downloads run on a small thread pool a
+    ``(rule, rule_p0, out_pos, n_valid, pid_host, il_host, ir_host)`` per
+    batch: the pattern id of every position of the batch and the row pair
+    the kernel decoded it to (int32; at a masked position, where
+    ``pid_host`` holds the sentinel ``n_patterns``, the pair means nothing).
+    With ``want_ids``, one pooled download a batch brings the three home, a
     few batches deep (yield order stays submission order), so downloads
     are not serialised on the driver thread (D2H latency against kernel
     time: not measured on this machine).
-    ``pid_host`` is None when ``want_ids`` is
+    The three are None when ``want_ids`` is
     False — then NO per-pair bytes cross the link at all: the only D2H is
     the int32 histogram accumulator flush every ~2^10 batches, so the
     EM-only pattern pass does not wait on per-batch downloads
@@ -1362,15 +1373,35 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
         counts[:] += acc_host[:n_patterns]
         ovf_total += int(acc_host[n_patterns + 1])
 
-    def wait(fut):
-        """A pooled download's result: the driver thread's D2H wait."""
+    def download_batch(outs):
+        """One batch's pattern ids and row pairs home, on a pool thread."""
+        return tuple(download(x) for x in outs)
+
+    def settle(entry):
+        """What the pass yields for the oldest batch in flight, once its
+        download is home: the driver thread's D2H wait, and the batch's
+        redo where it overflowed."""
+        nonlocal acc
+        pr, pp0, ps, n_valid, fut, rd = entry
         with span("d2h_wait") as sp:
-            arr = fut.result()
-            sp.count(bytes=arr.nbytes)
-        return arr
+            pid_h, il, ir = fut.result()
+            sp.count(bytes=pid_h.nbytes + il.nbytes + ir.nbytes)
+        if rd is not None and pid_h[-1]:
+            # two-phase overflow: the flagged batch skipped the histogram;
+            # redo through the exact twin (acc addition commutes, late redo
+            # identical). The row pairs are the decode's, which both
+            # kernels share: the ones already home stand.
+            efn, e_pos, e_ord, e_units, e_meta = rd
+            pid2, _, _, acc = efn()(
+                e_pos, packed, e_ord, *e_units, codes_dev,
+                uid_dev, res_ops_dev, e_meta, acc,
+            )
+            pid_h = fetch(pid2)
+        return pr, pp0, ps, n_valid, pid_h[:n_valid], il[:n_valid], ir[:n_valid]
 
     pool = ThreadPoolExecutor(max_workers=_D2H_DEPTH) if want_ids else None
-    inflight: deque = deque()  # (rule, rule_p0, out_pos, n_valid, future)
+    # (rule, rule_p0, out_pos, n_valid, future, redo arguments)
+    inflight: deque = deque()
     try:
         packed = program._packed
         uid_codes = (
@@ -1446,31 +1477,20 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                 )
                 if kkey not in rp.kernels_run:
                     rp.kernels_run[kkey] = (fn, _abstract_args(args))
-                pid, acc = fn(*args)
+                pid, i, j, acc = fn(*args)
                 if want_ids:
                     redo_args = (
                         exact_fn, pos_rule, order_dev, units_dev, meta_dev,
                     ) if mesh is None else None
                     inflight.append(
                         (r, p0, out_pos, p1 - p0,
-                         pool.submit(download, pid), redo_args)
+                         pool.submit(download_batch, (pid, i, j)), redo_args)
                     )
                     while len(inflight) > _D2H_DEPTH:
-                        pr, pp0, ps, n_valid, fut, rd = inflight.popleft()
-                        arr = wait(fut)
-                        if rd is not None and arr[-1]:
-                            # two-phase overflow: the flagged batch skipped
-                            # the histogram; redo through the exact twin
-                            # (acc addition commutes, late redo identical)
-                            efn, e_pos, e_ord, e_units, e_meta = rd
-                            pid2, acc = efn()(
-                                e_pos, packed, e_ord, *e_units, codes_dev,
-                                uid_dev, res_ops_dev, e_meta, acc,
-                            )
-                            arr = fetch(pid2)
-                        yield pr, pp0, ps, n_valid, arr[:n_valid]
+                        yield settle(inflight.popleft())
                 else:
-                    yield r, p0, out_pos, p1 - p0, None
+                    # the kernel's row pairs stay on the device with its ids
+                    yield r, p0, out_pos, p1 - p0, None, None, None
                 out_pos += p1 - p0
                 in_acc += 1
                 if in_acc >= flush_every:
@@ -1481,16 +1501,7 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                     acc = put(np.zeros(n_patterns + 2, np.int32))
                     in_acc = 0
         while inflight:
-            pr, pp0, ps, n_valid, fut, rd = inflight.popleft()
-            arr = wait(fut)
-            if rd is not None and arr[-1]:
-                efn, e_pos, e_ord, e_units, e_meta = rd
-                pid2, acc = efn()(
-                    e_pos, packed, e_ord, *e_units, codes_dev,
-                    uid_dev, res_ops_dev, e_meta, acc,
-                )
-                arr = fetch(pid2)
-            yield pr, pp0, ps, n_valid, arr[:n_valid]
+            yield settle(inflight.popleft())
         # unconditional: an overflow redo during the tail drain can land
         # in acc after the last scheduled flush
         flush_acc(acc)
@@ -1503,20 +1514,33 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
             pool.shutdown(wait=False, cancel_futures=True)
 
 
+class VirtualIds(NamedTuple):
+    """What a virtual pass keeps per candidate position, in the pass's order
+    (rule after rule): the pattern id (the sentinel ``n_patterns`` where the
+    position is masked) and the row pair the kernel decoded it to."""
+
+    pid: np.ndarray  # uint16 where the sentinel fits, else int32
+    il: np.ndarray  # int32
+    ir: np.ndarray  # int32
+
+
 def compute_virtual_pattern_ids(program, plan: VirtualPlan,
                                 batch_size: int, mesh=None,
                                 return_ids: bool = True):
-    """One device pass over the VIRTUAL pair stream: (pids, counts,
-    n_real). pids carries the sentinel value ``n_patterns`` for masked
-    (deduped) positions; counts excludes them; n_real = counts.sum().
+    """One device pass over the VIRTUAL pair stream: (ids, counts,
+    n_real). ``ids`` is a ``VirtualIds``: per candidate position the pattern
+    id — the sentinel value ``n_patterns`` for masked (deduped) positions —
+    and the row pair ``il`` / ``ir`` the kernel decoded the position to, so
+    that whoever keeps the ids never decodes a position again; counts
+    excludes the masked positions; n_real = counts.sum().
 
-    With ``return_ids=False`` the pass computes ONLY the histogram — pids
+    With ``return_ids=False`` the pass computes ONLY the histogram — ids
     comes back None and no per-pair bytes ever cross the host<->device
     link. This is the EM-path mode: EM needs nothing but counts (what
-    the per-batch pid download costs against the kernel is not measured
+    the per-batch download costs against the kernel is not measured
     on this machine; scripts/virtual_breakdown.py takes it). The
-    score-output stream recomputes ids chunk-wise later via
-    ``_virtual_pass_iter`` (kernels are cached on the plan, so the second
+    score-output stream recomputes ids and pairs chunk-wise later via
+    ``_virtual_pass_iter`` (the kernels are the process's, so the second
     pass pays no compile).
 
     With ``mesh``, each batch SHARDS over the mesh's data axis (see
@@ -1527,19 +1551,22 @@ def compute_virtual_pattern_ids(program, plan: VirtualPlan,
     # sentinel must be representable
     id_dtype = np.uint16 if pattern_ids_fit_uint16(n_patterns) else np.int32
     counts = np.zeros(n_patterns, np.int64)
-    pids = (
-        np.empty(plan.n_candidates, id_dtype) if return_ids else None
-    )
+    ids = VirtualIds(
+        np.empty(plan.n_candidates, id_dtype),
+        np.empty(plan.n_candidates, np.int32),
+        np.empty(plan.n_candidates, np.int32),
+    ) if return_ids else None
     overflow: list = []
     from .utils.profiling import count
 
-    for _, _, ps, n_valid, chunk in _virtual_pass_iter(
+    for _, _, ps, n_valid, *chunks in _virtual_pass_iter(
         program, plan, batch_size, mesh=mesh, want_ids=return_ids,
         counts_out=counts, overflow_out=overflow,
     ):
         count(batches=1)
         if return_ids:
-            pids[ps : ps + n_valid] = chunk.astype(id_dtype)
+            for kept, chunk in zip(ids, chunks):
+                kept[ps : ps + n_valid] = chunk
     if not return_ids and overflow and overflow[0]:
         # Histogram-only mode has no per-batch reads, so overflowed
         # batches (which contributed nothing) are only visible here:
@@ -1558,4 +1585,4 @@ def compute_virtual_pattern_ids(program, plan: VirtualPlan,
             counts_out=counts, two_phase=False,
         ):
             pass
-    return pids, counts, int(counts.sum())
+    return ids, counts, int(counts.sum())

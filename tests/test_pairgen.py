@@ -145,9 +145,10 @@ def test_device_kernel_matches_host_decode():
     table = encode_table(df, s)
     plan = build_virtual_plan(s, table, chunk=8)  # force many units
     program = GammaProgram(s, table)
-    pids, counts, n_real = compute_virtual_pattern_ids(
+    ids, counts, n_real = compute_virtual_pattern_ids(
         program, plan, batch_size=128
     )
+    pids = ids.pid
     # oracle: decode on host, score the unmasked pairs through the
     # materialised pattern pipeline
     i, j = _pairs_from_plan(plan)
@@ -241,7 +242,8 @@ def test_virtual_materialised_ids_stream_matches_recompute():
     # policy engaged: ids kept from the EM pass (checked mid-stream —
     # exhausting the generator releases them)
     assert kept._P_virtual is not None
-    assert kept._P_virtual.dtype == np.uint16
+    assert kept._P_virtual.pid.dtype == np.uint16
+    assert kept._P_virtual.il.dtype == kept._P_virtual.ir.dtype == np.int32
     chunks.extend(gen)
     assert kept._P_virtual is None  # released once the stream is exhausted
     out_kept = pd.concat(chunks, ignore_index=True)
@@ -487,9 +489,10 @@ def test_virtual_residual_device_kernel_matches_host():
     plan = build_virtual_plan(s, table, chunk=8)
     assert plan is not None and plan.res_ops
     program = GammaProgram(s, table)
-    pids, counts, n_real = compute_virtual_pattern_ids(
+    ids, counts, n_real = compute_virtual_pattern_ids(
         program, plan, batch_size=128
     )
+    pids = ids.pid
     i, j = _pairs_from_plan(plan)  # host oracle (incl. residual masks)
     assert n_real == len(i)
     want_p, want_c = program.compute_pattern_ids(i, j, batch_size=128)
@@ -640,7 +643,8 @@ def test_virtual_pattern_ids_mesh_bit_parity():
     )
     assert n1 == n2
     np.testing.assert_array_equal(counts1, counts2)
-    np.testing.assert_array_equal(pids1, pids2)
+    for one, sharded in zip(pids1, pids2):  # pattern ids, then the row pairs
+        np.testing.assert_array_equal(one, sharded)
 
 
 def test_virtual_mesh_with_derived_keys_and_residuals():
@@ -676,7 +680,8 @@ def test_virtual_mesh_with_derived_keys_and_residuals():
     )
     assert n1 == n2
     np.testing.assert_array_equal(counts1, counts2)
-    np.testing.assert_array_equal(pids1, pids2)
+    for one, sharded in zip(pids1, pids2):  # pattern ids, then the row pairs
+        np.testing.assert_array_equal(one, sharded)
 
 
 def test_linker_virtual_mesh_e2e_matches_single_device():

@@ -353,10 +353,27 @@ def _wait_for(predicate, timeout=WAIT):
     return False
 
 
-def test_hedged_race_yields_one_delivered_tree(engine, trained, capture):
+@pytest.fixture
+def slow_engine(engine, monkeypatch):
+    """The engine with 50 ms added to every batch: a primary that answers
+    before the router's 1 ms hedge timer thread gets to run (seen once under
+    a loaded six-worker run) cancels the hedge, and the race these tests
+    are about never happens."""
+    real = engine.query_arrays
+
+    def slow(*args, **kwargs):
+        time.sleep(0.05)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(engine, "query_arrays", slow)
+    return engine
+
+
+def test_hedged_race_yields_one_delivered_tree(slow_engine, trained, capture):
     """Both replicas serve the hedged request: the first delivery claims
     the shared root, the second closes `discarded` — never two delivered
     trees for one trace."""
+    engine = slow_engine
     df, _, _ = trained
     a = _service(engine, name="replica-a", trace_sample_rate=0.0)
     b = _service(engine, name="replica-b", trace_sample_rate=0.0)
@@ -380,11 +397,12 @@ def test_hedged_race_yields_one_delivered_tree(engine, trained, capture):
 
 
 def test_hedge_loser_shed_yields_one_delivered_tree(
-    engine, trained, capture
+    slow_engine, trained, capture
 ):
     """The satellite race: the hedge attempt lands on a replica that
     SHEDS it (closed) — exactly one delivered tree, and the loser's tree
     carries the machine-readable shed reason."""
+    engine = slow_engine
     df, _, _ = trained
     a = _service(engine, name="replica-a", trace_sample_rate=0.0)
     b = _service(engine, name="replica-b", trace_sample_rate=0.0)

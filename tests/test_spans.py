@@ -408,6 +408,29 @@ def test_counts_at_the_stage_boundaries(job):
     assert all(s["counts"]["bytes"] > 0 for s in by_name["h2d_put"])
 
 
+def test_decode_pairs_counts_positions_in_pairs_out_and_who_decoded(job):
+    """The score stream's ``decode_pairs`` spans: every candidate position
+    goes in (``rows``), the unmasked ones come out (``kept``), and the row
+    pair of every one of them was decoded by the kernel and not a second
+    time on the host (``device_decoded`` == ``rows``) — ROADMAP A3."""
+    name, linker, table = job
+    decodes = [s for s in table if s["name"] == "decode_pairs"]
+    if name != "dedupe":
+        assert not decodes  # materialised pairs: nothing virtual to decode
+        return
+    by_id = {s["id"]: s for s in table}
+    assert decodes and all(
+        by_id[s["parent"]]["name"] == "score_patterns" for s in decodes
+    )
+    assert all(set(s["counts"]) == {"rows", "kept", "device_decoded"}
+               for s in decodes)
+    assert sum(s["counts"]["rows"] for s in decodes) == linker._virtual.n_candidates
+    assert all(s["counts"]["device_decoded"] == s["counts"]["rows"] for s in decodes)
+    [scored] = [s for s in table if s["name"] == "scored_comparisons"]
+    assert sum(s["counts"]["kept"] for s in decodes) == scored["counts"]["pairs"]
+    assert any(s["counts"]["kept"] < s["counts"]["rows"] for s in decodes)
+
+
 def _gamma_pass_builds(table: list[dict]) -> dict[str, list[dict]]:
     """The build spans directly under the job's gamma pass, by name."""
     by_id = {s["id"]: s for s in table}
