@@ -4,7 +4,7 @@
 
 The quickest proof that the system still starts on the chip: one process
 drives BASELINE config 4 (six comparison columns, three blocking rules,
-data from benchmarks/datagen.py at a seed) through the entry points a user
+people from chipbench/datagen.py at a seed) through the entry points a user
 calls — ``Splink(...).get_scored_comparisons()``, ``export_index`` ->
 ``load_index`` -> ``QueryEngine.warmup()`` -> ``LinkageService.submit()`` —
 and checks what comes out against a pandas join, against a second linker on
@@ -39,7 +39,7 @@ import warnings
 
 import numpy as np
 
-ROWS = 300_000  # base rows -> ~390k with duplicates: > AUTO_MIN_PAIRS pairs
+ROWS = 390_000  # > AUTO_MIN_PAIRS candidate pairs
 REHEARSAL_ROWS = 12_000
 SEED = 4
 SERVE_REQUESTS = 300
@@ -106,17 +106,24 @@ class Leg:
 
 
 # ---------------------------------------------------------------------------
-# Model: BASELINE config 4 (benchmarks/run.py config_4_settings), nothing
-# dropped
+# Model: BASELINE config 4 as the benchmark defines it
+# (chipbench/configs/baseline_c4.json "settings"), nothing dropped
 # ---------------------------------------------------------------------------
 
 JW_COLUMNS = ["first_name", "surname", "postcode"]
+CONFIG_4 = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "chipbench", "configs", "baseline_c4.json")
+# what that file lists under "assumed" for its cell's size: the smoke's legs
+# choose the pair index and the batch themselves
+CELL_KEYS = ("device_pair_generation", "max_resident_pairs", "pair_batch_size")
 
 
 def smoke_settings(**extra) -> dict:
-    from benchmarks.run import config_4_settings
-
-    return {**config_4_settings(), **extra}
+    with open(CONFIG_4) as f:
+        model = json.load(f)["settings"]
+    for key in CELL_KEYS:
+        del model[key]
+    return {**model, **extra}
 
 
 def rule_keys() -> list[list[str]]:
@@ -558,7 +565,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse-on-cpu", action="store_true")
     ap.add_argument("--rows", type=int, default=0,
-                    help=f"base rows (default {ROWS}; rehearsal "
+                    help=f"rows (default {ROWS}; rehearsal "
                     f"{REHEARSAL_ROWS})")
     args = ap.parse_args()
     REHEARSAL = args.rehearse_on_cpu
@@ -569,7 +576,7 @@ def main() -> int:
     device = leg_identity()
     import jax
 
-    from benchmarks.datagen import make_people
+    from chipbench.datagen import make_people
 
     t0 = time.perf_counter()
     df = make_people(rows, seed=SEED)

@@ -30,8 +30,7 @@ against the compiled SPMD program:
             estimates from XLA ``cost_analysis()`` / ``memory_analysis()``,
             checked against committed JSON baselines
             (``shard_baselines.json``) within a tolerance — cost regressions
-            fail ``make lint`` the same way a lint finding does, making the
-            budgets part of the perf trajectory alongside ``BENCH_*.json``.
+            fail ``make lint`` the same way a lint finding does.
 
 The audit forces x64 OFF while lowering (mirroring trace_audit forcing it
 ON): baselines are recorded for the production-width program, so the gate

@@ -572,7 +572,7 @@ def generate_approx_candidates(
     chunk_cap = int(settings.get("blocking_chunk_pairs") or 0) or (1 << 22)
     # the budget shapes nothing in the plan (bands/threshold do), so read
     # it from the CALLER's settings — a reused plan composes with a
-    # different budget (the bench's unbudgeted-coverage pass relies on it)
+    # different budget (an unbudgeted-coverage pass over it relies on this)
     budget = int(settings.get("approx_pair_budget") or cfg.budget)
     # bounded pre-ranking working set: the host accumulates AT MOST
     # ~2x budget candidates — whenever the accumulation exceeds the cap it

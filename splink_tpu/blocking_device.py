@@ -2,8 +2,9 @@
 
 blocking.py's host joins were the last pipeline stage computed entirely on
 the host — np.argsort over every rule's key codes, np.repeat/np.cumsum pair
-expansion, 8.2M pairs/s single-threaded while the chip scores 28M+/s
-(BENCHMARKS.md). This module moves the join itself onto the device as a
+expansion, single-threaded (8.2M pairs/s on one CPU core against 28M+/s
+in a builders' chip session of round 4, older than the code: neither
+reproduced). This module moves the join itself onto the device as a
 sort-based hash join over the SAME packed key codes blocking.py builds
 (HyperBlocker, arXiv:2410.04349, maps rule-based blocking onto exactly this
 kind of accelerator parallelism):
@@ -804,10 +805,12 @@ def device_block_rules(
         from .blocking import estimate_pair_upper_bound
 
         if jax.default_backend() == "cpu":
-            # measured (BENCHMARKS.md round 8, 2-core container): the
-            # XLA-CPU tier ties the numpy host join and trails the native
-            # C++ one ~0.75x — on the CPU backend auto keeps the host
-            # path; 'on' still forces the device tier (tests, parity)
+            # CPU container, builders' round 8 (2 cores): the XLA-CPU tier
+            # tied the numpy host join and trailed the native C++ one
+            # ~0.75x — on the CPU backend auto keeps the host path; 'on'
+            # still forces the device tier (tests, parity). That the
+            # device join wins on the chip is unverified: no benchmark
+            # cell runs it (PERF.md §7)
             return None
         # exact-rules-only bound: this gate weighs the EXACT tier's jit
         # warmup against its join size, so the approx tier's budget (which

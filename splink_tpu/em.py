@@ -2,7 +2,8 @@
 
 The reference's EM driver round-trips driver <-> cluster every iteration and
 re-plans a fresh SQL query with the parameters baked in as literals
-(/root/reference/splink/iterate.py:20, expectation_step.py:212). Here the
+(/root/reference/splink/iterate.py:20,
+/root/reference/splink/expectation_step.py:212). Here the
 whole loop is a single ``lax.while_loop`` compiled once: parameters are traced
 arguments that stay in device memory, the convergence predicate evaluates on
 device, and per-iteration parameter history is written into preallocated
@@ -165,7 +166,8 @@ def run_em(
         if compute_ll:
             # Log likelihood under the *pre-update* params, stored at the
             # pre-update index — the reference computes ll in the E-step and
-            # archives it with those params (expectation_step.py:52-57).
+            # archives it with those params
+            # (/root/reference/splink/expectation_step.py:52-57).
             ll_val = log_likelihood(G, state.params, weights)
             ll_h = ll_h.at[state.it].set(ll_val)
         if host_hook:
@@ -252,8 +254,8 @@ def run_em_checkpointed(
     An earlier revision re-entered the compiled while_loop in
     K-iteration segments; XLA hoists the loop-invariant one-hot gamma
     expansion out of the loop body, so every re-entry re-paid it — ~30%
-    wall-clock overhead at K=5 on the CPU tier, vs <5% for this in-loop
-    form (BENCHMARKS.md).
+    wall-clock overhead at K=5, vs <5% for this in-loop form (CPU
+    container, PR 1; not reproduced on the chip).
 
     Histories are host numpy arrays in run_em's layout (index i = params
     before update i+1; ll index i = log likelihood under params i).

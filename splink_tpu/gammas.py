@@ -1067,8 +1067,8 @@ class GammaProgram:
         return self._kernel("gamma_body", (), _jit_gamma_body)
 
     def _gamma_batch(self, il, ir):
-        """The convenience path (bench.py's jitted score loop, ad-hoc
-        scoring): IMPOSSIBLE to misuse — when the two-phase survivor
+        """The convenience path (ad-hoc scoring, tests): IMPOSSIBLE to
+        misuse — when the two-phase survivor
         capacity blows it redoes the batch through the exact body on
         device. The double-buffered host paths use the flagged variants,
         whose host-side redo overlaps transfers."""

@@ -710,8 +710,9 @@ class Splink:
         stream is going to happen and they fit host RAM: the kernels run
         once instead of twice, and the downloads overlap the kernels either
         way. EM-only jobs keep the histogram-only pass — no per-pair bytes
-        ever cross the link (cost not measured on this machine;
-        scripts/virtual_breakdown.py)."""
+        ever cross the link (what the bytes cost when they do is the
+        ``d2h_wait`` / ``mesh_gather`` / ``decode_pairs`` spans of a
+        ``chipbench`` run)."""
         mode = self.settings.get("virtual_materialise_ids", "auto")
         if mode == "on":
             return True

@@ -42,7 +42,7 @@ Layers (each importable on its own, none imports jax at module scope):
     performance observatory (:mod:`..analysis.perf_audit` is the CI
     half).
   * :mod:`.cli`     — ``python -m splink_tpu.obs
-    summarize|export-trace|attribute|drift|bench-report|serve-dash``.
+    summarize|export-trace|attribute|drift|serve-dash|fleet-dash``.
 
 Zero-cost contract: with no sink configured (``telemetry_dir`` empty) the
 linker adds NO host callbacks and compiled programs are unchanged — the

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -758,219 +757,6 @@ def drift_events_report(events: list[dict]) -> str:
 
 
 # ---------------------------------------------------------------------------
-# bench-report: normalise the heterogeneous BENCH_r*.json history into one
-# per-metric, per-tier trajectory table and flag cross-round deltas
-# ---------------------------------------------------------------------------
-
-#: metric-name fragments whose direction is known (regression = value went
-#: the wrong way); anything else flags as a neutral CHANGE
-_LOWER_IS_BETTER = (
-    "seconds", "_ms", "latency", "overhead", "warmup", "cold",
-    "p50", "p95", "p99", "compiles", "recompile", "shed",
-)
-_HIGHER_IS_BETTER = (
-    # "recall" also covers the recall-per-budget family (rounds 11/14:
-    # recall_at_budget, recall_at_budget_tf) — pinned by the direction
-    # test beside the bench-report tests
-    "per_sec", "qps", "recall", "hit_rate", "throughput", "speedup",
-    "pairs_per",
-)
-
-
-def _metric_direction(name: str) -> str | None:
-    low = name.lower()
-    if any(f in low for f in _HIGHER_IS_BETTER):
-        return "higher"
-    if any(f in low for f in _LOWER_IS_BETTER):
-        return "lower"
-    return None
-
-
-def _bench_round(path: str, payload: dict) -> int | None:
-    import re
-
-    n = payload.get("n")
-    if isinstance(n, int):
-        return n
-    m = re.search(r"BENCH_r(\d+)", os.path.basename(path))
-    return int(m.group(1)) if m else None
-
-
-def _numeric_items(d: dict):
-    for k, v in d.items():
-        if isinstance(v, bool) or not isinstance(v, (int, float)):
-            continue
-        yield k, float(v)
-
-
-def normalise_bench_files(paths: list) -> tuple[list, list]:
-    """Flatten heterogeneous BENCH json artifacts into
-    ``(rows, failures)``. Every artifact shape in the history is handled:
-    the driver wrapper (``{"n", "cmd", "rc", "tail", "parsed"}`` — rounds
-    whose ``parsed`` is null land in ``failures`` so the trajectory still
-    shows them) and the raw one-line result objects. Each row is
-    ``{"metric", "round", "tier", "value", "file"}``; the headline
-    ``value`` key is renamed to its declared ``metric``, and nested
-    ``tiers_detail`` blocks (the cold-start bench's per-tier sweep) emit
-    rows labelled with the sub-tier name."""
-    rows: list[dict] = []
-    failures: list[dict] = []
-    for path in paths:
-        try:
-            with open(path, encoding="utf-8") as fh:
-                text = fh.read()
-            # two shapes on disk: one (pretty-printed) JSON document, or
-            # one JSON object per line — there the LAST line wins (bench
-            # prints a partial headline first, then the full result)
-            try:
-                payload = json.loads(text)
-            except ValueError:
-                payload = None
-                for line in text.splitlines():
-                    line = line.strip()
-                    if line.startswith("{"):
-                        try:
-                            payload = json.loads(line)
-                        except ValueError:
-                            continue
-        except OSError as e:
-            failures.append({"file": os.path.basename(path),
-                             "reason": str(e)})
-            continue
-        if not isinstance(payload, dict):
-            failures.append({"file": os.path.basename(path),
-                             "reason": "no JSON object found"})
-            continue
-        rnd = _bench_round(path, payload)
-        if "cmd" in payload and "rc" in payload:  # driver wrapper
-            parsed = payload.get("parsed")
-            if not isinstance(parsed, dict):
-                failures.append({
-                    "file": os.path.basename(path),
-                    "round": rnd,
-                    "reason": f"no parsed result (rc {payload.get('rc')})",
-                })
-                continue
-            payload = parsed
-        base = os.path.basename(path)
-        tier = str(payload.get("tier") or "?")
-        headline = payload.get("metric")
-
-        def emit(name: str, value: float, tier_label: str) -> None:
-            rows.append({
-                "metric": name, "round": rnd, "tier": tier_label,
-                "value": value, "file": base,
-            })
-
-        for key, value in _numeric_items(payload):
-            if key in ("n", "rc"):
-                continue
-            name = headline if key == "value" and headline else key
-            emit(str(name), value, tier)
-        detail = payload.get("tiers_detail")
-        if isinstance(detail, dict):
-            for sub, block in detail.items():
-                if isinstance(block, dict):
-                    for key, value in _numeric_items(block):
-                        emit(key, value, str(sub))
-    return rows, failures
-
-
-def bench_report_text(paths: list, threshold: float = 0.3) -> str:
-    """The trajectory report: one line per metric with its (round, tier)
-    point series, plus a flag section listing every consecutive delta
-    past ``threshold`` — compared across rounds within one tier, and
-    across tiers within one round (a tier sweep like the cold-start
-    bench's nocache->cache_warm->aot IS a trajectory) — labelled
-    REGRESSION / IMPROVEMENT where the metric name's direction is known,
-    CHANGE otherwise."""
-    rows, failures = normalise_bench_files(paths)
-    series: dict[str, list] = {}
-    for row in rows:
-        series.setdefault(row["metric"], []).append(row)
-    for pts in series.values():
-        pts.sort(key=lambda r: (r["round"] if r["round"] is not None else 0))
-    lines = [
-        f"bench trajectory: {len(paths)} artifact(s), "
-        f"{len(series)} metric(s)"
-    ]
-    for f in failures:
-        rnd = f.get("round")
-        lines.append(
-            f"  r{rnd:02d}: no result ({f['reason']}) [{f['file']}]"
-            if rnd is not None
-            else f"  {f.get('file')}: {f['reason']}"
-        )
-    lines.append("")
-    width = max((len(m) for m in series), default=10)
-    for metric in sorted(series):
-        pts = series[metric]
-        shown = pts if len(pts) <= 6 else pts[:3] + [None] + pts[-2:]
-        parts = []
-        for p in shown:
-            if p is None:
-                parts.append("..")
-                continue
-            tier = f"[{p['tier']}]" if p["tier"] != "?" else ""
-            parts.append(f"{_fmt_round(p['round'])}{tier}={_fmt_num(p['value'])}")
-        lines.append(f"{metric:<{width}}  " + " -> ".join(parts))
-    flags = []
-    for metric in sorted(series):
-        pts = series[metric]
-        direction = _metric_direction(metric)
-        for a, b in zip(pts, pts[1:]):
-            # round-less artifacts (no "n", filename without r<digits>)
-            # only compare within one tier — "same unknown round" is not
-            # a regime match
-            same_round = (
-                a["round"] is not None and a["round"] == b["round"]
-            )
-            same_tier = a["tier"] == b["tier"]
-            if not (same_round or same_tier):
-                continue  # different benchmark regimes: not comparable
-            if a["value"] == 0:
-                continue
-            rel = (b["value"] - a["value"]) / abs(a["value"])
-            if abs(rel) < threshold:
-                continue
-            if direction is None:
-                label = "CHANGE"
-            elif (rel > 0) == (direction == "higher"):
-                label = "IMPROVEMENT"
-            else:
-                label = "REGRESSION"
-            flags.append(
-                f"  {label:<12}{metric}: {_fmt_num(a['value'])} "
-                f"({_fmt_round(a['round'])}, {a['tier']}) -> "
-                f"{_fmt_num(b['value'])} ({_fmt_round(b['round'])}, "
-                f"{b['tier']}) [{rel:+.1%}]"
-            )
-    lines.append("")
-    if flags:
-        lines.append(f"flags (|delta| >= {threshold:.0%}):")
-        lines.extend(flags)
-    else:
-        lines.append(f"no deltas past {threshold:.0%}")
-    return "\n".join(lines)
-
-
-def _fmt_round(rnd) -> str:
-    return f"r{rnd:02d}" if rnd is not None else "r?"
-
-
-def _fmt_num(v: float) -> str:
-    if v == int(v) and abs(v) < 1e12:
-        return str(int(v))
-    return f"{v:.3f}" if abs(v) < 1000 else f"{v:.1f}"
-
-
-def _default_bench_paths(directory: str) -> list:
-    import glob as _glob
-
-    return sorted(_glob.glob(os.path.join(directory, "BENCH_*.json")))
-
-
-# ---------------------------------------------------------------------------
 # serve-dash: poll the Prometheus exposition endpoint, render a terminal view
 # ---------------------------------------------------------------------------
 
@@ -1212,23 +998,6 @@ def main(argv=None) -> int:
              "reference + alert timeline",
     )
     p_drift.add_argument("path", help="telemetry JSONL file")
-    p_bench = sub.add_parser(
-        "bench-report",
-        help="normalise the BENCH_r*.json history into one per-metric, "
-             "per-tier trajectory table and flag cross-round deltas",
-    )
-    p_bench.add_argument(
-        "paths", nargs="*",
-        help="BENCH json files (default: BENCH_*.json in --dir)",
-    )
-    p_bench.add_argument(
-        "--dir", default=".",
-        help="directory scanned for BENCH_*.json when no paths are given",
-    )
-    p_bench.add_argument(
-        "--threshold", type=float, default=0.3,
-        help="relative delta that flags a cross-round change (default 0.3)",
-    )
     p_dash = sub.add_parser(
         "serve-dash",
         help="live terminal dashboard over a service's Prometheus endpoint",
@@ -1263,15 +1032,6 @@ def main(argv=None) -> int:
     if args.command == "fleet-dash":
         return serve_dash(args.url, args.interval, args.count,
                           renderer=render_fleet_dash)
-
-    if args.command == "bench-report":
-        paths = args.paths or _default_bench_paths(args.dir)
-        if not paths:
-            print(f"error: no BENCH_*.json under {args.dir}",
-                  file=sys.stderr)
-            return 2
-        print(bench_report_text(paths, args.threshold))
-        return 0
 
     try:
         events = read_events(args.path)

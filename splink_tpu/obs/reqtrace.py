@@ -63,8 +63,8 @@ logger = logging.getLogger("splink_tpu")
 
 # Trace ids are <process-random prefix>-<counter>: unique across processes
 # (the prefix is 8 random hex chars drawn once) and ~40x cheaper to mint
-# than uuid4, which pays an os.urandom syscall per request — measured at
-# 40us of the close path's budget on the bench tier.
+# than uuid4, which pays an os.urandom syscall per request — 40us of the
+# close path's budget in a CPU container (builders' round 9).
 _TRACE_PREFIX = os.urandom(4).hex()
 _TRACE_COUNTER = itertools.count(1)
 
@@ -312,8 +312,8 @@ class ServeTracer:
 
     def phase_summary(self) -> dict:
         """p50/p99 milliseconds per phase (plus wall) over the recent
-        delivered-trace reservoir — the fields bench.py's serve mode emits
-        and the Prometheus endpoint exposes."""
+        delivered-trace reservoir — the fields the Prometheus endpoint
+        exposes."""
         with self._lock:
             snap = list(self._phases)
         if not snap:

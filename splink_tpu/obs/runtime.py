@@ -217,7 +217,7 @@ class RunContext:
     @contextmanager
     def span(self, name: str, **attrs):
         """Standalone stage-kind span for callers outside a linker run (the
-        serve loop's batches, bench.py): emitted to the record and kept in
+        serve loop's batches): emitted to the record and kept in
         no span table, so a long-lived service does not grow one."""
         if not self.enabled:
             yield

@@ -2,9 +2,10 @@
 
 The gamma program needs only the LEVEL a pair's JW similarity falls in, not
 the score itself — and on config-4-shaped blocked pairs ~92% of pairs sit
-below the lowest threshold (benchmarks/jw_bound_proto.py: survivor rates
-3.7% first_name / 2.9% surname / 0.2% postcode, plus 4-8% token-equal pairs
-whose level is known without any kernel). A sound upper bound that costs a
+below the lowest threshold (survivor rates 3.7% first_name / 2.9% surname /
+0.2% postcode, plus 4-8% token-equal pairs whose level is known without any
+kernel: counted once on a synthetic config-4 sample by a prototype since
+deleted, not per blocking rule — ROADMAP D1 asks for that reading). A sound upper bound that costs a
 few dozen word ops per pair therefore lets the exact O(L^2) kernel run on a
 compacted survivor subset only (gammas._jw_two_phase).
 

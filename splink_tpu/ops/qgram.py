@@ -27,7 +27,7 @@ intersections run as O(w^2) masked equality reductions over the <= w-q+1
 windows of the fixed-width strings. At linkage string widths (w <= 32) that
 is a few thousand VPU compares per pair: cheaper than a gather-heavy hash
 profile, and exact. (Round 1 hashed grams into 256 buckets; collisions
-inflated similarity, which VERDICT.md flagged — the hashed path is gone.)
+inflated similarity — the hashed path is gone.)
 """
 
 from __future__ import annotations

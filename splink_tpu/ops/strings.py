@@ -254,8 +254,8 @@ _jaro_winkler_bitmask_vmapped = jax.vmap(
 
 def jaro_winkler_vmapped(s1, s2, l1, l2, prefix_scale=0.1, boost_threshold=0.7):
     """Batched JW: packed-bitmask formulation when the width fits one uint32
-    (all practical columns; benchmarks/kernel_bench.py measures the gap),
-    vector formulation beyond."""
+    (all practical columns; fewer ops per pair — the gap has not been
+    timed on the chip), vector formulation beyond."""
     if s1.shape[1] <= 32:
         return _jaro_winkler_bitmask_vmapped(
             s1, s2, l1, l2, prefix_scale, boost_threshold

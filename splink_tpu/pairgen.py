@@ -1,10 +1,13 @@
 """Device-side candidate-pair generation: the virtual pair index.
 
-The measured bottleneck at the 10M-row configs is HOST pair
-materialisation — the joins emit 8.2M pairs/s single-threaded while the
-chip scores 28M+/s (BENCHMARKS.md), and every pair costs 8 bytes of
-host->device index traffic plus (spilled) 8 bytes of disk write and
-re-read. This module removes the pairs from the host entirely for
+At the 10M-row configs HOST pair materialisation is the cost to avoid:
+config 4 has 3.3B candidate pairs, and every materialised pair costs 8
+bytes of host->device index traffic plus (spilled) 8 bytes of disk write
+and re-read. (The rates this was first argued from — host joins 8.2M
+pairs/s on one CPU core, the chip 28M+/s in a builders' session of round
+4, older than the code — were never reproduced; what the virtual index
+delivers end to end is PERF.md's `c4_dedupe_virtual`.)
+This module removes the pairs from the host entirely for
 equality-rule blocking: pairs are DECODED ON DEVICE from per-rule group
 structure, the sequential-rule dedup becomes an on-device mask, and the
 gamma/pattern program consumes them in the same kernel — per batch the
@@ -1291,13 +1294,13 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     ``pid_host`` holds the sentinel ``n_patterns``, the pair means nothing).
     With ``want_ids``, one pooled download a batch brings the three home, a
     few batches deep (yield order stays submission order), so downloads
-    are not serialised on the driver thread (D2H latency against kernel
-    time: not measured on this machine).
+    are not serialised on the driver thread (what the driver still waits
+    is the ``d2h_wait`` span, 2.18 s of a ``c4_dedupe_virtual`` job; under
+    a mesh ``mesh_put`` + ``mesh_gather`` add 0.60 s a job: ledger PR 30).
     The three are None when ``want_ids`` is
     False — then NO per-pair bytes cross the link at all: the only D2H is
     the int32 histogram accumulator flush every ~2^10 batches, so the
-    EM-only pattern pass does not wait on per-batch downloads
-    (scripts/virtual_breakdown.py takes the breakdown).
+    EM-only pattern pass does not wait on per-batch downloads.
 
     The histogram accumulates into ``counts_out`` (int64, n_patterns); the
     caller owns the array. Host work per batch is O(units-in-batch): a
@@ -1537,8 +1540,8 @@ def compute_virtual_pattern_ids(program, plan: VirtualPlan,
     With ``return_ids=False`` the pass computes ONLY the histogram — ids
     comes back None and no per-pair bytes ever cross the host<->device
     link. This is the EM-path mode: EM needs nothing but counts (what
-    the per-batch download costs against the kernel is not measured
-    on this machine; scripts/virtual_breakdown.py takes it). The
+    the per-batch download costs is the ``d2h_wait`` / ``mesh_gather``
+    spans of a ``chipbench`` run). The
     score-output stream recomputes ids and pairs chunk-wise later via
     ``_virtual_pass_iter`` (the kernels are the process's, so the second
     pass pays no compile).

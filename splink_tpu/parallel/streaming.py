@@ -80,7 +80,8 @@ def run_em_streamed(
             runs pass ``parallel.distributed.all_sum_stats`` here so every
             process updates from the GLOBAL aggregate while streaming only
             its own ``global_pair_slice`` (the reference gets this from
-            Spark's global shuffle, maximisation_step.py:54-57).
+            Spark's global shuffle,
+            /root/reference/splink/maximisation_step.py:54-57).
         on_iteration: optional callback(iteration_index, FSParams, ll,
             converged) run after each update — the save_state_fn hook's
             internal analogue (and where resilience.EMCheckpointer plugs

@@ -3,7 +3,8 @@
 The bucket-shape menu (:mod:`.bucketing`) makes steady-state serving
 recompile-free, but every fresh process still pays one backend compile per
 (query-bucket × candidate-bucket) combination before it can take traffic —
-12.4 s of measured warmup on the CPU tier (BENCHMARKS.md), which the PR 6
+12.4 s of warmup in a CPU container (builders' round 7; on the chip not
+measured as a metric: no cold-start cell yet, PERF.md §7), which the PR 6
 ``ReplicaRouter`` fleet pays on every replica restart and the PR 8
 compile-stall health signal reads as a degraded window. This module
 removes that cost: after :meth:`~.engine.QueryEngine.warmup`, the engine

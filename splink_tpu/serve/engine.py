@@ -430,7 +430,7 @@ class QueryEngine:
         # docstring): default on whenever the index carries fold data
         # (serve_tf_adjust settings gate). ``tf_adjust=`` overrides the
         # gate like ``fused=`` so one index can serve TF-on and TF-off
-        # engines side by side (the bench's interleaved tier); it never
+        # engines side by side (tests/test_serve_tf.py does); it never
         # conjures a fold for an index without the data.
         self._tf_override = tf_adjust  # forwarded across swap_index
         want_tf = bool(
@@ -495,8 +495,7 @@ class QueryEngine:
         # serves unchanged and drift reporting states why it is dark.
         # ``sketch=`` overrides the settings gate (like ``fused=``) so one
         # profiled index can serve sketch-on and sketch-off engines
-        # side by side (the bench's interleaved overhead tier); it never
-        # conjures a sketch for a profile-less index.
+        # side by side; it never conjures a sketch for a profile-less index.
         self.sketch = None
         self._sketch_override = sketch  # forwarded across swap_index
         want_sketch = (

@@ -1267,8 +1267,9 @@ def build_index(linker, *, clear_caches: bool = True) -> LinkageIndex:
             }
 
         # same backend policy as device_block_rules: 'auto' keeps the host
-        # argsort on the CPU backend (the XLA-CPU sort measured slower —
-        # BENCHMARKS.md round 8); 'on' forces the device CSR anywhere
+        # argsort on the CPU backend (the XLA-CPU sort was slower in a CPU
+        # container, builders' round 8; that the device CSR wins on the
+        # chip is unverified); 'on' forces the device CSR anywhere
         import jax
 
         blk_mode = settings.get("device_blocking", "auto")

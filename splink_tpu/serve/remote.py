@@ -1088,7 +1088,7 @@ class RemoteReplica:
         """Rolling stats for the wire-overhead phases (serialize /
         network / server_queue / server_execute / deserialize) the
         netwatch accumulates — the per-remote per-hop attribution the
-        fleet dashboard and ``bench.py fleet`` render."""
+        fleet dashboard renders."""
         return {
             p: self._netwatch.phase_stats(p)
             for p in self._netwatch.phases()

@@ -65,8 +65,9 @@ Request-level observability (obs v2, docs/observability.md#serve-tracing):
   renders it live.
 
 All of it is host-side bookkeeping: compiled programs are untouched, the
-hot path gains no host sync, and sampling keeps obs-on overhead within the
-bench-measured budget (BENCHMARKS.md round 9).
+hot path gains no host sync, and sampling keeps obs-on overhead small
+(under ~2% in a CPU container, builders' round 9; unverified on the chip:
+no benchmark cell serves yet, PERF.md §7).
 """
 
 from __future__ import annotations
@@ -1336,8 +1337,8 @@ class LinkageService:
 
     def phase_summary(self) -> dict:
         """p50/p99 per phase (ms) over the recent delivered traces —
-        empty when tracing is off (``serve_trace_sample_rate`` 0). The
-        tail-latency attribution bench.py's serve mode emits."""
+        empty when tracing is off (``serve_trace_sample_rate`` 0): the
+        tail-latency attribution ``obs attribute`` renders."""
         return self._tracer.phase_summary()
 
     def slo_snapshot(self) -> dict:

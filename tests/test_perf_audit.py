@@ -3,10 +3,12 @@
 The falsifiability contract every audit layer holds: a healthy kernel
 measured against its own fresh baseline audits clean, and a baseline
 doctored to claim the kernel used to be faster/smaller makes the gate
-fire (PA-TIME / PA-MEM) — then a refresh clears it. Runtime findings are
-exercised with the absolute noise floors monkeypatched down (the real
-floors exist precisely so this 2-core container's jitter cannot flap CI;
-the tests must not depend on that jitter either way).
+fire (PA-TIME / PA-MEM) — then a refresh clears it. Every test that
+asserts on PA-TIME, or on an audit being clean, takes its clock readings
+from the ``readings`` fixture and not from the container: the kernel still
+compiles and runs, but the milliseconds the audit compares are the test's,
+so firing and clearing follow the baseline and not the machine's load
+(under six workers a real reading of a 0.05 ms kernel is anything).
 """
 
 import copy
@@ -33,6 +35,49 @@ def _measured_baselines(names, best_of=2):
 def tf_gather_baselines():
     """One cheap kernel (reg + x4) measured once for the module."""
     return _measured_baselines(["tf_gather"])
+
+
+# what the ``readings`` fixture answers until a test says otherwise: well
+# above the real bands' absolute floors (1 ms execute, 500 ms compile)
+EXECUTE_MS, COMPILE_MS = 50.0, 2000.0
+
+
+@pytest.fixture
+def readings(monkeypatch):
+    """The audit's two clock seams answer from this dict: ``_compile_cell``
+    still compiles and ``_execute_best_of`` still runs the kernel, and both
+    report the milliseconds held here (a list is read one entry a call, its
+    last entry for good)."""
+    held = {"execute_ms": EXECUTE_MS, "compile_ms": COMPILE_MS}
+
+    def reading(key):
+        value = held[key]
+        if isinstance(value, list):
+            return value.pop(0) if len(value) > 1 else value[0]
+        return value
+
+    real_compile, real_execute = pa._compile_cell, pa._execute_best_of
+
+    def compile_cell(name, factor):
+        return (*real_compile(name, factor)[:3], reading("compile_ms"))
+
+    def execute_best_of(compiled, args, kwargs, best_of):
+        real_execute(compiled, args, kwargs, 1)
+        return reading("execute_ms")
+
+    monkeypatch.setattr(pa, "_compile_cell", compile_cell)
+    monkeypatch.setattr(pa, "_execute_best_of", execute_best_of)
+    return held
+
+
+def _timed_as(baselines):
+    """The measured baselines with every record's two clock metrics set to
+    what ``readings`` answers."""
+    out = copy.deepcopy(baselines)
+    for shapes in out["tiers"][pa.current_tier()]["kernels"].values():
+        for rec in shapes.values():
+            rec["execute_ms"], rec["compile_ms"] = EXECUTE_MS, COMPILE_MS
+    return out
 
 
 def test_perf_plan_covers_registry():
@@ -86,19 +131,27 @@ def test_measure_cell_records_all_metrics(tf_gather_baselines):
     assert rec["peak_device_bytes"] is None
 
 
-def test_fresh_baseline_audits_clean(tf_gather_baselines):
+def test_fresh_baseline_audits_clean(tf_gather_baselines, readings):
+    """A kernel that reads what its baseline says — and one that reads
+    1.9x of it, inside the +100% band — audits clean."""
     findings, n = pa.run_perf_audit(
-        ["tf_gather"], tf_gather_baselines, best_of=2, remeasure=2
+        ["tf_gather"], _timed_as(tf_gather_baselines), best_of=2, remeasure=2
     )
     assert n == 2  # reg + x4
     assert findings == []
+    readings.update(execute_ms=95.0, compile_ms=3800.0)
+    findings, _ = pa.run_perf_audit(
+        ["tf_gather"], _timed_as(tf_gather_baselines), best_of=2, remeasure=2
+    )
+    assert findings == []
 
 
-def test_inflated_baseline_stays_clean_one_sided(tf_gather_baselines):
+def test_inflated_baseline_stays_clean_one_sided(tf_gather_baselines,
+                                                 readings):
     """The runtime gate is ONE-SIDED: a baseline slower/bigger than the
     measurement (the kernel got faster) is an improvement, not a
     finding."""
-    inflated = copy.deepcopy(tf_gather_baselines)
+    inflated = _timed_as(tf_gather_baselines)
     for shapes in inflated["tiers"][pa.current_tier()]["kernels"].values():
         for rec in shapes.values():
             for key in ("compile_ms", "execute_ms", "temp_bytes",
@@ -111,28 +164,50 @@ def test_inflated_baseline_stays_clean_one_sided(tf_gather_baselines):
     assert findings == []
 
 
-def test_doctored_time_baseline_fires_pa_time(tf_gather_baselines,
-                                              monkeypatch):
+def test_doctored_time_baseline_fires_pa_time(tf_gather_baselines, readings):
     """A baseline claiming the kernel used to run 1000x faster makes
-    PA-TIME fire — through the median-of-K noise guard — and the message
-    carries the diff-style drift numbers."""
-    monkeypatch.setattr(pa, "EXECUTE_ATOL_MS", 0.001)
-    doctored = copy.deepcopy(tf_gather_baselines)
+    PA-TIME fire — through the median-of-K noise guard, at the rule's own
+    bands — and the message carries the diff-style drift numbers."""
+    honest = _timed_as(tf_gather_baselines)
+    doctored = copy.deepcopy(honest)
     kern = doctored["tiers"][pa.current_tier()]["kernels"]["tf_gather"]
     kern["reg"]["execute_ms"] = kern["reg"]["execute_ms"] / 1000.0
     findings, _ = pa.run_perf_audit(
         ["tf_gather"], doctored, best_of=2, remeasure=2
     )
-    time_findings = [f for f in findings if f.rule == "PA-TIME"]
-    assert time_findings, findings
-    assert "execute_ms" in time_findings[0].message
-    assert "baseline" in time_findings[0].message
-    assert "tf_gather@reg" == time_findings[0].path
+    assert [(f.rule, f.path) for f in findings] == [
+        ("PA-TIME", "tf_gather@reg")
+    ], findings
+    assert "execute_ms: baseline 0.050, measured 50.000" in findings[0].message
+    assert "median of 2 re-runs" in findings[0].message
     # the refresh clears it (the falsifiability round-trip)
     findings, _ = pa.run_perf_audit(
-        ["tf_gather"], tf_gather_baselines, best_of=2, remeasure=2
+        ["tf_gather"], honest, best_of=2, remeasure=2
     )
-    assert [f for f in findings if f.rule == "PA-TIME"] == []
+    assert findings == []
+
+
+@pytest.mark.parametrize("metric", pa.TIME_KEYS)
+def test_one_slow_reading_is_absorbed_by_the_median_guard(
+        tf_gather_baselines, readings, metric):
+    """The first reading of a cell lands 10x over its baseline and every
+    re-measurement lands on it: the median-of-K guard reads a spike, and
+    PA-TIME stays quiet. Readings that STAY 10x over fire."""
+    honest = _timed_as(tf_gather_baselines)
+    steady = readings[metric]
+    readings[metric] = [steady * 10, steady]
+    findings, _ = pa.run_perf_audit(
+        ["tf_gather"], honest, best_of=2, remeasure=3
+    )
+    assert findings == []
+    readings[metric] = steady * 10
+    findings, _ = pa.run_perf_audit(
+        ["tf_gather"], honest, best_of=2, remeasure=3
+    )
+    assert {(f.rule, f.path) for f in findings} == {
+        ("PA-TIME", "tf_gather@reg"), ("PA-TIME", "tf_gather@x4")
+    }
+    assert all(metric in f.message for f in findings)
 
 
 def test_doctored_mem_baseline_fires_pa_mem(tf_gather_baselines):
@@ -169,7 +244,7 @@ def test_missing_baseline_fires_pa_base(tf_gather_baselines):
     assert {f.rule for f in findings} == {"PA-BASE"}
 
 
-def test_update_baselines_roundtrip(tmp_path):
+def test_update_baselines_roundtrip(tmp_path, readings):
     """update_baselines writes a tier-keyed file the audit then passes
     against; a second tier's block survives a refresh of this tier."""
     path = tmp_path / "perf_baselines.json"

@@ -482,13 +482,13 @@ class TestServeOfflineParityOnHardware:
             np.testing.assert_array_equal(fold, offline)
 
     def test_six_column_serve_scores_are_the_offline_floats(self):
-        from benchmarks.datagen import make_people
-        from benchmarks.run import config_4_settings
+        from chip_smoke import smoke_settings
+        from chipbench.datagen import make_people
         from splink_tpu import Splink
         from splink_tpu.serve import BucketPolicy, QueryEngine
 
-        df = make_people(3000, seed=4)
-        linker = Splink({**config_4_settings(), "max_iterations": 5}, df=df)
+        df = make_people(3900, seed=4)
+        linker = Splink(smoke_settings(max_iterations=5), df=df)
         df_e = linker.get_scored_comparisons()
         offline = dict(
             zip(
