@@ -15,6 +15,7 @@ happen on device, so the host never materialises the quadratic pair table.
 
 from __future__ import annotations
 
+from collections.abc import Callable
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -64,6 +65,26 @@ class EncodedTable:
         if name in self.numerics:
             return self.numerics[name].values
         return self.raw[name]
+
+    def frame_column(self, name: str, make: Callable[[], np.ndarray] | None = None):
+        """``name``'s row-level values as the array pandas infers for the
+        WHOLE column — what ``pd.DataFrame`` would make of them — encoded
+        once and kept with the table. A column of strings becomes pandas'
+        string array (Arrow-backed where pyarrow is installed), which the
+        scored frame takes by the pair index without a Python object per
+        pair; whatever pandas keeps on numpy (numbers, mixed objects) comes
+        back as the plain ndarray. ``make`` supplies the values of a column
+        the table holds under no name (the unique id, the source tag)."""
+        cache = self.__dict__.setdefault("_frame_cache", {})
+        if name not in cache:
+            import pandas as pd
+
+            values = self.column_values(name) if make is None else make()
+            arr = pd.Series(values, copy=False).array
+            if isinstance(arr, pd.arrays.NumpyExtensionArray):
+                arr = arr.to_numpy()
+            cache[name] = arr
+        return cache[name]
 
     def is_null(self, name: str) -> np.ndarray:
         if name in self.strings:

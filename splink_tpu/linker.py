@@ -1118,8 +1118,8 @@ class Splink:
 
     def _empty_df_e(self) -> "pd.DataFrame":
         n_cols = len(self.settings["comparison_columns"])
-        zero = np.zeros(0)
-        zero_cols = np.zeros((0, n_cols))
+        zero = np.zeros(0, self._float_dtype)
+        zero_cols = np.zeros((0, n_cols), self._float_dtype)
         return self._assemble_df_e(
             np.zeros((0, n_cols), np.int8),
             np.zeros(0, np.int64),
@@ -1972,12 +1972,26 @@ class Splink:
         match logits in ``z``, the frame carries a
         ``tf_match_probability`` column — the first-class TF-adjusted
         score, bit-identical to what the serve megakernel returns for the
-        same pairs."""
+        same pairs.
+
+        The dtype rule: a retained column's dtype is what pandas infers for
+        the WHOLE input column (``EncodedTable.frame_column``), in every
+        chunk and in the zero-row frame — never for the subset a chunk
+        holds. pyarrow stays optional: with it a column of strings is an
+        Arrow-backed ``str`` column taken by the pair index (counted as
+        ``columnar_strings``), without it the same code gathers the objects
+        pandas keeps."""
         with span("assemble_frame", rows=len(p)) as sp:
             cols = self._assemble_columns(G, il, ir, p, prob_m, prob_u, z)
+            typed = [
+                v.dtype for v in cols.values() if isinstance(v.dtype, pd.StringDtype)
+            ]
             sp.count(
                 columns=len(cols),
-                string_columns=sum(v.dtype == object for v in cols.values()),
+                string_columns=len(typed)
+                + sum(v.dtype == object for v in cols.values()),
+                # string columns that hold no Python object per pair
+                columnar_strings=sum(d.storage == "pyarrow" for d in typed),
             )
             return pd.DataFrame(cols)
 
@@ -1994,33 +2008,40 @@ class Splink:
                 else np.zeros(len(p), self._float_dtype)
             )
 
-        def add_lr(name, values):
-            cols.setdefault(f"{name}_l", values[il])
-            cols.setdefault(f"{name}_r", values[ir])
+        def add_lr(name, make=None):
+            values = table.frame_column(name, make)
+            if isinstance(values, np.ndarray):
+                left, right = values[il], values[ir]
+            else:  # a pandas array: taken as a column, typed as it arrives
+                left, right = values.take(il), values.take(ir)
+            cols.setdefault(f"{name}_l", left)
+            cols.setdefault(f"{name}_r", right)
 
-        add_lr(uid, table.unique_id)
+        add_lr(uid, lambda: table.unique_id)
         for c, col in enumerate(settings["comparison_columns"]):
             name = comparison_column_name(col)
             if "col_name" in col:
                 if settings["retain_matching_columns"] or col["term_frequency_adjustments"]:
-                    add_lr(name, table.column_values(name))
+                    add_lr(name)
             else:
                 if (
                     settings["retain_matching_columns"]
                     or col["term_frequency_adjustments"]
                 ):
                     for used in col["custom_columns_used"]:
-                        add_lr(used, table.column_values(used))
+                        add_lr(used)
             cols[f"gamma_{name}"] = G[:, c].astype(np.int64)
             if settings["retain_intermediate_calculation_columns"]:
                 cols[f"prob_gamma_{name}_non_match"] = prob_u[:, c]
                 cols[f"prob_gamma_{name}_match"] = prob_m[:, c]
 
         if settings["link_type"] == "link_and_dedupe":
-            src = np.array(["left", "right"], dtype=object)[table.source_table]
-            add_lr("_source_table", src)
+            add_lr(
+                "_source_table",
+                lambda: np.array(["left", "right"], dtype=object)[table.source_table],
+            )
         for extra in settings["additional_columns_to_retain"]:
-            add_lr(extra, table.column_values(extra))
+            add_lr(extra)
         return cols
 
 

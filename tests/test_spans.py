@@ -408,6 +408,44 @@ def test_counts_at_the_stage_boundaries(job):
     assert all(s["counts"]["bytes"] > 0 for s in by_name["h2d_put"])
 
 
+def test_assemble_frame_counts_its_string_columns_and_how_they_came(job):
+    """Every frame of both jobs retains first_name and surname per side:
+    ``string_columns`` counts the four, and ``columnar_strings`` says how
+    many of them entered the frame as a typed column taken by the pair index
+    — all of them where pyarrow is installed (pandas then infers an
+    Arrow-backed string array for the input column), none without it."""
+    import importlib.util
+
+    _name, _linker, table = job
+    frames = [s["counts"] for s in table if s["name"] == "assemble_frame"]
+    assert frames
+    arrow = importlib.util.find_spec("pyarrow") is not None
+    for counts in frames:
+        assert counts["string_columns"] == 4
+        assert counts["columnar_strings"] == (4 if arrow else 0)
+        assert counts["columns"] > counts["string_columns"]
+
+
+def test_numeric_only_frame_counts_no_string_column():
+    """Nothing retained (the config-4 cells' frame): ids, levels and
+    probabilities only, so neither counter finds a string column."""
+    settings = {
+        "link_type": "dedupe_only",
+        "comparison_columns": [{"col_name": "first_name"}, {"col_name": "surname"}],
+        "blocking_rules": ["l.city = r.city"],
+        "retain_matching_columns": False,
+        "max_iterations": 2,
+    }
+    linker = Splink(settings, df=_people(300, 3))
+    frame = linker.get_scored_comparisons()
+    [counts] = [s["counts"] for s in spans(run=linker.run_id)
+                if s["name"] == "assemble_frame"]
+    assert counts["rows"] == len(frame) > 0
+    assert counts["columns"] == len(frame.columns)
+    assert counts["string_columns"] == 0 and counts["columnar_strings"] == 0
+    assert not any(isinstance(t, pd.StringDtype) or t == object for t in frame.dtypes)
+
+
 def test_decode_pairs_counts_positions_in_pairs_out_and_who_decoded(job):
     """The score stream's ``decode_pairs`` spans: every candidate position
     goes in (``rows``), the unmasked ones come out (``kept``), and the row

@@ -406,3 +406,82 @@ def test_estimate_parameters_train_only():
     r2 = Splink(dict(s2), df=df)
     r2.get_scored_comparisons()
     assert abs(p2.params["λ"] - r2.params.params["λ"]) < 1e-12
+
+
+# ----------------------------------------------------------------------
+# one dtype per retained column, in every chunk
+# ----------------------------------------------------------------------
+
+
+def _noted_and_unnoted():
+    """100 people who block on city and carry a note, then 200 who block on
+    dob and carry none (and no city): thousands of consecutive pairs whose
+    retained ``note`` is null on both sides."""
+    rng = np.random.default_rng(11)
+    n_a, n_b = 100, 200
+    n = n_a + n_b
+    noted = np.arange(n) < n_a
+    firsts = np.array(["amelia", "oliver", "isla", "george", "ava", "noah"])
+    lasts = np.array(["smith", "jones", "taylor", "brown"])
+
+    def tags(letter, kinds):
+        return np.array([f"{letter}{i % kinds}" for i in range(n)], object)
+
+    df = pd.DataFrame(
+        {
+            "unique_id": np.arange(n),
+            "first_name": firsts[rng.integers(0, 6, n)],
+            "surname": lasts[rng.integers(0, 4, n)],
+            "city": np.where(noted, tags("c", 4), None),
+            "dob": np.where(noted, None, tags("d", 2)),
+            "note": np.where(noted, tags("n", 3), None),
+        }
+    )
+    settings = _settings(
+        blocking_rules=["l.city = r.city", "l.dob = r.dob"],
+        additional_columns_to_retain=["note"],
+        max_iterations=3,
+    )
+    return df, settings
+
+
+_STREAMS = {
+    "gamma_chunks": {"pair_batch_size": 1024, "max_resident_pairs": 1 << 20},
+    "pattern_chunks": {"pair_batch_size": 1024, "max_resident_pairs": 1024},
+    "virtual_chunks": {
+        "pair_batch_size": 1024, "max_resident_pairs": 1024,
+        "device_pair_generation": "on",
+    },
+}
+
+
+@pytest.mark.parametrize("stream", list(_STREAMS))
+def test_all_null_chunk_and_empty_frame_carry_the_columns_dtypes(stream):
+    """A retained column's dtype is what pandas infers for the WHOLE input
+    column, in every chunk: a chunk whose strings are all null, and the
+    zero-row frame, are typed like the others, so the concatenated frame has
+    the one-shot frame's dtypes (per-chunk inference typed such a chunk
+    `object`, and `pd.concat` then up-cast the whole column)."""
+    df, settings = _noted_and_unnoted()
+    whole = Splink(dict(settings), df=df).get_scored_comparisons()
+    assert isinstance(whole["note_l"].dtype, pd.StringDtype)
+
+    linker = Splink({**settings, **_STREAMS[stream]}, df=df)
+    assert linker._use_pattern_pipeline() == (stream != "gamma_chunks")
+    chunks = list(linker.stream_scored_comparisons())
+    assert len(chunks) > 4
+    nulls = [c["note_l"].isna().all() and c["note_r"].isna().all() for c in chunks]
+    assert any(nulls) and not all(nulls)
+    for chunk in chunks:
+        pd.testing.assert_series_equal(chunk.dtypes, whole.dtypes)
+    empty = linker._empty_df_e()
+    assert len(empty) == 0
+    pd.testing.assert_series_equal(empty.dtypes, whole.dtypes)
+
+    combined = pd.concat(chunks, ignore_index=True)
+    pd.testing.assert_series_equal(combined.dtypes, whole.dtypes)
+    kept = ["unique_id_l", "unique_id_r", "note_l", "note_r"]
+    pd.testing.assert_frame_equal(
+        combined[kept].sort_values(kept[:2]).reset_index(drop=True),
+        whole[kept].sort_values(kept[:2]).reset_index(drop=True),
+    )

@@ -289,6 +289,29 @@ def test_scored_frame_equals_one_assembled_from_the_oracles_pairs(link_type, ove
     assert all(c["device_decoded"] == c["rows"] for c in decodes)
 
 
+@pytest.mark.parametrize("generation", ["on", "off"])
+def test_zero_row_frame_is_typed_like_a_frame_with_pairs(generation):
+    """No candidate at all (every key unique) gives the zero-row frame, and
+    its columns carry the dtypes the same job's frame has when pairs exist:
+    a retained column is typed from the WHOLE input column, not from the
+    (here empty) subset of it that a frame holds."""
+    df = _people(400, seed=41)
+    df["key"] = [f"k{k}" for k in range(len(df))]
+    over = {"device_pair_generation": generation,
+            "additional_columns_to_retain": ["surname", "age"]}
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        empty = Splink(
+            _linker_settings(blocking_rules=["l.key = r.key"], **over), df=df
+        ).get_scored_comparisons()
+    full = Splink(_linker_settings(**over), df=df).get_scored_comparisons()
+    assert len(empty) == 0 and len(full) > 1024
+    pd.testing.assert_series_equal(empty.dtypes, full.dtypes)
+    for column in ("name_l", "city_r", "surname_l"):
+        assert isinstance(empty[column].dtype, pd.StringDtype)
+    assert empty["age_r"].dtype == np.float64
+
+
 def test_no_host_decode_left_in_the_program():
     """``decode_positions`` is the oracle: nothing under splink_tpu/ calls
     it but its own module."""
