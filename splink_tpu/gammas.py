@@ -969,6 +969,15 @@ def _jit_pattern_batch_mesh(parts: _Parts, mesh):
     )
 
 
+def _string_evals(spec: dict) -> int:
+    """String-kernel evaluations one comparison issues a pair position."""
+    if spec["kind"] == "name_inversion":  # the self pair and each cross pair
+        return 1 + len(spec.get("other_columns", []))
+    return int(spec["kind"] in (
+        "jaro_winkler", "levenshtein", "qgram_jaccard", "qgram_cosine"
+    ))
+
+
 class GammaProgram:
     """One encoded table packed on the device, and the gamma kernels of its
     settings. The table (``_packed``) belongs to this program; the jitted
@@ -1032,20 +1041,45 @@ class GammaProgram:
         # this program's kernels by (fun, variant): the registry is asked
         # once per kernel, and an eviction cannot take a kernel in use
         self._kernels: dict = {}
+        # whether a stage's pass was handed a kernel with the pruned
+        # Jaro-Winkler body (_kernel notes it; kernel_counts reports it)
+        self._two_phase_handed = False
 
         # The compiled-artifact analogue of the reference logging its
         # generated SQL at debug level (/root/reference/splink/gammas.py:120).
         probe = jnp.zeros(8, jnp.int32)
         log_jaxpr("gamma_program", self._gamma_batch, probe, probe)
 
+    def kernel_counts(self, positions: int) -> dict:
+        """Which kernel forms the pass this program just made over
+        ``positions`` pair positions was made of, for its stage span
+        (docs/observability.md): ``string_evals`` — string-kernel evaluations
+        issued, positions times the columns' sum (Jaro-Winkler, Levenshtein
+        and q-gram columns 1 each, name inversion 1 + its other columns);
+        ``two_phase`` — 1 when a kernel with the pruned Jaro-Winkler body was
+        handed out to run (``_kernel`` notes it where the body is picked),
+        else 0; how many columns are Levenshtein and how many name
+        inversion."""
+        specs = [c["comparison"] for c in self.settings["comparison_columns"]]
+        kinds = [spec["kind"] for spec in specs]
+        return {
+            "string_evals": int(positions) * sum(map(_string_evals, specs)),
+            "two_phase": int(self._two_phase_handed),
+            "levenshtein_columns": kinds.count("levenshtein"),
+            "name_inversion_columns": kinds.count("name_inversion"),
+        }
+
     def _kernel(self, fun: str, variant: tuple, build, shareable: bool = True,
-                mesh=None):
+                mesh=None, two_phase: bool = False):
         """The jitted program ``build(parts)`` makes, from the process's
         registry when this program has a signature and the caller could
         sign ``variant`` (the rest of what ``build`` closes over); else
         this program's own. ``build`` must not capture the program.
         ``mesh``: the mesh the program shards over (its key is in
-        ``variant``), for the lookup span's ``devices``."""
+        ``variant``), for the lookup span's ``devices``. ``two_phase``: the
+        caller picked the pruned Jaro-Winkler body for a kernel a stage's
+        pass is about to run — noted for ``kernel_counts``."""
+        self._two_phase_handed |= two_phase
         fn = self._kernels.get((fun, variant))
         if fn is None:
             key = (
@@ -1085,6 +1119,7 @@ class GammaProgram:
         return self._kernel(
             "gamma_flagged", (exact,),
             functools.partial(_jit_gamma_flagged, exact=exact),
+            two_phase=bool(self.two_phase_div) and not exact,
         )
 
     def _gamma_batch_flagged(self, il, ir):
@@ -1107,6 +1142,7 @@ class GammaProgram:
         return self._kernel(
             "pattern_batch", (exact,),
             functools.partial(_jit_pattern_batch, exact=exact),
+            two_phase=bool(self.two_phase_div) and not exact,
         )
 
     def _run_pattern_batch(self, il, ir, valid, acc):

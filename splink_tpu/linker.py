@@ -468,6 +468,7 @@ class Splink:
                      batches=-(-stream.total // stream.batch_size))
             if isinstance(stream, PatternStream):
                 self._count_mesh(st, stream.total)
+            st.count(**stream.program.kernel_counts(stream.total))
 
     def _maybe_spill_pairs(self) -> None:
         """Note the blocking-created spill dir (streamed regime): blocking's
@@ -528,7 +529,8 @@ class Splink:
                     batch_size=self.settings["pair_batch_size"],
                     keep_device=keep,
                 )
-                st.count(pairs=len(self._G))
+                st.count(pairs=len(self._G),
+                         **program.kernel_counts(len(self._G)))
         return self._G
 
     def _pattern_capable(self) -> bool:
@@ -771,6 +773,8 @@ class Splink:
                         self._P_virtual = ids
                     st.count(pairs=n_real)
                     self._count_mesh(st, self._virtual.n_candidates)
+                    st.count(**self._pattern_program.kernel_counts(
+                        self._virtual.n_candidates))
                 logger.info(
                     "device pair generation scored %d pairs (%d candidate "
                     "positions)", n_real, self._virtual.n_candidates,
@@ -794,6 +798,7 @@ class Splink:
                 )
                 st.count(pairs=len(self._P))
                 self._count_mesh(st, len(self._P))
+                st.count(**self._pattern_program.kernel_counts(len(self._P)))
         return self._P, self._pattern_counts, self._pattern_program
 
     def _tf_fold_ctx(self):

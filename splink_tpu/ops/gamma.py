@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 GAMMA_DTYPE = jnp.int8
 
@@ -41,14 +42,25 @@ def bucket_difference(diff, thresholds, null_mask):
         return apply_null(gamma, null_mask)
 
 
+def _two_ulps_up(t, dtype):
+    t = np.asarray(t, dtype)
+    return np.nextafter(np.nextafter(t, np.inf, dtype=dtype), np.inf, dtype=dtype)
+
+
 def bucket_difference_le(diff, thresholds, null_mask, equal, top_level):
     """Levenshtein-style levels: exact equality takes the top level, then
     ascending ``<=`` thresholds fill the middle levels
-    (cf. /root/reference/splink/case_statements.py:117-141)."""
+    (cf. /root/reference/splink/case_statements.py:117-141).
+
+    Ties: a ratio that equals a threshold as a rational number (1 / 5 against
+    0.2, 3 / 7.5 against 0.4) takes the level on every backend, so each
+    threshold is applied two ulps wide — the TPU's float32 division is good
+    to one ulp only (it lost 88 of 324 such ties: PERF.md §6, PR 33), and no
+    other quotient of an edit distance and a mean length comes that close."""
     with jax.named_scope("levels"):
         gamma = jnp.zeros(diff.shape, dtype=GAMMA_DTYPE)
         for t in thresholds:
-            gamma = gamma + (diff <= t).astype(GAMMA_DTYPE)
+            gamma = gamma + (diff <= _two_ulps_up(t, diff.dtype)).astype(GAMMA_DTYPE)
         gamma = jnp.where(equal, jnp.asarray(top_level, GAMMA_DTYPE), gamma)
         return apply_null(gamma, null_mask)
 

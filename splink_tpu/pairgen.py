@@ -1164,7 +1164,7 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
             has_uid_mask=has_uid_mask, own_res=own_res, prev_res=prev_res,
             mesh=mesh, two_phase=two_phase,
         ),
-        shareable=signed is not None, mesh=mesh,
+        shareable=signed is not None, mesh=mesh, two_phase=two_phase,
     )
 
 

@@ -118,6 +118,9 @@ def test_mesh_job_equals_the_reference(config, people, references, n, over):
     candidates = linker._virtual.n_candidates
     assert stage[0]["counts"]["devices"] == n
     assert stage[0]["counts"]["pairs_per_device"] == -(-candidates // n)
+    # the settings have prunable columns, but a mesh kernel is handed the exact body
+    assert stage[0]["counts"]["two_phase"] == 0
+    assert stage[0]["counts"]["string_evals"] == candidates * 4
     puts = [s for s in table if s["name"] == "mesh_put"]
     gathers = [s for s in table if s["name"] == "mesh_gather"]
     assert puts and all(s["counts"]["devices"] == n and s["counts"]["bytes"] > 0 for s in puts)
@@ -161,6 +164,7 @@ def test_job_without_a_mesh_closes_no_mesh_span_and_scores_the_same(config, peop
     assert not [s for s in table if s["name"] in ("mesh_put", "mesh_gather")]
     stage = [s for s in table if s["name"] == "gammas_patterns"][0]
     assert "devices" not in stage["counts"] and "pairs_per_device" not in stage["counts"]
+    assert stage["counts"]["two_phase"] == 1  # one chip: the pruned body
     assert {s["counts"]["devices"] for s in table if s["name"] == "kernel_lookup"} == {1}
     # the same pairs, levels and scores whatever the number of chips
     _, frame_4 = job(small(config, 4), people)

@@ -58,23 +58,28 @@ def uncached():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def job():
-    """(program, plan) of the four-chip deployment's model at a small table."""
+def small_job(name):
+    """(program, plan) of a configuration's model at a small table."""
     from chipbench import datagen
     from splink_tpu import Splink
 
-    with open(os.path.join(ROOT, "chipbench", "configs", "baseline_c4_v5e4.json")) as f:
+    with open(os.path.join(ROOT, "chipbench", "configs", f"{name}.json")) as f:
         config = json.load(f)
     gen = {k: v for k, v in config["generator"].items()
            if k not in ("kind", "population_seed", "rows")}
     people = datagen.make_people(rows=ROWS, seed=config["generator"]["population_seed"], **gen)
     settings = copy.deepcopy(config["settings"])
     settings.update(pair_batch_size=BATCH, max_resident_pairs=1024)
-    del settings["mesh"]  # the linker's own mesh would be of CPU devices
+    settings.pop("mesh", None)  # the linker's own mesh would be of CPU devices
     linker = Splink(settings, df=people)
     linker._ensure_encoded()
     return linker._ensure_pattern_program(), linker._virtual_plan()
+
+
+@pytest.fixture(scope="module")
+def job():
+    """The four-chip deployment's model."""
+    return small_job("baseline_c4_v5e4")
 
 
 def compile_rule(program, plan, mesh, sharding_of, monkeypatch):
@@ -88,7 +93,7 @@ def compile_rule(program, plan, mesh, sharding_of, monkeypatch):
     rule_bs = min(BATCH, 1 << max((rp.total - 1).bit_length(), 6))
     meta = pairgen._unit_batch_meta(rp.pc, rp.total, rule_bs)[0][2]
     fn = pairgen._build_virtual_pattern_fn(
-        program._parts, None, n_prev=0, has_uid_mask=plan.uid_codes is not None,
+        program._parts, program._gamma_batch_fn, n_prev=0, has_uid_mask=plan.uid_codes is not None,
         own_res=rp.residual_fn, prev_res=(), mesh=mesh, two_phase=False)
 
     def shape(a, sharding):
@@ -130,3 +135,19 @@ def test_sharded_pattern_kernel_compiles_for_four_chips(topo, uncached, job, mon
     single_temp = single.memory_analysis().temp_size_in_bytes
     # each chip holds a quarter of the batch's scratch (and a whole table)
     assert sharded_temp < 0.35 * single_temp, (sharded_temp, single_temp)
+
+
+def test_case_library_kernel_compiles_for_one_chip(topo, uncached, monkeypatch):
+    """The cell ``c4lib_dedupe_virtual``'s program (configuration
+    ``c4_case_library``): no prunable Jaro-Winkler column, so the one-chip
+    kernel is the EXACT body — four Jaro-Winkler evaluations (two name
+    inversions, self and cross pair, the cross pair padded to a common width)
+    and three Levenshtein ones, each a Mosaic call fed the whole batch."""
+    program, plan = small_job("c4_case_library")
+    assert program.two_phase_div is None
+    one = SingleDeviceSharding(topo.devices[0])
+    rule_bs, compiled = compile_rule(program, plan, None, (one, one), monkeypatch)
+    assert rule_bs == BATCH
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 7, len(calls)

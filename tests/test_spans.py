@@ -397,7 +397,11 @@ def test_counts_at_the_stage_boundaries(job):
         }
     else:
         assert by_name["blocking"][0]["counts"] == {"pairs": pairs}
-        assert by_name["gammas"][0]["counts"] == {"pairs": pairs, "batches": 1}
+        # two default (Jaro-Winkler) columns: the pruned body, one evaluation each
+        assert by_name["gammas"][0]["counts"] == {
+            "pairs": pairs, "batches": 1, "string_evals": 2 * pairs, "two_phase": 1,
+            "levenshtein_columns": 0, "name_inversion_columns": 0,
+        }
         assert by_name["score"][0]["counts"] == {"pairs": pairs, "batches": 1}
         [frame] = by_name["assemble_frame"]
         assert frame["counts"]["rows"] == pairs

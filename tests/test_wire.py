@@ -399,7 +399,13 @@ def test_partition_heals_and_publishes_events(fake_server):
     events.register_ambient(sink)
     try:
         assert rep.submit({}).result(timeout=WAIT).shed is False
+        # held in flight on the connection the partition drops: the
+        # `wire_shed` burst below is published for it, not for a submit
+        # that may find the link already down (a race with the reader)
+        held = rep.submit({"delay": 5.0})
         server.partition(0.3)
+        lost = held.result(timeout=WAIT)
+        assert lost.shed and lost.reason == "connection_lost"
         res = rep.submit({}).result(timeout=WAIT)
         assert res.shed and res.reason in (
             "connection_lost", "remote_unreachable", "breaker_open"
