@@ -12,6 +12,7 @@ Spark SQL.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import logging
 import os
@@ -78,6 +79,203 @@ def _gamma_histograms(settings, G, weights=None, chunk: int = 1 << 22) -> dict:
         comparison_column_name(col): [int(v) for v in acc[c]]
         for c, col in enumerate(cols)
     }
+
+
+class _FrameWriter:
+    """The scored frame's columns (the reference's layout and order,
+    /root/reference/splink/expectation_step.py:128-165), allocated once at
+    the length the caller knows — a whole job's pairs on the one-frame
+    path, one chunk's on the streaming API — and written chunk by chunk at
+    the chunk's offset: each byte of the frame is written once, into the
+    column the caller receives. ``frame()`` hands the columns to pandas as
+    they are (one block a column, nothing consolidated, nothing copied), so
+    the frame owns its memory: no column is a view of a linker table, a
+    per-pattern table or the ``frame_column`` cache.
+
+    With the TF u-probability fold active (``_tf_fold_ctx``) the frame
+    carries a ``tf_match_probability`` column — the first-class TF-adjusted
+    score, bit-identical to what the serve megakernel returns for the same
+    pairs.
+
+    The dtype rule: a retained column's dtype is what pandas infers for the
+    WHOLE input column (``EncodedTable.frame_column``), in every chunk and
+    in the zero-row frame — never for the subset a chunk holds. pyarrow
+    stays optional: with it a column of strings is an Arrow-backed ``str``
+    column taken by the pair index (counted as ``columnar_strings``),
+    without it the same code gathers the objects pandas keeps."""
+
+    def __init__(self, linker: "Splink", n: int):
+        self._linker = linker
+        self.n = n  # rows allocated: the pairs the caller expects, or a bound
+        self.rows = 0  # rows written so far
+        self.chunks = 0
+        self.cols = None  # allocated by the first write (or an empty frame)
+
+    def _allocate(self) -> None:
+        """The frame's columns, in its column order. Runs under the first
+        chunk's ``assemble_frame`` span: typing a retained input column
+        (``frame_column``, once a table) is part of the frame's cost."""
+        linker, n = self._linker, self.n
+        settings = linker.settings
+        table = linker._ensure_encoded()
+        self._n_table_rows = table.n_rows
+        dtype = linker._float_dtype
+        self._tf_ctx = linker._tf_fold_ctx()
+        # name -> the (n,) numpy column, or the list of per-chunk takes of
+        # an extension (Arrow string) column; in the frame's column order
+        cols: dict[str, np.ndarray | list] = {"match_probability": np.empty(n, dtype)}
+        if self._tf_ctx is not None:
+            cols["tf_match_probability"] = np.empty(n, dtype)
+        # retained column's name -> (the input column, 0: left / 1: right)
+        self._retained: dict[str, tuple] = {}
+
+        def add_lr(name, make=None):
+            if f"{name}_l" in cols:
+                return
+            values = table.frame_column(name, make)
+            for side, column in enumerate((f"{name}_l", f"{name}_r")):
+                self._retained[column] = (values, side)
+                cols[column] = (
+                    np.empty(n, values.dtype)
+                    if isinstance(values, np.ndarray) else []
+                )
+
+        add_lr(settings["unique_id_column_name"], lambda: table.unique_id)
+        # per comparison column: its gamma_, prob_.._non_match, prob_.._match names
+        self._scored = []
+        for col in settings["comparison_columns"]:
+            name = comparison_column_name(col)
+            if settings["retain_matching_columns"] or col["term_frequency_adjustments"]:
+                for used in [name] if "col_name" in col else col["custom_columns_used"]:
+                    add_lr(used)
+            names = [f"gamma_{name}"]
+            cols[names[0]] = np.empty(n, np.int64)
+            if settings["retain_intermediate_calculation_columns"]:
+                names += [f"prob_gamma_{name}_non_match", f"prob_gamma_{name}_match"]
+                for prob in names[1:]:
+                    cols[prob] = np.empty(n, dtype)
+            self._scored.append(names)
+        if settings["link_type"] == "link_and_dedupe":
+            add_lr(
+                "_source_table",
+                lambda: np.array(["left", "right"], dtype=object)[table.source_table],
+            )
+        for extra in settings["additional_columns_to_retain"]:
+            add_lr(extra)
+        self.cols = cols
+
+    def _counts(self) -> dict:
+        dtypes = [values.dtype for values, _ in self._retained.values()]
+        typed = [d for d in dtypes if isinstance(d, pd.StringDtype)]
+        return dict(
+            columns=len(self.cols),
+            string_columns=len(typed) + sum(d == object for d in dtypes),
+            # string columns that hold no Python object per pair
+            columnar_strings=sum(d.storage == "pyarrow" for d in typed),
+        )
+
+    def write(self, il, ir, levels, p, prob_m, prob_u, z, by=None) -> None:
+        """One chunk's rows at the next offset, under one ``assemble_frame``
+        span (``in_place_rows``: the rows that go straight into the frame's
+        own columns). ``levels[c]``, ``prob_m[c]`` and ``prob_u[c]`` are
+        comparison column c's values and ``p``, ``z`` the scores and match
+        logits: one entry a pair of the chunk — or, with the pairs' pattern
+        ids in ``by``, one a PATTERN, taken by id under ``lut_gather``."""
+        with span("assemble_frame", rows=len(il), in_place_rows=len(il)) as sp:
+            if self.cols is None:
+                self._allocate()
+            sp.count(**self._counts())
+            self._write(il, ir, levels, p, prob_m, prob_u, z, by)
+            self.chunks += 1
+
+    def _write(self, il, ir, levels, p, prob_m, prob_u, z, by) -> None:
+        start, stop = self.rows, self.rows + len(il)
+        if stop > self.n:
+            raise ValueError(
+                f"the pair stream holds more than the {self.n} pairs its "
+                "frame was allocated for"
+            )
+        cols = {
+            name: col[start:stop] if isinstance(col, np.ndarray) else col
+            for name, col in self.cols.items()
+        }
+        _check_index(il, self._n_table_rows)
+        _check_index(ir, self._n_table_rows)
+        if by is None:
+            gather = contextlib.nullcontext()
+        else:
+            _check_index(by, len(p))
+            gather = span("lut_gather", rows=len(il))
+        with gather:
+            _put(cols["match_probability"], p, by)
+            for c, names in enumerate(self._scored):
+                for name, src in zip(names, (levels, prob_u, prob_m)):
+                    _put(cols[name], src[c], by)
+            if by is not None and z is not None:
+                z = z[by]
+        if self._tf_ctx is not None and len(il):
+            self._linker._tf_fold_pairs(
+                z, il, ir, self._tf_ctx, out=cols["tf_match_probability"]
+            )
+        for name, (values, side) in self._retained.items():
+            idx = ir if side else il
+            if isinstance(values, np.ndarray):
+                _put(cols[name], values, idx)
+            else:  # a pandas array: taken as a column, typed as it arrives
+                cols[name].append(values.take(idx))
+        self.rows = stop
+
+    def frame(self) -> "pd.DataFrame":
+        """The rows written, as the frame the caller keeps, under one
+        ``concat_frame`` span: what is left of joining chunks. An extension
+        column's per-chunk takes become one column (Arrow: one chunked
+        array, nothing copied), no numeric byte moves, and pandas is handed
+        the columns as they are. Where fewer rows came than were allocated
+        for (``n`` was a bound) the columns are handed out as their leading
+        rows; no row at all is the typed zero-row frame."""
+        with span("concat_frame", chunks=self.chunks, rows=self.rows):
+            if self.cols is None:
+                self._allocate()
+            cols = {}
+            for name, col in self.cols.items():
+                if isinstance(col, np.ndarray):
+                    cols[name] = col[: self.rows]
+                elif len(col) > 1:
+                    cols[name] = pd.concat(
+                        [pd.Series(chunk, copy=False) for chunk in col],
+                        ignore_index=True,
+                    ).array
+                else:
+                    cols[name] = col[0] if col else self._retained[name][0][:0]
+            return pd.DataFrame(cols, copy=False)
+
+
+def _check_index(idx: np.ndarray, n: int) -> None:
+    """What numpy's own indexing checks and ``_put``'s take does not."""
+    if len(idx) and not (0 <= idx.min() and idx.max() < n):
+        raise IndexError(f"index out of bounds for {n} rows")
+
+
+# rows a take: numpy converts an int32 index to intp before it takes — a
+# temporary as large as the column written, once a COLUMN, and memory the
+# process touches for the first time costs several times a warm write. A
+# block at a time the temporary is half a megabyte that malloc hands back
+# warm and the cache keeps.
+_TAKE_ROWS = 1 << 16
+
+
+def _put(out: np.ndarray, src: np.ndarray, by: np.ndarray | None) -> None:
+    """``out[:] = src`` — or, with an index, ``out[:] = src[by]`` without
+    the intermediate. ``mode="clip"`` because numpy's default buffers
+    ``out`` whole (a copy of the chunk a column) to leave it untouched on an
+    index error: the caller has checked ``by`` (``_check_index``), once a
+    chunk and not once a column."""
+    if by is None:
+        out[:] = src
+        return
+    for a in range(0, len(by), _TAKE_ROWS):
+        b = a + _TAKE_ROWS
+        np.take(src, by[a:b], out=out[a:b], mode="clip")
 
 
 class Splink:
@@ -832,12 +1030,13 @@ class Splink:
                     self._tf_fold_cache = (tuple(spec), tids, logs)
         return self._tf_fold_cache or None
 
-    def _tf_fold_pairs(self, z, il, ir, ctx) -> np.ndarray:
+    def _tf_fold_pairs(self, z, il, ir, ctx, out=None) -> np.ndarray:
         """TF-adjusted match probabilities for pairs (il, ir) from their
         match logits ``z`` — the offline half of the serve parity
         contract, evaluated by the SAME jitted fold expression the serve
         megakernel runs (term_frequencies.make_tf_fold_fn). Chunked like
-        every other per-pair device pass."""
+        every other per-pair device pass; written into ``out`` (the scored
+        frame's own column) where the caller has one."""
         from .term_frequencies import make_tf_fold_fn
 
         spec, tids, logs = ctx
@@ -848,7 +1047,8 @@ class Splink:
         logs_dev = [jnp.asarray(t.astype(dtype)) for t in logs]
         n = len(z)
         batch = min(int(self.settings["pair_batch_size"]), max(n, 1))
-        out = np.empty(n, dtype)
+        if out is None:
+            out = np.empty(n, dtype)
         for s in range(0, n, batch):
             e = min(s + batch, n)
             host = [z[s:e]]
@@ -879,33 +1079,57 @@ class Splink:
         )
         return PM, p, pm, pu, z
 
+    def _pattern_frame_tables(self):
+        """The per-pattern tables in ``_FrameWriter.write``'s argument
+        order, one contiguous row a frame column: the levels as the int64
+        the frame carries, so a chunk's ``gamma_*`` column is one take by
+        pattern id and no ``(pairs, columns)`` matrix is made on the way."""
+        PM, p, pm, pu, z = self._pattern_score_luts()
+        rows = np.ascontiguousarray
+        return (
+            rows(PM.T, dtype=np.int64),
+            p,
+            None if pm is None else rows(pm.T),
+            None if pu is None else rows(pu.T),
+            z,
+        )
+
+    def _pattern_stream_pairs(self) -> int:
+        """How many pairs ``_iter_pattern_triples`` will yield, known before
+        it starts: the histogram's total, which leaves the masked positions
+        out (pairgen.compute_virtual_pattern_ids). Where no histogram pass
+        ran (manual weights over a virtual plan) the plan's candidate
+        positions bound it from above."""
+        if self._virtual_plan() is None:
+            return len(self._ensure_pattern_ids()[0])
+        if self._pattern_counts is not None:
+            return int(self._pattern_counts.sum())
+        return self._virtual.n_candidates
+
     def _stream_pattern_chunks(self):
-        """Yield scored chunks from the pattern-id pipeline: one LUT gather
-        + frame assembly per (il, ir, pattern-ids) chunk. The chunk source
-        (stored virtual ids / virtual recompute / materialised pairs) is
-        _iter_pattern_triples — the single definition of the pair stream."""
-        PM, p_lut, pm_lut, pu_lut, z_lut = self._pattern_score_luts()
+        """Yield scored chunks from the pattern-id pipeline: one frame a
+        (il, ir, pattern-ids) chunk, written at the chunk's length. The
+        chunk source (stored virtual ids / virtual recompute / materialised
+        pairs) is _iter_pattern_triples — the single definition of the pair
+        stream."""
+        tables = self._pattern_frame_tables()
         with self._stage("score_patterns") as st:
             for il, ir, Pk in self._iter_pattern_triples():
                 st.count(pairs=len(Pk), batches=1)
-                yield self._assemble_df_e(
-                    *self._lut_gather(PM, il, ir, Pk, p_lut, pm_lut, pu_lut, z_lut)
-                )
+                yield self._assemble_df_e(il, ir, *tables, by=Pk)
 
-    @staticmethod
-    def _lut_gather(PM, il, ir, Pk, p_lut, pm_lut, pu_lut, z_lut):
-        """One chunk's per-pair arrays from the per-pattern tables, in
-        ``_assemble_df_e``'s argument order."""
-        with span("lut_gather", rows=len(Pk)):
-            return (
-                PM[Pk],
-                il,
-                ir,
-                p_lut[Pk],
-                pm_lut[Pk] if pm_lut is not None else None,
-                pu_lut[Pk] if pu_lut is not None else None,
-                z_lut[Pk] if z_lut is not None else None,
-            )
+    def _score_patterns_frame(self) -> "pd.DataFrame":
+        """The whole pattern stream as ONE frame: its columns are allocated
+        once at the job's length and every chunk writes its rows at its
+        offset, so no byte of the frame is written twice. Zero pairs (no
+        candidates, or every position masked) is the typed zero-row frame."""
+        tables = self._pattern_frame_tables()
+        with self._stage("score_patterns") as st:
+            writer = _FrameWriter(self, self._pattern_stream_pairs())
+            for il, ir, Pk in self._iter_pattern_triples():
+                st.count(pairs=len(Pk), batches=1)
+                writer.write(il, ir, *tables, by=Pk)
+            return writer.frame()
 
     def _iter_pattern_triples(self):
         """Yield (idx_l, idx_r, pattern_ids) per chunk across the pattern
@@ -1035,7 +1259,8 @@ class Splink:
                     f"term-frequency column {name!r} is not an encoded "
                     "column; skipped in the streaming TF pass."
                 )
-            PM, p_lut, pm_lut, pu_lut, z_lut = self._pattern_score_luts()
+            tables = self._pattern_frame_tables()
+            p_lut = tables[1]
             base_lambda = float(self.params.params["λ"])
             sums = {n: np.zeros(nt + 1) for n, (_, nt) in cols.items()}
             counts = {n: np.zeros(nt + 1) for n, (_, nt) in cols.items()}
@@ -1057,9 +1282,7 @@ class Splink:
                 )
             with self._stage("score_tf_patterns"):
                 for il, ir, Pk in self._iter_pattern_triples():
-                    df = self._assemble_df_e(
-                        *self._lut_gather(PM, il, ir, Pk, p_lut, pm_lut, pu_lut, z_lut)
-                    )
+                    df = self._assemble_df_e(il, ir, *tables, by=Pk)
                     adj_arrays = []
                     for name, (tid, _nt) in cols.items():
                         tl = tid[il]
@@ -1110,36 +1333,12 @@ class Splink:
     # Public API (reference parity)
     # ------------------------------------------------------------------
 
-    def _concat_chunks(self, chunks) -> "pd.DataFrame":
-        """Concatenate streamed chunks; zero chunks (no candidates, or every
-        position masked) is a valid empty result, not a pandas error."""
-        chunks = list(chunks)
-        if not chunks:
-            return self._empty_df_e()
-        with span("concat_frame", chunks=len(chunks)) as sp:
-            df_e = pd.concat(chunks, ignore_index=True)
-            sp.count(rows=len(df_e))
-        return df_e
-
-    def _empty_df_e(self) -> "pd.DataFrame":
-        n_cols = len(self.settings["comparison_columns"])
-        zero = np.zeros(0, self._float_dtype)
-        zero_cols = np.zeros((0, n_cols), self._float_dtype)
-        return self._assemble_df_e(
-            np.zeros((0, n_cols), np.int8),
-            np.zeros(0, np.int64),
-            np.zeros(0, np.int64),
-            zero,
-            zero_cols,
-            zero_cols,
-        )
-
     def manually_apply_fellegi_sunter_weights(self):
         """Score using the m/u values in the settings, without running EM
         (/root/reference/splink/__init__.py:111-119)."""
         with self._call("manually_apply_fellegi_sunter_weights") as call:
             if self._use_pattern_pipeline():
-                df_e = self._concat_chunks(self._stream_pattern_chunks())
+                df_e = self._score_patterns_frame()
             else:
                 G = self._ensure_gammas()
                 df_e = self._build_df_e(G)
@@ -1240,7 +1439,7 @@ class Splink:
                 # pass instead of two)
                 self._virtual_want_ids = True
                 self._run_em_patterns(compute_ll)
-                df_e = self._concat_chunks(self._stream_pattern_chunks())
+                df_e = self._score_patterns_frame()
                 # the single-frame output is materialised — release the ids
                 # (same convention as _G_dev below); a later re-stream simply
                 # recomputes them chunk-wise
@@ -1968,86 +2167,20 @@ class Splink:
                 G, params_dev, want_z=self._tf_fold_ctx() is not None
             )
             st.count(pairs=len(G))
-        return self._assemble_df_e(G, il, ir, p, prob_m, prob_u, z=z)
+        return self._assemble_df_e(
+            il, ir, G.T, p,
+            None if prob_m is None else prob_m.T,
+            None if prob_u is None else prob_u.T,
+            z,
+        )
 
-    def _assemble_df_e(self, G, il, ir, p, prob_m, prob_u, z=None):
-        """Column assembly shared by the device-scoring and pattern-LUT
-        paths; all inputs are host arrays aligned with (il, ir). With the
-        TF u-probability fold active (``_tf_fold_ctx``) and the pairs'
-        match logits in ``z``, the frame carries a
-        ``tf_match_probability`` column — the first-class TF-adjusted
-        score, bit-identical to what the serve megakernel returns for the
-        same pairs.
-
-        The dtype rule: a retained column's dtype is what pandas infers for
-        the WHOLE input column (``EncodedTable.frame_column``), in every
-        chunk and in the zero-row frame — never for the subset a chunk
-        holds. pyarrow stays optional: with it a column of strings is an
-        Arrow-backed ``str`` column taken by the pair index (counted as
-        ``columnar_strings``), without it the same code gathers the objects
-        pandas keeps."""
-        with span("assemble_frame", rows=len(p)) as sp:
-            cols = self._assemble_columns(G, il, ir, p, prob_m, prob_u, z)
-            typed = [
-                v.dtype for v in cols.values() if isinstance(v.dtype, pd.StringDtype)
-            ]
-            sp.count(
-                columns=len(cols),
-                string_columns=len(typed)
-                + sum(v.dtype == object for v in cols.values()),
-                # string columns that hold no Python object per pair
-                columnar_strings=sum(d.storage == "pyarrow" for d in typed),
-            )
-            return pd.DataFrame(cols)
-
-    def _assemble_columns(self, G, il, ir, p, prob_m, prob_u, z) -> dict:
-        table = self._ensure_encoded()
-        settings = self.settings
-        uid = settings["unique_id_column_name"]
-        cols: dict[str, np.ndarray] = {"match_probability": p}
-        ctx = self._tf_fold_ctx()
-        if ctx is not None:
-            cols["tf_match_probability"] = (
-                self._tf_fold_pairs(z, il, ir, ctx)
-                if z is not None and len(p)
-                else np.zeros(len(p), self._float_dtype)
-            )
-
-        def add_lr(name, make=None):
-            values = table.frame_column(name, make)
-            if isinstance(values, np.ndarray):
-                left, right = values[il], values[ir]
-            else:  # a pandas array: taken as a column, typed as it arrives
-                left, right = values.take(il), values.take(ir)
-            cols.setdefault(f"{name}_l", left)
-            cols.setdefault(f"{name}_r", right)
-
-        add_lr(uid, lambda: table.unique_id)
-        for c, col in enumerate(settings["comparison_columns"]):
-            name = comparison_column_name(col)
-            if "col_name" in col:
-                if settings["retain_matching_columns"] or col["term_frequency_adjustments"]:
-                    add_lr(name)
-            else:
-                if (
-                    settings["retain_matching_columns"]
-                    or col["term_frequency_adjustments"]
-                ):
-                    for used in col["custom_columns_used"]:
-                        add_lr(used)
-            cols[f"gamma_{name}"] = G[:, c].astype(np.int64)
-            if settings["retain_intermediate_calculation_columns"]:
-                cols[f"prob_gamma_{name}_non_match"] = prob_u[:, c]
-                cols[f"prob_gamma_{name}_match"] = prob_m[:, c]
-
-        if settings["link_type"] == "link_and_dedupe":
-            add_lr(
-                "_source_table",
-                lambda: np.array(["left", "right"], dtype=object)[table.source_table],
-            )
-        for extra in settings["additional_columns_to_retain"]:
-            add_lr(extra)
-        return cols
+    def _assemble_df_e(self, il, ir, levels, p, prob_m, prob_u, z, by=None):
+        """One frame of the pairs (il, ir), written at its own length —
+        the resident path's whole job, the streaming API's chunk. The
+        arguments after the pairs are ``_FrameWriter.write``'s."""
+        writer = _FrameWriter(self, len(il))
+        writer.write(il, ir, levels, p, prob_m, prob_u, z, by=by)
+        return writer.frame()
 
 
 @check_types

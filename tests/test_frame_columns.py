@@ -1,12 +1,14 @@
 """Retained columns reach the scored frame as columns.
 
-``Splink._assemble_columns`` takes each retained column from the array pandas
+The frame writer (``linker._FrameWriter``) takes each retained column from the array pandas
 infers for the WHOLE input column (``EncodedTable.frame_column``: an Arrow
 string array for strings where pyarrow is installed) by the pair index. The
 reference here is the plain way it was done before: one object gather per
 side from the table's original values, then ``pd.DataFrame`` — the frame
 must equal it value for value and dtype for dtype.
 """
+
+import warnings
 
 import numpy as np
 import pandas as pd
@@ -148,3 +150,301 @@ def test_frame_column_is_encoded_once_and_numbers_stay_numpy():
     assert uid is table.unique_id  # nothing copied for a numeric column
     # a row window is a new table with its own (empty) cache
     assert "_frame_cache" not in table.slice_rows(0, 10).__dict__
+
+
+# ----------------------------------------------------------------------
+# every column of the frame is written once (ROADMAP A1b)
+# ----------------------------------------------------------------------
+#
+# The reference is the way the frame was made before the column writer: a
+# dict of fresh arrays a chunk (``PM[Pk]``, ``values[il]``,
+# ``G[:, c].astype(int64)``), ``pd.DataFrame`` of it, ``pd.concat`` of the
+# chunks. The one-frame result must equal it value for value, dtype for
+# dtype, column for column.
+
+_VIRTUAL = {**_STREAM, "device_pair_generation": "on"}
+_TWO_RULES = {"blocking_rules": ["l.dob = r.dob", "l.surname = r.surname"]}
+# name -> (link type, settings over _settings', how the job is asked for)
+_ONE_FRAME = {
+    # several chunks a rule, a later rule's positions masked by the first
+    "virtual_kept_ids": ("dedupe_only",
+                         {**_VIRTUAL, **_TWO_RULES, "virtual_materialise_ids": "on"}),
+    "virtual_recompute": ("dedupe_only",
+                          {**_VIRTUAL, **_TWO_RULES, "virtual_materialise_ids": "off"}),
+    "materialised_patterns": ("dedupe_only", {**_STREAM, **_TWO_RULES}),
+    "link_strings": ("link_only", {}),
+    "link_strings_no_pyarrow": ("link_only", {}),
+    "link_and_dedupe": ("link_and_dedupe", {}),
+    "link_and_dedupe_virtual": ("link_and_dedupe", {**_VIRTUAL, **_TWO_RULES}),
+    # the default retains the per-column probabilities
+    "no_intermediates_resident": ("link_only",
+                                  {"retain_intermediate_calculation_columns": False}),
+    "no_intermediates_virtual": ("dedupe_only",
+                                 {**_VIRTUAL, **_TWO_RULES,
+                                  "retain_intermediate_calculation_columns": False}),
+    "no_tf_fold_column": ("dedupe_only", {**_VIRTUAL, "serve_tf_adjust": False}),
+    # the config-4 cells' frame: ids, levels and one probability
+    "nothing_retained": ("dedupe_only",
+                         {**_VIRTUAL, "retain_matching_columns": False,
+                          "comparison_columns": [
+                              {"col_name": name, "num_levels": 2}
+                              for name in _STRINGS[:3]
+                          ],
+                          "retain_intermediate_calculation_columns": False,
+                          "additional_columns_to_retain": [],
+                          "serve_tf_adjust": False}),
+    # no histogram pass: the plan's positions only bound the frame's length
+    "manual_weights_virtual": ("dedupe_only", {**_VIRTUAL, **_TWO_RULES}),
+    "manual_weights_resident": ("link_only", {}),
+    "zero_pairs_virtual": ("dedupe_only",
+                           {**_VIRTUAL, "blocking_rules": ["l.unique_id = r.unique_id"]}),
+    "zero_pairs_resident": ("link_only",
+                            {"blocking_rules": ["l.unique_id = r.unique_id"]}),
+    "every_position_masked": (
+        "dedupe_only",
+        {**_VIRTUAL, "blocking_rules": ["l.dob = r.dob and l.height > r.height + 1e6"]},
+    ),
+}
+
+
+def _one_frame_linker(case):
+    link_type, over = _ONE_FRAME[case]
+    if link_type == "dedupe_only":
+        frames = {"df": _people(420, seed=5)}
+    else:
+        frames = {"df_l": _people(260, seed=5),
+                  "df_r": _people(240, seed=6, first_id=1000)}
+    return Splink(_settings(link_type, **over), **frames)
+
+
+def _columns_the_parents_way(linker, G, il, ir, p, prob_m, prob_u, z):
+    """The parent commit's ``_assemble_columns``: a fresh array a column."""
+    from splink_tpu.settings import comparison_column_name
+
+    table = linker._ensure_encoded()
+    settings = linker.settings
+    cols = {"match_probability": p}
+    ctx = linker._tf_fold_ctx()
+    if ctx is not None:
+        cols["tf_match_probability"] = (
+            linker._tf_fold_pairs(z, il, ir, ctx)
+            if z is not None and len(p)
+            else np.zeros(len(p), linker._float_dtype)
+        )
+
+    def add_lr(name, make=None):
+        values = table.frame_column(name, make)
+        if isinstance(values, np.ndarray):
+            left, right = values[il], values[ir]
+        else:
+            left, right = values.take(il), values.take(ir)
+        cols.setdefault(f"{name}_l", left)
+        cols.setdefault(f"{name}_r", right)
+
+    add_lr(settings["unique_id_column_name"], lambda: table.unique_id)
+    for c, col in enumerate(settings["comparison_columns"]):
+        name = comparison_column_name(col)
+        if settings["retain_matching_columns"] or col["term_frequency_adjustments"]:
+            add_lr(name)
+        cols[f"gamma_{name}"] = G[:, c].astype(np.int64)
+        if settings["retain_intermediate_calculation_columns"]:
+            cols[f"prob_gamma_{name}_non_match"] = prob_u[:, c]
+            cols[f"prob_gamma_{name}_match"] = prob_m[:, c]
+    if settings["link_type"] == "link_and_dedupe":
+        add_lr(
+            "_source_table",
+            lambda: np.array(["left", "right"], dtype=object)[table.source_table],
+        )
+    for extra in settings["additional_columns_to_retain"]:
+        add_lr(extra)
+    return cols
+
+
+def _frame_the_parents_way(linker):
+    """The linker's CURRENT parameters scored the way the parent commit made
+    its one frame: per chunk the gathered arrays, ``pd.DataFrame`` (which
+    consolidates and copies), then ``pd.concat``."""
+    import jax.numpy as jnp
+
+    from splink_tpu.models.fellegi_sunter import FSParams
+
+    def frame(*arrays):
+        return pd.DataFrame(_columns_the_parents_way(linker, *arrays))
+
+    def take(lut, Pk):
+        return None if lut is None else lut[Pk]
+
+    if linker._use_pattern_pipeline():
+        PM, *luts = linker._pattern_score_luts()
+        chunks = [
+            frame(PM[Pk], il, ir, *(take(lut, Pk) for lut in luts))
+            for il, ir, Pk in linker._iter_pattern_triples()
+        ]
+        if chunks:
+            return pd.concat(chunks, ignore_index=True)
+        n_cols = len(linker.settings["comparison_columns"])
+        zero = np.zeros((0, n_cols), linker._float_dtype)
+        none = np.zeros(0, np.int64)
+        return frame(np.zeros((0, n_cols), np.int8), none, none, zero[:, 0], zero, zero, None)
+    G = linker._ensure_gammas()
+    pairs = linker._ensure_pairs()
+    lam, m, u, _ = linker.params.to_arrays(dtype=linker._float_dtype)
+    params = FSParams(lam=jnp.asarray(lam), m=jnp.asarray(m), u=jnp.asarray(u))
+    scored = linker._score_batched(
+        G, params, want_z=linker._tf_fold_ctx() is not None
+    )
+    return frame(G, pairs.idx_l, pairs.idx_r, *scored)
+
+
+def _storage(case):
+    """pandas without pyarrow keeps strings as Python objects: the option
+    makes it infer that storage here, where pyarrow is installed."""
+    return pd.option_context(
+        "mode.string_storage", "python" if "no_pyarrow" in case else "auto"
+    )
+
+
+def _one_frame(linker, case):
+    if case.startswith("manual_weights"):
+        assert linker._pattern_counts is None  # no histogram pass ran
+        return linker.manually_apply_fellegi_sunter_weights()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # "no candidate pairs" where none are due
+        return linker.get_scored_comparisons()
+
+
+@pytest.mark.parametrize("case", list(_ONE_FRAME))
+def test_one_frame_equals_the_per_chunk_frames_concatenated(case):
+    with _storage(case):
+        linker = _one_frame_linker(case)
+        frame = _one_frame(linker, case)
+        reference = _frame_the_parents_way(linker)
+    pd.testing.assert_frame_equal(frame, reference, check_dtype=True, check_exact=True)
+    assert list(frame.columns) == list(reference.columns)
+    assert list(frame.dtypes) == list(reference.dtypes)
+    assert isinstance(frame.index, pd.RangeIndex)
+    # the case is what its name says
+    empty = case.startswith("zero_pairs") or case == "every_position_masked"
+    assert (len(frame) == 0) == empty
+    table = [s for s in spans(run=linker.run_id)]
+    chunks = [s["counts"] for s in table if s["name"] == "assemble_frame"]
+    if linker._use_pattern_pipeline():
+        assert len(chunks) > 2 or empty
+        if case == "every_position_masked":
+            assert linker._virtual.n_candidates > 0
+        if "virtual" in case and not empty:
+            assert len(frame) < linker._virtual.n_candidates  # some masked
+    else:
+        assert len(chunks) == 1
+    assert sum(c["rows"] for c in chunks) == len(frame)
+    assert all(c["in_place_rows"] == c["rows"] for c in chunks)
+    assert ("tf_match_probability" in frame) == (
+        "no_tf" not in case and case != "nothing_retained"
+    )
+    assert ("prob_gamma_city_match" in frame) == (
+        "no_intermediates" not in case and case != "nothing_retained"
+    )
+    assert ("_source_table_l" in frame) == ("link_and_dedupe" in case)
+    if case == "nothing_retained":
+        assert list(frame.columns) == [
+            "match_probability", "unique_id_l", "unique_id_r",
+            "gamma_first_name", "gamma_surname", "gamma_city",
+        ]
+    else:
+        storage = "python" if "no_pyarrow" in case else "pyarrow"
+        assert frame["first_name_l"].dtype.storage == storage
+    # one block a column: pandas was handed the columns and copied nothing
+    assert frame._mgr.nblocks == len(frame.columns)
+
+
+@pytest.mark.parametrize("case", ["virtual_kept_ids", "materialised_patterns",
+                                  "link_strings", "link_and_dedupe_virtual"])
+def test_streamed_chunks_concatenated_equal_the_one_frame(case):
+    linker = _one_frame_linker(case)
+    frame = linker.get_scored_comparisons()
+    chunks = list(linker.stream_scored_comparisons_after_em())
+    if linker._use_pattern_pipeline():
+        assert len(chunks) > 2
+    pd.testing.assert_frame_equal(
+        pd.concat(chunks, ignore_index=True), frame, check_exact=True
+    )
+    for chunk in chunks:
+        assert list(chunk.dtypes) == list(frame.dtypes)
+
+
+@pytest.mark.parametrize("case", ["virtual_kept_ids", "link_strings",
+                                  "nothing_retained"])
+def test_the_frame_owns_its_memory(case):
+    """Scribbling over every column of a returned frame changes neither the
+    next frame of the same linker nor the table's ``frame_column`` cache nor
+    the table itself: no column is a view of anything the linker keeps."""
+    linker = _one_frame_linker(case)
+    table = linker._ensure_encoded()
+    first = linker.get_scored_comparisons()
+    kept = linker.manually_apply_fellegi_sunter_weights()
+    want = kept.copy(deep=True)
+    cache = {k: (v.copy() if isinstance(v, np.ndarray) else v[:].copy())
+             for k, v in table._frame_cache.items()}
+    ids = table.unique_id.copy()
+    for frame in (first, kept):
+        for name in frame.columns:
+            values = frame[name].to_numpy()
+            for held in (*table._frame_cache.values(), table.unique_id):
+                if isinstance(held, np.ndarray) and held.dtype == values.dtype:
+                    assert not np.shares_memory(values, held), name
+            if isinstance(frame[name].dtype, pd.StringDtype):
+                frame.loc[:, name] = "scribble"
+            elif values.dtype == object:
+                frame.loc[:, name] = None
+            else:
+                values.flags.writeable = True  # pandas hands out a locked view
+                values[:] = 7
+                assert (frame[name] == 7).all()  # it WAS the frame's memory
+    again = linker.manually_apply_fellegi_sunter_weights()
+    pd.testing.assert_frame_equal(again, want, check_exact=True)
+    np.testing.assert_array_equal(table.unique_id, ids)
+    for name, held in table._frame_cache.items():
+        if isinstance(held, np.ndarray):
+            np.testing.assert_array_equal(held, cache[name])
+        else:
+            assert held.equals(cache[name])
+
+
+def test_the_writer_refuses_what_numpy_indexing_would_have_refused():
+    """The takes write unbuffered (``mode="clip"``), so the writer checks a
+    chunk's indices itself, once a chunk: a row, or a pattern id, out of
+    range raises as ``values[idx]`` did, and so does a stream longer than
+    the frame was allocated for; nothing of such a chunk counts as written."""
+    from splink_tpu.linker import _FrameWriter
+
+    linker = _one_frame_linker("nothing_retained")
+    rows = linker._ensure_encoded().n_rows
+    tables = linker._pattern_frame_tables()
+    ok = np.arange(8, dtype=np.int32)
+    writer = _FrameWriter(linker, 20)
+    writer.write(ok, ok, *tables, by=ok)
+    for il, ir, by in (
+        (ok + rows - 7, ok, ok), (ok, ok - 1, ok), (ok, ok, ok + len(tables[1])),
+    ):
+        with pytest.raises(IndexError):
+            writer.write(il, ir, *tables, by=by)
+    assert writer.rows == 8
+    writer.write(ok, ok, *tables, by=ok)
+    with pytest.raises(ValueError, match="more than the 20 pairs"):
+        writer.write(ok, ok, *tables, by=ok)
+    frame = writer.frame()
+    assert len(frame) == 16
+    assert list(frame["unique_id_l"]) == 2 * list(linker._ensure_encoded().unique_id[:8])
+
+
+def test_takes_a_block_of_rows_at_a_time_write_the_same_frame(monkeypatch):
+    """The takes run a block of rows at a time (numpy's index conversion
+    stays cache-sized): with blocks shorter than a chunk — and not dividing
+    it — the frame is the frame."""
+    import splink_tpu.linker as linker_module
+
+    want = _one_frame_linker("virtual_kept_ids").get_scored_comparisons()
+    monkeypatch.setattr(linker_module, "_TAKE_ROWS", 100)
+    got = _one_frame_linker("virtual_kept_ids").get_scored_comparisons()
+    assert len(got) > 10 * 1024  # chunks of 1024 rows, eleven blocks each
+    pd.testing.assert_frame_equal(got, want, check_exact=True)

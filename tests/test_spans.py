@@ -395,6 +395,7 @@ def test_counts_at_the_stage_boundaries(job):
         assert concat["counts"] == {
             "chunks": sp["counts"]["batches"], "rows": pairs
         }
+        assert sum(s["counts"]["rows"] for s in by_name["assemble_frame"]) == pairs
     else:
         assert by_name["blocking"][0]["counts"] == {"pairs": pairs}
         # two default (Jaro-Winkler) columns: the pruned body, one evaluation each
@@ -428,6 +429,35 @@ def test_assemble_frame_counts_its_string_columns_and_how_they_came(job):
         assert counts["string_columns"] == 4
         assert counts["columnar_strings"] == (4 if arrow else 0)
         assert counts["columns"] > counts["string_columns"]
+
+
+def test_every_row_is_written_in_place_and_the_stage_covers_the_fill(job):
+    """The one-frame path of both jobs writes every row straight into the
+    columns of the frame it hands out (``in_place_rows`` == ``rows`` on
+    every ``assemble_frame``, one a chunk written), and the dedupe job's
+    ``score_patterns`` stage closes round ALL of the frame's work — the
+    writes, the per-pattern takes and what is left of the concat — so
+    ``score_output_s`` covers ``frame_assembly_s`` (ROADMAP D14)."""
+    name, _linker, table = job
+    by_id = {s["id"]: s for s in table}
+    frames = [s for s in table if s["name"] == "assemble_frame"]
+    [scored] = [s for s in table if s["name"] == "scored_comparisons"]
+    assert sum(s["counts"]["in_place_rows"] for s in frames) == scored["counts"]["pairs"]
+    assert all(s["counts"]["in_place_rows"] == s["counts"]["rows"] for s in frames)
+    gathers = [s for s in table if s["name"] == "lut_gather"]
+    [concat] = [s for s in table if s["name"] == "concat_frame"]
+    assert concat["counts"] == {
+        "chunks": len(frames), "rows": scored["counts"]["pairs"]
+    }
+    if name != "dedupe":
+        assert len(frames) == 1 and not gathers  # nothing per pattern to take
+        return
+    assert len(frames) == len(gathers) >= 2
+    assert all(by_id[s["parent"]]["name"] == "assemble_frame" for s in gathers)
+    [stage] = [s for s in table if s["name"] == "score_patterns"]
+    for s in (*frames, concat):
+        assert s["parent"] == stage["id"], s["name"]
+        assert stage["t0"] <= s["t0"] and s["t1"] <= stage["t1"]
 
 
 def test_numeric_only_frame_counts_no_string_column():

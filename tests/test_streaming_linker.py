@@ -11,6 +11,7 @@ import pandas as pd
 import pytest
 
 from splink_tpu import Splink
+from splink_tpu.linker import _FrameWriter
 
 
 def _df(n=200, seed=0):
@@ -474,7 +475,7 @@ def test_all_null_chunk_and_empty_frame_carry_the_columns_dtypes(stream):
     assert any(nulls) and not all(nulls)
     for chunk in chunks:
         pd.testing.assert_series_equal(chunk.dtypes, whole.dtypes)
-    empty = linker._empty_df_e()
+    empty = _FrameWriter(linker, 0).frame()
     assert len(empty) == 0
     pd.testing.assert_series_equal(empty.dtypes, whole.dtypes)
 

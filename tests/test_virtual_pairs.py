@@ -17,6 +17,7 @@ import pytest
 from splink_tpu import Splink
 from splink_tpu.data import concat_tables, encode_table
 from splink_tpu.gammas import GammaProgram
+from splink_tpu.linker import _FrameWriter
 from splink_tpu.pairgen import (
     _virtual_pass_iter,
     build_virtual_plan,
@@ -224,12 +225,13 @@ def _linker_settings(link_type="dedupe_only", **over):
 def _oracle_frame(linker):
     """The scored frame assembled from the HOST oracle's pairs: every
     position decoded and masked by ``decode_positions``, the pattern ids of
-    the pairs left from the materialised pattern pass, scored and assembled
-    by the linker's own tables."""
+    the pairs left from the materialised pattern pass, scored and written
+    by the linker's own tables and frame writer (one chunk a rule, into
+    columns as long as the plan's positions: a bound, not the count)."""
     plan = linker._virtual
     program = linker._ensure_pattern_program()
-    PM, *luts = linker._pattern_score_luts()
-    chunks = []
+    tables = linker._pattern_frame_tables()
+    writer = _FrameWriter(linker, plan.n_candidates)
     for r, rp in enumerate(plan.rules):
         i, j, masked = decode_positions(
             plan, r, np.arange(rp.total, dtype=np.int64)
@@ -238,12 +240,8 @@ def _oracle_frame(linker):
         if not len(i):
             continue
         Pk, _ = program.compute_pattern_ids(i, j, batch_size=4096)
-        chunks.append(
-            linker._assemble_df_e(
-                *linker._lut_gather(PM, i, j, Pk.astype(np.int32), *luts)
-            )
-        )
-    return linker._concat_chunks(iter(chunks))
+        writer.write(i, j, *tables, by=Pk.astype(np.int32))
+    return writer.frame()
 
 
 @pytest.mark.parametrize(
