@@ -969,7 +969,7 @@ class Splink:
                     )
                     if want_ids:
                         self._P_virtual = ids
-                    st.count(pairs=n_real)
+                    st.count(pairs=n_real, ids_kept=int(want_ids))
                     self._count_mesh(st, self._virtual.n_candidates)
                     st.count(**self._pattern_program.kernel_counts(
                         self._virtual.n_candidates))
@@ -994,7 +994,7 @@ class Splink:
                         mesh=self._pattern_mesh(),
                     )
                 )
-                st.count(pairs=len(self._P))
+                st.count(pairs=len(self._P), ids_kept=1)
                 self._count_mesh(st, len(self._P))
                 st.count(**self._pattern_program.kernel_counts(len(self._P)))
         return self._P, self._pattern_counts, self._pattern_program
@@ -1111,12 +1111,14 @@ class Splink:
         (il, ir, pattern-ids) chunk, written at the chunk's length. The
         chunk source (stored virtual ids / virtual recompute / materialised
         pairs) is _iter_pattern_triples — the single definition of the pair
-        stream."""
+        stream. The stage steps aside while a chunk is with the consumer."""
         tables = self._pattern_frame_tables()
         with self._stage("score_patterns") as st:
             for il, ir, Pk in self._iter_pattern_triples():
                 st.count(pairs=len(Pk), batches=1)
-                yield self._assemble_df_e(il, ir, *tables, by=Pk)
+                chunk = self._assemble_df_e(il, ir, *tables, by=Pk)
+                with st.suspended():
+                    yield chunk
 
     def _score_patterns_frame(self) -> "pd.DataFrame":
         """The whole pattern stream as ONE frame: its columns are allocated
@@ -1146,9 +1148,17 @@ class Splink:
         branches take the kernel's pairs — kept beside the ids, or home
         with them from the recompute pass — and nothing here decodes a
         position again. What is left under the ``decode_pairs`` span is
-        dropping the masked positions."""
+        dropping the masked positions.
+
+        The stage that drives the stream gets its regime as counts:
+        ``ids_kept`` 1 where it reads ids the pattern pass kept (virtual or
+        materialised pairs), 0 where it computes every position's id again,
+        which ``recomputed_positions`` then counts."""
         batch = int(self.settings["pair_batch_size"])
-        if self._virtual_plan() is not None:
+        virtual = self._virtual_plan() is not None
+        kept = not virtual or self._P_virtual is not None
+        count(ids_kept=int(kept), recomputed_positions=0)
+        if virtual:
             from .pairgen import _virtual_pass_iter
 
             plan = self._virtual
@@ -1184,9 +1194,10 @@ class Splink:
                             yield t
                     out_base += rp.total
                 return
-            for _, _, _, _, *chunk in _virtual_pass_iter(
+            for _, _, _, n_valid, *chunk in _virtual_pass_iter(
                 program, plan, batch, mesh=self._pattern_mesh()
             ):
+                count(recomputed_positions=n_valid)
                 t = unmasked(*chunk)
                 if t is not None:
                     yield t
@@ -1893,25 +1904,34 @@ class Splink:
         The reference returns a lazy Spark DataFrame at any scale
         (/root/reference/splink/__init__.py:121-145); chunked emission is the
         single-host equivalent — each chunk can be appended to parquet etc.
+
+        Its call span ``stream_scored_comparisons`` runs from the first
+        ``next()`` to exhaustion or ``close()`` and counts ``pairs`` and
+        ``chunks`` handed out and ``suspended_s``, the seconds the chunks
+        sat with the consumer: the span steps aside at every yield
+        (``StageTimer.suspended``), as ``score_patterns`` does below it.
         """
-        if self._use_pattern_pipeline():
-            # scoring follows EM: let the virtual pass keep its ids (the
-            # auto policy still bounds them against available RAM)
-            self._virtual_want_ids = True
-            self._run_em_patterns(compute_ll)
+        call = self._call("stream_scored_comparisons")
+        call.count(pairs=0, chunks=0, suspended_s=0.0)
+        with call:
             try:
-                yield from self._stream_pattern_chunks()
+                if self._use_pattern_pipeline():
+                    # scoring follows EM: let the virtual pass keep its ids
+                    # (the auto policy still bounds them against free RAM)
+                    self._virtual_want_ids = True
+                    self._run_em_patterns(compute_ll)
+                else:
+                    self._run_em(self._ensure_gammas(), compute_ll)
+                for chunk in self.stream_scored_comparisons_after_em():
+                    call.count(pairs=len(chunk), chunks=1)
+                    with call.suspended():
+                        yield chunk
             finally:
                 # release the (potentially multi-GB) ids on exhaustion AND
                 # on an abandoned/closed generator — same convention as the
                 # one-frame path; a re-stream simply recomputes chunk-wise
                 self._P_virtual = None
                 self._obs.finish()
-            return
-        G = self._ensure_gammas()
-        self._run_em(G, compute_ll)
-        yield from self.stream_scored_comparisons_after_em()
-        self._obs.finish()
 
     def stream_scored_comparisons_after_em(self):
         """Yield scored-comparison chunks using the current parameters

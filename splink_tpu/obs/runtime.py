@@ -184,7 +184,8 @@ class RunContext:
         """One span into the record. Stages also feed the compile/execute
         split, the stage metrics and a device-memory snapshot; a span's
         counts are THE source of the run's counters."""
-        elapsed = t1 - t0
+        # a generator's span sat out its consumer's time (suspended_s)
+        elapsed = t1 - t0 - counts.get("suspended_s", 0.0)
         execute_s = max(elapsed - compile_s, 0.0)
         attrs = dict(counts)
         if kind != "build":

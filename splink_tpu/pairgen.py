@@ -88,10 +88,12 @@ CHUNK = 2048
 # shape is the wrong tool anyway; such inputs fall back to host blocking.
 MAX_UNITS_PER_GROUP = (1 << 20) - 1
 
-# Concurrent pattern-id downloads in the ids-returning virtual pass: how
-# many batches may be in flight on the D2H thread pool before the driver
-# blocks. Bounded so pid buffers are not pinned on device without limit;
-# the value 3 is not measured on this machine (speed queue).
+# Concurrent downloads in a virtual pass (a batch's pattern ids and row
+# pairs, or in the histogram-only pass its overflow flag alone): how many
+# batches may be in flight on the D2H thread pool before the driver blocks.
+# Bounded so pid buffers are not pinned on device without limit. The value 3
+# is not tuned: the cell ``c5_dedupe_stream`` (PERF.md §5) is where both
+# passes run several batches deep and reads what it costs (``d2h_wait``).
 _D2H_DEPTH = 3
 
 
@@ -107,7 +109,7 @@ class RulePlan:
     pc: np.ndarray  # (U+1,) int64 cumulative pair counts over units
     residual: str | None = None  # translated residual predicate source
     residual_fn: object = None  # compiled device closure (see _ResCompiler)
-    # (batch size, mesh key, two_phase) -> (kernel, the abstract arguments
+    # (batch size, mesh key) -> (kernel, the abstract arguments
     # — shape, dtype, sharding — of its first call): the pattern kernels
     # this rule has RUN, for compiled_kernel_texts. The kernels themselves
     # are the process's (utils/kernel_registry via GammaProgram._kernel):
@@ -1285,8 +1287,7 @@ def compiled_kernel_texts(plan: VirtualPlan) -> list[tuple[int, str]]:
 
 
 def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
-                       mesh=None, want_ids: bool = True, counts_out=None,
-                       two_phase: bool = True, overflow_out=None):
+                       mesh=None, want_ids: bool = True, counts_out=None):
     """Drive one device pass over the virtual pair stream, yielding
     ``(rule, rule_p0, out_pos, n_valid, pid_host, il_host, ir_host)`` per
     batch: the pattern id of every position of the batch and the row pair
@@ -1298,9 +1299,17 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     is the ``d2h_wait`` span, 2.18 s of a ``c4_dedupe_virtual`` job; under
     a mesh ``mesh_put`` + ``mesh_gather`` add 0.60 s a job: ledger PR 30).
     The three are None when ``want_ids`` is
-    False — then NO per-pair bytes cross the link at all: the only D2H is
-    the int32 histogram accumulator flush every ~2^10 batches, so the
-    EM-only pattern pass does not wait on per-batch downloads.
+    False — then NO per-pair bytes cross the link at all: what comes home is
+    the int32 histogram accumulator every ~2^10 batches and, where the
+    kernel prunes (two-phase Jaro-Winkler on one device), each batch's
+    overflow flag, one element on the same pool.
+
+    A batch that overflowed the two-phase survivor capacity added nothing to
+    the histogram; it is redone ALONE through the exact twin when its flag is
+    home (``settle``), whether ids are wanted or not. The innermost open
+    stage gets the pass's counts: ``overflow_batches``, ``redo_positions``
+    (the flagged batches' positions), ``overflow_rule_<r>`` (flagged batches
+    of rule r) and ``hist_flushes``.
 
     The histogram accumulates into ``counts_out`` (int64, n_patterns); the
     caller owns the array. Host work per batch is O(units-in-batch): a
@@ -1313,7 +1322,7 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     import jax.numpy as jnp
 
     from .gammas import _HIST_FLUSH_BATCHES
-    from .utils.profiling import fetch, span
+    from .utils.profiling import count, fetch, span
 
     n_patterns = program.n_patterns
     total = plan.n_candidates
@@ -1368,16 +1377,21 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     # acc carries [histogram, masked sentinel, two-phase overflow count]
     acc = put(np.zeros(n_patterns + 2, np.int32))
     in_acc = 0
-    ovf_total = 0
+    # only the pruned body can overflow: a mesh kernel and a program without
+    # two-phase Jaro-Winkler are exact and carry no flag worth a download
+    flagged = mesh is None and bool(program.two_phase_div)
+    count(overflow_batches=0, redo_positions=0, hist_flushes=0)
 
     def flush_acc(acc_dev):
-        nonlocal ovf_total
-        acc_host = fetch(acc_dev)
-        counts[:] += acc_host[:n_patterns]
-        ovf_total += int(acc_host[n_patterns + 1])
+        counts[:] += fetch(acc_dev)[:n_patterns]
+        count(hist_flushes=1)
 
     def download_batch(outs):
-        """One batch's pattern ids and row pairs home, on a pool thread."""
+        """One batch home, on a pool thread: its pattern ids and row pairs,
+        or, from a pass that wants no ids, the overflow flag alone (sliced
+        HERE: an eager slice blocks the thread that dispatches it)."""
+        if not want_ids:
+            return (np.asarray(outs[0][-1:]),)
         return tuple(download(x) for x in outs)
 
     def settle(entry):
@@ -1387,8 +1401,8 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
         nonlocal acc
         pr, pp0, ps, n_valid, fut, rd = entry
         with span("d2h_wait") as sp:
-            pid_h, il, ir = fut.result()
-            sp.count(bytes=pid_h.nbytes + il.nbytes + ir.nbytes)
+            pid_h, *pair = fut.result()
+            sp.count(bytes=pid_h.nbytes + sum(a.nbytes for a in pair))
         if rd is not None and pid_h[-1]:
             # two-phase overflow: the flagged batch skipped the histogram;
             # redo through the exact twin (acc addition commutes, late redo
@@ -1399,10 +1413,19 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                 e_pos, packed, e_ord, *e_units, codes_dev,
                 uid_dev, res_ops_dev, e_meta, acc,
             )
-            pid_h = fetch(pid2)
+            count(overflow_batches=1, redo_positions=n_valid,
+                  **{f"overflow_rule_{pr}": 1})
+            if want_ids:
+                pid_h = fetch(pid2)
+        if not want_ids:
+            return pr, pp0, ps, n_valid, None, None, None
+        il, ir = pair
         return pr, pp0, ps, n_valid, pid_h[:n_valid], il[:n_valid], ir[:n_valid]
 
-    pool = ThreadPoolExecutor(max_workers=_D2H_DEPTH) if want_ids else None
+    pool = (
+        ThreadPoolExecutor(max_workers=_D2H_DEPTH)
+        if want_ids or flagged else None
+    )
     # (rule, rule_p0, out_pos, n_valid, future, redo arguments)
     inflight: deque = deque()
     try:
@@ -1455,10 +1478,11 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                 "prev_res": tuple(p.residual_fn for p in plan.rules[:r]),
             }
             fn = make_virtual_pattern_fn(
-                program, rule_bs, n_prev=r, mesh=mesh, two_phase=two_phase,
-                **res,
+                program, rule_bs, n_prev=r, mesh=mesh, **res
             )
-            kkey = (rule_bs, mesh_key(mesh), two_phase)
+            kkey = (rule_bs, mesh_key(mesh))
+            if flagged:
+                count(**{f"overflow_rule_{r}": 0})
 
             def exact_fn(r=r, rule_bs=rule_bs, res=res):
                 """The rule's exact-twin kernel for overflow redos, built
@@ -1481,18 +1505,21 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                 if kkey not in rp.kernels_run:
                     rp.kernels_run[kkey] = (fn, _abstract_args(args))
                 pid, i, j, acc = fn(*args)
-                if want_ids:
+                if pool is not None:
                     redo_args = (
                         exact_fn, pos_rule, order_dev, units_dev, meta_dev,
-                    ) if mesh is None else None
+                    ) if flagged else None
+                    # without ids the row pairs are let go with the call
+                    home = (pid, i, j) if want_ids else (pid,)
                     inflight.append(
                         (r, p0, out_pos, p1 - p0,
-                         pool.submit(download_batch, (pid, i, j)), redo_args)
+                         pool.submit(download_batch, home), redo_args)
                     )
                     while len(inflight) > _D2H_DEPTH:
                         yield settle(inflight.popleft())
                 else:
-                    # the kernel's row pairs stay on the device with its ids
+                    # exact kernels and no ids wanted: nothing of the batch
+                    # comes home
                     yield r, p0, out_pos, p1 - p0, None, None, None
                 out_pos += p1 - p0
                 in_acc += 1
@@ -1508,8 +1535,6 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
         # unconditional: an overflow redo during the tail drain can land
         # in acc after the last scheduled flush
         flush_acc(acc)
-        if overflow_out is not None:
-            overflow_out.append(ovf_total)
     finally:
         # consumer may abandon the generator mid-stream (exception in
         # a scoring chunk): do not leak pool threads or pinned buffers
@@ -1539,9 +1564,10 @@ def compute_virtual_pattern_ids(program, plan: VirtualPlan,
 
     With ``return_ids=False`` the pass computes ONLY the histogram — ids
     comes back None and no per-pair bytes ever cross the host<->device
-    link. This is the EM-path mode: EM needs nothing but counts (what
-    the per-batch download costs is the ``d2h_wait`` / ``mesh_gather``
-    spans of a ``chipbench`` run). The
+    link (a batch's two-phase overflow flag does, and the flagged batch is
+    redone alone, as when ids are kept). This is the EM-path mode: EM needs
+    nothing but counts (what the per-batch download costs is the
+    ``d2h_wait`` / ``mesh_gather`` spans of a ``chipbench`` run). The
     score-output stream recomputes ids and pairs chunk-wise later via
     ``_virtual_pass_iter`` (the kernels are the process's, so the second
     pass pays no compile).
@@ -1559,33 +1585,14 @@ def compute_virtual_pattern_ids(program, plan: VirtualPlan,
         np.empty(plan.n_candidates, np.int32),
         np.empty(plan.n_candidates, np.int32),
     ) if return_ids else None
-    overflow: list = []
     from .utils.profiling import count
 
     for _, _, ps, n_valid, *chunks in _virtual_pass_iter(
         program, plan, batch_size, mesh=mesh, want_ids=return_ids,
-        counts_out=counts, overflow_out=overflow,
+        counts_out=counts,
     ):
         count(batches=1)
         if return_ids:
             for kept, chunk in zip(ids, chunks):
                 kept[ps : ps + n_valid] = chunk
-    if not return_ids and overflow and overflow[0]:
-        # Histogram-only mode has no per-batch reads, so overflowed
-        # batches (which contributed nothing) are only visible here:
-        # rerun the whole pass through the exact kernels. Rare — the
-        # survivor capacity carries ~3x headroom over measured rates.
-        import logging
-
-        logging.getLogger("splink_tpu").warning(
-            "two-phase JW survivor capacity overflowed in %d batch(es); "
-            "recomputing the histogram pass with exact kernels",
-            overflow[0],
-        )
-        counts[:] = 0
-        for _ in _virtual_pass_iter(
-            program, plan, batch_size, mesh=mesh, want_ids=False,
-            counts_out=counts, two_phase=False,
-        ):
-            pass
     return ids, counts, int(counts.sum())

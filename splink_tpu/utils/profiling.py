@@ -34,6 +34,13 @@ A span's SELF time is its duration minus the part its children cover; build
 spans may overlap (a jit traced inside another reports its own trace time),
 so sum them as the union of their intervals.
 
+A span that a GENERATOR holds open lives across its yields: around each
+``yield`` it steps aside (:meth:`StageTimer.suspended`) — off this thread's
+stack, so that nothing the consumer opens becomes its child, with the seconds
+until the generator runs again added to its count ``suspended_s``. ``t0`` to
+``t1`` then holds the consumer's time too; :func:`stage_timings` leaves it
+out of a stage's seconds.
+
 Spans opened outside any run scope (ad-hoc profiling, tests) land in a
 default scope. Retained scopes are bounded (``_MAX_RETAINED_RUNS``).
 """
@@ -148,6 +155,25 @@ class StageTimer(contextlib.AbstractContextManager):
         """Add to the span's counts (work done inside it)."""
         for key, n in counts.items():
             self.counts[key] = self.counts.get(key, 0) + n
+
+    @contextlib.contextmanager
+    def suspended(self):
+        """Around a ``yield`` of the generator that holds this span open: the
+        span leaves this thread's stack and its trace annotation closes, and
+        the seconds until the generator is resumed (or closed) are added to
+        the count ``suspended_s`` — the consumer's time, not the program's."""
+        stack = _stack()
+        if self in stack:
+            stack.remove(self)
+        self._annotation.__exit__(None, None, None)
+        t = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.count(suspended_s=time.perf_counter() - t)
+            self._annotation = _trace_annotation(self.stage)
+            self._annotation.__enter__()
+            _stack().append(self)
 
     def __enter__(self):
         stack = _stack()
@@ -282,7 +308,9 @@ def stage_timings(run: str | None = None) -> dict[str, list[float]]:
     ]
     out: dict[str, list[float]] = {}
     for s in sorted(stages, key=lambda s: s["t1"]):
-        out.setdefault(s["name"], []).append(s["t1"] - s["t0"])
+        out.setdefault(s["name"], []).append(
+            s["t1"] - s["t0"] - s["counts"].get("suspended_s", 0.0)
+        )
     return out
 
 

@@ -26,7 +26,7 @@ from splink_tpu.pairgen import (
 )
 from splink_tpu.parallel.mesh import make_mesh
 from splink_tpu.settings import complete_settings_dict
-from splink_tpu.utils.profiling import spans
+from splink_tpu.utils.profiling import StageTimer, spans
 
 
 def _settings(rules, link_type="dedupe_only", cols=None, **over):
@@ -184,14 +184,14 @@ def test_kernel_pairs_through_the_two_phase_overflow_redo():
     s, table, plan = _jw_overflow_job()
     program = GammaProgram(s, table)
     assert program.two_phase_div and plan.n_candidates > 3 * 4096
-    overflow: list = []
-    batches, real = _assert_pass_equals_oracle(
-        program, plan, 4096, overflow_out=overflow
-    )
+    with StageTimer("gammas_patterns") as stage:
+        batches, real = _assert_pass_equals_oracle(program, plan, 4096)
     # every batch of 4096 survivors blew the capacity of 1024 and was redone
     # through the exact twin: its pairs are the first download's, its ids and
     # its share of the histogram the redo's
-    assert overflow == [batches] and batches >= 3
+    assert stage.counts["overflow_batches"] == batches >= 3
+    assert stage.counts["overflow_rule_0"] == batches
+    assert stage.counts["redo_positions"] == plan.n_candidates
     exact = GammaProgram(dict(s, two_phase_jw="off"), table)
     ids, counts, _ = compute_virtual_pattern_ids(program, plan, 4096)
     want, want_counts, _ = compute_virtual_pattern_ids(exact, plan, 4096)
@@ -199,6 +199,26 @@ def test_kernel_pairs_through_the_two_phase_overflow_redo():
         np.testing.assert_array_equal(got, oracle)
     np.testing.assert_array_equal(counts, want_counts)
     assert counts.sum() == real
+
+
+def test_histogram_only_pass_under_overflow_is_exact_and_runs_once():
+    s, table, plan = _jw_overflow_job()
+    program = GammaProgram(s, table)
+    assert program.two_phase_div and plan.n_candidates > 3 * 4096
+    with StageTimer("gammas_patterns") as stage:
+        ids, counts, n_real = compute_virtual_pattern_ids(
+            program, plan, 4096, return_ids=False)
+    batches = -(-plan.n_candidates // 4096)
+    assert ids is None and n_real == counts.sum() > 0
+    assert stage.counts["batches"] == batches  # once a batch: no second pass
+    assert stage.counts["overflow_batches"] == stage.counts["overflow_rule_0"] == batches
+    assert stage.counts["redo_positions"] == plan.n_candidates
+    exact = GammaProgram(dict(s, two_phase_jw="off"), table)
+    _, want, _ = compute_virtual_pattern_ids(exact, plan, 4096, return_ids=False)
+    np.testing.assert_array_equal(counts, want)
+    kept, with_ids, _ = compute_virtual_pattern_ids(program, plan, 4096)
+    np.testing.assert_array_equal(counts, with_ids)
+    assert n_real == int((kept.pid != program.n_patterns).sum())
 
 
 # ----------------------------------------------------------------------
@@ -350,11 +370,19 @@ def test_em_only_pass_downloads_nothing_per_pair(mesh):
         return False
 
     # what came home during the pass: the histogram accumulator, whose size
-    # is the pattern space's and not the pairs'
-    acc_bytes = 4 * (linker._ensure_pattern_program().n_patterns + 2)
-    waits = [s for s in table if s["name"] == "d2h_wait" and under(s)]
-    assert waits and all(s["counts"]["bytes"] == acc_bytes for s in waits)
-    assert acc_bytes < candidates  # less than a byte a pair, all told
+    # is the pattern space's and not the pairs', and on one device, where the
+    # Jaro-Winkler body prunes, one overflow flag (an id's width) a batch
+    program = linker._ensure_pattern_program()
+    acc_bytes = 4 * (program.n_patterns + 2)
+    waits = [s["counts"]["bytes"] for s in table
+             if s["name"] == "d2h_wait" and under(s)]
+    flags = [b for b in waits if b != acc_bytes]
+    assert acc_bytes in waits and all(b <= 4 for b in flags)
+    flagged = mesh is None and bool(program.two_phase_div)
+    assert len(flags) == (stage["counts"]["batches"] if flagged else 0)
+    assert sum(waits) < candidates  # less than a byte a pair, all told
+    assert stage["counts"]["ids_kept"] == 0
+    assert stage["counts"]["overflow_batches"] == 0
     assert not [s for s in table if s["name"] == "mesh_gather"]
     assert not [s for s in table if s["name"] == "decode_pairs"]
 
