@@ -707,7 +707,7 @@ def _ensure_default_registry() -> None:
         fn = lambda G, p: score_pairs(G, p)  # noqa: E731
         return fn, (G, params), {}
 
-    # Gamma batch (exact body — the variant mesh kernels compose): packed
+    # Gamma batch (the one body every kernel composes): packed
     # table replicated, pair indices sharded, ZERO collectives. This is the
     # kernel whose width-changing bitcast used to all-gather the batch.
     @register_shard_kernel("gamma_batch_sharded", n_pairs=256)
@@ -719,16 +719,10 @@ def _ensure_default_registry() -> None:
 
         mesh = audit_mesh()
         program = shared_gamma_program()
-        body = (
-            program._exact_gamma_body()
-            if program.two_phase_div
-            else program._gamma_batch_fn
-        )
         packed = jax.device_put(program._packed, replicated(mesh))
         il = jax.device_put(np.zeros(256, np.int32), pair_sharding(mesh))
         ir = jax.device_put(np.ones(256, np.int32), pair_sharding(mesh))
-        fn = lambda packed, il, ir: body(packed, il, ir)  # noqa: E731
-        return fn, (packed, il, ir), {}
+        return program._gamma_batch_fn, (packed, il, ir), {}
 
     # Materialised pattern-histogram kernel on the mesh: exactly ONE psum
     # (the replicated histogram accumulator), nothing else.
@@ -788,7 +782,7 @@ def _ensure_default_registry() -> None:
             np.array([0, bs, 0, imax, imax, imax], np.int32), rep
         )
         acc = jax.device_put(
-            np.zeros(program.n_patterns + 2, np.int32), rep
+            np.zeros(program.n_patterns + 1, np.int32), rep
         )
         prev_codes = jax.device_put(np.zeros((1, 6), np.int32), rep)
         uid_codes = jax.device_put(np.zeros(6, np.int32), rep)

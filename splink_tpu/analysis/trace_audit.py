@@ -513,7 +513,7 @@ def _ensure_default_registry() -> None:
         meta = jnp.asarray(
             np.array([0, bs, 0, imax, imax, imax], np.int32)
         )
-        acc = jnp.asarray(np.zeros(program.n_patterns + 2, np.int32))
+        acc = jnp.asarray(np.zeros(program.n_patterns + 1, np.int32))
         prev_codes = jnp.asarray(np.zeros((1, 6), np.int32))
         uid_codes = jnp.asarray(np.zeros(6, np.int32))
         return (

@@ -152,23 +152,6 @@ def _charset_key(name: str) -> str:
     return f"\x00charset:{name}"
 
 
-def _jw_key(name: str) -> str:
-    return f"\x00jwbound:{name}"
-
-
-class _JwBoundField:
-    """Lane layout of one column's Jaro-Winkler bound auxiliaries
-    (jw_bound.jw_bound_row_aux): 4 lanes of 32x 4-bit hashed-class counts
-    + 1 prefix/overflow lane. Packed only for columns the two-phase JW
-    path covers."""
-
-    __slots__ = ("counts", "pref_lane")
-
-    def __init__(self, counts, pref_lane):
-        self.counts = counts  # lane slice, 4 uint32 lanes
-        self.pref_lane = pref_lane
-
-
 class _CharsetField:
     """Lane layout of one column's precomputed charset auxiliaries
     (qgram_ops.charset_row_aux) for the CASE compiler's jaccard_sim fast
@@ -266,21 +249,6 @@ def charset_specs_for(settings: dict) -> tuple[str, ...]:
     return tuple(cols)
 
 
-def jw_specs_for(settings: dict) -> tuple[str, ...]:
-    """Columns whose JW-bound aux lanes should ride in the packed table:
-    every thresholded jaro_winkler comparison's input column. (Empty
-    thresholds mean every pair lands in level 0 — nothing to prune;
-    name_inversion's cross-column sims keep the exact kernel.)"""
-    cols: dict[str, None] = {}
-    for c in settings["comparison_columns"]:
-        spec = c.get("comparison") or {}
-        if spec.get("kind") == "jaro_winkler" and spec.get("thresholds"):
-            name = _comparison_input_column(c)
-            if name:
-                cols.setdefault(name)
-    return tuple(cols)
-
-
 def comparison_columns_used(settings: dict) -> set[str] | None:
     """Encoded-column names the gamma program reads, or None for 'all'
     (a registered custom comparison may touch any column)."""
@@ -311,7 +279,6 @@ def pack_table(
     include=None,
     qgram_specs=(),
     charset_specs=(),
-    jw_specs=(),
 ):
     """Pack encoded columns into one (n_rows, n_lanes) uint32 matrix.
 
@@ -385,17 +352,6 @@ def pack_table(
             add(space.view(np.uint32)).start,
         )
 
-    for jname in jw_specs:
-        sc = table.strings.get(jname)
-        if sc is None or (include is not None and jname not in include):
-            continue
-        from .ops import jw_bound
-
-        cnt, pref = jw_bound.jw_bound_row_aux(
-            sc.bytes_, sc.lengths, sc.token_ids
-        )
-        layout[_jw_key(jname)] = _JwBoundField(add(cnt), add(pref).start)
-
     f64 = float_dtype == jnp.float64
     num_names = [
         c for c in table.numerics if include is None or c in include
@@ -430,36 +386,10 @@ class PairContext:
     gathers happen after construction.
     """
 
-    def __init__(
-        self,
-        layout: dict,
-        rows_l,
-        rows_r,
-        two_phase_div: int | None = None,
-    ):
+    def __init__(self, layout: dict, rows_l, rows_r):
         self._layout = layout
         self._rows_l = rows_l
         self._rows_r = rows_r
-        # Two-phase JW: survivor capacity = batch // two_phase_div (None =
-        # exact kernels everywhere). Each two-phase column records a
-        # did-its-survivors-overflow flag here; the kernel returns their
-        # sum so the driver can redo the batch with the exact twin.
-        self.two_phase_div = two_phase_div
-        self.overflow: list = []
-
-    def survivor_capacity(self, b: int) -> int:
-        return min(b, max(1024, b // self.two_phase_div))
-
-    def record_overflow(self, flag) -> None:
-        self.overflow.append(flag)
-
-    def overflow_count(self):
-        if not self.overflow:
-            return jnp.int32(0)
-        total = self.overflow[0].astype(jnp.int32)
-        for f in self.overflow[1:]:
-            total = total + f.astype(jnp.int32)
-        return total
 
     def _string_side(self, f: _StringField, rows):
         lanes = rows[:, f.chars]
@@ -515,18 +445,6 @@ class PairContext:
 
         return side(self._rows_l), side(self._rows_r)
 
-    def jw_aux(self, name: str):
-        """Per-side JW-bound aux ((counts, prefix) each side), or None when
-        the packed table does not carry it for this column."""
-        f = self._layout.get(_jw_key(name))
-        if f is None:
-            return None
-
-        def side(rows):
-            return rows[:, f.counts], rows[:, f.pref_lane]
-
-        return side(self._rows_l), side(self._rows_r)
-
     def charset_aux(self, name: str):
         """Per-side precomputed charset aux (mask, count, space flag), or
         None when the packed table does not carry it for this column."""
@@ -564,54 +482,6 @@ def _pad_chars(chars, width: int):
     if out.shape[1] < width:
         out = jnp.pad(out, ((0, 0), (0, width - out.shape[1])))
     return out
-
-
-def _jw_two_phase(ctx: PairContext, pc: PairColumn, aux, thresholds):
-    """Two-phase Jaro-Winkler gamma: cheap upper bound excludes the bulk of
-    below-lowest-threshold pairs (ops/jw_bound), token-equal pairs take
-    their level from sim == 1.0 without any kernel, and the exact O(L^2)
-    kernel runs only on the compacted survivors (capacity B //
-    two_phase_div; an overflowing batch is flagged for the exact twin).
-    Bit-identical to the exact branch: excluded pairs provably sit below
-    every threshold, survivors get the same kernel + bucketing
-    (tests/test_jw_two_phase.py property-checks this)."""
-    from .ops import jw_bound
-
-    (cl, pl), (cr, pr) = aux
-    ub = jw_bound.jw_upper_bound(cl, pl, cr, pr, pc.len_l, pc.len_r, 0.1, 0.7)
-    lowest = min(thresholds)
-    # bucket_similarity is strict (sim > t): a token-equal pair's level is
-    # the count of thresholds strictly below 1.0 — static, so computed here
-    equal_level = sum(1 for t in thresholds if 1.0 > t)
-    equal = (pc.tok_l == pc.tok_r) & (pc.len_l > 0)
-    surv = (ub >= lowest - jw_bound.BOUND_MARGIN) & ~equal & ~pc.null
-    b = surv.shape[0]
-    cap = ctx.survivor_capacity(b)
-    # survivor compaction: pos[k] = index of the k-th True in surv, padded
-    # with b — jnp.nonzero(size=cap, fill_value=b) semantics, but built from
-    # an int32 cumsum-rank scatter because nonzero's internals run int64
-    # under x64 (ranks are unique so the scatter is deterministic; ranks
-    # >= cap drop, which matches nonzero's truncation)
-    rank = jnp.cumsum(surv, dtype=jnp.int32) - 1
-    pos = (
-        jnp.full((cap,), b, jnp.int32)
-        .at[jnp.where(surv, rank, cap)]
-        .set(jnp.arange(b, dtype=jnp.int32), mode="drop")
-    )
-    ctx.record_overflow(jnp.sum(surv, dtype=jnp.int32) > cap)
-    posc = jnp.minimum(pos, b - 1)
-    sim = string_ops.jaro_winkler(
-        pc.chars_l[posc], pc.chars_r[posc],
-        pc.len_l[posc], pc.len_r[posc], 0.1, 0.7,
-    )
-    lvl_s = bucket_similarity(sim, thresholds, None)
-    base = jnp.where(
-        equal,
-        jnp.asarray(equal_level, GAMMA_DTYPE),
-        jnp.asarray(0, GAMMA_DTYPE),
-    )
-    lvl = base.at[pos].set(lvl_s, mode="drop")
-    return apply_null(lvl, pc.null)
 
 
 def _spec_gamma(col_settings: dict, ctx: PairContext) -> jnp.ndarray:
@@ -671,9 +541,6 @@ def _spec_gamma(col_settings: dict, ctx: PairContext) -> jnp.ndarray:
         return apply_null(gamma, pc.null)
 
     if kind == "jaro_winkler":
-        aux = ctx.jw_aux(name) if thresholds else None
-        if aux is not None and ctx.two_phase_div:
-            return _jw_two_phase(ctx, pc, aux, thresholds)
         sim = string_ops.jaro_winkler(
             pc.chars_l, pc.chars_r, pc.len_l, pc.len_r, 0.1, 0.7
         )
@@ -764,7 +631,6 @@ class _Parts(NamedTuple):
 
     cols: tuple  # the comparison columns' settings (private copies)
     layout: dict  # packed-table field layout: lane metadata, no data
-    two_phase_div: int | None
     strides: tuple  # mixed-radix pattern strides
     n_patterns: int
 
@@ -783,14 +649,14 @@ def _layout_signature(layout: dict) -> tuple:
     ))
 
 
-def _signature(cols, layout, two_phase_div, float_dtype):
+def _signature(cols, layout, float_dtype):
     """The registry key of a gamma program — the comparison columns'
     settings by CONTENT (a caller may deep-copy its settings per job), the
-    layout, the two-phase divisor and the float dtype; level counts, strides
-    and the pattern count follow from the columns. None when it cannot be
-    signed: a ``custom`` column runs whatever callable is registered under
-    its name right now, and settings json cannot write have no canonical
-    form. Such a program is built per linker."""
+    layout and the float dtype; level counts, strides and the pattern count
+    follow from the columns. None when it cannot be signed: a ``custom``
+    column runs whatever callable is registered under its name right now,
+    and settings json cannot write have no canonical form. Such a program
+    is built per linker."""
     if any((c.get("comparison") or {}).get("kind") == "custom" for c in cols):
         return None
     try:
@@ -800,17 +666,13 @@ def _signature(cols, layout, two_phase_div, float_dtype):
     return (
         cols_json,
         _layout_signature(layout),
-        two_phase_div,
         jnp.dtype(float_dtype).name,
     )
 
 
-# ONE body template, instantiated twice: the two-phase body (primary on a
-# single device) and the exact body (mesh sharding — survivor compaction
-# does not partition trivially — and the overflow-redo twin). Both return
-# (G, overflow_count); the property tests pin them bit-identical on the
-# gamma output.
-def _make_gamma_body(parts: _Parts, two_phase_div):
+# ONE gamma body: every kernel — one chip or a mesh, virtual or materialised
+# pairs, ids kept or not — composes this function and no other.
+def _make_gamma_body(parts: _Parts):
     cols, layout = parts.cols, parts.layout
 
     # The packed table is an explicit argument, NOT a closure capture: a
@@ -823,59 +685,50 @@ def _make_gamma_body(parts: _Parts, two_phase_div):
         with jax.named_scope("row_gather"):
             rows_l = packed[idx_l]
             rows_r = packed[idx_r]
-        ctx = PairContext(layout, rows_l, rows_r, two_phase_div)
+        ctx = PairContext(layout, rows_l, rows_r)
         gammas = []
         for c in cols:
             with jax.named_scope(f"cmp/{comparison_column_name(c)}"):
                 gammas.append(_spec_gamma(c, ctx))
-        return jnp.stack(gammas, axis=1), ctx.overflow_count()
+        return jnp.stack(gammas, axis=1)
 
     return _gamma_body
 
 
 def _mesh_gamma_body(parts: _Parts, mesh):
-    """The exact gamma body as a per-shard program over the mesh's
-    data axis (packed table replicated, pair indices and G split).
+    """The gamma body as a per-shard program over the mesh's data axis
+    (packed table replicated, pair indices and G split).
 
     ``jit(out_shardings=...)`` alone cannot do this on a TPU: the
     string kernels are Pallas (Mosaic) custom calls there, which XLA's
     partitioner does not split — it would gather every pair onto every
     chip in front of the call. Every op in the body is per-pair, so
     under shard_map each chip runs the single-device program on its
-    own ``batch / N`` slice. Two-phase survivor compaction
-    (jnp.nonzero along the sharded pair axis) would need a
-    cross-device prefix sum, so the pruning stays a single-device
-    optimisation; tests/test_jw_two_phase.py pins the two bodies
-    bit-identical."""
+    own ``batch / N`` slice."""
     from jax.sharding import PartitionSpec as P
 
     from .parallel.mesh import DATA_AXIS
 
     return jax.shard_map(
-        _make_gamma_body(parts, None),
+        _make_gamma_body(parts),
         mesh=mesh,
         in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
-        out_specs=(P(DATA_AXIS), P()),
+        out_specs=P(DATA_AXIS),
         # the kernels' scans start from unvarying constants (a carry
-        # type mismatch under the check); the overflow count the exact
-        # body returns is the constant 0 on every shard
+        # type mismatch under the check)
         check_vma=False,
     )
 
 
-# ONE pattern-kernel template over a gamma body. The returned pid array
-# carries one extra trailing element: the batch's overflow flag (0/1), so
-# the per-batch host read that fetches the ids anyway also learns whether
-# the two-phase survivor capacity blew. An overflowed batch contributes
-# NOTHING to the histogram — the driver redoes it through the exact twin,
-# and int32 addition commuting makes the late redo bit-identical.
-def _make_pattern_kernel(parts: _Parts, gamma_body, append_flag=True):
+# ONE pattern-kernel template over a gamma body: a batch's pattern ids
+# (exactly ``batch`` of them) and the histogram accumulator with the batch
+# added.
+def _make_pattern_kernel(parts: _Parts, gamma_body):
     strides_dev = jnp.asarray(parts.strides, jnp.int32)
     n_patterns = parts.n_patterns
 
     def _pattern_kernel(packed, idx_l, idx_r, valid, acc):
-        G, ovf = gamma_body(packed, idx_l, idx_r)
-        G = G.astype(jnp.int32)
+        G = gamma_body(packed, idx_l, idx_r).astype(jnp.int32)
         pid = jnp.sum(
             (G + 1) * strides_dev[None, :], axis=1, dtype=jnp.int32
         )
@@ -884,22 +737,12 @@ def _make_pattern_kernel(parts: _Parts, gamma_body, append_flag=True):
             pid,
             n_patterns,
         )
-        ovf_flag = (ovf > 0).astype(jnp.int32)
-        acc = acc + int32_histogram(
-            masked, n_patterns + 1
-        ) * (1 - ovf_flag)
+        acc = acc + int32_histogram(masked, n_patterns + 1)
         if pattern_ids_fit_uint16(n_patterns):
             # narrow on device: halves the per-batch D2H (all
             # real ids < n_patterns <= 65535; padding-tail pids
             # are sliced off host-side before use)
             pid = pid.astype(jnp.uint16)
-        if append_flag:
-            # overflow flag rides as pid[-1]; mesh kernels skip
-            # it (a B+1 output cannot shard evenly, and the
-            # exact body they compose never overflows)
-            pid = jnp.concatenate(
-                [pid, ovf_flag.astype(pid.dtype)[None]]
-            )
         return pid, acc
 
     return _pattern_kernel
@@ -909,62 +752,27 @@ def _make_pattern_kernel(parts: _Parts, gamma_body, append_flag=True):
 # GammaProgram._kernel hands the registry as ``build``.
 
 
-def _jit_gamma_body(parts: _Parts):
-    return jax.jit(_make_gamma_body(parts, parts.two_phase_div))
+def _jit_gamma_batch(parts: _Parts):
+    body = _make_gamma_body(parts)
 
-
-def _jit_gamma_safe(parts: _Parts):
-    """Two-phase body with the overflow redo ON DEVICE (lax.cond —
-    jit-composable, so no caller can drop the overflow flag the
-    tuple-returning fns carry)."""
-    body = _make_gamma_body(parts, parts.two_phase_div)
-    exact_body = _make_gamma_body(parts, None)
-
-    def _safe_body(packed, idx_l, idx_r):
-        G, ovf = body(packed, idx_l, idx_r)
-        return jax.lax.cond(
-            ovf > 0,
-            lambda ops: exact_body(*ops)[0],
-            lambda ops: G,
-            (packed, idx_l, idx_r),
-        )
-
-    return jax.jit(_safe_body)
-
-
-def _jit_gamma_flagged(parts: _Parts, exact: bool):
-    """Host-batched G paths read back one array per batch; the overflow
-    flag rides as one extra G row (int8 flag at [-1, 0]) so detecting it
-    costs no second device fetch."""
-    body = _make_gamma_body(parts, None if exact else parts.two_phase_div)
-
-    # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches
-    # the XLA module ``jit_fn(`` (see pairgen.make_virtual_pattern_fn,
-    # the only other program of that name).
+    # Named ``fn`` on purpose: the benchmark's gamma metrics match the XLA
+    # module ``jit_fn(`` (see pairgen.make_virtual_pattern_fn, the only
+    # other program of that name).
     def fn(packed, idx_l, idx_r):
-        G, ovf = body(packed, idx_l, idx_r)
-        flag_row = (
-            jnp.zeros((1, G.shape[1]), G.dtype)
-            .at[0, 0]
-            .set((ovf > 0).astype(G.dtype))
-        )
-        return jnp.concatenate([G, flag_row])
+        return body(packed, idx_l, idx_r)
 
     return jax.jit(fn)
 
 
-def _jit_pattern_batch(parts: _Parts, exact: bool):
-    body = _make_gamma_body(parts, None if exact else parts.two_phase_div)
-    return jax.jit(_make_pattern_kernel(parts, body))
+def _jit_pattern_batch(parts: _Parts):
+    return jax.jit(_make_pattern_kernel(parts, _make_gamma_body(parts)))
 
 
 def _jit_pattern_batch_mesh(parts: _Parts, mesh):
     from .parallel.mesh import pair_sharding, replicated
 
     return jax.jit(
-        _make_pattern_kernel(
-            parts, _mesh_gamma_body(parts, mesh), append_flag=False
-        ),
+        _make_pattern_kernel(parts, _mesh_gamma_body(parts, mesh)),
         out_shardings=(pair_sharding(mesh), replicated(mesh)),
     )
 
@@ -983,9 +791,9 @@ class GammaProgram:
     settings. The table (``_packed``) belongs to this program; the jitted
     kernels take it as an argument and are the PROCESS's — shared through
     ``utils.kernel_registry`` with every program whose comparison columns,
-    packed layout, two-phase divisor and float dtype are equal, so a second
-    linker on the same model traces, lowers and reads back nothing. A
-    program that cannot be signed (:func:`_signature`) builds its own."""
+    packed layout and float dtype are equal, so a second linker on the same
+    model traces, lowers and reads back nothing. A program that cannot be
+    signed (:func:`_signature`) builds its own."""
 
     def __init__(self, settings: dict, table: EncodedTable, float_dtype=jnp.float32):
         self.settings = settings
@@ -993,26 +801,18 @@ class GammaProgram:
         self.max_levels = max(
             c["num_levels"] for c in settings["comparison_columns"]
         )
-        # Two-phase JW scoring (ops/jw_bound): on unless the settings switch
-        # it off or no column qualifies. The divisor sets the survivor
-        # capacity (batch // div); measured survivor rates on config-4
-        # shapes are 2.9-3.7% so 8 leaves ~3x headroom, with the exact-twin
-        # redo protocol guaranteeing correctness beyond it.
-        self.two_phase_div = None
-        if settings.get("two_phase_jw", "on") != "off" and jw_specs_for(settings):
-            self.two_phase_div = int(settings.get("jw_survivor_divisor", 8))
 
         # Pack the compared columns into one uint32 matrix and push it to
         # device once: each pair batch then costs exactly two row gathers.
-        with span("pack_table", rows=int(table.n_rows)):
+        with span("pack_table", rows=int(table.n_rows)) as sp:
             packed, layout = pack_table(
                 table,
                 float_dtype,
                 include=comparison_columns_used(settings),
                 qgram_specs=qgram_specs_for(settings),
                 charset_specs=charset_specs_for(settings),
-                jw_specs=jw_specs_for(settings) if self.two_phase_div else (),
             )
+            sp.count(lanes=int(packed.shape[1]))  # words of a packed row
         with span("h2d_put", bytes=packed.nbytes):
             self._packed = jnp.asarray(packed)
         self._layout = layout
@@ -1028,22 +828,18 @@ class GammaProgram:
         strides, self.n_patterns = pattern_strides_for(self.level_counts)
         self._pattern_strides = strides
 
-        self._sig = _signature(cols, layout, self.two_phase_div, float_dtype)
+        self._sig = _signature(cols, layout, float_dtype)
         self._parts = _Parts(
             # a shared kernel may retrace for new shapes long after this
             # linker mutated or dropped its settings: it reads its own copy
             cols=tuple(cols if self._sig is None else copy.deepcopy(cols)),
             layout=dict(layout),
-            two_phase_div=self.two_phase_div,
             strides=tuple(strides),
             n_patterns=self.n_patterns,
         )
         # this program's kernels by (fun, variant): the registry is asked
         # once per kernel, and an eviction cannot take a kernel in use
         self._kernels: dict = {}
-        # whether a stage's pass was handed a kernel with the pruned
-        # Jaro-Winkler body (_kernel notes it; kernel_counts reports it)
-        self._two_phase_handed = False
 
         # The compiled-artifact analogue of the reference logging its
         # generated SQL at debug level (/root/reference/splink/gammas.py:120).
@@ -1056,30 +852,23 @@ class GammaProgram:
         (docs/observability.md): ``string_evals`` — string-kernel evaluations
         issued, positions times the columns' sum (Jaro-Winkler, Levenshtein
         and q-gram columns 1 each, name inversion 1 + its other columns);
-        ``two_phase`` — 1 when a kernel with the pruned Jaro-Winkler body was
-        handed out to run (``_kernel`` notes it where the body is picked),
-        else 0; how many columns are Levenshtein and how many name
-        inversion."""
+        how many columns are Levenshtein and how many name inversion."""
         specs = [c["comparison"] for c in self.settings["comparison_columns"]]
         kinds = [spec["kind"] for spec in specs]
         return {
             "string_evals": int(positions) * sum(map(_string_evals, specs)),
-            "two_phase": int(self._two_phase_handed),
             "levenshtein_columns": kinds.count("levenshtein"),
             "name_inversion_columns": kinds.count("name_inversion"),
         }
 
     def _kernel(self, fun: str, variant: tuple, build, shareable: bool = True,
-                mesh=None, two_phase: bool = False):
+                mesh=None):
         """The jitted program ``build(parts)`` makes, from the process's
         registry when this program has a signature and the caller could
         sign ``variant`` (the rest of what ``build`` closes over); else
         this program's own. ``build`` must not capture the program.
         ``mesh``: the mesh the program shards over (its key is in
-        ``variant``), for the lookup span's ``devices``. ``two_phase``: the
-        caller picked the pruned Jaro-Winkler body for a kernel a stage's
-        pass is about to run — noted for ``kernel_counts``."""
-        self._two_phase_handed |= two_phase
+        ``variant``), for the lookup span's ``devices``."""
         fn = self._kernels.get((fun, variant))
         if fn is None:
             key = (
@@ -1094,59 +883,23 @@ class GammaProgram:
 
     @property
     def _gamma_batch_fn(self):
-        """The pure (packed-explicit) jitted body, for composition into
-        larger jitted programs (pairgen's virtual pair kernels) without
-        turning the packed table into a jaxpr constant; returns
-        (G, overflow)."""
-        return self._kernel("gamma_body", (), _jit_gamma_body)
+        """The jitted gamma program (packed-explicit): what the host-batched
+        G paths and ad-hoc scoring run, one (batch, columns) array a call."""
+        return self._kernel("gamma_batch", (), _jit_gamma_batch)
 
     def _gamma_batch(self, il, ir):
-        """The convenience path (ad-hoc scoring, tests): IMPOSSIBLE to
-        misuse — when the two-phase survivor
-        capacity blows it redoes the batch through the exact body on
-        device. The double-buffered host paths use the flagged variants,
-        whose host-side redo overlaps transfers."""
-        if self.two_phase_div:
-            safe = self._kernel("gamma_safe", (), _jit_gamma_safe)
-            return safe(self._packed, il, ir)
-        return self._gamma_batch_fn(self._packed, il, ir)[0]
-
-    def _gamma_flagged_fn(self, exact: bool = False):
-        """The jitted flagged G kernel (packed-explicit); ``exact`` is the
-        overflow-redo twin. With two-phase off the primary IS exact and
-        nothing builds twice."""
-        exact = bool(exact and self.two_phase_div)
-        return self._kernel(
-            "gamma_flagged", (exact,),
-            functools.partial(_jit_gamma_flagged, exact=exact),
-            two_phase=bool(self.two_phase_div) and not exact,
-        )
-
-    def _gamma_batch_flagged(self, il, ir):
-        return self._gamma_flagged_fn()(self._packed, il, ir)
-
-    def _gamma_batch_flagged_exact(self, il, ir):
-        """Exact-twin flagged batch (for redoing an overflowed G batch);
-        it only compiles if a two-phase batch ever overflows."""
-        return self._gamma_flagged_fn(exact=True)(self._packed, il, ir)
+        return self._gamma_batch_fn(self._packed, il, ir)
 
     @property
     def _pattern_kernel(self):
-        """The un-jitted pattern kernel over the primary body (audits)."""
+        """The un-jitted pattern kernel (audits)."""
         return _make_pattern_kernel(
-            self._parts, _make_gamma_body(self._parts, self.two_phase_div)
-        )
-
-    def _pattern_batch_fn(self, exact: bool = False):
-        exact = bool(exact and self.two_phase_div)
-        return self._kernel(
-            "pattern_batch", (exact,),
-            functools.partial(_jit_pattern_batch, exact=exact),
-            two_phase=bool(self.two_phase_div) and not exact,
+            self._parts, _make_gamma_body(self._parts)
         )
 
     def _run_pattern_batch(self, il, ir, valid, acc):
-        return self._pattern_batch_fn()(self._packed, il, ir, valid, acc)
+        fn = self._kernel("pattern_batch", (), _jit_pattern_batch)
+        return fn(self._packed, il, ir, valid, acc)
 
     @property
     def _pattern_batch(self):
@@ -1157,20 +910,6 @@ class GammaProgram:
         cycle, and the packed table would stay on the device until the
         cyclic collector happened to run."""
         return self._run_pattern_batch if self.n_patterns <= MAX_PATTERNS else None
-
-    def _pattern_batch_exact(self, il, ir, valid, acc):
-        """Exact-twin pattern batch (overflow redo): flagged like the
-        primary so the host read path is uniform; it only compiles if a
-        two-phase batch ever overflows."""
-        return self._pattern_batch_fn(exact=True)(
-            self._packed, il, ir, valid, acc
-        )
-
-    def _exact_gamma_body(self):
-        """The exact (no two-phase) gamma body, un-jitted — what the
-        overflow redo runs and the mesh kernels compose. (G, overflow)
-        signature, overflow always 0."""
-        return _make_gamma_body(self._parts, None)
 
     def _pattern_batch_for_mesh(self, mesh):
         """Mesh-sharded twin of the pattern-batch kernel (same
@@ -1267,22 +1006,9 @@ class GammaProgram:
         in_acc = 0
         pending = None
 
-        has_flag = mesh is None  # mesh kernels are exact and unflagged
-
-        def read_pending(pending, acc):
-            """Fetch a batch's ids; an overflow flag (pid[-1], two-phase
-            survivor capacity blown) redoes it through the exact twin —
-            the flagged batch skipped the histogram, so the late redo's
-            acc addition commutes into an identical total."""
-            ps, pe, prev, pbl, pbr = pending
-            arr = fetch(prev, via=ids_home)
-            if has_flag and arr[-1]:
-                pid2, acc = self._pattern_batch_exact(
-                    jnp.asarray(pbl), jnp.asarray(pbr), pe - ps, acc
-                )
-                arr = fetch(pid2)
-            pids[ps:pe] = arr[: pe - ps].astype(id_dtype)
-            return acc
+        def read_pending(pending):
+            ps, pe, prev = pending
+            pids[ps:pe] = fetch(prev, via=ids_home)[: pe - ps].astype(id_dtype)
 
         for start in range(0, n, batch_size):
             stop = min(start + batch_size, n)
@@ -1295,17 +1021,17 @@ class GammaProgram:
             pid, acc = run_batch(bl, br, stop - start, acc)
             count(batches=1)
             if pending is not None:
-                acc = read_pending(pending, acc)
-            pending = (start, stop, pid, bl, br)
+                read_pending(pending)
+            pending = (start, stop, pid)
             in_acc += 1
             if in_acc >= flush_every:
-                acc = read_pending(pending, acc)
+                read_pending(pending)
                 pending = None
                 total += fetch(acc)[:-1]
                 acc = zero_acc()
                 in_acc = 0
         if pending is not None:
-            acc = read_pending(pending, acc)
+            read_pending(pending)
         if in_acc:
             total += fetch(acc)[:-1]
         return pids, total
@@ -1372,27 +1098,18 @@ class GammaProgram:
 
         Double-buffered: batch k+1 is dispatched before batch k's result is
         pulled to the host, so device compute overlaps the D2H transfer
-        (JAX dispatch is async; np.asarray is the only sync point). The
-        flagged kernel carries the two-phase overflow flag as an extra G
-        row ([-1, 0]); a flagged batch is redone through the exact twin at
-        its read point, before anything consumes it. Shared by
+        (JAX dispatch is async; np.asarray is the only sync point). Shared by
         :meth:`compute_with_device` (resident G) and
         :meth:`iter_gamma_chunks` (the spill-fed stream) — their
         bit-identity contract is this single implementation.
         """
         n = len(idx_l)
         batch_size = min(batch_size, max(n, 1))
-        pending = None  # (rows_in_batch, device result, bl, br)
+        pending = None  # (rows_in_batch, device result)
 
         def read_pending(pending):
-            valid, pG, pbl, pbr = pending
-            arr = fetch(pG)
-            if arr[-1, 0]:
-                pG = self._gamma_batch_flagged_exact(
-                    jnp.asarray(pbl), jnp.asarray(pbr)
-                )
-                arr = fetch(pG)
-            return arr[:valid], pG, valid
+            valid, pG = pending
+            return fetch(pG)[:valid], pG, valid
 
         for start in range(0, n, batch_size):
             stop = min(start + batch_size, n)
@@ -1402,11 +1119,11 @@ class GammaProgram:
                 pad = batch_size - (stop - start)
                 bl = np.concatenate([bl, np.zeros(pad, bl.dtype)])
                 br = np.concatenate([br, np.zeros(pad, br.dtype)])
-            G = self._gamma_batch_flagged(*_put_pair_batch(bl, br))
+            G = self._gamma_batch(*_put_pair_batch(bl, br))
             count(batches=1)
             if pending is not None:
                 yield read_pending(pending)
-            pending = (stop - start, G, bl, br)
+            pending = (stop - start, G)
         yield read_pending(pending)
 
     def iter_gamma_chunks(
@@ -1524,27 +1241,21 @@ class GammaStream(_StreamBatcher):
         )
 
     def _read_pending(self):
-        v, prev, pbl, pbr = self._pending
-        arr = fetch(prev)
-        if arr[-1, 0]:  # two-phase overflow: redo through the exact twin
-            prev = self.program._gamma_batch_flagged_exact(
-                jnp.asarray(pbl), jnp.asarray(pbr)
-            )
-            arr = fetch(prev)
-        self._out_parts.append(arr[:v])
+        v, prev = self._pending
+        self._out_parts.append(fetch(prev)[:v])
         if self._device_batches is not None:
             self._device_batches.append(prev[:v])
         self._pending = None
 
     def _emit(self, bl, br, valid):
-        G = self.program._gamma_batch_flagged(*_put_pair_batch(bl, br))
+        G = self.program._gamma_batch(*_put_pair_batch(bl, br))
         if self._device_batches is not None and self.total > self.keep_limit:
             self._device_batches = None  # too big: free HBM
         # double buffer: read back the PREVIOUS batch (it has finished by
         # the time the next one is dispatched), keeping dispatch async
         if self._pending is not None:
             self._read_pending()
-        self._pending = (valid, G, bl, br)
+        self._pending = (valid, G)
 
     def finish(self):
         self._flush_tail()
@@ -1605,7 +1316,6 @@ class PatternStream(_StreamBatcher):
         self._parts: list[np.ndarray] = []
         self._pending = None
         self._acc = self._zero_acc()
-        self._acc_dirty = False
         self._in_acc = 0
         self._flush_every = max(
             min(_HIST_FLUSH_BATCHES, (1 << 30) // batch_size), 1
@@ -1613,17 +1323,8 @@ class PatternStream(_StreamBatcher):
         self._total_counts = np.zeros(program.n_patterns, np.int64)
 
     def _read_pending(self):
-        v, prev, pbl, pbr = self._pending
+        v, prev = self._pending
         arr = fetch(prev, via=self._ids_home)
-        if self.mesh is None and arr[-1]:
-            # two-phase overflow: the flagged batch skipped the histogram;
-            # redo through the exact twin (any acc generation works — the
-            # int64 total sums every generation, so addition commutes)
-            pid2, self._acc = self.program._pattern_batch_exact(
-                jnp.asarray(pbl), jnp.asarray(pbr), v, self._acc
-            )
-            arr = fetch(pid2)
-            self._acc_dirty = True  # a redo may land after the last flush
         self._parts.append(arr[:v].astype(self.id_dtype))
         self._pending = None
 
@@ -1636,7 +1337,7 @@ class PatternStream(_StreamBatcher):
             )
         if self._pending is not None:
             self._read_pending()
-        self._pending = (valid, pid, bl, br)
+        self._pending = (valid, pid)
         self._in_acc += 1
         if self._in_acc >= self._flush_every:
             self._total_counts += fetch(self._acc)[:-1]
@@ -1647,10 +1348,9 @@ class PatternStream(_StreamBatcher):
         self._flush_tail()
         if self._pending is not None:
             self._read_pending()
-        if self._in_acc or self._acc_dirty:
+        if self._in_acc:
             self._total_counts += fetch(self._acc)[:-1]
             self._in_acc = 0
-            self._acc_dirty = False
         pids = np.empty(self.total, self.id_dtype)
         parts = self._parts
         self._parts = []

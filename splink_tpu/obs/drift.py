@@ -145,7 +145,7 @@ def make_sketch_fn(layout: dict, comparison_columns, bins: int):
         k = top_rows.shape[1]
         rows_l = jnp.repeat(packed_q, k, axis=0)
         rows_r = packed_ref[top_rows.reshape(-1)]
-        ctx = PairContext(layout, rows_l, rows_r, None)
+        ctx = PairContext(layout, rows_l, rows_r)
         p = top_p.reshape(-1)
         valid = top_valid.reshape(-1)
         matched = valid & (p >= p.dtype.type(MATCH_PROBABILITY))

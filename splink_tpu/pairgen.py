@@ -88,9 +88,9 @@ CHUNK = 2048
 # shape is the wrong tool anyway; such inputs fall back to host blocking.
 MAX_UNITS_PER_GROUP = (1 << 20) - 1
 
-# Concurrent downloads in a virtual pass (a batch's pattern ids and row
-# pairs, or in the histogram-only pass its overflow flag alone): how many
-# batches may be in flight on the D2H thread pool before the driver blocks.
+# Concurrent downloads in a virtual pass that keeps ids (a batch's pattern
+# ids and row pairs): how many batches may be in flight on the D2H thread
+# pool before the driver blocks.
 # Bounded so pid buffers are not pinned on device without limit. The value 3
 # is not tuned: the cell ``c5_dedupe_stream`` (PERF.md §5) is where both
 # passes run several batches deep and reads what it costs (``d2h_wait``).
@@ -1121,7 +1121,7 @@ def decode_positions(plan: VirtualPlan, rule: int, q: np.ndarray,
 
 def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
                             has_uid_mask: bool, own_res=None,
-                            prev_res=(), mesh=None, two_phase=True):
+                            prev_res=(), mesh=None):
     """Jitted (pid, i, j, acc) kernel decoding + scoring one batch of virtual
     pair positions; ``i`` / ``j`` are the row pairs it decoded (int32, one per
     position), always there and downloaded only by a pass that wants the
@@ -1132,8 +1132,8 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
 
     The kernel is the PROCESS's, not the caller's: it comes from the
     kernel registry under the program's signature plus everything else it
-    closes over — ``n_prev``, ``has_uid_mask``, ``two_phase``, the mesh by
-    value and the residuals' signatures (compile_residual_device) — so a
+    closes over — ``n_prev``, ``has_uid_mask``, the mesh by value and the
+    residuals' signatures (compile_residual_device) — so a
     second linker on the same model gets the same jitted function and
     builds nothing. A residual that carries no signature cannot be keyed:
     that kernel is the program's own.
@@ -1148,33 +1148,29 @@ def make_virtual_pattern_fn(program, batch_size: int, n_prev: int,
     unit, the way the reference's Spark join distributed its shuffle
     partitions (/root/reference/splink/blocking.py:210)."""
     prev_res = tuple(prev_res)
-    # the exact twin of a program without two-phase IS its primary, and a
-    # mesh kernel is exact whatever it is asked
-    two_phase = bool(two_phase and mesh is None and program.two_phase_div)
     residuals = (own_res, *prev_res)
     signed = residual_signatures(residuals)
     variant = (
-        n_prev, bool(has_uid_mask), two_phase, mesh_key(mesh),
+        n_prev, bool(has_uid_mask), mesh_key(mesh),
         # unsigned: the closures themselves, for the program's own memo
         residuals if signed is None else signed,
     )
     return program._kernel(
         "virtual_pattern", variant,
         functools.partial(
-            _build_virtual_pattern_fn,
-            gamma_batch_fn=program._gamma_batch_fn, n_prev=n_prev,
+            _build_virtual_pattern_fn, n_prev=n_prev,
             has_uid_mask=has_uid_mask, own_res=own_res, prev_res=prev_res,
-            mesh=mesh, two_phase=two_phase,
+            mesh=mesh,
         ),
-        shareable=signed is not None, mesh=mesh, two_phase=two_phase,
+        shareable=signed is not None, mesh=mesh,
     )
 
 
-def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
-                              own_res, prev_res, mesh, two_phase):
+def _build_virtual_pattern_fn(parts, n_prev, has_uid_mask, own_res,
+                              prev_res, mesh):
     """The virtual pattern kernel from a gamma program's parts
-    (gammas._Parts) and its jitted body — nothing of a linker, a plan or a
-    table, which a registered kernel would pin."""
+    (gammas._Parts) — nothing of a linker, a plan or a table, which a
+    registered kernel would pin."""
     import jax
     import jax.numpy as jnp
 
@@ -1182,19 +1178,12 @@ def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
 
     n_patterns = parts.n_patterns
     strides_dev = jnp.asarray(parts.strides, jnp.int32)
-    # Mesh kernels and the overflow-redo twin compose the EXACT gamma body
-    # (two-phase survivor compaction does not partition along a sharded
-    # pair axis); the single-device primary composes the two-phase body.
-    # acc layout: [patterns 0..n_patterns-1, masked sentinel, overflow
-    # count] — an overflowed batch contributes nothing to the histogram
-    # and bumps the overflow slot instead; non-mesh kernels also append
-    # the flag to pid so the ids path can redo per batch.
+    # one gamma body, per shard under a mesh; acc layout: [patterns
+    # 0..n_patterns-1, masked sentinel]
     if mesh is not None:
         gamma_fn = _mesh_gamma_body(parts, mesh)
-    elif not two_phase and parts.two_phase_div:
-        gamma_fn = _make_gamma_body(parts, None)
     else:
-        gamma_fn = gamma_batch_fn
+        gamma_fn = _make_gamma_body(parts)
 
     jit_kwargs = {}
     if mesh is not None:
@@ -1207,9 +1196,9 @@ def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
             "out_shardings": (pairs, pairs, pairs, replicated(mesh)),
         }
 
-    # Named ``fn`` on purpose: the benchmark's gamma_hbm_roofline matches the
-    # XLA module ``jit_fn(``, and its files are not this code's to edit. This
-    # and gammas._jit_gamma_flagged are the only two programs of that name,
+    # Named ``fn`` on purpose: the benchmark's gamma metrics match the XLA
+    # module ``jit_fn(``, and its files are not this code's to edit. This
+    # and gammas._jit_gamma_batch are the only two programs of that name,
     # so ``jit_fn(`` means the gamma body and nothing else.
     @functools.partial(jax.jit, **jit_kwargs)
     def fn(pos, packed, order, ua, la, ub, lb, prev_codes, uid_codes,
@@ -1238,24 +1227,16 @@ def _build_virtual_pattern_fn(parts, gamma_batch_fn, n_prev, has_uid_mask,
                 holds = holds & v & ~unk
             masked = masked | holds
 
-        G, ovf = gamma_fn(packed, i, j)
-        G = G.astype(jnp.int32)
+        G = gamma_fn(packed, i, j).astype(jnp.int32)
         pid = jnp.sum(
             (G + 1) * strides_dev[None, :], axis=1, dtype=jnp.int32
         )
         pid = jnp.where(masked, n_patterns, pid)
-        ovf_flag = (ovf > 0).astype(jnp.int32)
-        hist = int32_histogram(pid, n_patterns + 1)
-        acc = acc.at[: n_patterns + 1].add(hist * (1 - ovf_flag))
-        acc = acc.at[n_patterns + 1].add(ovf_flag)
+        acc = acc + int32_histogram(pid, n_patterns + 1)
         if pattern_ids_fit_uint16(n_patterns):
             # narrow ON DEVICE: every value (sentinel included) fits
             # uint16 — half the D2H bytes of the int32 it was computed in
             pid = pid.astype(jnp.uint16)
-        if mesh is None:
-            # overflow flag rides as pid[-1] (a B+1 output cannot shard
-            # evenly, and mesh kernels are exact anyway)
-            pid = jnp.concatenate([pid, ovf_flag.astype(pid.dtype)[None]])
         # the row pairs the ids were computed from go out beside them, so
         # that no caller decodes the positions a second time
         return pid, i, j, acc
@@ -1299,17 +1280,13 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     is the ``d2h_wait`` span, 2.18 s of a ``c4_dedupe_virtual`` job; under
     a mesh ``mesh_put`` + ``mesh_gather`` add 0.60 s a job: ledger PR 30).
     The three are None when ``want_ids`` is
-    False — then NO per-pair bytes cross the link at all: what comes home is
-    the int32 histogram accumulator every ~2^10 batches and, where the
-    kernel prunes (two-phase Jaro-Winkler on one device), each batch's
-    overflow flag, one element on the same pool.
+    False — then NOTHING of a batch crosses the link: what comes home is the
+    int32 histogram accumulator every ~2^10 batches and at the end
+    (``flush_acc``, where the driver waits for the pass's kernels).
 
-    A batch that overflowed the two-phase survivor capacity added nothing to
-    the histogram; it is redone ALONE through the exact twin when its flag is
-    home (``settle``), whether ids are wanted or not. The innermost open
-    stage gets the pass's counts: ``overflow_batches``, ``redo_positions``
-    (the flagged batches' positions), ``overflow_rule_<r>`` (flagged batches
-    of rule r) and ``hist_flushes``.
+    The innermost open stage gets the pass's counts: ``hist_flushes``, and
+    ``redo_positions``, the constant 0 (no batch is ever run twice; the
+    benchmark's ``stream_redo_positions`` reads it).
 
     The histogram accumulates into ``counts_out`` (int64, n_patterns); the
     caller owns the array. Host work per batch is O(units-in-batch): a
@@ -1374,59 +1351,30 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     # per-bucket iota cache: rules sharing a rule_bs bucket share one array
     pos_cache: dict = {}
     flush_every = max(min(_HIST_FLUSH_BATCHES, (1 << 30) // batch_size), 1)
-    # acc carries [histogram, masked sentinel, two-phase overflow count]
-    acc = put(np.zeros(n_patterns + 2, np.int32))
+    # acc carries [histogram, masked sentinel]
+    acc = put(np.zeros(n_patterns + 1, np.int32))
     in_acc = 0
-    # only the pruned body can overflow: a mesh kernel and a program without
-    # two-phase Jaro-Winkler are exact and carry no flag worth a download
-    flagged = mesh is None and bool(program.two_phase_div)
-    count(overflow_batches=0, redo_positions=0, hist_flushes=0)
+    count(redo_positions=0, hist_flushes=0)
 
     def flush_acc(acc_dev):
         counts[:] += fetch(acc_dev)[:n_patterns]
         count(hist_flushes=1)
 
     def download_batch(outs):
-        """One batch home, on a pool thread: its pattern ids and row pairs,
-        or, from a pass that wants no ids, the overflow flag alone (sliced
-        HERE: an eager slice blocks the thread that dispatches it)."""
-        if not want_ids:
-            return (np.asarray(outs[0][-1:]),)
+        """One batch's pattern ids and row pairs home, on a pool thread."""
         return tuple(download(x) for x in outs)
 
     def settle(entry):
         """What the pass yields for the oldest batch in flight, once its
-        download is home: the driver thread's D2H wait, and the batch's
-        redo where it overflowed."""
-        nonlocal acc
-        pr, pp0, ps, n_valid, fut, rd = entry
+        download is home: the driver thread's D2H wait."""
+        pr, pp0, ps, n_valid, fut = entry
         with span("d2h_wait") as sp:
-            pid_h, *pair = fut.result()
-            sp.count(bytes=pid_h.nbytes + sum(a.nbytes for a in pair))
-        if rd is not None and pid_h[-1]:
-            # two-phase overflow: the flagged batch skipped the histogram;
-            # redo through the exact twin (acc addition commutes, late redo
-            # identical). The row pairs are the decode's, which both
-            # kernels share: the ones already home stand.
-            efn, e_pos, e_ord, e_units, e_meta = rd
-            pid2, _, _, acc = efn()(
-                e_pos, packed, e_ord, *e_units, codes_dev,
-                uid_dev, res_ops_dev, e_meta, acc,
-            )
-            count(overflow_batches=1, redo_positions=n_valid,
-                  **{f"overflow_rule_{pr}": 1})
-            if want_ids:
-                pid_h = fetch(pid2)
-        if not want_ids:
-            return pr, pp0, ps, n_valid, None, None, None
-        il, ir = pair
-        return pr, pp0, ps, n_valid, pid_h[:n_valid], il[:n_valid], ir[:n_valid]
+            home = fut.result()
+            sp.count(bytes=sum(a.nbytes for a in home))
+        return (pr, pp0, ps, n_valid, *(a[:n_valid] for a in home))
 
-    pool = (
-        ThreadPoolExecutor(max_workers=_D2H_DEPTH)
-        if want_ids or flagged else None
-    )
-    # (rule, rule_p0, out_pos, n_valid, future, redo arguments)
+    pool = ThreadPoolExecutor(max_workers=_D2H_DEPTH) if want_ids else None
+    # (rule, rule_p0, out_pos, n_valid, future)
     inflight: deque = deque()
     try:
         packed = program._packed
@@ -1472,26 +1420,14 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
             # the program hands out the process's kernel for this rule
             # (make_virtual_pattern_fn): the same jitted function in every
             # pass and every linker on this model
-            res = {
-                "has_uid_mask": plan.uid_codes is not None,
-                "own_res": rp.residual_fn,
-                "prev_res": tuple(p.residual_fn for p in plan.rules[:r]),
-            }
             fn = make_virtual_pattern_fn(
-                program, rule_bs, n_prev=r, mesh=mesh, **res
+                program, rule_bs, n_prev=r,
+                has_uid_mask=plan.uid_codes is not None,
+                own_res=rp.residual_fn,
+                prev_res=tuple(p.residual_fn for p in plan.rules[:r]),
+                mesh=mesh,
             )
             kkey = (rule_bs, mesh_key(mesh))
-            if flagged:
-                count(**{f"overflow_rule_{r}": 0})
-
-            def exact_fn(r=r, rule_bs=rule_bs, res=res):
-                """The rule's exact-twin kernel for overflow redos, built
-                on first use (it only ever compiles if a batch overflows
-                the two-phase survivor capacity)."""
-                return make_virtual_pattern_fn(
-                    program, rule_bs, n_prev=r, mesh=None, two_phase=False,
-                    **res,
-                )
             # One metadata row per batch (_unit_batch_meta), uploaded per
             # batch with device_put — uploads are ASYNC, where an EAGER
             # device-side op like meta_dev[b] is a blocking dispatch;
@@ -1505,21 +1441,16 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                 if kkey not in rp.kernels_run:
                     rp.kernels_run[kkey] = (fn, _abstract_args(args))
                 pid, i, j, acc = fn(*args)
-                if pool is not None:
-                    redo_args = (
-                        exact_fn, pos_rule, order_dev, units_dev, meta_dev,
-                    ) if flagged else None
-                    # without ids the row pairs are let go with the call
-                    home = (pid, i, j) if want_ids else (pid,)
+                if want_ids:
                     inflight.append(
                         (r, p0, out_pos, p1 - p0,
-                         pool.submit(download_batch, home), redo_args)
+                         pool.submit(download_batch, (pid, i, j)))
                     )
                     while len(inflight) > _D2H_DEPTH:
                         yield settle(inflight.popleft())
                 else:
-                    # exact kernels and no ids wanted: nothing of the batch
-                    # comes home
+                    # nothing of the batch comes home: its ids and row
+                    # pairs are let go with the call
                     yield r, p0, out_pos, p1 - p0, None, None, None
                 out_pos += p1 - p0
                 in_acc += 1
@@ -1528,13 +1459,12 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                     # reset through put(): a plain jnp.zeros would drop the
                     # replicated sharding under a mesh and force a reshard /
                     # second executable on the next batch
-                    acc = put(np.zeros(n_patterns + 2, np.int32))
+                    acc = put(np.zeros(n_patterns + 1, np.int32))
                     in_acc = 0
         while inflight:
             yield settle(inflight.popleft())
-        # unconditional: an overflow redo during the tail drain can land
-        # in acc after the last scheduled flush
-        flush_acc(acc)
+        if in_acc:
+            flush_acc(acc)
     finally:
         # consumer may abandon the generator mid-stream (exception in
         # a scoring chunk): do not leak pool threads or pinned buffers
@@ -1564,10 +1494,9 @@ def compute_virtual_pattern_ids(program, plan: VirtualPlan,
 
     With ``return_ids=False`` the pass computes ONLY the histogram — ids
     comes back None and no per-pair bytes ever cross the host<->device
-    link (a batch's two-phase overflow flag does, and the flagged batch is
-    redone alone, as when ids are kept). This is the EM-path mode: EM needs
-    nothing but counts (what the per-batch download costs is the
-    ``d2h_wait`` / ``mesh_gather`` spans of a ``chipbench`` run). The
+    link. This is the EM-path mode: EM needs nothing but counts (what the
+    per-batch download costs is the ``d2h_wait`` / ``mesh_gather`` spans
+    of a ``chipbench`` run). The
     score-output stream recomputes ids and pairs chunk-wise later via
     ``_virtual_pass_iter`` (the kernels are the process's, so the second
     pass pays no compile).

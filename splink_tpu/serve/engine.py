@@ -236,7 +236,7 @@ def make_score_topk_fn(layout: dict, comparison_columns, k: int,
         rows_l = jnp.repeat(packed_q, capacity, axis=0)
         rflat = cand.reshape(-1)
         rows_r = packed_ref[rflat]
-        ctx = PairContext(layout, rows_l, rows_r, None)
+        ctx = PairContext(layout, rows_l, rows_r)
         G = jnp.stack([_spec_gamma(c, ctx) for c in cols], axis=1)
         if not tf_spec:
             p = match_probability(G, params)
@@ -317,7 +317,7 @@ def make_score_fused_fn(layout: dict, comparison_columns, k: int,
         rows_l = jnp.repeat(packed_q, capacity, axis=0)
         rflat = cand.reshape(-1)
         rows_r = packed_ref[rflat]
-        ctx = PairContext(layout, rows_l, rows_r, None)
+        ctx = PairContext(layout, rows_l, rows_r)
         log_m = _safe_log(params.m)  # (C, L)
         log_u = _safe_log(params.u)
         n_levels = log_m.shape[1]

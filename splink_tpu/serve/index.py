@@ -611,7 +611,6 @@ class LinkageIndex:
             include=comparison_columns_used(settings),
             qgram_specs=qgram_specs_for(settings),
             charset_specs=charset_specs_for(settings),
-            jw_specs=(),
         )
         if packed_q.shape[1] != self.n_lanes:
             raise ServeIndexError(
@@ -1102,7 +1101,6 @@ def _pack_table_out_of_core(
         include=include,
         qgram_specs=qgram_specs,
         charset_specs=charset_specs,
-        jw_specs=(),
     )
     n_lanes = probe.shape[1]
     data_path = os.path.join(out_dir, "packed.bin")
@@ -1163,7 +1161,6 @@ def _pack_table_out_of_core(
                 include=include,
                 qgram_specs=qgram_specs,
                 charset_specs=charset_specs,
-                jw_specs=(),
             )
             if arr.shape[1] != n_lanes:  # pragma: no cover - layout is static
                 raise ServeIndexError(
@@ -1249,7 +1246,6 @@ def build_index(linker, *, clear_caches: bool = True) -> LinkageIndex:
                 include=include,
                 qgram_specs=qgram_specs_for(settings),
                 charset_specs=charset_specs_for(settings),
-                jw_specs=(),
             )
         string_cols = [
             n for n in table.strings if include is None or n in include
@@ -1545,7 +1541,6 @@ def _attach_rebuilt_layout(index: LinkageIndex) -> LinkageIndex:
         include=comparison_columns_used(settings),
         qgram_specs=qgram_specs_for(settings),
         charset_specs=charset_specs_for(settings),
-        jw_specs=(),
     )
     if probe.shape[1] != index.n_lanes:
         raise IndexMismatchError(

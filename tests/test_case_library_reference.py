@@ -11,8 +11,8 @@ states. The reference's two new kinds are held to scalar definitions written
 out longhand, ties and null guards included; the tie rule (a level is accepted
 where it is reachable by settling each tied comparison either way) and the
 span counts that say which kernel forms a gamma program is made of
-(``string_evals``, ``two_phase``, ``levenshtein_columns``,
-``name_inversion_columns``) are held here too.
+(``string_evals``, ``levenshtein_columns``, ``name_inversion_columns``) are
+held here too.
 """
 
 import copy
@@ -105,7 +105,7 @@ def test_facade_job_equals_the_reference(config, population):
     counts = pattern_stage(linker)
     positions = linker._virtual.n_candidates
     assert counts["string_evals"] == positions * 7  # 2 + 2 Jaro-Winkler, 3 Levenshtein
-    assert counts["two_phase"] == 0  # no prunable column: the exact body
+    assert "two_phase" not in counts  # there is one body, and no count of another
     assert counts["levenshtein_columns"] == 3 and counts["name_inversion_columns"] == 2
     # and the reader of span counts finds it: the mean over the window's jobs
     run = {"jobs": [{}], "failed": 0}
@@ -128,13 +128,13 @@ def test_bfloat16_control_fails(config):
     assert {n for n, v, lim in rows if lim is not None and v > lim} >= {"gamma_wrong"}
 
 
-def test_baseline_c4_reports_the_pruned_body_and_no_new_kind():
+def test_baseline_c4_reports_four_string_evaluations_and_no_new_kind():
     config = load("baseline_c4")
     linker, _ = job(small(config), people_of(config, 25))
     counts = pattern_stage(linker)
     # first_name, surname, postcode Jaro-Winkler and the bigram Jaccard
     assert counts["string_evals"] == linker._virtual.n_candidates * 4
-    assert counts["two_phase"] == 1
+    assert "two_phase" not in counts and counts["redo_positions"] == 0
     assert counts["levenshtein_columns"] == 0 and counts["name_inversion_columns"] == 0
 
 
@@ -145,7 +145,7 @@ def test_materialised_pass_counts_its_kernels_on_the_gammas_stage(config):
              if s["name"] in ("gammas", "gammas_patterns") and "string_evals" in s["counts"]]
     assert len(stage) == 1
     assert stage[0]["counts"]["string_evals"] == len(frame) * 7
-    assert stage[0]["counts"]["two_phase"] == 0
+    assert "two_phase" not in stage[0]["counts"]
 
 
 # --------------------------------------------------------------------------

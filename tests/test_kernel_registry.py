@@ -139,16 +139,12 @@ def _float64(s, df):
     s["float64"] = True
 
 
-def _two_phase_off(s, df):
-    s["two_phase_jw"] = "off"
-
-
 def _added_rule(s, df):
     s["blocking_rules"].append("l.first_name = r.first_name")
 
 
 _CHANGES = [_jw_threshold, _num_levels, _dropped_column, _wider_string,
-            _float64, _two_phase_off, _added_rule]
+            _float64, _added_rule]
 
 
 @pytest.mark.parametrize("change", _CHANGES, ids=lambda f: f.__name__[1:])
@@ -417,7 +413,7 @@ def test_equal_meshes_share_an_entry_and_other_devices_do_not():
     assert other[0] is not first[0] and other[1] is not first[1]
     assert kernel_registry.mesh_key(None) is None
     # key = (fun, program signature, variant); the mesh sits in the variant
-    at = {"virtual_pattern": 3, "pattern_batch_mesh": 0}
+    at = {"virtual_pattern": 2, "pattern_batch_mesh": 0}
     assert {k[2][at[k[0]]] for k in kernel_registry.keys() if k[0] in at} == {
         ((devices[0].id, devices[1].id), (2,), (DATA_AXIS,)),
         ((devices[2].id, devices[3].id), (2,), (DATA_AXIS,)),

@@ -118,8 +118,8 @@ def test_mesh_job_equals_the_reference(config, people, references, n, over):
     candidates = linker._virtual.n_candidates
     assert stage[0]["counts"]["devices"] == n
     assert stage[0]["counts"]["pairs_per_device"] == -(-candidates // n)
-    # the settings have prunable columns, but a mesh kernel is handed the exact body
-    assert stage[0]["counts"]["two_phase"] == 0
+    # one body on one chip and four: the stage has no count of a pruned one
+    assert "two_phase" not in stage[0]["counts"]
     assert stage[0]["counts"]["string_evals"] == candidates * 4
     puts = [s for s in table if s["name"] == "mesh_put"]
     gathers = [s for s in table if s["name"] == "mesh_gather"]
@@ -164,7 +164,8 @@ def test_job_without_a_mesh_closes_no_mesh_span_and_scores_the_same(config, peop
     assert not [s for s in table if s["name"] in ("mesh_put", "mesh_gather")]
     stage = [s for s in table if s["name"] == "gammas_patterns"][0]
     assert "devices" not in stage["counts"] and "pairs_per_device" not in stage["counts"]
-    assert stage["counts"]["two_phase"] == 1  # one chip: the pruned body
+    assert "two_phase" not in stage["counts"]  # one chip runs the mesh's body
+    assert stage["counts"]["redo_positions"] == 0
     assert {s["counts"]["devices"] for s in table if s["name"] == "kernel_lookup"} == {1}
     # the same pairs, levels and scores whatever the number of chips
     _, frame_4 = job(small(config, 4), people)

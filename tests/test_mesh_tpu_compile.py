@@ -93,8 +93,8 @@ def compile_rule(program, plan, mesh, sharding_of, monkeypatch):
     rule_bs = min(BATCH, 1 << max((rp.total - 1).bit_length(), 6))
     meta = pairgen._unit_batch_meta(rp.pc, rp.total, rule_bs)[0][2]
     fn = pairgen._build_virtual_pattern_fn(
-        program._parts, program._gamma_batch_fn, n_prev=0, has_uid_mask=plan.uid_codes is not None,
-        own_res=rp.residual_fn, prev_res=(), mesh=mesh, two_phase=False)
+        program._parts, n_prev=0, has_uid_mask=plan.uid_codes is not None,
+        own_res=rp.residual_fn, prev_res=(), mesh=mesh)
 
     def shape(a, sharding):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
@@ -105,7 +105,7 @@ def compile_rule(program, plan, mesh, sharding_of, monkeypatch):
             *(shape(a, repl) for a in (rp.ua, rp.la, rp.ub, rp.lb)),
             shape(plan.codes, repl), shape(uid, repl),
             tuple(shape(a, repl) for a in plan.res_ops), shape(meta, repl),
-            jax.ShapeDtypeStruct((program.n_patterns + 2,), jnp.int32, sharding=repl))
+            jax.ShapeDtypeStruct((program.n_patterns + 1,), jnp.int32, sharding=repl))
     # the suite runs with x64 on (conftest) and Mosaic takes no int64 index:
     # the program's own processes run without it, as the chip does
     with jax.enable_x64(False):
@@ -135,16 +135,21 @@ def test_sharded_pattern_kernel_compiles_for_four_chips(topo, uncached, job, mon
     single_temp = single.memory_analysis().temp_size_in_bytes
     # each chip holds a quarter of the batch's scratch (and a whole table)
     assert sharded_temp < 0.35 * single_temp, (sharded_temp, single_temp)
+    # one chip runs the body the mesh runs: the same Mosaic calls (the three
+    # Jaro-Winkler columns), each fed the whole batch, from a 30-word row
+    single_calls = [ln for ln in single.as_text().splitlines()
+                    if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(single_calls) == len(calls) == 3, (len(single_calls), len(calls))
+    assert program._packed.shape[1] == 30
+    assert f"u32[{program._packed.shape[0]},30]" in single.as_text()
 
 
 def test_case_library_kernel_compiles_for_one_chip(topo, uncached, monkeypatch):
     """The cell ``c4lib_dedupe_virtual``'s program (configuration
-    ``c4_case_library``): no prunable Jaro-Winkler column, so the one-chip
-    kernel is the EXACT body — four Jaro-Winkler evaluations (two name
-    inversions, self and cross pair, the cross pair padded to a common width)
-    and three Levenshtein ones, each a Mosaic call fed the whole batch."""
+    ``c4_case_library``): four Jaro-Winkler evaluations (two name inversions,
+    self and cross pair, the cross pair padded to a common width) and three
+    Levenshtein ones, each a Mosaic call fed the whole batch."""
     program, plan = small_job("c4_case_library")
-    assert program.two_phase_div is None
     one = SingleDeviceSharding(topo.devices[0])
     rule_bs, compiled = compile_rule(program, plan, None, (one, one), monkeypatch)
     assert rule_bs == BATCH

@@ -398,11 +398,14 @@ def test_counts_at_the_stage_boundaries(job):
         assert sum(s["counts"]["rows"] for s in by_name["assemble_frame"]) == pairs
     else:
         assert by_name["blocking"][0]["counts"] == {"pairs": pairs}
-        # two default (Jaro-Winkler) columns: the pruned body, one evaluation each
+        # two default (Jaro-Winkler) columns, one evaluation each; the stage
+        # has no count of a pruned body, and pack_table says the row's words
         assert by_name["gammas"][0]["counts"] == {
-            "pairs": pairs, "batches": 1, "string_evals": 2 * pairs, "two_phase": 1,
+            "pairs": pairs, "batches": 1, "string_evals": 2 * pairs,
             "levenshtein_columns": 0, "name_inversion_columns": 0,
         }
+        [pack] = by_name["pack_table"]
+        assert pack["counts"]["rows"] > 0 and pack["counts"]["lanes"] >= 2 * (2 + 2)
         assert by_name["score"][0]["counts"] == {"pairs": pairs, "batches": 1}
         [frame] = by_name["assemble_frame"]
         assert frame["counts"]["rows"] == pairs
@@ -527,7 +530,7 @@ def test_build_spans_under_the_first_linker_of_a_key_and_none_after():
                for s in under["jax_backend_compile"])
     assert all(s["t1"] - s["t0"] >= 1e-3 for s in under.get("jax_trace", ()))
     lookups = [s["counts"] for s in under["kernel_lookup"]]
-    assert {c["fun"] for c in lookups} == {"gamma_body", "virtual_pattern"}
+    assert {c["fun"] for c in lookups} == {"virtual_pattern"}
     assert all(c == {"fun": c["fun"], "hit": 0, "shared": 1, "devices": 1} for c in lookups)
     # second pass over the same linker: the program holds its kernels
     before = len(table)
@@ -639,7 +642,7 @@ def test_jitted_programs_have_names_of_their_own():
 
     from splink_tpu import blocking_device, em, term_frequencies
     from splink_tpu.data import encode_table
-    from splink_tpu.gammas import GammaProgram, _pattern_counts_batch
+    from splink_tpu.gammas import GammaProgram, _jit_pattern_batch, _pattern_counts_batch
     from splink_tpu.pairgen import make_virtual_pattern_fn
     from splink_tpu.settings import complete_settings_dict
 
@@ -652,10 +655,9 @@ def test_jitted_programs_have_names_of_their_own():
                            float_dtype=jnp.float32)
     gamma = [
         make_virtual_pattern_fn(program, 64, n_prev=0, has_uid_mask=False),
-        program._gamma_flagged_fn(),
-        program._gamma_flagged_fn(exact=True),
+        program._gamma_batch_fn,
     ]
-    assert [f.__name__ for f in gamma] == ["fn", "fn", "fn"]
+    assert [f.__name__ for f in gamma] == ["fn", "fn"]
     others = [
         blocking_device.make_segment_sort_fn(),
         blocking_device.make_bucket_csr_fn(),
@@ -665,7 +667,7 @@ def test_jitted_programs_have_names_of_their_own():
         term_frequencies._device_token_stats_fn(8),
         term_frequencies._device_token_gather_fn(8),
         term_frequencies.make_tf_fold_fn(((0, "first_name", 1),)),
-        program._gamma_batch_fn,
+        program._kernel("pattern_batch", (), _jit_pattern_batch),
         _pattern_counts_batch,
         em.run_em,
         em.score_pairs,

@@ -6,13 +6,13 @@ on a histogram-only pass, the scores come from a second device pass and the
 output leaves as chunks) at twelve thousand rows on the CPU: the streamed job
 has to give the pair set, every gamma level, λ/m/u and every score of
 ``chipbench.reference`` within the limits the configuration's file states,
-through ``chipbench.correct_stream``, at a batch that gives every rule several
-batches — with no batch, some batches and every batch overflowing the
-two-phase survivor capacity, where a flagged batch is redone ALONE in both
-passes. Held beside it: the chunks put end to end are the one-frame job's
+through ``chipbench.correct_stream``, at batches that give every rule several
+batches — on the people as they are, and with a prefix shared by every value
+of one and of all three Jaro-Winkler columns (the data a prefilter once could
+not prune: every pair close to the thresholds). Every batch runs once in each
+pass. Held beside it: the chunks put end to end are the one-frame job's
 frame; the spans and counts the deployment adds (the histogram-only pass
-under overflow, exact and run once: ``tests/test_virtual_pairs.py``, beside
-its ids-keeping twin).
+against its ids-keeping twin: ``tests/test_virtual_pairs.py``).
 """
 
 import copy
@@ -35,15 +35,14 @@ from splink_tpu import Splink  # noqa: E402
 from splink_tpu.utils.profiling import StageTimer, spans, stage_timings  # noqa: E402
 
 ROWS = 12000
-# a shared prefix makes every unequal pair of a Jaro-Winkler column a
-# survivor (tests/test_jw_two_phase.py), and a divisor this large drops the
-# capacity to its floor of 1024, which a batch of 2048 survivors overflows
-OVERFLOW = {"pair_batch_size": 2048, "jw_survivor_divisor": 10**6}
+# a shared prefix puts every unequal pair of a Jaro-Winkler column close to
+# the thresholds: the same data and batch sizes that once overflowed a
+# prefilter's capacity (no batch, the dob rule's batches, every batch)
 CASES = {
     # id: (columns that get the shared prefix, settings changed)
-    "no_batch_overflows": ((), {"pair_batch_size": 1024}),
-    "dob_rule_overflows": (("surname",), OVERFLOW),
-    "every_batch_overflows": (("first_name", "surname", "postcode"), OVERFLOW),
+    "people_as_they_are": ((), {"pair_batch_size": 1024}),
+    "surname_prefixed": (("surname",), {"pair_batch_size": 2048}),
+    "every_name_prefixed": (("first_name", "surname", "postcode"), {"pair_batch_size": 2048}),
 }
 
 
@@ -104,7 +103,7 @@ def test_streamed_job_equals_the_reference(config, jobs, case):
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_flagged_batches_are_redone_alone_in_both_passes(jobs, case):
+def test_every_batch_runs_once_in_both_passes(jobs, case):
     job = jobs(case)
     linker, plan = job["linker"], job["linker"]._virtual
     batch = job["settings"]["pair_batch_size"]
@@ -112,20 +111,25 @@ def test_flagged_batches_are_redone_alone_in_both_passes(jobs, case):
     assert min(per_rule) >= 2  # every rule runs several batches
     for name in ("gammas_patterns", "score_patterns"):
         counts = stage_counts(linker, name)
-        flagged = [counts[f"overflow_rule_{r}"] for r in range(len(per_rule))]
         assert counts["batches"] == sum(per_rule)  # no batch of a second pass
-        assert counts["overflow_batches"] == sum(flagged)
         assert counts["ids_kept"] == 0
-        if case == "no_batch_overflows":
-            assert flagged == [0, 0, 0] and counts["redo_positions"] == 0
-        elif case == "dob_rule_overflows":
-            assert flagged[0] == per_rule[0] and flagged[2] == 0
-            assert plan.rules[0].total <= counts["redo_positions"] < plan.n_candidates
-        else:
-            assert flagged == per_rule
-            assert counts["redo_positions"] == plan.n_candidates
+        # present and 0 on both virtual stages (chipbench's
+        # stream_redo_positions reads it); nothing counts an overflow
+        assert counts["redo_positions"] == 0
+        assert not [k for k in counts if k.startswith("overflow") or k == "two_phase"]
     assert stage_counts(linker, "score_patterns")["recomputed_positions"] == plan.n_candidates
     assert stage_counts(linker, "gammas_patterns")["hist_flushes"] == 1
+    # the histogram-only pass waited once, for the accumulator, and the
+    # second pass once a batch, for ids and row pairs (10 B a position)
+    table = spans(run=linker.run_id)
+    stage = {s["name"]: s["id"] for s in table if s["kind"] == "stage"}
+    waits = {n: [s["counts"]["bytes"] for s in table
+                 if s["name"] == "d2h_wait" and s["parent"] == stage[n]]
+             for n in ("gammas_patterns", "score_patterns")}
+    program = linker._ensure_pattern_program()
+    acc_bytes = 4 * (program.n_patterns + 1)
+    assert waits["gammas_patterns"] == [acc_bytes]
+    assert sorted(waits["score_patterns"]) == [acc_bytes] + [10 * batch] * sum(per_rule)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -145,7 +149,7 @@ def test_chunks_end_to_end_are_the_one_frame_jobs_frame(jobs, case):
 
 
 def test_the_bfloat16_control_fails(config, jobs):
-    job = jobs("no_batch_overflows")
+    job = jobs("people_as_they_are")
     frames = {"df": job["df"]}
     control = reference.run(job["settings"], frames, precision="bfloat16")
     got = correct_stream.compare(correct_stream.stand_in(control), job["prep"])
@@ -169,7 +173,7 @@ def test_the_bfloat16_control_fails(config, jobs):
     (lambda c: c[:4] + [c[0].iloc[:0]] + c[4:], "chunks_empty"),
 ], ids=["chunk_dropped", "chunk_twice", "chunk_too_long", "chunk_column_cast", "chunk_empty"])
 def test_a_fault_in_the_chunks_is_caught_by_its_own_number(config, jobs, fault, number):
-    job = jobs("no_batch_overflows")
+    job = jobs("people_as_they_are")
     assert len(job["chunks"][0]) + len(job["chunks"][1]) > 1024
     got = numbers({**job, "chunks": fault(list(job["chunks"]))})
     ok, rows = correct_stream.verdict(got, config["limits"])
@@ -190,7 +194,7 @@ def test_no_whole_pass_rerun_is_left_in_the_program():
 
 
 def test_call_span_counts_chunks_pairs_and_the_consumers_time(jobs):
-    job = jobs("no_batch_overflows")
+    job = jobs("people_as_they_are")
     linker = Splink(copy.deepcopy(job["settings"]), df=job["df"])
     nap, taken, inside = 0.02, 0, []
     for chunk in linker.stream_scored_comparisons():
@@ -220,7 +224,7 @@ def test_call_span_counts_chunks_pairs_and_the_consumers_time(jobs):
 
 
 def test_a_stream_closed_after_its_first_chunk_closes_its_call_span(jobs):
-    job = jobs("no_batch_overflows")
+    job = jobs("people_as_they_are")
     linker = Splink({**copy.deepcopy(job["settings"]), "virtual_materialise_ids": "on"},
                     df=job["df"])
     stream = linker.stream_scored_comparisons()

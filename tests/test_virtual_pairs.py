@@ -2,8 +2,8 @@
 back the row pairs it computed its ids from, they travel home with the ids,
 and the score stream takes them. Held here: the kernel's pairs equal the host
 oracle's (``pairgen.decode_positions``) position for position, for every kind
-of plan, with and without a mesh and through the two-phase overflow redo; the
-scored frame equals one assembled from the oracle's pairs, row order
+of plan, with and without a mesh, and the one-chip and the mesh kernels give
+the same ids and histogram; the scored frame equals one assembled from the oracle's pairs, row order
 included, in the stored and the recompute stream; and a pass nobody wants ids
 from downloads nothing per pair.
 """
@@ -155,16 +155,20 @@ def test_kernel_pairs_equal_the_host_oracle(kind, devices):
         base += rp.total
 
 
-def _jw_overflow_job():
-    """A plan whose every batch overflows the two-phase survivor capacity
-    (tests/test_jw_two_phase.py: a shared prefix makes every pair a survivor,
-    the divisor drops the capacity to its floor of 1024)."""
+def _jw_job():
+    """A plan of Jaro-Winkler pairs of every kind: shared prefixes (close to
+    and across the thresholds), token-equal names, unlike names, nulls."""
     n = 400
     rng = np.random.default_rng(5)
+    names = np.array([f"prefix{i:04d}" for i in range(n)], dtype=object)
+    base = np.array(["amelia", "amelie", "oliver", "olivia", "georgia", "", None],
+                    dtype=object)
+    some = rng.random(n) < 0.4
+    names[some] = base[rng.integers(0, len(base), int(some.sum()))]
     df = pd.DataFrame(
         {
             "unique_id": np.arange(n),
-            "name": np.array([f"prefix{i:04d}" for i in range(n)], dtype=object),
+            "name": names,
             "city": np.array(["x", "y"], dtype=object)[rng.integers(0, 2, n)],
         }
     )
@@ -174,51 +178,66 @@ def _jw_overflow_job():
             "col_name": "name", "num_levels": 3,
             "comparison": {"kind": "jaro_winkler", "thresholds": [0.94, 0.88]},
         }],
-        jw_survivor_divisor=10**6,
     )
     table = encode_table(df, s)
     return s, table, build_virtual_plan(s, table, chunk=64)
 
 
-def test_kernel_pairs_through_the_two_phase_overflow_redo():
-    s, table, plan = _jw_overflow_job()
+@pytest.mark.parametrize("devices", [2, 4])
+def test_one_chip_and_mesh_kernels_give_the_same_ids_and_histogram(devices):
+    s, table, plan = _jw_job()
     program = GammaProgram(s, table)
-    assert program.two_phase_div and plan.n_candidates > 3 * 4096
+    assert plan.n_candidates > 3 * 4096
     with StageTimer("gammas_patterns") as stage:
         batches, real = _assert_pass_equals_oracle(program, plan, 4096)
-    # every batch of 4096 survivors blew the capacity of 1024 and was redone
-    # through the exact twin: its pairs are the first download's, its ids and
-    # its share of the histogram the redo's
-    assert stage.counts["overflow_batches"] == batches >= 3
-    assert stage.counts["overflow_rule_0"] == batches
-    assert stage.counts["redo_positions"] == plan.n_candidates
-    exact = GammaProgram(dict(s, two_phase_jw="off"), table)
+    assert batches >= 3
+    # no batch is ever run twice, and nothing counts an overflow any more
+    assert stage.counts["redo_positions"] == 0
+    assert not [k for k in stage.counts if k.startswith("overflow") or k == "two_phase"]
     ids, counts, _ = compute_virtual_pattern_ids(program, plan, 4096)
-    want, want_counts, _ = compute_virtual_pattern_ids(exact, plan, 4096)
+    with StageTimer("gammas_patterns") as sharded:
+        want, want_counts, _ = compute_virtual_pattern_ids(
+            program, plan, 4096, mesh=make_mesh(devices))
+    assert sharded.counts["redo_positions"] == 0
     for got, oracle in zip(ids, want):
         np.testing.assert_array_equal(got, oracle)
     np.testing.assert_array_equal(counts, want_counts)
     assert counts.sum() == real
+    assert len(np.unique(ids.pid)) == 4  # null and the three levels
 
 
-def test_histogram_only_pass_under_overflow_is_exact_and_runs_once():
-    s, table, plan = _jw_overflow_job()
+def test_histogram_only_pass_equals_the_ids_pass_and_runs_each_batch_once():
+    s, table, plan = _jw_job()
     program = GammaProgram(s, table)
-    assert program.two_phase_div and plan.n_candidates > 3 * 4096
     with StageTimer("gammas_patterns") as stage:
         ids, counts, n_real = compute_virtual_pattern_ids(
             program, plan, 4096, return_ids=False)
     batches = -(-plan.n_candidates // 4096)
     assert ids is None and n_real == counts.sum() > 0
-    assert stage.counts["batches"] == batches  # once a batch: no second pass
-    assert stage.counts["overflow_batches"] == stage.counts["overflow_rule_0"] == batches
-    assert stage.counts["redo_positions"] == plan.n_candidates
-    exact = GammaProgram(dict(s, two_phase_jw="off"), table)
-    _, want, _ = compute_virtual_pattern_ids(exact, plan, 4096, return_ids=False)
-    np.testing.assert_array_equal(counts, want)
+    assert stage.counts["batches"] == batches >= 3  # once a batch
+    assert stage.counts["redo_positions"] == 0 and stage.counts["hist_flushes"] == 1
     kept, with_ids, _ = compute_virtual_pattern_ids(program, plan, 4096)
     np.testing.assert_array_equal(counts, with_ids)
     assert n_real == int((kept.pid != program.n_patterns).sum())
+
+
+@pytest.mark.parametrize("devices", [None, 4], ids=["one_device", "mesh_of_4"])
+def test_histogram_only_pass_waits_at_the_flush_and_nowhere_else(devices):
+    """Nothing of a batch comes home when no ids are wanted: the pass opens
+    no ``d2h_wait`` span a batch, on one device as under a mesh, and its one
+    fetch is the accumulator's (``flush_acc``)."""
+    s, table, plan = _jw_job()
+    program = GammaProgram(s, table)
+    mesh = make_mesh(devices) if devices else None
+    with StageTimer("gammas_patterns") as stage:
+        got = list(_virtual_pass_iter(
+            program, plan, 4096, mesh=mesh, want_ids=False,
+            counts_out=np.zeros(program.n_patterns, np.int64)))
+    assert len(got) >= 3 and all(t[4:] == (None, None, None) for t in got)
+    waits = [t for t in spans() if t["name"] == "d2h_wait" and t["parent"] == stage.span["id"]]
+    assert [t["counts"]["bytes"] for t in waits] == [4 * (program.n_patterns + 1)]
+    assert stage.counts["hist_flushes"] == 1
+    assert not [t for t in spans() if t["name"] == "mesh_gather" and t["t0"] >= stage.span["t0"]]
 
 
 # ----------------------------------------------------------------------
@@ -370,19 +389,16 @@ def test_em_only_pass_downloads_nothing_per_pair(mesh):
         return False
 
     # what came home during the pass: the histogram accumulator, whose size
-    # is the pattern space's and not the pairs', and on one device, where the
-    # Jaro-Winkler body prunes, one overflow flag (an id's width) a batch
+    # is the pattern space's and not the pairs', once — no wait a batch, on
+    # one device as under a mesh
     program = linker._ensure_pattern_program()
-    acc_bytes = 4 * (program.n_patterns + 2)
     waits = [s["counts"]["bytes"] for s in table
              if s["name"] == "d2h_wait" and under(s)]
-    flags = [b for b in waits if b != acc_bytes]
-    assert acc_bytes in waits and all(b <= 4 for b in flags)
-    flagged = mesh is None and bool(program.two_phase_div)
-    assert len(flags) == (stage["counts"]["batches"] if flagged else 0)
+    assert waits == [4 * (program.n_patterns + 1)]
     assert sum(waits) < candidates  # less than a byte a pair, all told
     assert stage["counts"]["ids_kept"] == 0
-    assert stage["counts"]["overflow_batches"] == 0
+    assert stage["counts"]["redo_positions"] == 0
+    assert "overflow_batches" not in stage["counts"]
     assert not [s for s in table if s["name"] == "mesh_gather"]
     assert not [s for s in table if s["name"] == "decode_pairs"]
 
