@@ -82,6 +82,7 @@ from .pairgen import (
 )
 from .utils import kernel_registry
 from .utils.kernel_registry import mesh_key
+from .utils.profiling import dispatched, fetch, fetch_pooled, poll
 
 logger = logging.getLogger("splink_tpu")
 
@@ -470,15 +471,11 @@ def build_device_plan(
             ent_side = np.concatenate([ent_side, np.zeros(pad, np.int32)])
             ent_rank = np.concatenate([ent_rank, np.zeros(pad, np.int32)])
             ent_rows = np.concatenate([ent_rows, np.zeros(pad, np.int32)])
-        row_s, seg_start, l_cnt, r_cnt, n_seg, n_valid = sort_fn(
-            ent_codes, ent_side, ent_rank, ent_rows
-        )
-        order = np.asarray(row_s)
-        seg_start = np.asarray(seg_start)
-        l_cnt = np.asarray(l_cnt)
-        r_cnt = np.asarray(r_cnt)
-        n_seg_h = int(np.asarray(n_seg))
-        n_valid_h = int(np.asarray(n_valid))
+        sorted_dev = sort_fn(ent_codes, ent_side, ent_rank, ent_rows)
+        dispatched("block_segment_sort", sorted_dev[-1], rows=m)
+        order, seg_start, l_cnt, r_cnt, n_seg, n_valid = fetch(sorted_dev)
+        n_seg_h = int(n_seg)
+        n_valid_h = int(n_valid)
         starts = seg_start[:n_seg_h].astype(np.int64)
         lz = l_cnt[:n_seg_h].astype(np.int64)
         rz = r_cnt[:n_seg_h].astype(np.int64)
@@ -525,6 +522,28 @@ def build_device_plan(
 # --------------------------------------------------------------------------
 
 
+def _chunk_home(out_i, out_j, keep, n_valid, compact_dev, sharded, own):
+    """One emitted chunk's surviving pairs on the host, ``(i, j)``: the
+    download both emission loops run on their pool threads. Where the
+    survivors are a prefix of the downloaded buffers the slice VIEWS go
+    through ``own(view, lanes)``; a boolean selection already copies."""
+    ih, jh = np.asarray(out_i), np.asarray(out_j)
+    poll()  # the chunk's program has ended: its device record closes
+    if keep is None:  # maskless kernel: only the tail drops
+        n = n_valid
+    elif compact_dev:  # compacted on the device, the count in the last lane
+        n = int(ih[-1])
+    else:
+        # compacted here. Under a mesh padded tail positions carry
+        # keep=False; on one device rule overlap is rare, so most chunks
+        # keep everything: detect that and hand out the prefix
+        kh = np.asarray(keep) if sharded else np.asarray(keep)[:n_valid]
+        if sharded or not kh.all():
+            return ih[: len(kh)][kh], jh[: len(kh)][kh]
+        n = n_valid
+    return own(ih[:n], len(ih)), own(jh[:n], len(jh))
+
+
 def _emission_context(plan: DeviceBlockPlan, batch_size: int, mesh):
     """Shared device setup for the TWO emission drivers
     (:func:`iter_device_pairs` — streaming — and
@@ -567,6 +586,7 @@ def _emission_context(plan: DeviceBlockPlan, batch_size: int, mesh):
         "put": put,
         "shard": shard,
         "compact_dev": compact_dev,
+        "on_mesh": {} if mesh is None else {"devices": mesh.devices.size},
         "ranks": put(plan.ranks),
         "codes_l": put(
             plan.codes_l if len(plan.codes_l) else np.zeros((1, 1), np.int32)
@@ -688,32 +708,9 @@ def iter_device_pairs(plan: DeviceBlockPlan, batch_size: int, mesh=None):
         doesn't pin the whole chunk buffer for a sliver of survivors."""
         return arr.copy() if 2 * len(arr) < lanes else arr
 
-    def fetch(r, out_i, out_j, keep, n_valid):
-        if keep is None:  # maskless kernel: only the tail drops
-            return (
-                r,
-                own(np.asarray(out_i)[:n_valid], out_i.shape[0]),
-                own(np.asarray(out_j)[:n_valid], out_j.shape[0]),
-            )
-        if compact_dev:
-            ih = np.asarray(out_i)
-            jh = np.asarray(out_j)
-            cnt = int(ih[-1])
-            return r, own(ih[:cnt], len(ih)), own(jh[:cnt], len(jh))
-        if mesh is None:
-            # uncompacted CPU backend: compact host-side. Rule overlap is
-            # rare in practice, so most chunks keep everything — detect
-            # the all-keep case and return zero-copy slices instead of
-            # paying the boolean-indexed copy
-            kh = np.asarray(keep)[:n_valid]
-            ih = np.asarray(out_i)[:n_valid]
-            jh = np.asarray(out_j)[:n_valid]
-            if kh.all():
-                return r, own(ih, out_i.shape[0]), own(jh, out_j.shape[0])
-            return r, ih[kh], jh[kh]  # boolean indexing already copies
-        # mesh: padded tail positions carry keep=False, compact directly
-        kh = np.asarray(keep)
-        return r, np.asarray(out_i)[kh], np.asarray(out_j)[kh]
+    def download(r, out_i, out_j, keep, n_valid):
+        return (r, *_chunk_home(out_i, out_j, keep, n_valid, compact_dev,
+                                mesh is not None, own))
 
     try:
         for r, rp in enumerate(plan.rules):
@@ -731,20 +728,22 @@ def iter_device_pairs(plan: DeviceBlockPlan, batch_size: int, mesh=None):
                     codes_l_dev, codes_r_dev, uid_dev, res_ops_dev,
                     meta_dev,
                 )
+                dispatched("block_pair_emit", out_i, positions=p1 - p0,
+                           **ctx["on_mesh"])
                 stats["submitted"] += 1
                 stats["candidates"] += p1 - p0
                 stats["fill_sum"] += (p1 - p0) / rule_bs
                 inflight.append(
-                    pool.submit(fetch, r, out_i, out_j, keep, p1 - p0)
+                    pool.submit(download, r, out_i, out_j, keep, p1 - p0)
                 )
                 occ = len(inflight)
                 stats["occ_sum"] += occ
                 if occ > stats["occ_max"]:
                     stats["occ_max"] = occ
                 while len(inflight) > _D2H_DEPTH:
-                    yield account(inflight.popleft().result())
+                    yield account(fetch_pooled(inflight.popleft()))
         while inflight:
-            yield account(inflight.popleft().result())
+            yield account(fetch_pooled(inflight.popleft()))
         stats["completed"] = True
     finally:
         # the consumer may abandon the generator mid-stream (a sink error):
@@ -1010,8 +1009,10 @@ def emit_pairs_sharded(
         shard_s = pair_sharding(mesh)
         repl = replicated(mesh)
         put = lambda a: jax.device_put(jnp.asarray(a), repl)  # noqa: E731
+        on_mesh = {"devices": msz}
     else:
         put = jnp.asarray
+        on_mesh = {}
 
     compact_dev = mesh is None and jax.default_backend() != "cpu"
     ranks_dev = put(plan.ranks)
@@ -1039,38 +1040,17 @@ def emit_pairs_sharded(
     emitted = sum(s.pairs for s in store.segments)
     t_start = _time.perf_counter()
 
-    def fetch(out_i, out_j, keep, n_valid, dig):
-        """Download + host-compact one chunk (the iter_device_pairs fetch
-        logic, minus zero-copy slicing — segment bytes are written
-        immediately, so owning copies buy nothing)."""
-        if keep is None:
-            return (
-                np.asarray(out_i)[:n_valid].copy(),
-                np.asarray(out_j)[:n_valid].copy(),
-                None,
-            )
-        if compact_dev:
-            ih = np.asarray(out_i)
-            jh = np.asarray(out_j)
-            cnt = int(ih[-1])
-            d = None if dig is None else int(np.asarray(dig))
-            return ih[:cnt].copy(), jh[:cnt].copy(), d
-        if mesh is None:
-            kh = np.asarray(keep)[:n_valid]
-            ih = np.asarray(out_i)[:n_valid]
-            jh = np.asarray(out_j)[:n_valid]
-            d = None if dig is None else int(np.asarray(dig))
-            if kh.all():
-                return ih.copy(), jh.copy(), d
-            return ih[kh], jh[kh], d
-        kh = np.asarray(keep)
-        d = None if dig is None else int(np.asarray(dig))
-        return np.asarray(out_i)[kh], np.asarray(out_j)[kh], d
+    def download(out_i, out_j, keep, n_valid, dig):
+        """One chunk home as owning copies (segment bytes are written
+        immediately, so zero-copy slices buy nothing) with its digest."""
+        i, j = _chunk_home(out_i, out_j, keep, n_valid, compact_dev,
+                           mesh is not None, lambda view, _lanes: view.copy())
+        return i, j, None if dig is None else int(np.asarray(dig))
 
     def drain_one():
         nonlocal emitted
         r, s, k, fut = inflight.popleft()
-        i, j, dig = fut.result()
+        i, j, dig = fetch_pooled(fut)
         if budget is not None and emitted + len(i) > budget:
             keep = max(budget - emitted, 0)
             i, j, dig = i[:keep], j[:keep], None
@@ -1168,6 +1148,8 @@ def emit_pairs_sharded(
                         codes_l_dev, codes_r_dev, uid_dev, res_ops_dev,
                         meta_dev,
                     )
+                    dispatched("block_pair_emit", out_i, positions=p1 - _p0,
+                               **on_mesh)
                     dig = None
                     if keep is not None:
                         # compact layout passes positions (the count rides
@@ -1180,7 +1162,8 @@ def emit_pairs_sharded(
                         )
                     inflight.append(
                         (r, s_idx, k,
-                         pool.submit(fetch, out_i, out_j, keep, p1 - _p0, dig))
+                         pool.submit(download, out_i, out_j, keep, p1 - _p0,
+                                     dig))
                     )
                     while len(inflight) > _D2H_DEPTH:
                         drain_one()

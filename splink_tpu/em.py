@@ -34,6 +34,7 @@ from .models.fellegi_sunter import (
     sufficient_stats,
     update_params,
 )
+from .utils.profiling import dispatched, span
 
 
 class EMResult(NamedTuple):
@@ -483,10 +484,12 @@ def run_em_checkpointed(
                 compute_ll=compute_ll,
                 host_hook=hook_needed,
             )
+            dispatched("run_em", result.n_updates, rows=G.shape[0])
             # drain before releasing the hook: dispatch is async and the
             # trailing callbacks may still be in flight
-            jax.block_until_ready(result.n_updates)
-            jax.effects_barrier()
+            with span("d2h_wait", bytes=0):
+                jax.block_until_ready(result.n_updates)
+                jax.effects_barrier()
         finally:
             _active_em_hook = None
         if deferred:

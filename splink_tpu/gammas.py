@@ -44,7 +44,7 @@ from .ops.gamma import (
 from .settings import comparison_column_name
 from .utils import kernel_registry
 from .utils.logging_utils import log_jaxpr
-from .utils.profiling import count, fetch, span
+from .utils.profiling import count, dispatched, fetch, span
 
 logger = logging.getLogger("splink_tpu")
 
@@ -888,7 +888,9 @@ class GammaProgram:
         return self._kernel("gamma_batch", (), _jit_gamma_batch)
 
     def _gamma_batch(self, il, ir):
-        return self._gamma_batch_fn(self._packed, il, ir)
+        G = self._gamma_batch_fn(self._packed, il, ir)
+        dispatched("fn", G, rows=il.shape[0])
+        return G
 
     @property
     def _pattern_kernel(self):
@@ -899,7 +901,9 @@ class GammaProgram:
 
     def _run_pattern_batch(self, il, ir, valid, acc):
         fn = self._kernel("pattern_batch", (), _jit_pattern_batch)
-        return fn(self._packed, il, ir, valid, acc)
+        pid, acc = fn(self._packed, il, ir, valid, acc)
+        dispatched("_pattern_kernel", acc, rows=il.shape[0])
+        return pid, acc
 
     @property
     def _pattern_batch(self):
@@ -942,13 +946,18 @@ class GammaProgram:
         fn = self._pattern_batch_for_mesh(mesh)
 
         def run_batch(bl, br, valid, acc):
-            return fn(
+            pid, acc = fn(
                 packed_dev,
                 jax.device_put(bl, shard),
                 jax.device_put(br, shard),
                 valid,
                 acc,
             )
+            dispatched(
+                "_pattern_kernel", acc, rows=len(bl),
+                devices=mesh.devices.size,
+            )
+            return pid, acc
 
         def zero_acc():
             return jax.device_put(
@@ -1419,13 +1428,14 @@ def pattern_counts_from_gammas(
         acc = _pattern_counts_batch(
             jnp.asarray(Gb), stop - start, strides_dev, n_patterns, acc
         )
+        dispatched("_pattern_counts_batch", acc, rows=batch_size)
         batches_in_acc += 1
         if batches_in_acc >= flush_every:
-            total += np.asarray(acc[:-1], np.int64)
+            total += fetch(acc)[:-1]
             acc = jnp.zeros(n_patterns + 1, jnp.int32)
             batches_in_acc = 0
     if batches_in_acc:
-        total += np.asarray(acc[:-1], np.int64)
+        total += fetch(acc)[:-1]
     return total
 
 

@@ -39,7 +39,7 @@ from .params import Params, load_params_from_json
 from .parallel.mesh import mesh_from_settings, shard_pairs
 from .settings import comparison_column_name, complete_settings_dict
 from .utils.compile_cache import enable_compilation_cache
-from .utils.profiling import StageTimer, count, fetch, span
+from .utils.profiling import StageTimer, count, dispatched, fetch, span
 
 logger = logging.getLogger("splink_tpu")
 
@@ -1056,7 +1056,9 @@ class Splink:
             host += [tid[ir[s:e]] for tid in tids]
             with span("h2d_put", bytes=sum(a.nbytes for a in host)):
                 args = [jnp.asarray(a) for a in host]
-            out[s:e] = fetch(fold(*args[:1], u_dev, *args[1:], *logs_dev))
+            folded = fold(*args[:1], u_dev, *args[1:], *logs_dev)
+            dispatched("tf_fold", folded, rows=e - s)
+            out[s:e] = fetch(folded)
         return out
 
     def _pattern_score_luts(self):
@@ -1571,6 +1573,7 @@ class Splink:
                     result = run_em(
                         G_dev, init, max_iterations=max_iterations, **em_kwargs
                     )
+                    dispatched("run_em", result.n_updates, rows=G_dev.shape[0])
                 self._replay_history(result, compute_ll)
                 converged = bool(result.converged)
             else:
@@ -1578,6 +1581,7 @@ class Splink:
                 params_dev = init
                 for k in range(max_iterations):
                     result = run_em(G_dev, params_dev, max_iterations=1, **em_kwargs)
+                    dispatched("run_em", result.n_updates, rows=G_dev.shape[0])
                     params_dev = result.params
                     self._replay_history(result, compute_ll)
                     if tel is not None:
@@ -1845,11 +1849,8 @@ class Splink:
             if compute_ll and ll is not None:
                 self.params.params["log_likelihood"] = float(ll)
                 self.params.log_likelihood_exists = True
-            self.params.update_from_arrays(
-                float(params_dev.lam),
-                np.asarray(params_dev.m),
-                np.asarray(params_dev.u),
-            )
+            lam, m, u = fetch((params_dev.lam, params_dev.m, params_dev.u))
+            self.params.update_from_arrays(float(lam), m, u)
             # checkpoint BEFORE save_state_fn and the em_iteration fault
             # site: an injected kill at iteration N must find update N
             # already durable (the kill-and-resume contract)
@@ -1992,16 +1993,16 @@ class Splink:
         object so history, convergence logging, charts and save/load match
         the reference's per-iteration bookkeeping."""
         self._last_em_result = result
-        n_updates = int(result.n_updates)
-        ll_hist = np.asarray(result.ll_history)
+        # where the driver meets EM: one wait for the loop, then its
+        # histories home in one piece (a slice a device array per update
+        # would be an eager dispatch each)
+        n_updates, lam_h, m_h, u_h, ll_hist = fetch((
+            result.n_updates, result.lam_history, result.m_history,
+            result.u_history, result.ll_history,
+        ))
+        n_updates = int(n_updates)
         self._replay_em_history(
-            result.lam_history,
-            result.m_history,
-            result.u_history,
-            ll_hist,
-            0,
-            n_updates,
-            compute_ll,
+            lam_h, m_h, u_h, ll_hist, 0, n_updates, compute_ll
         )
         if compute_ll and not np.isnan(ll_hist[n_updates]):
             self.params.params["log_likelihood"] = float(ll_hist[n_updates])
@@ -2148,6 +2149,7 @@ class Splink:
                 res = score_pairs_with_logits(Gb, params_dev)
             else:
                 res = (score_pairs(Gb, params_dev),)
+            dispatched("score_pairs", res[0], rows=batch)
             res = tuple(r[: stop - s] for r in res)
             if pending is not None:
                 self._drain_score_batch(pending, p, prob_m, prob_u, z)

@@ -1299,7 +1299,14 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
     import jax.numpy as jnp
 
     from .gammas import _HIST_FLUSH_BATCHES
-    from .utils.profiling import count, fetch, span
+    from .utils.profiling import (
+        count,
+        dispatched,
+        fetch,
+        fetch_pooled,
+        poll,
+        span,
+    )
 
     n_patterns = program.n_patterns
     total = plan.n_candidates
@@ -1339,9 +1346,11 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
         put = lambda a: jax.device_put(a, repl)  # noqa: E731
         place = functools.partial(put_on_mesh, repl)
         download = gather_from_mesh
+        on_mesh = {"devices": msz}
     else:
         put = jnp.asarray
         download = np.asarray
+        on_mesh = {}
 
         def place(*arrays):
             """The pass's table and plan arrays onto the device; under a
@@ -1362,15 +1371,15 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
 
     def download_batch(outs):
         """One batch's pattern ids and row pairs home, on a pool thread."""
-        return tuple(download(x) for x in outs)
+        home = tuple(download(x) for x in outs)
+        poll()  # the batch's program has ended: its device record closes
+        return home
 
     def settle(entry):
         """What the pass yields for the oldest batch in flight, once its
         download is home: the driver thread's D2H wait."""
         pr, pp0, ps, n_valid, fut = entry
-        with span("d2h_wait") as sp:
-            home = fut.result()
-            sp.count(bytes=sum(a.nbytes for a in home))
+        home = fetch_pooled(fut)
         return (pr, pp0, ps, n_valid, *(a[:n_valid] for a in home))
 
     pool = ThreadPoolExecutor(max_workers=_D2H_DEPTH) if want_ids else None
@@ -1441,6 +1450,9 @@ def _virtual_pass_iter(program, plan: VirtualPlan, batch_size: int,
                 if kkey not in rp.kernels_run:
                     rp.kernels_run[kkey] = (fn, _abstract_args(args))
                 pid, i, j, acc = fn(*args)
+                # polled by the accumulator: a few words, and the next
+                # batch's input, so the record pins nothing of the batch
+                dispatched("fn", acc, positions=p1 - p0, **on_mesh)
                 if want_ids:
                     inflight.append(
                         (r, p0, out_pos, p1 - p0,

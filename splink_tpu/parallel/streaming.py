@@ -26,6 +26,7 @@ from ..models.fellegi_sunter import (
     sufficient_stats,
     update_params,
 )
+from ..utils.profiling import dispatched, fetch, span
 from .mesh import pair_sharding, shard_pairs
 
 
@@ -150,6 +151,7 @@ def run_em_streamed(
             stats, ll = _batch_stats(
                 jnp.asarray(G), params, max_levels, w, compute_ll
             )
+            dispatched("_batch_stats", ll, rows=len(G))
             acc = acc + stats
             if compute_ll:
                 ll_parts.append(ll)
@@ -184,11 +186,12 @@ def run_em_streamed(
         # and the histories need these scalars on host, and everything
         # upstream (per-batch stats, ll parts, the update+delta) stayed on
         # device.
-        delta = float(delta_dev)  # jaxlint: disable=JL011 — sanctioned
-        lam_f = float(params.lam)  # jaxlint: disable=JL011 — same sync point
-        ll_total = (  # jaxlint: disable=JL011 — same sync point
-            float(ll_dev) if compute_ll else 0.0
-        )
+        with span("d2h_wait", bytes=0):  # the pass's programs, a few scalars
+            delta = float(delta_dev)  # jaxlint: disable=JL011 — sanctioned
+            lam_f = float(params.lam)  # jaxlint: disable=JL011 — same sync point
+            ll_total = (  # jaxlint: disable=JL011 — same sync point
+                float(ll_dev) if compute_ll else 0.0
+            )
         lam_hist.append(lam_f)
         m_hist.append(np.asarray(params.m))
         u_hist.append(np.asarray(params.u))
@@ -230,4 +233,6 @@ def score_stream(batch_iter, params: FSParams):
 
     for batch in batch_iter:
         G = batch[0] if isinstance(batch, tuple) else batch
-        yield np.asarray(score_pairs(jnp.asarray(G), params))
+        p = score_pairs(jnp.asarray(G), params)
+        dispatched("score_pairs", p, rows=len(G))
+        yield fetch(p)

@@ -30,7 +30,7 @@ import numpy as np
 
 from .params import Params
 from .check_types import check_types
-from .utils.profiling import fetch, span
+from .utils.profiling import dispatched, fetch, span
 
 
 def bayes_combine(probs: list[np.ndarray]) -> np.ndarray:
@@ -225,6 +225,7 @@ def compute_token_adjustment_device(
         with span("h2d_put", bytes=cl.nbytes + cr.nbytes + pc.nbytes):
             chunk_dev = jnp.asarray(cl), jnp.asarray(cr), jnp.asarray(pc, dtype)
         sums, counts = stats_fn(*chunk_dev, sums, counts)
+        dispatched("tf_token_stats", counts, rows=chunk)
 
     tok_lambda = sums / jnp.maximum(counts, 1.0)
     # Bayes-combine each token lambda with (1 - base lambda)
@@ -250,6 +251,7 @@ def compute_token_adjustment_device(
         with span("h2d_put", bytes=cl.nbytes + cr.nbytes):
             chunk_dev = jnp.asarray(cl), jnp.asarray(cr)
         out = gather_fn(*chunk_dev, adjusted)
+        dispatched("tf_token_gather", out, rows=chunk)
         if pending is not None:
             ps, pout = pending
             adj[ps : ps + chunk] = fetch(pout)[: max(0, min(chunk, n - ps))]

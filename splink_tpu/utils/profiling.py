@@ -9,7 +9,9 @@ each scope keeps an in-memory table of spans::
 the clock a caller's own job wall uses), ``id`` is the span's index in its
 run's table (assigned when it opens), ``parent`` the id of the enclosing
 open span of the same thread in the same run (or None), ``counts`` the
-work done inside it (rows, pairs, batches, bytes). Kinds:
+work done inside it (rows, pairs, batches, bytes). A span a generator held
+open also carries ``suspended``, the ``(t0, t1)`` of each time it stepped
+aside. Kinds:
 
   * ``call``  — one public linker call (the roots: ``init``,
     ``scored_comparisons``, ``tf``, ...);
@@ -18,7 +20,23 @@ work done inside it (rows, pairs, batches, bytes). Kinds:
   * ``span``  — a sub-stage or one batch's wait, ``span(name, **counts)``;
   * ``build`` — jax tracing / lowering / backend compile (or persistent-cache
     read), appended closed by the ``jax.monitoring`` listener in
-    ``obs/metrics.py`` under whatever span was open on that thread.
+    ``obs/metrics.py`` under whatever span was open on that thread;
+  * ``device`` — one jitted BATCH program the host asked the device to run,
+    ``dispatched(name, out, **counts)``: the other party. ``t0`` is when the
+    dispatching call returned, ``t1`` the first moment the host KNEW the
+    program had finished, ``parent`` the span open on the dispatching
+    thread, ``name`` the program's jitted name (``fn`` for the gamma
+    programs, ``block_pair_emit``, ``run_em``, ``score_pairs``, ...),
+    ``counts`` what it was asked to do (``positions`` or ``rows``;
+    ``devices`` under a mesh). No wait is added to learn ``t1``: the output
+    is polled with the non-blocking ``Array.is_ready()`` whenever a span
+    opens or closes, a program is dispatched or a download lands
+    (:func:`poll`), oldest first, so a record never closes before its
+    program ended and closes at most one such boundary after it.
+    :func:`spans`, :func:`stage_timings` and ``span_seconds`` never see
+    these records (a span's self time is its own); :func:`device_spans`
+    returns them and :func:`exposure` sets the two lanes side by side:
+    which driver seconds had nothing in flight and which the device hid.
 
 Always on: no setting, no environment switch. A span costs two clock reads,
 one append and a ``jax.profiler.TraceAnnotation`` (a flag check while no
@@ -50,6 +68,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+from collections import deque
 
 import numpy as np
 
@@ -59,6 +78,10 @@ _TABLES: dict[str, list[dict]] = {_DEFAULT_RUN: []}
 _CURRENT_RUN = _DEFAULT_RUN
 _LOCAL = threading.local()  # .stack: this thread's open StageTimers
 _APPEND_LOCK = threading.Lock()  # id == index must hold across threads
+# the open device records with the output each is polled by, in dispatch
+# order (process-wide: a chip runs programs in the order they were given)
+_INFLIGHT: deque = deque()
+_POLL_LOCK = threading.Lock()  # one poller at a time; the others move on
 
 # Retained run scopes are bounded: a long-lived service constructing one
 # linker per request must not grow the tables forever. Oldest scopes are
@@ -167,10 +190,14 @@ class StageTimer(contextlib.AbstractContextManager):
             stack.remove(self)
         self._annotation.__exit__(None, None, None)
         t = time.perf_counter()
+        poll()
         try:
             yield
         finally:
-            self.count(suspended_s=time.perf_counter() - t)
+            poll()
+            back = time.perf_counter()
+            self.count(suspended_s=back - t)
+            self.span.setdefault("suspended", []).append((t, back))
             self._annotation = _trace_annotation(self.stage)
             self._annotation.__enter__()
             _stack().append(self)
@@ -197,12 +224,14 @@ class StageTimer(contextlib.AbstractContextManager):
         stack.append(self)
         self._annotation = _trace_annotation(self.stage)
         self._annotation.__enter__()
+        poll()  # what ended before this span opened closes before it
         self.span["t0"] = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.span["t1"] = t1 = time.perf_counter()
         self.elapsed = t1 - self.span["t0"]
+        poll()  # after t1: a wait this span held is in flight to its end
         self._annotation.__exit__(*exc)
         stack = _stack()
         if stack and stack[-1] is self:
@@ -233,14 +262,72 @@ def count(**counts) -> None:
 
 
 def fetch(x, via=np.asarray):
-    """``np.asarray(x)`` for a device array, under a ``d2h_wait`` span: the
-    one place the driver thread blocks on a device-to-host copy. ``via`` is
-    the copy itself — ``parallel.mesh.gather_from_mesh`` for an array
-    sharded over a mesh, whose ``mesh_gather`` span then lies inside."""
+    """``np.asarray(x)`` for a device array (or each array of a tuple),
+    under a ``d2h_wait`` span: how the driver thread blocks on the device —
+    the program that makes ``x`` plus the copy home; ``bytes`` is what came
+    (0 where the wait brings nothing). ``via`` is the copy itself —
+    ``parallel.mesh.gather_from_mesh`` for an array sharded over a mesh,
+    whose ``mesh_gather`` span then lies inside."""
     with span("d2h_wait") as sp:
-        arr = via(x)
-        sp.count(bytes=arr.nbytes)
-    return arr
+        home = tuple(via(a) for a in x) if isinstance(x, tuple) else via(x)
+        sp.count(bytes=sum(
+            a.nbytes for a in (home if isinstance(home, tuple) else (home,))
+            if hasattr(a, "nbytes")
+        ))
+    return home
+
+
+def fetch_pooled(future):
+    """What a download running on a pool thread brings home, under the
+    DRIVER's ``d2h_wait`` span (the pool thread's own copy is under none:
+    it is not the driver's time)."""
+    return fetch(future, via=_result)
+
+
+def _result(future):
+    return future.result()
+
+
+def dispatched(name: str, out, **counts) -> None:
+    """Mark one jitted BATCH program just dispatched: a ``device`` record
+    under the span open on this thread, open until ``out`` — an output of
+    the program, kept alive until then, so name a small one — is ready.
+    Call it right after the jitted call returns; per-batch metadata uploads
+    and eager slices get none."""
+    stack = getattr(_LOCAL, "stack", None)
+    outer = stack[-1] if stack else None
+    table = (
+        outer._table if outer is not None
+        else _TABLES.setdefault(_CURRENT_RUN, [])
+    )
+    rec = _append(
+        table, name, "device", time.perf_counter(), None,
+        None if outer is None else outer.span["id"], counts,
+    )
+    _INFLIGHT.append((rec, out))
+    poll()
+
+
+def poll() -> None:
+    """Close every device record at the head of the dispatch order whose
+    output is ready — non-blocking, one ``is_ready()`` call past the last
+    one closed. Spans poll as they open and close; a pool thread calls it
+    when its download has landed."""
+    if not _INFLIGHT or not _POLL_LOCK.acquire(blocking=False):
+        return
+    try:
+        while _INFLIGHT:
+            rec, out = _INFLIGHT[0]
+            try:
+                # is_ready() on a deleted buffer aborts the process
+                if not (out.is_deleted() or out.is_ready()):
+                    return
+            except AttributeError:
+                pass  # a host value: nothing to poll
+            _INFLIGHT.popleft()
+            rec["t1"] = time.perf_counter()
+    finally:
+        _POLL_LOCK.release()
 
 
 def add_closed(name: str, kind: str, secs: float, **counts) -> None:
@@ -286,15 +373,27 @@ def _trace_annotation(name: str):
     return _TRACE_ANNOTATION(name)
 
 
-def spans(run: str | None = None) -> list[dict]:
-    """The closed spans of the current run scope, or of ``run`` when given,
-    in the order they opened (copies; ``parent`` refers to ``id``)."""
+def _closed(run: str | None, device: bool) -> list[dict]:
     key = _CURRENT_RUN if run is None else run
     return [
         dict(s, counts=dict(s["counts"]))
         for s in _TABLES.get(key, ())
-        if s["t1"] is not None
+        if s["t1"] is not None and (s["kind"] == "device") == device
     ]
+
+
+def spans(run: str | None = None) -> list[dict]:
+    """The closed HOST spans of the current run scope, or of ``run`` when
+    given, in the order they opened (copies; ``parent`` refers to ``id``).
+    Device records are not among them: see :func:`device_spans`."""
+    return _closed(run, device=False)
+
+
+def device_spans(run: str | None = None) -> list[dict]:
+    """The closed ``device`` records of the scope, in dispatch order
+    (copies; ``parent`` is the ``id`` of a span of :func:`spans`)."""
+    poll()
+    return _closed(run, device=True)
 
 
 def stage_timings(run: str | None = None) -> dict[str, list[float]]:
@@ -312,6 +411,139 @@ def stage_timings(run: str | None = None) -> dict[str, list[float]]:
             s["t1"] - s["t0"] - s["counts"].get("suspended_s", 0.0)
         )
     return out
+
+
+def _union(intervals) -> list[tuple[float, float]]:
+    """The intervals merged: sorted, disjoint, none empty."""
+    out: list[tuple[float, float]] = []
+    for t0, t1 in sorted(intervals):
+        if t1 <= t0:
+            continue
+        if out and t0 <= out[-1][1]:
+            if t1 > out[-1][1]:
+                out[-1] = (out[-1][0], t1)
+        else:
+            out.append((t0, t1))
+    return out
+
+
+def _meet(a: list, b: list) -> list[tuple[float, float]]:
+    """What two unions share."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            out.append((lo, hi))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def _less(a: list, b: list) -> list[tuple[float, float]]:
+    """Union ``a`` without what union ``b`` covers."""
+    out, j = [], 0
+    for t0, t1 in a:
+        while j < len(b) and b[j][1] <= t0:
+            j += 1
+        k = j
+        while k < len(b) and b[k][0] < t1:
+            if b[k][0] > t0:
+                out.append((t0, b[k][0]))
+            t0 = max(t0, b[k][1])
+            k += 1
+        if t0 < t1:
+            out.append((t0, t1))
+    return out
+
+
+def _seconds(a: list) -> float:
+    return sum(t1 - t0 for t0, t1 in a)
+
+
+def exposure(run: str | None = None) -> dict:
+    """Why was the chip idle: one run's two lanes side by side, from its one
+    table. The DRIVER is the thread of the run's root ``call`` spans; its
+    clock inside them splits three ways::
+
+        wall_s = exposed_s + inflight_s + suspended_s
+
+    ``inflight_s`` — the union of the device records' intervals, clipped to
+    the driver's running time: some program was dispatched and not yet
+    known to have ended (host work in it is hidden behind the device);
+    ``exposed_s`` — the driver ran with nothing in flight (the chip sat idle
+    for it), of which ``head_s`` before the run's first dispatch, ``tail_s``
+    after its last record closed and ``unspanned_exposed_s`` in the call
+    spans' own self time; ``suspended_s`` — a generator-held call span
+    stepped aside (the consumer's time: neither). ``spans`` gives per host
+    span name of the driver ``n``, ``self_s`` and its split ``exposed_s`` /
+    ``hidden_s``; ``other_threads`` the spans closed elsewhere (pooled
+    downloads, ``mesh_gather``) with their own self time, never added to
+    the driver's; ``device`` per program ``n`` and its summed seconds.
+    An empty dict where the run closed no call span."""
+    host, device = spans(run), device_spans(run)
+    roots = [s for s in host if s["kind"] == "call" and s["parent"] is None]
+    if not roots:
+        return {}
+    driver = roots[0]["thread"]
+    roots = [s for s in roots if s["thread"] == driver]
+
+    def ran(s):  # a span's own interval less the times it stepped aside
+        return _less([(s["t0"], s["t1"])], _union(s.get("suspended", ())))
+
+    wall = _union((s["t0"], s["t1"]) for s in roots)
+    running = _union(iv for s in roots for iv in ran(s))
+    inflight = _meet(_union((d["t0"], d["t1"]) for d in device), running)
+    exposed = _less(running, inflight)
+    children: dict = {}
+    for s in host:
+        children.setdefault(s["parent"], []).append(s)
+    by_name: dict = {}
+    elsewhere: dict = {}
+    unspanned = 0.0
+    for s in host:
+        own = _less(ran(s), _union(
+            iv for c in children.get(s["id"], ()) for iv in ran(c)
+        ))
+        if s["thread"] != driver:
+            row = elsewhere.setdefault(
+                s["name"], {"n": 0, "self_s": 0.0, "threads": set()}
+            )
+            row["threads"].add(s["thread"])
+        else:
+            row = by_name.setdefault(
+                s["name"],
+                {"n": 0, "self_s": 0.0, "exposed_s": 0.0, "hidden_s": 0.0},
+            )
+            bare = _seconds(_meet(own, exposed))
+            row["exposed_s"] += bare
+            row["hidden_s"] += _seconds(_meet(own, inflight))
+            if s["kind"] == "call" and s["parent"] is None:
+                unspanned += bare
+        row["n"] += 1
+        row["self_s"] += _seconds(own)
+    for row in elsewhere.values():
+        row["threads"] = sorted(row["threads"])
+    programs: dict = {}
+    for d in device:
+        row = programs.setdefault(d["name"], {"n": 0, "seconds": 0.0})
+        row["n"] += 1
+        row["seconds"] += d["t1"] - d["t0"]
+    first = min((d["t0"] for d in device), default=wall[-1][1])
+    last = max((d["t1"] for d in device), default=wall[-1][1])
+    return {
+        "wall_s": _seconds(wall),
+        "suspended_s": _seconds(wall) - _seconds(running),
+        "inflight_s": _seconds(inflight),
+        "exposed_s": _seconds(exposed),
+        "head_s": _seconds(_meet(exposed, [(wall[0][0], first)])),
+        "tail_s": _seconds(_meet(exposed, [(last, wall[-1][1])])),
+        "unspanned_exposed_s": unspanned,
+        "spans": by_name,
+        "other_threads": elsewhere,
+        "device": programs,
+    }
 
 
 def reset_timings(run: str | None = None) -> None:

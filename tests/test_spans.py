@@ -685,3 +685,321 @@ def test_jitted_programs_have_names_of_their_own():
     assert {n for n in names if n.startswith("tf_")} == {
         "tf_token_stats", "tf_token_gather", "tf_fold",
     }
+
+
+# ---------------------------------------------------------------------------
+# the device lane: one record a dispatched batch program, and exposure()
+# ---------------------------------------------------------------------------
+
+_PROGRAMS = {
+    "dedupe": {"fn", "run_em", "score_pairs"},
+    "link_tf": {"fn", "run_em", "score_pairs", "tf_fold", "tf_token_stats",
+                "tf_token_gather"},
+}
+
+
+class _Handle:
+    """Stands for a program's output: ready when told."""
+
+    def __init__(self, ready=False):
+        self.ready = ready
+
+    def is_deleted(self):
+        return False
+
+    def is_ready(self):
+        return self.ready
+
+
+def _raw(run):
+    return profiling._TABLES[run]
+
+
+def test_every_dispatched_program_has_a_closed_record_under_a_host_span(job):
+    name, linker, table = job
+    records = profiling.device_spans(run=linker.run_id)
+    assert {d["name"] for d in records} == _PROGRAMS[name]
+    assert not [s for s in _raw(linker.run_id) if s["t1"] is None]  # none left open
+    host = {s["id"]: s for s in table}
+    for d in records:
+        assert d["kind"] == "device" and d["t1"] >= d["t0"]
+        assert d["id"] not in host and d["parent"] in host
+        assert host[d["parent"]]["t0"] <= d["t0"] <= host[d["parent"]]["t1"]
+        assert d["counts"].get("rows", 0) + d["counts"].get("positions", 0) > 0
+    assert all(s["kind"] != "device" for s in table)
+    # the virtual pass dispatches one gamma program a batch of a rule's
+    # candidate positions: together every position, masked ones included
+    if name == "dedupe":
+        stage = {s["name"]: s for s in table}["gammas_patterns"]
+        fns = [d for d in records if d["name"] == "fn"]
+        assert len(fns) == stage["counts"]["batches"] >= 2
+        assert sum(d["counts"]["positions"] for d in fns
+                   if d["parent"] == stage["id"]) == stage["counts"]["pairs"] + sum(
+            s["counts"]["rows"] - s["counts"]["kept"]
+            for s in table if s["name"] == "decode_pairs")
+
+
+def test_device_blocking_records_its_sort_and_emit_programs_and_waits_in_spans():
+    settings = {
+        "link_type": "link_only",
+        "comparison_columns": [{"col_name": "first_name"}, {"col_name": "surname"}],
+        "blocking_rules": ["l.city = r.city"],
+        "max_iterations": 2,
+        "device_blocking": "on",
+    }
+    df = _people(900, 5)
+    linker = Splink(settings, df_l=df.iloc[:600], df_r=df.iloc[600:])
+    frame = linker.get_scored_comparisons()
+    table = spans(run=linker.run_id)
+    blocking = {s["name"]: s for s in table}["blocking"]
+    under = [d for d in profiling.device_spans(run=linker.run_id)
+             if d["parent"] == blocking["id"]]
+    assert {d["name"] for d in under} == {"block_segment_sort", "block_pair_emit"}
+    assert sum(d["counts"].get("positions", 0) for d in under) >= len(frame)
+    waits = [s for s in table
+             if s["name"] == "d2h_wait" and s["parent"] == blocking["id"]]
+    # the sort's six arrays in one wait, and one wait a pooled chunk download
+    assert len(waits) == 1 + sum(d["name"] == "block_pair_emit" for d in under)
+    assert sum(w["counts"]["bytes"] for w in waits) >= 2 * 4 * len(frame)
+
+
+def test_a_record_stays_open_until_its_own_output_turns_ready(scope):
+    import jax.numpy as jnp
+
+    late = _Handle()
+    with StageTimer("call", kind="call"):
+        profiling.dispatched("late", late, rows=1)
+        with span("boundary"):
+            pass
+        profiling.fetch(jnp.arange(8) + 1)  # a wait on ANOTHER output returns
+        assert profiling.device_spans() == []
+        assert [s["name"] for s in _raw(scope) if s["t1"] is None] == [
+            "call", "late"]
+        late.ready = True
+        turned = time.perf_counter()
+        assert _raw(scope)[1]["t1"] is None  # nobody has looked yet
+        with span("next_boundary"):
+            pass
+    (rec,) = profiling.device_spans()
+    assert rec["name"] == "late" and rec["counts"] == {"rows": 1}
+    assert rec["t1"] >= turned > rec["t0"]
+    assert rec["parent"] == 0 and rec["thread"] == threading.get_ident()
+
+
+def test_records_close_in_dispatch_order_when_a_later_wait_returns(scope):
+    import jax
+    import jax.numpy as jnp
+
+    step = jax.jit(lambda x: x * 2 + 1)
+    with StageTimer("call", kind="call"):
+        x = jnp.ones(1 << 12)
+        outs = []
+        for k in range(3):
+            x = step(x)
+            profiling.dispatched("step", x, rows=k)
+            outs.append(x)
+        np.testing.assert_array_equal(profiling.fetch(outs[-1]), np.full(1 << 12, 15.0))
+        # the wait on the LAST output has returned: every record up to it is closed
+        closed = profiling.device_spans()
+        assert [d["counts"]["rows"] for d in closed] == [0, 1, 2]
+        t1s = [d["t1"] for d in closed]
+        assert t1s == sorted(t1s) and all(d["t1"] >= d["t0"] for d in closed)
+        # a record whose output is not ready holds those dispatched after it
+        head, behind = _Handle(), _Handle(ready=True)
+        profiling.dispatched("head", head)
+        profiling.dispatched("behind", behind)
+        with span("boundary"):
+            pass
+        assert len(profiling.device_spans()) == 3
+        head.ready = True
+    names = [d["name"] for d in profiling.device_spans()]
+    assert names == ["step"] * 3 + ["head", "behind"]
+    by = {d["name"]: d for d in profiling.device_spans()}
+    assert by["head"]["t1"] <= by["behind"]["t1"]
+
+
+def test_a_host_value_or_a_deleted_buffer_closes_at_once(scope):
+    import jax.numpy as jnp
+
+    gone = jnp.arange(4) * 2
+    gone.delete()
+    with StageTimer("call", kind="call"):
+        profiling.dispatched("host_value", np.arange(3))
+        profiling.dispatched("deleted", gone)
+    assert [d["name"] for d in profiling.device_spans()] == ["host_value", "deleted"]
+
+
+def test_device_records_change_no_host_reading(scope):
+    """The same work with and without a device record under it: ids stay
+    the table's indices, ``spans()`` and ``stage_timings()`` see host spans
+    alone, and a span's self time is not shrunk by the record it dispatched."""
+    with StageTimer("stage") as st:
+        time.sleep(0.01)
+        profiling.dispatched("program", _Handle(ready=False), rows=5)
+        with span("child"):
+            time.sleep(0.01)
+        time.sleep(0.03)
+        profiling._INFLIGHT[-1][1].ready = True
+    table = spans()
+    assert [(s["id"], s["name"]) for s in table] == [(0, "stage"), (2, "child")]
+    (rec,) = profiling.device_spans()
+    assert rec["id"] == 1 and rec["parent"] == 0 and rec["t1"] - rec["t0"] > 0.035
+    own = _self_seconds(table)
+    child = table[1]["t1"] - table[1]["t0"]
+    assert own[0] == pytest.approx(st.elapsed - child)  # the record covers nothing
+    assert stage_timings() == {"stage": [st.elapsed]}
+    assert profiling.builds_under(_raw(scope), st.span) == []
+
+
+def test_exposure_identity_and_lanes_on_both_jobs(job):
+    name, linker, table = job
+    e = profiling.exposure(run=linker.run_id)
+    assert e["suspended_s"] == 0.0
+    assert e["exposed_s"] + e["inflight_s"] == pytest.approx(e["wall_s"], rel=1e-9)
+    roots = [s for s in table if s["kind"] == "call" and s["parent"] is None]
+    assert e["wall_s"] == pytest.approx(sum(s["t1"] - s["t0"] for s in roots))
+    assert 0 < e["inflight_s"] < e["wall_s"] and e["head_s"] > 0
+    assert e["head_s"] + e["tail_s"] <= e["exposed_s"] + 1e-9
+    assert set(e["device"]) == _PROGRAMS[name]
+    for row in e["spans"].values():
+        assert row["exposed_s"] + row["hidden_s"] == pytest.approx(row["self_s"], abs=1e-9)
+    # build spans of one parent may overlap; everything else tiles the driver's clock
+    tiled = sum(r["exposed_s"] for n, r in e["spans"].items() if not n.startswith("jax_"))
+    assert tiled <= e["exposed_s"] + 1e-9
+    # a wait on the device is time with something in flight
+    wait = e["spans"]["d2h_wait"]
+    assert wait["hidden_s"] >= 0.9 * wait["self_s"]
+    assert e["unspanned_exposed_s"] == pytest.approx(
+        sum(e["spans"][s["name"]]["exposed_s"] for s in roots))
+
+
+def test_exposure_on_a_streamed_job_with_a_slow_consumer():
+    """The consumer's seconds are neither exposed nor hidden."""
+    settings = {
+        "link_type": "dedupe_only",
+        "comparison_columns": [{"col_name": "first_name"}, {"col_name": "surname"}],
+        "blocking_rules": ["l.city = r.city", "l.surname = r.surname"],
+        "max_iterations": 2,
+        "device_pair_generation": "on",
+        "max_resident_pairs": 1024,
+        "pair_batch_size": 1 << 14,
+        "virtual_materialise_ids": "off",
+    }
+    linker = Splink(settings, df=_people(900, 7))
+    chunks = 0
+    for _chunk in linker.stream_scored_comparisons():
+        chunks += 1
+        time.sleep(0.03)
+    assert chunks >= 3
+    e = profiling.exposure(run=linker.run_id)
+    table = spans(run=linker.run_id)
+    call = {s["name"]: s for s in table}["stream_scored_comparisons"]
+    assert len(call["suspended"]) == chunks
+    assert all(t1 - t0 >= 0.03 for t0, t1 in call["suspended"])
+    assert e["suspended_s"] == pytest.approx(call["counts"]["suspended_s"], rel=1e-6)
+    assert e["suspended_s"] >= 0.03 * chunks
+    assert e["exposed_s"] + e["inflight_s"] + e["suspended_s"] == pytest.approx(
+        e["wall_s"], rel=1e-9)
+    # the suspended stretches lie in no span's self time, exposed or hidden
+    streamed = e["spans"]["stream_scored_comparisons"]
+    assert streamed["self_s"] < (call["t1"] - call["t0"]) - e["suspended_s"]
+    assert sum(r["exposed_s"] + r["hidden_s"] for n, r in e["spans"].items()
+               if not n.startswith("jax_")) <= e["wall_s"] - e["suspended_s"] + 1e-6
+    # both passes dispatched the gamma program: histogram only, then the recompute
+    assert e["device"]["fn"]["n"] >= 2 * 2
+
+
+def test_a_pool_threads_span_never_counts_on_the_driver(scope):
+    def download():
+        with span("mesh_gather"):
+            time.sleep(0.05)
+        return threading.get_ident()
+
+    with StageTimer("call", kind="call"):
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            fut = pool.submit(download)
+            with span("driver_work"):
+                time.sleep(0.02)
+            pool_thread = profiling.fetch_pooled(fut)
+    e = profiling.exposure()
+    assert set(e["spans"]) == {"call", "driver_work", "d2h_wait"}
+    assert e["other_threads"]["mesh_gather"]["n"] == 1
+    assert e["other_threads"]["mesh_gather"]["threads"] == [pool_thread]
+    assert e["other_threads"]["mesh_gather"]["self_s"] >= 0.05
+    # nothing was dispatched: all of the driver's clock is exposed, once
+    assert e["inflight_s"] == 0.0 and e["exposed_s"] == pytest.approx(e["wall_s"])
+    assert sum(r["self_s"] for r in e["spans"].values()) == pytest.approx(e["wall_s"])
+    assert e["spans"]["d2h_wait"]["n"] == 1  # fetch_pooled waited on the driver
+
+
+def test_exposure_of_a_scope_without_a_call_span_is_empty(scope):
+    with StageTimer("stage"):
+        profiling.dispatched("program", np.zeros(1))
+    assert profiling.exposure() == {}
+    assert [d["name"] for d in profiling.device_spans()] == ["program"]
+
+
+def test_fetch_takes_a_tuple_in_one_wait_and_counts_its_bytes(scope):
+    import jax.numpy as jnp
+
+    a, b = profiling.fetch((jnp.arange(4, dtype=jnp.int32), jnp.zeros(3, jnp.float32)))
+    assert a.tolist() == [0, 1, 2, 3] and b.shape == (3,)
+    (wait,) = spans()
+    assert wait["name"] == "d2h_wait" and wait["counts"] == {"bytes": 16 + 12}
+
+
+# where the offline driver may wait for the device outside a ``d2h_wait``
+# span, and why: (file under splink_tpu/, enclosing function)
+_WAITS_ELSEWHERE = {
+    ("utils/profiling.py", "_result"): "the via of fetch_pooled: inside its d2h_wait",
+    ("parallel/mesh.py", "gather_from_mesh"): "the via of fetch, or a pool thread's",
+    ("parallel/mesh.py", "put_on_mesh"): "an UPLOAD's wait, under its mesh_put span",
+}
+
+
+def test_no_offline_wait_on_the_device_lies_outside_a_d2h_wait_span():
+    """``block_until_ready(`` and ``<future>.result()`` in the offline tree
+    (not serve/, obs/, approx/; analysis/ holds the audits, which time
+    kernels and drive no job) lie lexically under ``span("d2h_wait")``, or
+    are one of the three named above."""
+    import ast
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "splink_tpu")
+    found, stray = set(), []
+    for folder, dirs, files in os.walk(root):
+        if folder == root:
+            dirs[:] = [d for d in dirs
+                       if d not in ("serve", "obs", "approx", "analysis")]
+        for fname in files:
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(folder, fname)
+            rel = os.path.relpath(path, root)
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read())
+
+            def visit(node, func, waiting):
+                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                    func = node.name
+                if isinstance(node, ast.With):
+                    for item in node.items:
+                        c = item.context_expr
+                        if (isinstance(c, ast.Call) and c.args
+                                and isinstance(c.args[0], ast.Constant)
+                                and c.args[0].value == "d2h_wait"):
+                            waiting = True
+                if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute):
+                    attr = node.func.attr
+                    if attr == "block_until_ready" or (
+                            attr == "result" and not node.args and not node.keywords):
+                        if (rel, func) in _WAITS_ELSEWHERE:
+                            found.add((rel, func))
+                        elif not waiting:
+                            stray.append(f"{rel}:{node.lineno} in {func}")
+                for child in ast.iter_child_nodes(node):
+                    visit(child, func, waiting)
+
+            visit(tree, None, False)
+    assert stray == []
+    assert found == set(_WAITS_ELSEWHERE)  # the list holds no stale entry
