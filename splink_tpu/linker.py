@@ -2061,11 +2061,17 @@ class Splink:
         table = self._ensure_encoded()
         # Full-column comparison: a sampled check could miss a small
         # permutation and silently misattribute probabilities to token ids.
+        # A block at a time, so that the ids the pair index names are never
+        # gathered whole, and a frame that differs is known at its first
+        # block that does.
         for c, idx in zip(cols, (self._pairs.idx_l, self._pairs.idx_r)):
-            want = np.asarray(table.unique_id[idx])
-            got = df_e[c].to_numpy()
-            if not np.array_equal(got, want):
-                return False
+            got = df_e[c].array
+            for a in range(0, n, _TAKE_ROWS):
+                rows = slice(a, a + _TAKE_ROWS)
+                if not np.array_equal(
+                    np.asarray(got[rows]), table.unique_id[idx[rows]]
+                ):
+                    return False
         return True
 
     def close_telemetry(self) -> None:

@@ -33,24 +33,52 @@ from .check_types import check_types
 from .utils.profiling import dispatched, fetch, span
 
 
+# rows a step of bayes_combine: its intermediates are three buffers of this
+# length, reused, in place of seven arrays as long as the input — memory a
+# process touches for the first time costs several times a warm write, and
+# at frame length every temporary is fresh (the block is the one the frame
+# writer's takes settled on)
+_COMBINE_ROWS = 1 << 16
+
+
 def bayes_combine(probs: list[np.ndarray]) -> np.ndarray:
     """prod(p) / (prod(p) + prod(1-p)) — the reference's sql_gen_bayes_string
-    (/root/reference/splink/term_frequencies.py:21-46)."""
-    num = np.ones_like(np.asarray(probs[0], dtype=np.float64))
-    den = np.ones_like(num)
-    for p in probs:
-        p = np.asarray(p, dtype=np.float64)
-        num = num * p
-        den = den * (1.0 - p)
-    # contradictory evidence (some p exactly 1 AND some p exactly 0, or
-    # underflow of both products) drives num and den both to 0; 0.5 is
-    # the no-information posterior, matching the disagreeing-pair
-    # convention below. On every other input the guarded division is
-    # bit-identical to num / (num + den).
-    tot = num + den
-    return np.where(
-        tot > 0, num / np.maximum(tot, np.finfo(np.float64).tiny), 0.5
-    )
+    (/root/reference/splink/term_frequencies.py:21-46). float64 throughout;
+    the only array of the inputs' length it allocates is the one it
+    returns."""
+    probs = [np.asarray(p) for p in probs]
+    n = len(probs[0])
+    if any(p.shape != (n,) for p in probs):
+        raise ValueError(
+            "bayes_combine takes one-dimensional arrays of one length, got "
+            f"shapes {[p.shape for p in probs]}"
+        )
+    out = np.empty(n, np.float64)
+    rows = min(n, _COMBINE_ROWS)
+    num, den, tmp = (np.empty(rows, np.float64) for _ in range(3))
+    tiny = np.finfo(np.float64).tiny
+    for a in range(0, n, _COMBINE_ROWS):
+        res = out[a : a + _COMBINE_ROWS]
+        m = len(res)
+        nu, de, t = num[:m], den[:m], tmp[:m]
+        nu.fill(1.0)
+        de.fill(1.0)
+        for p in probs:
+            # through float64 first: 1 - p of a float32 p is another number
+            np.copyto(t, p[a : a + m])
+            np.multiply(nu, t, out=nu)
+            np.subtract(1.0, t, out=t)
+            np.multiply(de, t, out=de)
+        # contradictory evidence (some p exactly 1 AND some p exactly 0, or
+        # underflow of both products) drives num and den both to 0; 0.5 is
+        # the no-information posterior, matching the disagreeing-pair
+        # convention below. On every other input the guarded division is
+        # bit-identical to num / (num + den).
+        np.add(nu, de, out=t)
+        np.maximum(t, tiny, out=res)
+        np.divide(nu, res, out=res)
+        res[~(t > 0)] = 0.5
+    return out
 
 
 def compute_token_adjustment(values_l, values_r, match_probability, base_lambda):
@@ -376,6 +404,16 @@ def make_adjustment_for_term_frequencies(
 ):
     """Add ``tf_adjusted_match_prob`` to a scored comparisons frame.
 
+    Returns a NEW frame: ``tf_adjusted_match_prob`` and ``match_probability``
+    lead, as in the reference, then the other columns of ``df_e`` in their
+    order, then (with ``retain_adjustment_columns``) one ``<col>_adj`` a
+    flagged column. The pass reads ``match_probability`` (and, on the host
+    path, the flagged columns' values) and allocates at the frame's length
+    only the columns it adds: every column of ``df_e`` reaches the result
+    as it is, its bytes neither read nor written (pandas' copy-on-write).
+    ``df_e`` has the same columns and values after the call as before, and
+    a write through pandas into either frame never shows in the other.
+
     pair_token_ids (optional, supplied by the linker): maps column name ->
     (tid_l, tid_r, n_tokens) int32 arrays aligned with df_e's rows; when
     present the per-token aggregation runs on device instead of a host
@@ -389,41 +427,43 @@ def make_adjustment_for_term_frequencies(
         )
         return df_e
 
-    # tf_frame: the pandas work (copy, column writes, reorder); its self
-    # time excludes the tf_device spans below it
-    with span("tf_frame", rows=len(df_e)):
-        df = df_e.copy()
+    import pandas as pd
+
+    # tf_frame: the host work round the frame (the combine, the new frame);
+    # its self time excludes the tf_device spans below it
+    with span("tf_frame", rows=len(df_e)) as sp:
+        p = df_e["match_probability"].to_numpy()
         base_lambda = params.params["λ"]
-        adj_arrays = []
+        adj = {}
         for col in tf_cols:
             if pair_token_ids is not None and col in pair_token_ids:
                 tid_l, tid_r, n_tokens = pair_token_ids[col]
-                with span("tf_device", rows=len(df)):
-                    adj, _, _ = compute_token_adjustment_device(
-                        tid_l,
-                        tid_r,
-                        df["match_probability"].to_numpy(),
-                        base_lambda,
-                        n_tokens,
+                with span("tf_device", rows=len(df_e)):
+                    adj[f"{col}_adj"], _, _ = compute_token_adjustment_device(
+                        tid_l, tid_r, p, base_lambda, n_tokens
                     )
             else:
-                adj, _ = compute_token_adjustment(
-                    df[f"{col}_l"].to_numpy(dtype=object),
-                    df[f"{col}_r"].to_numpy(dtype=object),
-                    df["match_probability"].to_numpy(),
+                adj[f"{col}_adj"], _ = compute_token_adjustment(
+                    df_e[f"{col}_l"].to_numpy(dtype=object),
+                    df_e[f"{col}_r"].to_numpy(dtype=object),
+                    p,
                     base_lambda,
                 )
-            df[f"{col}_adj"] = adj
-            adj_arrays.append(adj)
-
-        df["tf_adjusted_match_prob"] = bayes_combine(
-            [df["match_probability"].to_numpy()] + adj_arrays
-        )
-        if not retain_adjustment_columns:
-            df = df.drop(columns=[f"{c}_adj" for c in tf_cols])
 
         # Column order: tf_adjusted_match_prob leads, as in the reference
-        # (/root/reference/splink/term_frequencies.py:108-115).
-        lead = ["tf_adjusted_match_prob", "match_probability"]
-        rest = [c for c in df.columns if c not in lead]
-        return df[lead + rest]
+        # (/root/reference/splink/term_frequencies.py:108-115). A Series of
+        # df_e carries its reference to df_e's memory into the new frame
+        # (copy-on-write); the new columns are arrays nobody else holds.
+        new = {"tf_adjusted_match_prob": bayes_combine([p, *adj.values()])}
+        if retain_adjustment_columns:
+            new.update(adj)
+        order = dict.fromkeys(
+            ["tf_adjusted_match_prob", "match_probability", *df_e.columns, *new]
+        )
+        names = [c for c in order if retain_adjustment_columns or c not in adj]
+        sp.count(shared_columns=len(names) - len(new), added_columns=len(new))
+        return pd.DataFrame(
+            {c: new[c] if c in new else df_e[c] for c in names},
+            index=df_e.index,
+            copy=False,
+        )

@@ -463,6 +463,25 @@ def test_every_row_is_written_in_place_and_the_stage_covers_the_fill(job):
         assert stage["t0"] <= s["t0"] and s["t1"] <= stage["t1"]
 
 
+def test_tf_frame_counts_the_columns_it_shares_and_the_columns_it_adds(job):
+    """The TF pass hands every column of the scored frame on as it is
+    (``shared_columns`` == the frame's column count: the counter that says
+    ROADMAP A4's mechanism engaged) and allocates two: the flagged column's
+    adjustment and ``tf_adjusted_match_prob``. A job without a TF call
+    closes no ``tf_frame``."""
+    name, _linker, table = job
+    frames = [s["counts"] for s in table if s["name"] == "tf_frame"]
+    if name != "link_tf":
+        assert not frames
+        return
+    [scored] = [s["counts"] for s in table if s["name"] == "assemble_frame"]
+    [tf] = [s["counts"] for s in table if s["name"] == "tf"]
+    assert frames == [{
+        "rows": tf["rows"], "shared_columns": scored["columns"],
+        "added_columns": 2,
+    }]
+
+
 def test_numeric_only_frame_counts_no_string_column():
     """Nothing retained (the config-4 cells' frame): ids, levels and
     probabilities only, so neither counter finds a string column."""

@@ -169,7 +169,8 @@ def test_device_path_matches_host_groupby():
     np.testing.assert_allclose(adj_dev, adj_host, rtol=1e-9, atol=1e-12)
 
 
-def test_linker_uses_device_path_and_falls_back_when_misaligned():
+@pytest.fixture(scope="module")
+def aligned_linker():
     from splink_tpu import Splink
 
     rng = np.random.default_rng(5)
@@ -192,7 +193,11 @@ def test_linker_uses_device_path_and_falls_back_when_misaligned():
         "max_iterations": 3,
     }
     linker = Splink(s, df=df)
-    df_e = linker.get_scored_comparisons()
+    return linker, linker.get_scored_comparisons()
+
+
+def test_linker_uses_device_path_and_falls_back_when_misaligned(aligned_linker):
+    linker, df_e = aligned_linker
     assert linker._df_e_aligned_with_pairs(df_e)
     out_fast = linker.make_term_frequency_adjustments(df_e)
 
@@ -467,3 +472,307 @@ def test_streaming_tf_link_only_and_mesh():
             one["tf_adjusted_match_prob"].to_numpy(),
             rtol=1e-9,
         )
+
+
+# ---------------------------------------------------------------------------
+# The pass touches only the columns it adds (ROADMAP A4): no copy of the
+# scored frame, no frame-length temporaries. The old construction — deep
+# copy, column inserts, whole-array combine, reorder — is written out here
+# as the reference.
+# ---------------------------------------------------------------------------
+
+
+def _bayes_whole(probs):
+    """bayes_combine as it was: seven arrays of the inputs' length."""
+    num = np.ones_like(np.asarray(probs[0], dtype=np.float64))
+    den = np.ones_like(num)
+    for p in probs:
+        p = np.asarray(p, dtype=np.float64)
+        num = num * p
+        den = den * (1.0 - p)
+    tot = num + den
+    with np.errstate(invalid="ignore", divide="ignore"):
+        return np.where(
+            tot > 0, num / np.maximum(tot, np.finfo(np.float64).tiny), 0.5
+        )
+
+
+def _tf_params():
+    return Params(
+        {
+            "link_type": "dedupe_only",
+            "proportion_of_matches": 0.3,
+            "comparison_columns": [
+                {"col_name": "name", "term_frequency_adjustments": True},
+                {"col_name": "city", "term_frequency_adjustments": True},
+                {"col_name": "dob"},
+            ],
+            "blocking_rules": ["l.dob = r.dob"],
+        }
+    )
+
+
+def _scored_frame(n=700, seed=3, consolidated=False):
+    """A frame shaped like the linker's: float32 probabilities, int64 ids
+    and levels, ``str`` (Arrow-backed where pyarrow is) retained columns
+    with nulls, one block a column as ``_FrameWriter.frame`` hands it out —
+    or consolidated, as a user's own frame usually is. Returns the frame
+    and the token ids of its two flagged columns."""
+    rng = np.random.default_rng(seed)
+    names = np.array(["ann", "bob", "cat", "dan", None], dtype=object)
+    cities = np.array(["x", "y", None], dtype=object)
+    cols = {
+        "match_probability": rng.random(n).astype(np.float32),
+        "unique_id_l": np.arange(n),
+        "unique_id_r": np.arange(n)[::-1].copy(),
+    }
+    token_ids = {}
+    for col, vocab in (("name", names), ("city", cities)):
+        tid_l = rng.integers(0, len(vocab), n)
+        tid_r = np.where(rng.random(n) < 0.6, tid_l, rng.integers(0, len(vocab), n))
+        for side, tid in (("l", tid_l), ("r", tid_r)):
+            cols[f"{col}_{side}"] = pd.array(vocab[tid], dtype="str")
+        cols[f"gamma_{col}"] = rng.integers(-1, 3, n)
+        null = len(vocab) - 1  # the vocabulary's None
+        token_ids[col] = (
+            np.where(tid_l == null, -1, tid_l).astype(np.int32),
+            np.where(tid_r == null, -1, tid_r).astype(np.int32),
+            null,
+        )
+    cols["prob_gamma_name_match"] = rng.random(n).astype(np.float32)
+    # planted: certain and impossible pairs beside any evidence
+    cols["match_probability"][:4] = [0.0, 1.0, 0.5, 0.0]
+    df_e = pd.DataFrame(cols, copy=False)
+    assert df_e._mgr.nblocks == len(df_e.columns)
+    if consolidated:
+        df_e = df_e.copy()
+        assert df_e._mgr.nblocks < len(df_e.columns)
+    return df_e, token_ids
+
+
+def _adjusted_the_old_way(df_e, params, retain, pair_token_ids):
+    from splink_tpu.term_frequencies import (
+        compute_token_adjustment_device,
+        term_frequency_columns,
+    )
+
+    tf_cols = list(term_frequency_columns(params.settings))
+    df = df_e.copy()
+    lam = params.params["λ"]
+    adj_arrays = []
+    for col in tf_cols:
+        if pair_token_ids is not None:
+            adj, _, _ = compute_token_adjustment_device(
+                *pair_token_ids[col][:2], df["match_probability"].to_numpy(),
+                lam, pair_token_ids[col][2],
+            )
+        else:
+            adj, _ = compute_token_adjustment(
+                df[f"{col}_l"].to_numpy(dtype=object),
+                df[f"{col}_r"].to_numpy(dtype=object),
+                df["match_probability"].to_numpy(), lam,
+            )
+        df[f"{col}_adj"] = adj
+        adj_arrays.append(adj)
+    df["tf_adjusted_match_prob"] = _bayes_whole(
+        [df["match_probability"].to_numpy()] + adj_arrays
+    )
+    if not retain:
+        df = df.drop(columns=[f"{c}_adj" for c in tf_cols])
+    lead = ["tf_adjusted_match_prob", "match_probability"]
+    return df[lead + [c for c in df.columns if c not in lead]]
+
+
+@pytest.mark.parametrize("retain", [False, True], ids=["drop_adj", "retain_adj"])
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_adjusted_frame_equals_the_deep_copy_construction(path, retain):
+    params = _tf_params()
+    df_e, token_ids = _scored_frame()
+    ids = token_ids if path == "device" else None
+    want = _adjusted_the_old_way(df_e, params, retain, ids)
+    got = make_adjustment_for_term_frequencies(
+        df_e, params, params.settings,
+        retain_adjustment_columns=retain, pair_token_ids=ids,
+    )
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert list(got.columns[:2]) == ["tf_adjusted_match_prob", "match_probability"]
+    assert ("name_adj" in got.columns) == retain
+    # and again over its own output: the columns it adds replace their
+    # namesakes where they stand, as the in-place writes did
+    again = make_adjustment_for_term_frequencies(
+        got, params, params.settings,
+        retain_adjustment_columns=retain, pair_token_ids=ids,
+    )
+    pd.testing.assert_frame_equal(again, got, check_exact=True)
+
+
+def _buffers(series):
+    """The addresses of the memory a column's values live in."""
+    arr = series.array
+    if hasattr(arr, "__arrow_array__"):
+        return [
+            b.address
+            for chunk in arr.__arrow_array__().chunks
+            for b in chunk.buffers() if b is not None
+        ]
+    return [series.to_numpy().__array_interface__["data"][0]]
+
+
+def _overwrite(frame):
+    """Every column written through ``.loc``: whole columns and single
+    cells, strings and numbers."""
+    for i, c in enumerate(frame.columns):
+        fill = "zz" if frame[c].dtype.kind in "OTU" else 7
+        if i % 2:
+            frame.loc[:, c] = fill
+        else:
+            frame.loc[frame.index[:5], c] = fill
+
+
+@pytest.mark.parametrize("consolidated", [False, True],
+                         ids=["block_a_column", "consolidated"])
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_adjusted_frame_shares_its_input_and_neither_sees_the_others_writes(
+    path, consolidated
+):
+    params = _tf_params()
+    df_e, token_ids = _scored_frame(consolidated=consolidated)
+    # a user's index, with a repeated label: nothing may align by it
+    df_e.index = pd.Index(np.arange(len(df_e)) // 2 * 3)
+    before = df_e.copy(deep=True)
+    ids = token_ids if path == "device" else None
+    out = make_adjustment_for_term_frequencies(
+        df_e, params, params.settings, retain_adjustment_columns=True,
+        pair_token_ids=ids,
+    )
+    pd.testing.assert_frame_equal(df_e, before, check_exact=True)
+    assert out.index.equals(before.index)
+    # no column's bytes were copied: the input's memory IS the output's
+    for c in df_e.columns:
+        assert _buffers(out[c]) == _buffers(df_e[c]), c
+    kept = out.copy(deep=True)
+    _overwrite(out)
+    assert not out[list(before.columns)].equals(before)
+    pd.testing.assert_frame_equal(df_e, before, check_exact=True)
+
+    out = make_adjustment_for_term_frequencies(
+        df_e, params, params.settings, retain_adjustment_columns=True,
+        pair_token_ids=ids,
+    )
+    pd.testing.assert_frame_equal(out, kept, check_exact=True)
+    _overwrite(df_e)
+    assert not df_e.equals(before)
+    pd.testing.assert_frame_equal(out, kept, check_exact=True)
+
+
+def test_tf_frame_span_counts_shared_and_added_columns():
+    from splink_tpu.utils.profiling import begin_run, discard_run, spans
+
+    params = _tf_params()
+    df_e, token_ids = _scored_frame()
+    run = begin_run("test-tf-frame-counts")
+    try:
+        for retain, ids in ((True, token_ids), (False, None)):
+            make_adjustment_for_term_frequencies(
+                df_e, params, params.settings,
+                retain_adjustment_columns=retain, pair_token_ids=ids,
+            )
+        counts = [s["counts"] for s in spans(run) if s["name"] == "tf_frame"]
+    finally:
+        discard_run(run)
+    n_in = len(df_e.columns)
+    assert counts == [
+        {"rows": len(df_e), "shared_columns": n_in, "added_columns": 3},
+        {"rows": len(df_e), "shared_columns": n_in, "added_columns": 1},
+    ]
+
+
+_BLOCK = 1 << 16
+
+
+@pytest.mark.parametrize("factors", [1, 2, 3])
+@pytest.mark.parametrize("n", [0, 1, _BLOCK - 1, _BLOCK, 3 * _BLOCK + 5])
+def test_blocked_bayes_combine_is_bit_equal_to_the_whole_array_form(n, factors):
+    import splink_tpu.term_frequencies as tf
+
+    assert tf._COMBINE_ROWS == _BLOCK
+    rng = np.random.default_rng(1000 * factors + n % 997)
+    probs = [rng.random(n) for _ in range(factors)]
+    planted = np.array([0.0, 1.0, 0.5, np.nextafter(0.0, 1), np.nextafter(1.0, 0)])
+    for p in probs:
+        where = rng.random(n) < 0.2
+        p[where] = rng.choice(planted, int(where.sum()))
+    if n > 8 and factors > 1:
+        # contradictory evidence, at a block's first and last rows too
+        for row in (0, 7, n - 1, min(n - 1, _BLOCK - 1), min(n - 1, _BLOCK)):
+            probs[0][row], probs[-1][row] = 0.0, 1.0
+    for first in (np.float64, np.float32):  # match_probability is float32 on the chip
+        given = [probs[0].astype(first), *probs[1:]]
+        as_given = [p.copy() for p in given]
+        got = bayes_combine(given)
+        want = _bayes_whole(given)
+        assert got.dtype == np.float64 and got.shape == (n,)
+        assert got.tobytes() == want.tobytes()
+        if n > 8 and factors > 1:
+            assert got[0] == 0.5 and got[n - 1] == 0.5
+        for p, q in zip(given, as_given):  # the inputs are read, not written
+            assert p.tobytes() == q.tobytes()
+
+
+def test_bayes_combine_refuses_factors_of_unequal_length():
+    with pytest.raises(ValueError, match="one length"):
+        bayes_combine([np.full(3, 0.5), np.full(1, 0.5)])
+
+
+class _CountingIds(np.ndarray):
+    """The table's ids, counting the takes the check makes of them."""
+
+    takes = 0
+
+    def __getitem__(self, key):
+        type(self).takes += 1
+        return np.asarray(super().__getitem__(key))
+
+
+@pytest.mark.parametrize(
+    "fault", ["none", "swap_in_last_block", "swap_in_first_block", "one_row_short"]
+)
+def test_alignment_check_compares_every_row_a_block_at_a_time(
+    aligned_linker, monkeypatch, fault
+):
+    import splink_tpu.linker as linker_mod
+
+    linker, df_e = aligned_linker
+    block = 64
+    monkeypatch.setattr(linker_mod, "_TAKE_ROWS", block)
+    n = len(df_e)
+    n_blocks = -(-n // block)
+    assert n_blocks > 3 and n % block  # a ragged last block
+    table = linker._ensure_encoded()
+    monkeypatch.setattr(table, "unique_id", table.unique_id.view(_CountingIds))
+    monkeypatch.setattr(_CountingIds, "takes", 0)
+
+    frame = df_e.copy()
+    if fault == "one_row_short":
+        frame = frame.iloc[:-1]
+    elif fault != "none":
+        # two rows of one side exchange their ids: a permutation no sample
+        # of the column need see
+        ids = {c: frame[c].to_numpy().copy() for c in ("unique_id_l", "unique_id_r")}
+        if fault == "swap_in_last_block":
+            col, a = "unique_id_r", n - 1
+            b = next(i for i in range(a - 1, -1, -1) if ids[col][i] != ids[col][a])
+            assert b // block == a // block == n_blocks - 1
+        else:
+            col, a, b = "unique_id_l", 1, n - 2
+            assert ids[col][a] != ids[col][b]
+        ids = ids[col]
+        ids[[a, b]] = ids[[b, a]]
+        frame[col] = ids
+    assert linker._df_e_aligned_with_pairs(frame) is (fault == "none")
+    assert _CountingIds.takes == {
+        "none": 2 * n_blocks,  # both sides, every block
+        "swap_in_last_block": 2 * n_blocks,  # the right side's last
+        "swap_in_first_block": 1,  # and nothing after the block that differs
+        "one_row_short": 0,
+    }[fault]
