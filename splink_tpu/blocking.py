@@ -36,7 +36,10 @@ Pair-set semantics are preserved exactly:
     (/root/reference/splink/blocking.py:133-139): dedupe_only keeps
     ``uid_l < uid_r``; link_only crosses the two tables with the left input
     on the l side; link_and_dedupe orders by (source_table, uid),
-  * empty rules -> cartesian join (with the documented quadratic warning).
+  * empty rules -> every pair (with the documented quadratic warning): the
+    device tier's one keyless group where ``device_blocking`` engages it,
+    ``cartesian_block`` on the host otherwise (the CPU backend under
+    "auto") and as the oracle; a ``keyless_pairs`` span says which.
 
 Rules that are not pure equality conjunctions keep their equality part as the
 join key and evaluate the residual predicate on the joined candidates (or,
@@ -55,6 +58,7 @@ from . import native
 from .check_types import check_types
 from .compat_sql import parse_blocking_rule
 from .data import EncodedTable
+from .utils import profiling
 
 logger = logging.getLogger("splink_tpu")
 
@@ -948,7 +952,7 @@ def block_using_rules(
     link_type = settings["link_type"]
     rules = settings.get("blocking_rules") or []
     if not rules:
-        return cartesian_block(settings, table, n_left, pair_consumer)
+        return _block_every_pair(settings, table, n_left, pair_consumer)
 
     # Pair indices are stored int32 when the table allows (they always do —
     # int32 row indices cover 2^31 rows); at billions of candidate pairs this
@@ -1148,6 +1152,42 @@ def _split_join_keys(
         else:
             asym.append((lc, rc))
     return sym, asym, residual
+
+
+def _block_every_pair(settings, table, n_left, pair_consumer) -> PairIndex:
+    """An empty rule list: every pair of rows. The device tier takes it as
+    ONE keyless group (blocking_device.build_device_plan) where
+    ``device_blocking`` lets it — "on", or "auto" on an accelerator backend
+    for a job worth the warm-up; :func:`cartesian_block` is the host path
+    and the parity oracle. Either way the one ``keyless_pairs`` span says
+    who built the pair ids (``host_built``); the consumer's calls lie inside
+    it, under their own spans."""
+    profiling.count(keyless_rules=1)
+    with profiling.span(
+        "keyless_pairs", groups=1, units=0, host_built=0
+    ) as sp:
+
+        def consumer(i, j):  # a chunk of ids handed over, by either path
+            sp.count(pairs=len(i), chunks=1)
+            if pair_consumer is not None:
+                pair_consumer(i, j)
+
+        mode = settings.get("device_blocking", "auto")
+        if mode in ("auto", "on"):
+            from .blocking_device import device_block_rules
+
+            with _PairSink(
+                settings.get("spill_dir"), _idx_dtype(table.n_rows)
+            ) as sink:
+                out = device_block_rules(
+                    settings, table, n_left, sink, consumer, mode
+                )
+                if out is not None:
+                    return out
+                sink.abort()  # nothing sunk: the host path brings its own
+        out = cartesian_block(settings, table, n_left, consumer)
+        sp.count(host_built=out.n_pairs)
+        return out
 
 
 def _all_pairs(table: EncodedTable, link_type: str, n_left: int | None):

@@ -34,9 +34,17 @@ kind of accelerator parallelism):
      all-at-once. Chunk shapes are power-of-two stable, so steady-state
      emission never recompiles.
 
-The host path in blocking.py is retained as the fallback (cartesian rules,
-residuals the device compiler rejects, degenerate near-constant keys,
->=2^31 key codes) and as the parity oracle: the device pair set is
+An EMPTY rule list — the reference compares every pair of rows then — is
+the limiting case of a hot key and takes the same road: ONE keyless group of
+all rows (a constant key code, which the segment sort orders by uid rank like
+any key), tiled into the same units and emitted in the same budgeted chunks;
+blocking._block_every_pair's ``keyless_pairs`` span says who built the pair
+ids (``host_built`` 0 here: nobody's numpy).
+
+The host path in blocking.py is retained as the fallback (a keyless rule
+WITH a residual, residuals the device compiler rejects, a group past
+MAX_UNITS_PER_GROUP, >=2^31 key codes; the CPU backend under "auto") and as
+the parity oracle: the device pair set is
 bit-equal AS A SET to the host pair set on every supported shape
 (tests/test_blocking_device.py; ``make blocking-smoke`` gates it).
 
@@ -82,7 +90,7 @@ from .pairgen import (
 )
 from .utils import kernel_registry
 from .utils.kernel_registry import mesh_key
-from .utils.profiling import dispatched, fetch, fetch_pooled, poll
+from .utils.profiling import count_here, dispatched, fetch, fetch_pooled, poll
 
 logger = logging.getLogger("splink_tpu")
 
@@ -102,6 +110,10 @@ AUTO_MIN_PAIRS = 1 << 21
 _D2H_DEPTH = 2
 
 _IMAX = np.iinfo(np.int32).max
+
+# The one rule of a plan built from an EMPTY rule list: every pair of rows
+# (link_only: every left x right pair), as the reference compares them.
+KEYLESS_RULE = "<no blocking rule: every pair>"
 
 
 def _pow2(n: int) -> int:
@@ -365,12 +377,17 @@ def build_device_plan(
     chunk: int | None = None,
 ) -> DeviceBlockPlan | None:
     """Build the device join plan, or None when a rule needs the host path
-    (cartesian, an uncompilable residual, >=2^31 key codes, or a
-    near-constant key exceeding the per-group unit cap)."""
+    (a keyless rule with a residual, an uncompilable residual, >=2^31 key
+    codes, or a group exceeding the per-group unit cap).
+
+    An EMPTY rule list (the reference compares every pair then) is the one
+    KEYLESS rule: ONE group holding every row — a constant key code, sorted
+    and tiled into the same bounded triangles / rectangles as any hot key
+    (10,000 rows = 5 chunks = 15 units)."""
     chunk = chunk or CHUNK
     link_type = settings["link_type"]
     rules = settings.get("blocking_rules") or []
-    if not rules or table.n_rows == 0:
+    if table.n_rows == 0:
         return None
     n = table.n_rows
     if link_type == "link_only":
@@ -385,11 +402,14 @@ def build_device_plan(
     res_idx: dict = {}
     res_aux: dict = {}
     parsed = []
+    if not rules:
+        zeros = np.zeros(n, np.int64)  # one group, all rows
+        parsed.append((zeros, zeros, False, None, None))
     for rule in rules:
         eq_pairs, residual = parse_blocking_rule(rule)
         sym, asym, residual = _split_join_keys(eq_pairs, residual)
         if not sym and not asym:
-            return None  # cartesian rule: host path (with its warning)
+            return None  # every pair against a residual: host path
         if asym:
             codes_l, codes_r = _key_codes_asym(table, sym, asym)
         else:
@@ -421,6 +441,7 @@ def build_device_plan(
     sort_fn = make_segment_sort_fn()
     all_rows = np.arange(n, dtype=np.int32)
     plans: list[DeviceRule] = []
+    rules = rules or [KEYLESS_RULE]
     codes_l_all = np.empty((len(rules), n), np.int32)
     codes_r_all = np.empty((len(rules), n), np.int32)
     for r, (codes_l, codes_r, is_asym, residual, res_fn) in enumerate(parsed):
@@ -828,6 +849,9 @@ def device_block_rules(
         "device blocking: %d candidate positions, %d rules",
         plan.n_candidates, len(plan.rules),
     )
+    # onto whatever is open: the ``blocking`` stage, or the ``keyless_pairs``
+    # span of blocking._block_every_pair (10,000 rows, no rule: 15)
+    count_here(units=sum(len(rp.ua) for rp in plan.rules))
     for _r, i, j in iter_device_pairs(plan, batch):
         sink.append(i, j)
         if pair_consumer is not None:

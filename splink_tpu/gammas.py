@@ -786,6 +786,29 @@ def _string_evals(spec: dict) -> int:
     ))
 
 
+# The fewest rows the device's table has on a TPU. XLA's TPU compiler stages a
+# gather operand of under ~300,000 rows row-major in its fast memory and then
+# writes every gathered row padded from its 12-30 words to 128 lanes: 2,070 B
+# of scratch a pair position for a 10,000-row table of 12 words, against 336 B
+# from a table past that size, whose rows it gathers column-major (PERF.md
+# section 6, PR 39: no batch above 2^23 compiled for the small table, and the
+# program moved ten times the bytes). Neither a transposed operand nor a layout
+# constraint on the result changes its mind; rows nobody indexes do.
+# tests/test_mesh_tpu_compile.py holds the scratch of both sizes.
+_MIN_TPU_TABLE_ROWS = 1 << 19
+
+
+def _device_table(packed: np.ndarray) -> np.ndarray:
+    """``packed`` as it is uploaded: on a TPU with zero rows appended up to
+    ``_MIN_TPU_TABLE_ROWS`` (no pair index reaches them)."""
+    short = _MIN_TPU_TABLE_ROWS - packed.shape[0]
+    if short <= 0 or jax.default_backend() != "tpu":
+        return packed
+    return np.concatenate(
+        [packed, np.zeros((short, packed.shape[1]), packed.dtype)]
+    )
+
+
 class GammaProgram:
     """One encoded table packed on the device, and the gamma kernels of its
     settings. The table (``_packed``) belongs to this program; the jitted
@@ -813,6 +836,7 @@ class GammaProgram:
                 charset_specs=charset_specs_for(settings),
             )
             sp.count(lanes=int(packed.shape[1]))  # words of a packed row
+        packed = _device_table(packed)
         with span("h2d_put", bytes=packed.nbytes):
             self._packed = jnp.asarray(packed)
         self._layout = layout

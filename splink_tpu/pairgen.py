@@ -40,6 +40,11 @@ predicate holds (the reference's ``AND NOT ifnull(prev, false)``,
 output stream filters on the sentinel. The kernel hands the row pairs it
 decoded back beside the ids, so the stream never decodes a position again.
 
+An EMPTY rule list (every pair of rows, the reference's behaviour with no
+rule) is one keyless group of all rows in the same plan: past
+``max_resident_pairs`` (100,000 rows are 5.0e9 pairs) the all-pairs job runs
+on the virtual index like any other.
+
 Supported: all three link types on a single device — link_and_dedupe
 self-joins the concatenated table ordered by (source, uid), link_only
 tiles left x right group rectangles. Residual (non-equality) predicates
@@ -50,7 +55,8 @@ to 2*pos or the odd insertion rank; cross-column compares re-rank over
 the union vocabulary), numeric contexts use NaN-null float arrays with
 the host's pd.to_numeric coercion applied once at plan build. Predicates
 the device can't honour (unsortable mixed-type columns, literal/column
-type mismatches) reject the plan and fall back to host blocking. Note:
+type mismatches) reject the plan and fall back to host blocking, as does a
+rule with no equality conjunct beside a residual. Note:
 on TPU numeric residual thresholds evaluate in f32 (the chip has no
 f64), so a pair exactly on a threshold may land differently than the
 f64 host path — the CPU tier (x64) is bit-identical.
@@ -74,6 +80,7 @@ from .blocking import (
 )
 from .data import EncodedTable
 from .gammas import int32_histogram, pattern_ids_fit_uint16
+from .utils import profiling
 from .utils.kernel_registry import mesh_key
 
 # Unit extent bound. 2048 keeps the triangle discriminant (2s-1)^2 < 2^24
@@ -918,15 +925,34 @@ def build_virtual_plan(
     settings: dict, table: EncodedTable, n_left: int | None = None,
     chunk: int | None = None,
 ) -> VirtualPlan | None:
-    """Build the device-decodable plan, or None when unsupported
-    (cartesian fallback, a rule with no equality conjunction, a residual
-    predicate the device compiler can't honour, or a degenerate
-    near-constant blocking key — see MAX_UNITS_PER_GROUP)."""
+    """Build the device-decodable plan, or None when unsupported (a rule
+    with no equality conjunction, a residual predicate the device compiler
+    can't honour, or a group past MAX_UNITS_PER_GROUP).
+
+    An EMPTY rule list (the reference compares every pair then) is one
+    KEYLESS rule: one group holding every row under a constant key code,
+    tiled into units as any hot key is (10,000 rows = 5 chunks = 15 units);
+    blocking_device.build_device_plan builds the same group for the
+    materialised regime. Its ``keyless_pairs`` span (the one
+    blocking._block_every_pair opens on the materialised paths) says that
+    nobody's numpy made the pair ids."""
+    if settings.get("blocking_rules"):
+        return _build_virtual_plan(settings, table, n_left, chunk)
+    profiling.count(keyless_rules=1)
+    with profiling.span(
+        "keyless_pairs", groups=1, chunks=0, host_built=0
+    ) as sp:
+        plan = _build_virtual_plan(settings, table, n_left, chunk)
+        if plan is not None:
+            sp.count(units=len(plan.rules[0].ua), pairs=plan.rules[0].total)
+    return plan
+
+
+def _build_virtual_plan(settings, table, n_left, chunk):
     chunk = chunk or CHUNK
     link_type = settings["link_type"]
     rules = settings.get("blocking_rules") or []
-    if not rules:
-        return None
+    keyless = not rules
     parsed_cols = []
     residuals: list[tuple[str | None, object]] = []
     res_ops: list[np.ndarray] = []
@@ -984,10 +1010,14 @@ def build_virtual_plan(
         ranks, _ = _uid_ranks(table, link_type)
         uid_codes = _uid_mask_codes(table, link_type)
 
+    if keyless:
+        parsed_cols, residuals = [None], [(None, None)]
     plans: list[RulePlan] = []
-    codes_all = np.empty((len(rules), n), np.int32)
+    codes_all = np.empty((len(parsed_cols), n), np.int32)
     for r, join_cols in enumerate(parsed_cols):
-        codes = _key_codes(table, join_cols)
+        codes = (
+            np.zeros(n, np.int64) if keyless else _key_codes(table, join_cols)
+        )
         codes_all[r] = codes.astype(np.int32)  # codes < n <= 2^31
         if link_type in ("dedupe_only", "link_and_dedupe"):
             rows = np.flatnonzero(codes >= 0).astype(np.int32)

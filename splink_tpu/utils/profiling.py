@@ -261,6 +261,14 @@ def count(**counts) -> None:
             return
 
 
+def count_here(**counts) -> None:
+    """Add to the counts of the innermost open span of this thread, whatever
+    its kind: work a callee reports to whoever opened a span round it."""
+    stack = getattr(_LOCAL, "stack", None)
+    if stack:
+        stack[-1].count(**counts)
+
+
 def fetch(x, via=np.asarray):
     """``np.asarray(x)`` for a device array (or each array of a tuple),
     under a ``d2h_wait`` span: how the driver thread blocks on the device —

@@ -322,8 +322,10 @@ def test_pair_consumer_chunks_cover_stream():
 
 
 def test_unsupported_shapes_fall_back():
-    """Cartesian rules and monster groups reject the device plan; the host
-    path serves them (block_using_rules still answers)."""
+    """A keyless rule beside a residual and monster groups reject the device
+    plan; the host path serves them (block_using_rules still answers). No
+    rule at all is NOT among them: one keyless group
+    (tests/test_cartesian_deployment.py has its parity)."""
     # a rule with no equality condition anywhere in the list
     s = _settings(["l.amount < r.amount"])
     t = encode_table(_df(25, 18), s)
@@ -332,6 +334,11 @@ def test_unsupported_shapes_fall_back():
         warnings.simplefilter("ignore")
         host, dev, _, _ = _block_both(s, t)
     assert dev == host
+    s = _settings([])
+    plan = build_device_plan(s, t)
+    assert [len(r.ua) for r in plan.rules] == [1] and plan.n_candidates == 300
+    host, dev, _, _ = _block_both(s, t, chunk=64)
+    assert dev == host and len(host) == 300
 
 
 def test_monster_group_falls_back(monkeypatch):
@@ -498,12 +505,24 @@ def test_spill_block_rules_settings_shapes(tmp_path):
     )
     assert m["meta"]["n_shards"] == 2
     assert {seg["shard"] for seg in m["segments"]} <= {0, 1}
-    # cartesian rule: no device plan, caller falls back
+    # a keyless rule WITH a residual: no device plan, caller falls back
     s2 = _settings(["l.amount < r.amount"])
     t2 = encode_table(
         _df(25, 18).assign(amount=np.arange(25.0)), s2
     )
     assert spill_block_rules(s2, t2, None, str(tmp_path / "no")) is None
+    # NO rule at all: one keyless group of every row in the same plan, so
+    # the spill store holds every pair once, as the host oracle orders them
+    s3 = _settings([], emit_shard_chunks=2, blocking_chunk_pairs=256)
+    t3 = encode_table(_df(60, 20), s3)
+    pi3 = spill_block_rules(s3, t3, None, str(tmp_path / "all"))
+    assert pi3 is not None and pi3.n_pairs == 60 * 59 // 2
+    from splink_tpu.blocking import cartesian_block
+
+    want = cartesian_block(s3, t3)
+    assert set(zip(pi3.idx_l.tolist(), pi3.idx_r.tolist())) == set(
+        zip(want.idx_l.tolist(), want.idx_r.tolist())
+    )
 
 
 # ----------------------------------------------------------------------

@@ -41,6 +41,36 @@ def test_jaro_winkler_levels():
     assert G[:, 0].tolist() == [2, 2, 0, -1]
 
 
+def test_device_table_has_a_floor_of_rows_on_a_tpu_and_only_there(monkeypatch):
+    """On a TPU the uploaded table never has fewer than _MIN_TPU_TABLE_ROWS rows
+    (the compiler pads the gathered rows of a smaller one to 128 lanes:
+    tests/test_mesh_tpu_compile.py); the rows appended are zeros that no pair
+    index reaches, so the levels are those of the table as packed."""
+    import jax
+
+    from splink_tpu import gammas
+
+    df = pd.DataFrame({"unique_id": range(5),
+                       "name": ["martha", "martha", "marhta", "mx", None]})
+    cols = [{"col_name": "name", "num_levels": 2, "comparison": {"kind": "exact"}}]
+    il, ir = _pairs_vs_first(df)
+    prog, _ = _program(cols, df)
+    want = prog.compute(il, ir)
+    assert prog._packed.shape[0] == 5 and want[:, 0].tolist() == [1, 0, 0, -1]
+    packed = np.arange(40, dtype=np.uint32).reshape(10, 4)
+    assert gammas._device_table(packed) is packed  # this backend: as packed
+    monkeypatch.setattr(gammas, "_MIN_TPU_TABLE_ROWS", 16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    padded = gammas._device_table(packed)
+    assert padded.shape == (16, 4) and padded.dtype == packed.dtype
+    assert np.array_equal(padded[:10], packed) and not padded[10:].any()
+    tall = np.zeros((16, 4), np.uint32)
+    assert gammas._device_table(tall) is tall  # at the floor: as packed
+    prog, _ = _program(cols, df)
+    assert prog._packed.shape[0] == 16
+    assert np.array_equal(prog.compute(il, ir), want)
+
+
 def test_exact_levels_and_nulls():
     df = pd.DataFrame(
         {"unique_id": range(4), "name": ["ann", "ann", "bob", None]}

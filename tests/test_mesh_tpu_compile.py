@@ -156,3 +156,49 @@ def test_case_library_kernel_compiles_for_one_chip(topo, uncached, monkeypatch):
     calls = [ln for ln in compiled.as_text().splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(calls) == 7, len(calls)
+
+
+def test_small_table_gathers_rows_in_real_bytes(topo, uncached, monkeypatch):
+    """The cell ``c2_dedupe_cartesian``'s gamma program (configuration
+    ``baseline_c2``: two Jaro-Winkler columns, a 12-word row, 10,000 rows).
+    From a table that small the TPU's compiler writes every gathered row
+    padded to 128 lanes — over 1,500 B of scratch a pair position, so no batch
+    above 2^23 fitted the chip — and from the table as ``GammaProgram`` uploads
+    it on a TPU (``gammas._device_table``: rows up to ``_MIN_TPU_TABLE_ROWS``)
+    it gathers column-major like the large tables of the other cells: under
+    400 B a position. If the first reading ever falls, the floor can go."""
+    from chipbench import datagen
+    from splink_tpu import Splink, gammas
+
+    with open(os.path.join(ROOT, "chipbench", "configs", "baseline_c2.json")) as f:
+        config = json.load(f)
+    gen = {k: v for k, v in config["generator"].items()
+           if k not in ("kind", "population_seed", "rows")}
+    rows = config["generator"]["rows"]
+    people = datagen.make_people(rows=rows, seed=config["generator"]["population_seed"], **gen)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the table and kernels of the chip
+    settings = copy.deepcopy(config["settings"])
+    with pytest.warns(UserWarning, match="quadratic"):
+        linker = Splink(settings, df=people)
+    linker._ensure_encoded()
+    program = linker._ensure_pattern_program()
+    lanes = program._packed.shape[1]
+    assert program._packed.shape == (gammas._MIN_TPU_TABLE_ROWS, 12)
+    assert not np.asarray(program._packed[rows:]).any()  # rows nobody indexes
+    one = SingleDeviceSharding(topo.devices[0])
+    batch = 1 << 20
+    fn = gammas._jit_gamma_batch(program._parts)
+
+    def scratch_per_position(table_rows):
+        args = (jax.ShapeDtypeStruct((table_rows, lanes), jnp.uint32, sharding=one),
+                jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one),
+                jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one))
+        with jax.enable_x64(False):
+            compiled = fn.lower(*args).compile()
+        calls = [ln for ln in compiled.as_text().splitlines()
+                 if "custom-call(" in ln and "tpu_custom_call" in ln]
+        assert len(calls) == 2, len(calls)  # the two Jaro-Winkler columns
+        return compiled.memory_analysis().temp_size_in_bytes / batch
+
+    assert scratch_per_position(rows) > 1500
+    assert scratch_per_position(program._packed.shape[0]) < 400

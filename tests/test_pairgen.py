@@ -128,9 +128,10 @@ def test_virtual_pairs_equal_host_blocking_link_only(chunk):
 
 def test_unsupported_shapes_fall_back():
     df = _df(40, seed=1)
-    # cartesian
+    # no rule at all is one keyless group of every row, not a fallback
     s = _settings([])
-    assert build_virtual_plan(s, encode_table(df, s)) is None
+    plan = build_virtual_plan(s, encode_table(df, s))
+    assert plan.n_candidates == 40 * 39 // 2 and len(plan.rules) == 1
     # rule with no equality conjunction at all
     s = _settings(["l.dob != r.dob"])
     assert build_virtual_plan(s, encode_table(df, s)) is None

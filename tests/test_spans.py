@@ -248,6 +248,17 @@ def test_count_targets_the_innermost_stage(scope):
     assert by_name["sub"]["counts"] == {}
 
 
+def test_count_here_targets_the_innermost_span_of_any_kind(scope):
+    profiling.count_here(units=1)  # nothing open: nothing to count into
+    with StageTimer("stage") as st:
+        profiling.count_here(units=2)
+        with span("sub") as sub:
+            profiling.count_here(units=3)
+            count(units=4)  # the stage's, as ever
+        profiling.count_here(units=5)
+    assert sub.counts == {"units": 3} and st.counts == {"units": 11}
+
+
 def test_add_closed_lands_under_the_open_span_or_nowhere(scope):
     add_closed("jax_lower", "build", 0.5, fun="f")  # nothing open: dropped
     assert spans() == []
