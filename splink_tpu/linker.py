@@ -14,9 +14,12 @@ from __future__ import annotations
 
 import contextlib
 import copy
+import functools
 import logging
+import operator
 import os
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
 
 import jax.numpy as jnp
@@ -180,15 +183,29 @@ class _FrameWriter:
         own columns). ``levels[c]``, ``prob_m[c]`` and ``prob_u[c]`` are
         comparison column c's values and ``p``, ``z`` the scores and match
         logits: one entry a pair of the chunk — or, with the pairs' pattern
-        ids in ``by``, one a PATTERN, taken by id under ``lut_gather``."""
+        ids in ``by``, one a PATTERN, taken by id.
+
+        A chunk of ``_POOL_ROWS`` rows or more is filled by a pool of host
+        threads (``pooled_rows``: its rows, ``fill_threads``: the workers;
+        0 and 1 where the chunk was filled on the driver alone). The
+        pattern-id takes run together with the retained columns' takes, so
+        with ``by`` a ``lut_gather`` span covers the driver's wait for the
+        whole fill; the TF fold (a device call) stays on the driver and runs
+        before that wait, while the pool fills the other columns."""
         with span("assemble_frame", rows=len(il), in_place_rows=len(il)) as sp:
             if self.cols is None:
                 self._allocate()
             sp.count(**self._counts())
-            self._write(il, ir, levels, p, prob_m, prob_u, z, by)
+            threads = self._write(il, ir, levels, p, prob_m, prob_u, z, by)
+            sp.count(pooled_rows=len(il) if threads > 1 else 0,
+                     fill_threads=threads)
             self.chunks += 1
 
-    def _write(self, il, ir, levels, p, prob_m, prob_u, z, by) -> None:
+    def _write(self, il, ir, levels, p, prob_m, prob_u, z, by) -> int:
+        """Fills the chunk's rows of every column; returns the threads that
+        did it. Each task writes a part of the frame no other task writes —
+        a row span of a numpy column, or one side of an extension column —
+        so the bytes are the driver's own, whoever wrote them."""
         start, stop = self.rows, self.rows + len(il)
         if stop > self.n:
             raise ValueError(
@@ -206,24 +223,48 @@ class _FrameWriter:
         else:
             _check_index(by, len(p))
             gather = span("lut_gather", rows=len(il))
-        with gather:
-            _put(cols["match_probability"], p, by)
-            for c, names in enumerate(self._scored):
-                for name, src in zip(names, (levels, prob_u, prob_m)):
-                    _put(cols[name], src[c], by)
-            if by is not None and z is not None:
-                z = z[by]
-        if self._tf_ctx is not None and len(il):
-            self._linker._tf_fold_pairs(
-                z, il, ir, self._tf_ctx, out=cols["tf_match_probability"]
-            )
+        # (out, src, index) of every numpy column, and the extension columns'
+        # takes: whole columns, appended to the column's chunks
+        puts = [(cols["match_probability"], p, by)]
+        for c, names in enumerate(self._scored):
+            for name, src in zip(names, (levels, prob_u, prob_m)):
+                puts.append((cols[name], src[c], by))
+        tasks = []
         for name, (values, side) in self._retained.items():
             idx = ir if side else il
             if isinstance(values, np.ndarray):
-                _put(cols[name], values, idx)
+                puts.append((cols[name], values, idx))
             else:  # a pandas array: taken as a column, typed as it arrives
-                cols[name].append(values.take(idx))
+                tasks.append(functools.partial(_take_into, cols[name], values, idx))
+        pooled = len(il) >= _POOL_ROWS
+        step = _SPAN_BLOCKS * _TAKE_ROWS if pooled else None
+        # the takes first: the longest tasks, none of them split
+        tasks += [functools.partial(_put, *part)
+                  for put in puts for part in _row_spans(*put, step)]
+        threads = max(min(_host_cores(), len(tasks)), 1) if pooled else 1
+        fold = None
+        if self._tf_ctx is not None and len(il):
+            def fold():
+                logits = z[by] if by is not None and z is not None else z
+                self._linker._tf_fold_pairs(
+                    logits, il, ir, self._tf_ctx, out=cols["tf_match_probability"]
+                )
+        pool = (ThreadPoolExecutor(threads, thread_name_prefix="frame_fill")
+                if threads > 1 else None)
+        try:
+            # the pool's tasks are all submitted here, the driver's wait after
+            # the fold; without a pool the driver runs them in the same place
+            done = (map if pool is None else pool.map)(operator.call, tasks)
+            if fold is not None:
+                fold()
+            with gather:
+                for _ in done:  # the first task's exception raises here
+                    pass
+        finally:
+            if pool is not None:
+                pool.shutdown(wait=True, cancel_futures=True)
         self.rows = stop
+        return threads
 
     def frame(self) -> "pd.DataFrame":
         """The rows written, as the frame the caller keeps, under one
@@ -276,6 +317,41 @@ def _put(out: np.ndarray, src: np.ndarray, by: np.ndarray | None) -> None:
     for a in range(0, len(by), _TAKE_ROWS):
         b = a + _TAKE_ROWS
         np.take(src, by[a:b], out=out[a:b], mode="clip")
+
+
+# rows a chunk has before a pool of host threads fills its columns: under
+# it, starting the pool costs more than the threads save (tier-1's frames,
+# the streaming API's short chunks stay on the driver)
+_POOL_ROWS = 1 << 18
+# blocks of _TAKE_ROWS rows in one pooled task of a numpy column (2^21
+# rows), so that a frame of few, long columns still gives every worker a share
+_SPAN_BLOCKS = 32
+
+
+def _host_cores() -> int:
+    """The cores this process may run on."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # pragma: no cover (no affinity on this OS)
+        return os.cpu_count() or 1
+
+
+def _row_spans(out: np.ndarray, src: np.ndarray, by: np.ndarray | None,
+               step: int | None):
+    """``_put``'s arguments for consecutive spans of ``step`` rows of
+    ``out`` (one span of all of them without a step)."""
+    if step is None:
+        yield out, src, by
+        return
+    for a in range(0, len(out), step):
+        b = a + step
+        yield (out[a:b], src[a:b], None) if by is None else (out[a:b], src, by[a:b])
+
+
+def _take_into(chunks: list, values, idx: np.ndarray) -> None:
+    """An extension column's take, appended to the column's chunks (one
+    take a column a chunk: the chunk order is the writes' order)."""
+    chunks.append(values.take(idx))
 
 
 class Splink:

@@ -448,3 +448,77 @@ def test_takes_a_block_of_rows_at_a_time_write_the_same_frame(monkeypatch):
     got = _one_frame_linker("virtual_kept_ids").get_scored_comparisons()
     assert len(got) > 10 * 1024  # chunks of 1024 rows, eleven blocks each
     pd.testing.assert_frame_equal(got, want, check_exact=True)
+
+
+# ----------------------------------------------------------------------
+# the columns filled by a pool of host threads (ROADMAP A1c, step 1)
+# ----------------------------------------------------------------------
+#
+# A chunk of ``_POOL_ROWS`` rows or more is filled by a pool; below it by
+# the driver alone. The module constant is set to 0 (every chunk pooled)
+# and to above any frame (every chunk serial); ``_TAKE_ROWS`` is cut and
+# ``_SPAN_BLOCKS`` set to 3 so that the numeric columns split into several
+# row spans of several blocks at this size.
+
+# name -> (the _ONE_FRAME case, how the frames are asked for, _TAKE_ROWS)
+_POOLED = {
+    "virtual_by_pattern": ("virtual_kept_ids", "frame", 64),
+    "resident_tf_fold_strings": ("link_strings", "frame", 64),
+    "stream_chunks": ("materialised_patterns", "stream", 64),
+    "zero_rows": ("zero_pairs_resident", "frame", 64),
+    # blocks of 100 rows, spans of 300: no chunk a multiple of either
+    "rows_off_the_blocks": ("virtual_recompute", "frame", 100),
+}
+
+
+def _frames(linker, how):
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # "no candidate pairs" where none are due
+        if how == "stream":
+            return list(linker.stream_scored_comparisons_after_em())
+        return [linker.manually_apply_fellegi_sunter_weights()]
+
+
+def _arrow_chunks(column):
+    pa_array = getattr(column.array, "_pa_array", None)
+    return None if pa_array is None else pa_array.num_chunks
+
+
+@pytest.mark.parametrize("case", list(_POOLED))
+def test_pooled_fill_writes_the_serial_fill_bit_for_bit(case, monkeypatch):
+    import splink_tpu.linker as linker_module
+
+    one_frame, how, take_rows = _POOLED[case]
+    linker = _one_frame_linker(one_frame)
+    _one_frame(linker, one_frame)  # EM, or the weights the case applies
+    monkeypatch.setattr(linker_module, "_TAKE_ROWS", take_rows)
+    monkeypatch.setattr(linker_module, "_SPAN_BLOCKS", 3)
+    monkeypatch.setattr(linker_module, "_POOL_ROWS", 1 << 62)
+    serial = _frames(linker, how)
+    monkeypatch.setattr(linker_module, "_POOL_ROWS", 0)
+    before = len(spans(run=linker.run_id))
+    pooled = _frames(linker, how)
+    assert len(pooled) == len(serial) >= (3 if how == "stream" else 1)
+    for got, want in zip(pooled, serial):
+        assert got.equals(want)
+        assert list(got.columns) == list(want.columns)
+        assert list(got.dtypes) == list(want.dtypes)
+        for name in want.columns:
+            a, b = got[name].array, want[name].array
+            assert type(a) is type(b), name
+            assert _arrow_chunks(got[name]) == _arrow_chunks(want[name]), name
+            if b.dtype.kind in "fiu":  # the same bits, NaN payloads and all
+                assert got[name].to_numpy().tobytes() == want[name].to_numpy().tobytes()
+    # the case is what its name says, and the pool did the writing
+    frames = [s["counts"] for s in spans(run=linker.run_id)[before:]
+              if s["name"] == "assemble_frame"]
+    assert frames and all(c["pooled_rows"] == c["rows"] for c in frames)
+    assert (sum(c["rows"] for c in frames) == 0) == (case == "zero_rows")
+    if case == "resident_tf_fold_strings":
+        [frame] = pooled
+        assert "tf_match_probability" in frame and frame["height_l"].dtype == np.float64
+        assert _arrow_chunks(frame["first_name_l"]) == 1
+    if how == "stream" or case == "virtual_by_pattern":
+        assert len(frames) > 2
+    if case == "rows_off_the_blocks":
+        assert any(c["rows"] % 100 and c["rows"] > 300 for c in frames)

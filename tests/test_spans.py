@@ -493,6 +493,74 @@ def test_tf_frame_counts_the_columns_it_shares_and_the_columns_it_adds(job):
     }]
 
 
+@pytest.mark.parametrize("pool_rows", [1, 1 << 62], ids=["above", "below"])
+@pytest.mark.parametrize("make", sorted(_JOBS))
+def test_assemble_frame_counts_the_rows_its_pool_of_threads_wrote(
+        make, pool_rows, monkeypatch):
+    """A chunk of ``_POOL_ROWS`` rows or more is filled by a pool of host
+    threads: ``pooled_rows`` == ``rows`` and ``fill_threads`` the workers
+    (more than one where the process may use more than one core); below it
+    ``pooled_rows`` is 0 and ``fill_threads`` 1. Either way ``lut_gather``
+    stays the child of its chunk's ``assemble_frame`` with the chunk's rows,
+    and the TF fold's uploads stay the driver's, under ``assemble_frame``."""
+    import splink_tpu.linker as linker_module
+
+    monkeypatch.setattr(linker_module, "_POOL_ROWS", pool_rows)
+    linker = _JOBS[make]()
+    table = spans(run=linker.run_id)
+    by_id = {s["id"]: s for s in table}
+    frames = [s for s in table if s["name"] == "assemble_frame"]
+    assert frames
+    cores = linker_module._host_cores()
+    for frame in frames:
+        counts = frame["counts"]
+        assert counts["rows"] > 0
+        if pool_rows == 1:
+            assert counts["pooled_rows"] == counts["rows"]
+            assert (counts["fill_threads"] > 1) == (cores > 1)
+        else:
+            assert counts["pooled_rows"] == 0 and counts["fill_threads"] == 1
+    gathers = [s for s in table if s["name"] == "lut_gather"]
+    assert len(gathers) == (len(frames) if make == "dedupe" else 0)
+    for gather in gathers:
+        parent = by_id[gather["parent"]]
+        assert parent["name"] == "assemble_frame"
+        assert gather["counts"] == {"rows": parent["counts"]["rows"]}
+    if make == "link_tf":
+        [frame] = frames
+        puts = [s for s in table if s["name"] == "h2d_put" and s["parent"] == frame["id"]]
+        assert puts  # the fold's, opened on the driver
+
+
+def test_a_failing_fill_task_raises_from_write_and_leaves_no_thread(monkeypatch):
+    """The first exception a pool task raises is raised from ``write()``,
+    and the pool's threads are gone when it has."""
+    import itertools
+
+    import splink_tpu.linker as linker_module
+
+    class Planted(RuntimeError):
+        pass
+
+    linker = _dedupe_job()
+    calls = itertools.count()
+    put = linker_module._put
+
+    def failing_put(out, src, by):
+        if next(calls) == 2:
+            raise Planted("the third task")
+        put(out, src, by)
+
+    monkeypatch.setattr(linker_module, "_POOL_ROWS", 1)
+    monkeypatch.setattr(linker_module, "_put", failing_put)
+    with pytest.raises(Planted, match="the third task"):
+        linker.manually_apply_fellegi_sunter_weights()
+    assert next(calls) > 2
+    assert not [t for t in threading.enumerate() if t.name.startswith("frame_fill")]
+    [frame] = [s for s in spans(run=linker.run_id) if s["name"] == "assemble_frame"][-1:]
+    assert "pooled_rows" not in frame["counts"]  # the chunk was never written
+
+
 def test_numeric_only_frame_counts_no_string_column():
     """Nothing retained (the config-4 cells' frame): ids, levels and
     probabilities only, so neither counter finds a string column."""
