@@ -1484,6 +1484,32 @@ def pattern_counts_from_gammas(
     return total
 
 
+@functools.partial(jax.jit, static_argnames=("levels",))
+def level_counts(G, levels: tuple[int, ...]):
+    """int32[C, max(levels) + 1]: per column c of the gamma matrix ``G``
+    (int8[n, C], on the device), the count at level -1 (null), 0, ...,
+    ``levels[c] - 1`` — ``linker._gamma_histograms``'s bins, a value below
+    -1 or above ``levels[c] - 1`` counted in the end bin it is nearest, and
+    a column's bins past its own ``levels[c] + 1`` left 0. One compare-and-sum
+    on ``G`` as it is, which XLA fuses into a single read of it: no
+    (n, C, L) temporary, no widened copy. Exact while n < 2^31 (the resident
+    regime's ``max_resident_pairs``)."""
+    n, n_cols = G.shape
+    top = max(levels)
+    # at_least[c, k]: the values of column c at level k or above (k < L_c)
+    at_least = jnp.sum(G[:, :, None] >= jnp.arange(top, dtype=G.dtype),
+                       axis=0, dtype=jnp.int32)
+    at_least = jnp.where(
+        jnp.arange(top, dtype=jnp.int32) < jnp.asarray(levels, jnp.int32)[:, None],
+        at_least, 0,
+    )
+    at_least = jnp.concatenate([
+        jnp.full((n_cols, 1), n, jnp.int32), at_least,
+        jnp.zeros((n_cols, 1), jnp.int32),
+    ], axis=1)
+    return at_least[:, :-1] - at_least[:, 1:]
+
+
 def pattern_rows_for(level_counts: list[int], ids: np.ndarray) -> np.ndarray:
     """(len(ids), C) int8: the gamma vector each mixed-radix pattern id of
     ``ids`` decodes to."""

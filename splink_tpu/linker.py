@@ -36,7 +36,7 @@ from .em import (
     score_pairs_with_intermediates_logits,
     score_pairs_with_logits,
 )
-from .gammas import GammaProgram, register_comparison  # noqa: F401 (re-export)
+from .gammas import GammaProgram, level_counts, register_comparison  # noqa: F401 (re-export)
 from .models.fellegi_sunter import FSParams
 from .params import Params, load_params_from_json
 from .parallel.mesh import mesh_from_settings, shard_pairs
@@ -62,13 +62,15 @@ def _gamma_histograms(settings, G, weights=None, chunk: int = 1 << 22) -> dict:
     """Per-comparison-column gamma-level histogram (telemetry record):
     column name -> [count at level -1 (null), level 0, ..., level L-1].
     ``G`` is either the per-pair gamma matrix or — with ``weights`` (the
-    pattern-count vector) — the pattern matrix. Chunked so the int64
-    promotion temporaries stay O(chunk): the streamed regime reaches here
-    with a G that is huge by definition, and observability must not
-    multiply that path's host footprint."""
+    pattern-count vector) — the pattern matrix. Counted on the host, where
+    no device copy of ``G`` is kept (``device_rows`` 0; where one is,
+    ``Splink._run_em`` counts it there with ``gammas.level_counts``).
+    Chunked so the int64 promotion temporaries stay O(chunk): the streamed
+    regime reaches here with a G that is huge by definition, and
+    observability must not multiply that path's host footprint."""
     cols = settings["comparison_columns"]
     acc = [np.zeros(int(col["num_levels"]) + 1, np.float64) for col in cols]
-    with span("gamma_histogram", rows=len(G)):
+    with span("gamma_histogram", rows=len(G), device_rows=0):
         for s in range(0, len(G), chunk):
             Gc = G[s : s + chunk]
             w = weights[s : s + chunk] if weights is not None else None
@@ -78,9 +80,17 @@ def _gamma_histograms(settings, G, weights=None, chunk: int = 1 << 22) -> dict:
                 acc[c] += np.bincount(
                     np.clip(g, 0, levels), weights=w, minlength=levels + 1
                 )[: levels + 1]
+    return _level_dict(settings, acc)
+
+
+def _level_dict(settings, counts) -> dict:
+    """The histogram's dict from per-column bin counts (row c: at least the
+    column's ``num_levels + 1`` bins, level -1 first)."""
     return {
-        comparison_column_name(col): [int(v) for v in acc[c]]
-        for c, col in enumerate(cols)
+        comparison_column_name(col): [
+            int(v) for v in counts[c][: int(col["num_levels"]) + 1]
+        ]
+        for c, col in enumerate(settings["comparison_columns"])
     }
 
 
@@ -1634,38 +1644,46 @@ class Splink:
         self._last_em_result = None
         # the gamma histogram doubles as the EM diagnostics' level-support
         # evidence (obs/quality.em_diagnostics) and as the quality
-        # profile's raw material; in the resident regime it is cheap
-        # relative to the gamma computation that just ran, so compute it
-        # there unconditionally — the huge streamed-with-telemetry-off
-        # case alone skips it (diagnostics then omit support counts)
-        hist = None
-        if self._obs.enabled or len(G) <= int(
-            self.settings["max_resident_pairs"]
-        ):
-            hist = _gamma_histograms(self.settings, G)
-            if self._obs.enabled:
-                self._obs.record("gamma_histogram", hist)
-        if len(G) > int(self.settings["max_resident_pairs"]):
-            self._run_em_streamed(G, compute_ll)
-            self._emit_em_diagnostics(hist)
-            return
-        # the resident attempt may replay completed updates into
-        # self.params (checkpoint boundaries / save_state_fn) before it
-        # OOMs; the fallback must restart from the PRE-attempt state or
-        # those updates would be applied twice
-        params_snapshot = copy.deepcopy(self.params)
-        try:
-            active_plan(self.settings).fire("resident_em", pairs=len(G))
-            self._run_em_resident(G, compute_ll)
-        except Exception as e:  # noqa: BLE001 - is_oom() decides
-            if not is_oom(e):
-                raise
-            self.params = params_snapshot
-            warn_degraded(
-                "resident_em", "streamed_em", f"{type(e).__name__}: {e}",
-                pairs=len(G),
+        # profile's raw material. Where the matrix is resident on the
+        # device it is counted there, queued ahead of EM and brought home
+        # after it; else the host counts it, but for the huge
+        # streamed-with-telemetry-off case (diagnostics then omit support
+        # counts)
+        resident = len(G) <= int(self.settings["max_resident_pairs"])
+        hist = counts = None
+        if self._G_dev is not None and G is self._G:
+            levels = tuple(
+                int(c["num_levels"]) for c in self.settings["comparison_columns"]
             )
+            with span("gamma_histogram", rows=len(G), device_rows=len(G)):
+                counts = level_counts(self._G_dev, levels)
+                dispatched("level_counts", counts, rows=len(G))
+        elif self._obs.enabled or resident:
+            hist = _gamma_histograms(self.settings, G)
+        if not resident:
             self._run_em_streamed(G, compute_ll)
+        else:
+            # the resident attempt may replay completed updates into
+            # self.params (checkpoint boundaries / save_state_fn) before it
+            # OOMs; the fallback must restart from the PRE-attempt state or
+            # those updates would be applied twice
+            params_snapshot = copy.deepcopy(self.params)
+            try:
+                active_plan(self.settings).fire("resident_em", pairs=len(G))
+                self._run_em_resident(G, compute_ll)
+            except Exception as e:  # noqa: BLE001 - is_oom() decides
+                if not is_oom(e):
+                    raise
+                self.params = params_snapshot
+                warn_degraded(
+                    "resident_em", "streamed_em", f"{type(e).__name__}: {e}",
+                    pairs=len(G),
+                )
+                self._run_em_streamed(G, compute_ll)
+        if counts is not None:
+            hist = _level_dict(self.settings, fetch(counts))
+        if self._obs.enabled:
+            self._obs.record("gamma_histogram", hist)
         self._emit_em_diagnostics(hist)
 
     def _run_em_resident(self, G: np.ndarray, compute_ll: bool) -> None:

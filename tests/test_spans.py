@@ -740,7 +740,12 @@ def test_jitted_programs_have_names_of_their_own():
 
     from splink_tpu import blocking_device, em, term_frequencies
     from splink_tpu.data import encode_table
-    from splink_tpu.gammas import GammaProgram, _jit_pattern_batch, _pattern_counts_batch
+    from splink_tpu.gammas import (
+        GammaProgram,
+        _jit_pattern_batch,
+        _pattern_counts_batch,
+        level_counts,
+    )
     from splink_tpu.pairgen import make_virtual_pattern_fn
     from splink_tpu.settings import complete_settings_dict
 
@@ -767,6 +772,7 @@ def test_jitted_programs_have_names_of_their_own():
         term_frequencies.make_tf_fold_fn(((0, "first_name", 1),)),
         program._kernel("pattern_batch", (), _jit_pattern_batch),
         _pattern_counts_batch,
+        level_counts,
         em.run_em,
         em.score_pairs,
         em.score_pairs_with_intermediates,
@@ -791,8 +797,8 @@ def test_jitted_programs_have_names_of_their_own():
 
 _PROGRAMS = {
     "dedupe": {"fn", "run_em", "score_pairs"},
-    "link_tf": {"fn", "run_em", "score_pairs", "tf_fold", "tf_token_stats",
-                "tf_token_gather"},
+    "link_tf": {"fn", "level_counts", "run_em", "score_pairs", "tf_fold",
+                "tf_token_stats", "tf_token_gather"},
 }
 
 
