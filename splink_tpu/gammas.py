@@ -136,10 +136,12 @@ class _StringField:
     __slots__ = ("kind", "width", "chars", "len_lane", "tok_lane")
 
     def __init__(self, kind, width, chars, len_lane, tok_lane):
-        self.kind = kind  # "ascii" (4 chars/lane) | "wide" (1 codepoint/lane)
+        # "ascii" (4 chars/lane) | "wide" (1 codepoint/lane) | "token" (the
+        # token lane alone: no chars, no length lane)
+        self.kind = kind
         self.width = width
-        self.chars = chars  # lane slice
-        self.len_lane = len_lane
+        self.chars = chars  # lane slice, None for "token"
+        self.len_lane = len_lane  # None for "token"
         self.tok_lane = tok_lane
 
 
@@ -299,19 +301,54 @@ def comparison_columns_used(settings: dict) -> set[str] | None:
     return used
 
 
+def token_only_columns(settings: dict) -> frozenset[str]:
+    """Columns every comparison reads through the token alone: the input
+    column of ``exact`` comparisons that no other comparison names — not as
+    its input, not as a name inversion's other column, a CASE expression's
+    column or a phonetic column. ``pack_table`` gives such a string column
+    its token lane and nothing else (its characters and length would widen
+    every row gather unread). Empty where a ``custom`` comparison may read
+    any lane; numeric columns are never narrowed (``pack_table`` applies
+    the set to string columns only)."""
+    from .data import phonetic_column_name
+
+    exact: set[str] = set()
+    other: set[str] = set()
+    for col in settings["comparison_columns"]:
+        spec = col.get("comparison") or {}
+        kind = spec.get("kind")
+        if kind == "custom":
+            return frozenset()
+        name = _comparison_input_column(col)
+        if name:
+            (exact if kind == "exact" else other).add(name)
+            if kind == "dmetaphone":
+                other.add(phonetic_column_name(name))
+        other.update(spec.get("other_columns", []))
+        other.update(spec.get("columns_used", []))
+        other.update(
+            phonetic_column_name(c) for c in spec.get("phonetic_columns", [])
+        )
+    return frozenset(exact - other)
+
+
 def pack_table(
     table: EncodedTable,
     float_dtype=jnp.float32,
     include=None,
     qgram_specs=(),
     charset_specs=(),
+    token_only=(),
 ):
     """Pack encoded columns into one (n_rows, n_lanes) uint32 matrix.
 
     Layout per string column: chars (width/4 lanes for ASCII, width lanes for
     wide-unicode), then a length lane and a token-id lane (token -1 doubles as
-    the null flag). Numeric columns contribute one (f32) or two (f64) bitcast
-    value lanes; their null bits are packed 32-per-lane at the end.
+    the null flag). A string column named in ``token_only``
+    (:func:`token_only_columns`) gets the token lane alone: a field of kind
+    ``"token"`` whose ``chars`` and ``len_lane`` are None. Numeric columns contribute one (f32)
+    or two (f64) bitcast value lanes; their null bits are packed 32-per-lane
+    at the end.
 
     ``include`` limits packing to those column names (row gathers are the
     measured bottleneck, so columns used only host-side — e.g. derived
@@ -336,6 +373,10 @@ def pack_table(
 
     for name, sc in table.strings.items():
         if include is not None and name not in include:
+            continue
+        if name in token_only:
+            tok_lane = add(sc.token_ids.astype(np.int32).view(np.uint32)).start
+            layout[name] = _StringField("token", sc.width, None, None, tok_lane)
             continue
         if sc.bytes_.dtype == np.uint8:
             w = sc.width
@@ -418,6 +459,9 @@ class PairContext:
         self._rows_r = rows_r
 
     def _string_side(self, f: _StringField, rows):
+        tok = jax.lax.bitcast_convert_type(rows[:, f.tok_lane], jnp.int32)
+        if f.kind == "token":
+            return None, None, tok
         lanes = rows[:, f.chars]
         if f.kind == "ascii":
             chars = _u32_bytes_le(lanes)
@@ -425,7 +469,6 @@ class PairContext:
         else:
             chars = lanes
         ln = jax.lax.bitcast_convert_type(rows[:, f.len_lane], jnp.int32)
-        tok = jax.lax.bitcast_convert_type(rows[:, f.tok_lane], jnp.int32)
         return chars, ln, tok
 
     def _numeric_side(self, f: _NumericField, rows):
@@ -860,8 +903,14 @@ class GammaProgram:
                 include=comparison_columns_used(settings),
                 qgram_specs=qgram_specs_for(settings),
                 charset_specs=charset_specs_for(settings),
+                token_only=token_only_columns(settings),
             )
-            sp.count(lanes=int(packed.shape[1]))  # words of a packed row
+            sp.count(
+                lanes=int(packed.shape[1]),  # words of a packed row
+                token_only_columns=sum(
+                    getattr(f, "kind", None) == "token" for f in layout.values()
+                ),
+            )
         packed = _device_table(packed)
         with span("h2d_put", bytes=packed.nbytes):
             self._packed = jnp.asarray(packed)
