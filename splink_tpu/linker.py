@@ -16,12 +16,14 @@ import contextlib
 import copy
 import functools
 import logging
+import math
 import operator
 import os
 import warnings
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -40,17 +42,12 @@ from .gammas import GammaProgram, level_counts, register_comparison  # noqa: F40
 from .models.fellegi_sunter import FSParams
 from .params import Params, load_params_from_json
 from .parallel.mesh import mesh_from_settings, shard_pairs
+from .regime import Facts, Wait, decide
 from .settings import comparison_column_name, complete_settings_dict
 from .utils.compile_cache import enable_compilation_cache
 from .utils.profiling import StageTimer, count, dispatched, fetch, span
 
 logger = logging.getLogger("splink_tpu")
-
-# RAM cap for keeping the virtual pass's per-candidate pattern ids and row
-# pairs (pairgen.VirtualIds: 10 bytes a candidate with uint16 ids, 12 with
-# int32) for a later score stream: 8.6 GB. Above it the stream recomputes
-# them chunk-wise instead (virtual_materialise_ids="on" overrides).
-_MAX_RESIDENT_ID_BYTES = 1 << 33
 
 try:  # pandas is required for the linker facade (not for the kernels)
     import pandas as pd
@@ -463,12 +460,11 @@ class Splink:
         self._virtual = None  # pairgen.VirtualPlan (device pair generation)
         self._virtual_checked = False
         # per-candidate pattern ids from the virtual pass (sentinel kept),
-        # materialised when a score stream is known to follow — one kernel
-        # pass instead of two (see _virtual_ids_policy): a pairgen.VirtualIds,
+        # where the regime keeps them (``ids_kept``): a pairgen.VirtualIds,
         # the ids with the row pairs the kernel decoded
         self._P_virtual = None
-        self._virtual_want_ids = False
         self._pair_bound: int | None = None  # estimate_pair_upper_bound memo
+        self._approx: bool | None = None  # approx_blocking's tier has columns
         # last EMResult replayed into Params (EM diagnostics attach its
         # trimmed trajectory: per-iteration ll lives only device-side)
         self._last_em_result = None
@@ -539,8 +535,6 @@ class Splink:
         if self._float_dtype_cache is None:
             resolved = np.float32
             if self.settings["float64"]:
-                import jax
-
                 # resolve fully before caching: an exception here (flaky
                 # backend init, interrupt) must not poison the cache with
                 # the float32 fallback
@@ -648,166 +642,111 @@ class Splink:
     def _ensure_pairs(self) -> PairIndex:
         if self._pairs is None:
             table = self._ensure_encoded()
-            build_dir = self.settings.get("build_spill_dir") or None
-            if build_dir and self.settings.get("approx_blocking"):
-                # the spill driver emits EXACT-rule pairs only; when the
-                # approximate LSH tier can actually run, taking it would
-                # silently drop every approx pair — the recall feature the
-                # setting opts into (the same hazard gate _virtual_plan
-                # applies to the virtual pair index)
-                from .approx.lsh import approx_columns
-
-                if approx_columns(self.settings, table):
-                    from .utils.logging_utils import warn_degraded
-
-                    warn_degraded(
-                        "spill_blocking", "host_blocking",
-                        "approx_blocking needs materialised blocking (the "
-                        "spill emission driver has no approximate tier)",
-                    )
-                    build_dir = None
-            if build_dir:
-                # The durable write path (docs/blocking.md#offline-scale):
+            spill = self._regime("pairs") == "spill"
+            if spill:
+                # the durable write path (docs/blocking.md#offline-scale):
                 # sharded, manifest-committed, RESUMABLE emission into the
-                # caller-owned spill store. Overlap scoring is off here by
-                # design — a resumed build skips committed segments, so no
-                # per-chunk consumer can be fed consistently; the streamed
-                # EM consumes the manifest afterwards instead.
+                # caller-owned spill store
                 from .blocking_device import spill_block_rules
                 from .parallel.distributed import spill_shard_dir
 
                 with self._stage("blocking") as st:
-                    pairs = spill_block_rules(
+                    self._pairs = spill_block_rules(
                         self.settings, table, self._n_left,
-                        spill_shard_dir(build_dir),
+                        spill_shard_dir(self.settings["build_spill_dir"]),
                     )
-                    if pairs is not None:
-                        st.count(pairs=int(pairs.n_pairs))
-                if pairs is not None:
-                    self._pairs = pairs
-                    logger.info(
-                        "blocking produced %d candidate pairs (spill store)",
-                        pairs.n_pairs,
+                    if self._pairs is not None:
+                        st.count(pairs=int(self._pairs.n_pairs))
+            if self._pairs is None:
+                if self.settings.get("build_spill_dir"):
+                    from .utils.logging_utils import warn_degraded
+
+                    warn_degraded(
+                        "spill_blocking", "host_blocking",
+                        "rule shapes unsupported by the device emission plan"
+                        if spill else "approx_blocking needs materialised "
+                        "blocking (the spill emission driver has no "
+                        "approximate tier)",
                     )
-                    from .blocking import clear_key_code_cache
-
-                    clear_key_code_cache(table)
-                    return self._pairs
-                from .utils.logging_utils import warn_degraded
-
-                warn_degraded(
-                    "spill_blocking", "host_blocking",
-                    "rule shapes unsupported by the device emission plan",
-                )
-            stream = self._overlap_stream(table)
-            with self._stage("blocking") as st:
-                self._pairs = block_using_rules(
-                    self.settings,
-                    table,
-                    self._n_left,
-                    pair_consumer=stream.feed if stream is not None else None,
-                )
-                st.count(pairs=int(self._pairs.n_pairs))
-            logger.info("blocking produced %d candidate pairs", self._pairs.n_pairs)
-            if self._obs.enabled:
-                # block-size skew telemetry rides the still-warm key-code
-                # cache; freed with it just below
-                from .blocking import block_size_stats
-
-                self._obs.record(
-                    "largest_blocks",
-                    block_size_stats(self.settings, table, self._n_left),
-                )
-            self._maybe_spill_pairs()
-            if stream is not None:
-                self._finish_overlap(stream)
+                self._block_on_host(table)
+            logger.info(
+                "blocking produced %d candidate pairs%s",
+                self._pairs.n_pairs, " (spill store)" if spill else "",
+            )
             from .blocking import clear_key_code_cache
 
             clear_key_code_cache(table)
         return self._pairs
 
-    def _overlap_stream(self, table: EncodedTable):
-        """Device-scoring consumer fed DURING blocking (VERDICT round 2 #2:
-        end-to-end wall ≈ max(blocking, scoring), not their sum). jax
-        dispatch is async, so the accelerator computes rule k's
-        gammas/pattern ids while the host joins rule k+1; the second sweep
-        over the (possibly disk-spilled) pair index disappears. Spark
-        gets the same overlap from lazy evaluation
-        (/root/reference/splink/blocking.py:210).
+    def _block_on_host(self, table: EncodedTable) -> None:
+        """Host blocking, feeding the overlap's device consumer as it joins."""
+        stream = self._overlap_stream(table)
+        with self._stage("blocking") as st:
+            self._pairs = block_using_rules(
+                self.settings, table, self._n_left,
+                pair_consumer=stream.feed if stream is not None else None,
+            )
+            st.count(pairs=int(self._pairs.n_pairs))
+        if self._obs.enabled:
+            # block-size skew telemetry rides the still-warm key-code cache
+            from .blocking import block_size_stats
 
-        The regime is chosen BEFORE blocking from a cheap O(n) upper bound
-        on the pair count (per-rule key-group histograms): resident-size
-        jobs stream the gamma matrix and keep it device-resident for EM
-        (no pattern-decode/re-upload penalty); larger jobs stream 3-byte
-        pattern ids, which serve both the streamed LUT regime and — decoded
-        through the pattern matrix — the resident one if dedup shrank the
-        run after all. Custom kernels and pattern-space overflow always
-        take GammaStream."""
-        if not self.settings.get("overlap_blocking", True):
+            self._obs.record(
+                "largest_blocks",
+                block_size_stats(self.settings, table, self._n_left),
+            )
+        if self._pairs.spill_tmp is not None:
+            # blocking's pair sink wrote every chunk to disk-backed memmaps;
+            # the PairIndex owns the directory (weakref finalizer)
+            logger.info("pair index spilled to %s", self._pairs.spill_tmp)
+        if stream is None:
+            return
+        # the stream's batches were dispatched while blocking ran; this
+        # stage is the tail flush, and carries the whole pass's counts
+        patterns = self._regime("stream") == "patterns"
+        with self._stage("gammas_patterns" if patterns else "gammas") as st:
+            if patterns:
+                self._P, self._pattern_counts = stream.finish()
+            else:
+                self._G, self._G_dev = stream.finish()
+            st.count(pairs=stream.total,
+                     batches=-(-stream.total // stream.batch_size))
+            if patterns:
+                st.count(pattern_id_bytes=self._P.nbytes)
+                self._count_mesh(st, stream.total)
+            st.count(**stream.program.kernel_counts(stream.total))
+
+    def _overlap_stream(self, table: EncodedTable):
+        """The device consumer host blocking feeds as it joins, or None (the
+        regime's ``overlap`` and ``stream``): jax dispatch is async, so the
+        accelerator computes rule k's gammas or pattern ids while the host
+        joins rule k+1, as Spark's lazy evaluation overlaps them in the
+        reference (``splink/blocking.py:210``)."""
+        if not self._regime("overlap"):
             return None
         from .gammas import GammaStream, PatternStream
 
         program = GammaProgram(
             self.settings, table, float_dtype=self._float_dtype
         )
-        mesh = mesh_from_settings(self.settings)
-        max_resident = int(self.settings["max_resident_pairs"])
-        bound = self._estimate_pair_bound(table)
+        stream = self._regime("stream")  # learns the pair bound
         # clamp the device batch to the job bound (like the sequential
         # paths clamp to n) so a small job doesn't pad its single batch up
         # to pair_batch_size
         batch = int(self.settings["pair_batch_size"])
-        batch = max(min(batch, -(-max(bound, 1) // 8) * 8), 1024)
-        # _pattern_capable covers the custom-kernel and pattern-space
-        # conditions; under a mesh the PatternStream shards its batches
-        # over the data axis (gammas.PatternStream mesh support)
-        if bound > max_resident and self._pattern_capable():
+        batch = max(min(batch, -(-max(self._pair_bound, 1) // 8) * 8), 1024)
+        if stream == "patterns":
             self._pattern_program = program
             return PatternStream(program, batch, mesh=self._pattern_mesh())
-        keep_limit = max_resident if mesh is None else 0
-        return GammaStream(program, batch, keep_device_limit=keep_limit)
-
-    def _finish_overlap(self, stream) -> None:
-        from .gammas import PatternStream
-
-        # the stream's batches were dispatched while blocking ran; this
-        # stage is the tail flush, and carries the whole pass's counts
-        stage = "gammas_patterns" if isinstance(stream, PatternStream) else "gammas"
-        with self._stage(stage) as st:
-            if isinstance(stream, PatternStream):
-                self._P, self._pattern_counts = stream.finish()
-            else:
-                self._G, self._G_dev = stream.finish()
-            st.count(pairs=stream.total,
-                     batches=-(-stream.total // stream.batch_size))
-            if isinstance(stream, PatternStream):
-                st.count(pattern_id_bytes=self._P.nbytes)
-                self._count_mesh(st, stream.total)
-            st.count(**stream.program.kernel_counts(stream.total))
-
-    def _maybe_spill_pairs(self) -> None:
-        """Note the blocking-created spill dir (streamed regime): blocking's
-        pair sink streams every pair chunk straight to disk-backed memmaps
-        when spill_dir is set — rule path and cartesian fallback alike — so
-        there is nothing left to copy here. The PairIndex owns the directory
-        lifetime via its weakref finalizer; the stale-orphan sweep ran before
-        any bytes were written."""
-        if self._pairs.spill_tmp is not None:
-            self._spill_tmp = self._pairs.spill_tmp
-            logger.info("pair index spilled to %s (streamed)", self._spill_tmp)
+        return GammaStream(program, batch, keep_device_limit=self._regime("keep_limit"))
 
     def _ensure_gammas(self) -> np.ndarray:
         if self._G is None:
             table = self._ensure_encoded()
             pairs = self._ensure_pairs()  # overlap may set _G or _P here
-            if self._multihost_spill_store(pairs) is not None:
-                # this process's store holds ONLY its shard subset — a
-                # gamma matrix over it would feed scoring/EM paths that
-                # assume the FULL pair set, silently producing divergent
-                # parameters or subset-only output frames per controller.
-                # Training is supported (estimate_parameters routes to the
-                # manifest-fed streamed EM with cross-process reduction);
-                # scoring output is a single-controller operation.
+            if self._facts().multihost_store:
+                # this process's store holds ONLY its shard subset: scores
+                # or EM over it would silently differ per controller
                 raise RuntimeError(
                     "this pair index is a per-process spill shard subset "
                     "(multi-controller emission): scoring APIs need the "
@@ -817,23 +756,13 @@ class Splink:
                 )
             if self._G is not None:
                 return self._G
-            if self._P is not None:
-                # overlap streamed pattern ids but the run ended small
-                # enough for the resident regime: decode the gamma matrix
-                # from the pattern LUT (bit-identical to recomputation —
-                # the pattern id IS the gamma vector in mixed radix)
+            if self._regime("gammas") == "decode":
                 with self._stage("gammas") as st:
                     seen = self._seen_patterns()
                     self._G = seen.rows[seen.row_of(self._P)]
                     st.count(pairs=len(self._G))
                 return self._G
-            # In the resident regime (and without a mesh, which shards its
-            # own upload), keep the device-side gamma batches so EM doesn't
-            # re-upload the matrix that was just computed there.
-            keep = (
-                pairs.n_pairs <= int(self.settings["max_resident_pairs"])
-                and mesh_from_settings(self.settings) is None
-            )
+            keep = self._regime("keep_device")
             with self._stage("gammas") as st:
                 program = GammaProgram(
                     self.settings, table, float_dtype=self._float_dtype
@@ -848,27 +777,65 @@ class Splink:
                          **program.kernel_counts(len(self._G)))
         return self._G
 
-    def _pattern_capable(self) -> bool:
-        """Static part of the pattern-pipeline test: a pattern space whose
-        dense device accumulators fit their bytes (``gammas.
-        pattern_space_fits``: 12 B a pattern, 1 GiB in all — the host's
-        tables follow the patterns SEEN, ``_seen_patterns``) and no custom
-        comparison kernels — a registered kernel could emit gammas outside
-        [-1, num_levels-1], which would alias pattern ids. A mesh does NOT
-        disqualify: both the virtual pair index
-        (pairgen.make_virtual_pattern_fn) and the materialised pattern
-        pass (GammaProgram._pattern_batch_for_mesh, PatternStream) shard
-        their batches over the mesh's data axis."""
-        from .gammas import pattern_space_fits, pattern_strides_for
+    def _regime(self, outcome: str, **facts):
+        """One outcome of the job's regime (``regime.decide``), learning now the
+        facts it waits on; ``facts``: what the caller knows and the linker
+        keeps nowhere (a score stream follows; a gamma matrix's rows)."""
+        while isinstance(value := decide(self._facts(**facts))[outcome], Wait):
+            facts[value.fact] = self._learn(value.fact)
+        return value
 
-        for c in self.settings["comparison_columns"]:
-            if (c.get("comparison") or {}).get("kind") == "custom":
-                return False
-        level_counts = [
-            int(c["num_levels"]) for c in self.settings["comparison_columns"]
-        ]
-        _, n_patterns = pattern_strides_for(level_counts)
-        return pattern_space_fits(n_patterns)
+    def _facts(self, **known) -> Facts:
+        """What this linker has learnt of its job, learning nothing new."""
+        from .gammas import pattern_ids_fit_uint16, pattern_space_fits, pattern_strides_for
+
+        cols = self.settings["comparison_columns"]
+        n_patterns = pattern_strides_for([int(c["num_levels"]) for c in cols])[1]
+        custom = any((c.get("comparison") or {}).get("kind") == "custom" for c in cols)
+        mesh, plan, pairs = bool(self.settings.get("mesh")), self._virtual, self._pairs
+        store = getattr(pairs, "spill_store", None)
+        return Facts(**{
+            "settings": self.settings,
+            "pattern_capable": not custom and pattern_space_fits(n_patterns),
+            "mesh": mesh,
+            "process_count": jax.process_count() if mesh else 1,
+            "approx_columns": self._approx,
+            "pair_bound": self._pair_bound,
+            "plan": (plan is not None) if self._virtual_checked else None,
+            "n_candidates": None if plan is None else plan.n_candidates,
+            "ids_fit_uint16": pattern_ids_fit_uint16(n_patterns),
+            "n_pairs": None if pairs is None else pairs.n_pairs,
+            "spill_store": store is not None,
+            # written under multi-controller emission: this process's share
+            "multihost_store": store is not None
+            and int(store.meta.get("process_count", 1) or 1) > 1,
+            **known,
+        })
+
+    def _learn(self, fact: str):
+        """The fact the regime waits on, learnt now (``_regime``)."""
+        if fact == "plan":
+            return self._virtual_plan() is not None
+        if fact == "n_pairs":
+            return self._ensure_pairs().n_pairs
+        if fact == "free_ram":  # what is free now
+            try:
+                return os.sysconf("SC_AVPHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+            except (ValueError, OSError, AttributeError):
+                return math.inf  # no probe here; the byte cap still bounds
+        table = self._ensure_encoded()
+        if fact == "pair_bound":
+            from .blocking import estimate_pair_upper_bound
+
+            with span("pair_bound", rows=int(table.n_rows)):
+                self._pair_bound = estimate_pair_upper_bound(
+                    self.settings, table, self._n_left
+                )
+            return self._pair_bound
+        from .approx.lsh import approx_columns
+
+        self._approx = bool(approx_columns(self.settings, table))
+        return self._approx
 
     @property
     def device_pair_generation_active(self) -> bool:
@@ -887,16 +854,6 @@ class Splink:
         plan = self._virtual_plan()
         return [] if plan is None else compiled_kernel_texts(plan)
 
-    def _estimate_pair_bound(self, table: EncodedTable) -> int:
-        if self._pair_bound is None:
-            from .blocking import estimate_pair_upper_bound
-
-            with span("pair_bound", rows=int(table.n_rows)):
-                self._pair_bound = estimate_pair_upper_bound(
-                    self.settings, table, self._n_left
-                )
-        return self._pair_bound
-
     def _virtual_plan(self):
         """The device-pair-generation plan, or None (pairgen module
         docstring has the full story). Checked once: the plan build does
@@ -905,42 +862,11 @@ class Splink:
         if self._virtual_checked:
             return self._virtual
         self._virtual_checked = True
-        mode = self.settings.get("device_pair_generation", "auto")
-        if mode == "off":
+        if not self._regime("virtual"):
             return None
-        if not self._pattern_capable():
-            if mode == "on":
-                logger.info(
-                    "device pair generation 'on' declined: the model is not "
-                    "pattern-capable (a custom comparison, or a pattern space "
-                    "past gammas.MAX_PATTERNS)"
-                )
-            return None
-        if self.settings.get("approx_blocking"):
-            # the virtual pair index enumerates EXACT-rule pairs only; the
-            # approximate LSH tier emits through materialised blocking, so
-            # taking the virtual path here would silently drop every
-            # approx pair — the recall feature the setting opts into.
-            # With no sketchable string column the tier is a no-op and
-            # the virtual path loses nothing (same gate as
-            # estimate_pair_upper_bound).
-            from .approx.lsh import approx_columns
-
-            if approx_columns(self.settings, self._ensure_encoded()):
-                logger.info(
-                    "device pair generation disabled: approx_blocking "
-                    "needs materialised blocking (the virtual pair index "
-                    "has no approximate tier)"
-                )
-                return None
         from .pairgen import build_virtual_plan
 
         table = self._ensure_encoded()
-        if mode == "auto":
-            # small jobs: the resident/overlap paths are already optimal
-            bound = self._estimate_pair_bound(table)
-            if bound <= int(self.settings["max_resident_pairs"]):
-                return None
         with self._stage("pairgen_plan"):
             self._virtual = build_virtual_plan(
                 self.settings, table, self._n_left
@@ -958,53 +884,9 @@ class Splink:
             )
         return self._virtual
 
-    def _use_pattern_pipeline(self) -> bool:
-        """Whether the streamed pattern-id pipeline applies: device pair
-        generation active, or a large materialised pair set with
-        pattern-capable settings."""
-        if self._virtual_plan() is not None:
-            return True
-        if not self._pattern_capable():
-            return False
-        pairs = self._ensure_pairs()
-        if self._multihost_spill_store(pairs) is not None:
-            # a per-process spill store's n_pairs is LOCAL and differs per
-            # controller — a count-dependent regime choice here could put
-            # controllers on different EM paths (one in a collective, one
-            # not: deadlock). The manifest-fed streamed driver is the one
-            # multi-controller-correct path for these stores, so the
-            # decision is pinned deterministically (process_count is
-            # identical in every store's meta).
-            return False
-        return pairs.n_pairs > int(self.settings["max_resident_pairs"])
-
-    @staticmethod
-    def _multihost_spill_store(pairs):
-        """The pair index's spill store when it was written under
-        MULTI-CONTROLLER emission (and therefore holds only this
-        process's shard subset) — None otherwise."""
-        store = getattr(pairs, "spill_store", None)
-        if store is not None and (
-            int(store.meta.get("process_count", 1) or 1) > 1
-        ):
-            return store
-        return None
-
     def _pattern_mesh(self):
-        """The mesh pattern passes shard over: the configured mesh on a
-        single controller; None under multi-controller — the sharded
-        passes device_put host-local full arrays onto the mesh, which is a
-        single-controller layout. Each host then runs the full pattern
-        pass on its own default device: duplicated device work, but no
-        gamma matrix ever materialises and every host derives the same
-        histogram/params (a host-sliced multi-controller pattern pass is
-        future work)."""
-        mesh = mesh_from_settings(self.settings)
-        if mesh is None:
-            return None
-        import jax
-
-        return mesh if jax.process_count() == 1 else None
+        """The mesh pattern passes shard over (``shard_patterns``), or None."""
+        return mesh_from_settings(self.settings) if self._regime("shard_patterns") else None
 
     def _count_mesh(self, st, positions: int) -> None:
         """On a pattern pass's stage: over how many chips it was sharded
@@ -1029,63 +911,25 @@ class Splink:
             )
         return self._pattern_program
 
-    def _virtual_ids_policy(self) -> bool:
-        """Should the virtual pattern pass ALSO bring home what it knows of
-        every candidate — its pattern id and the row pair the kernel decoded
-        (pairgen.VirtualIds: 2 or 4 bytes of id and 8 of pair)? One pass
-        (ids + pairs + histogram together) beats two (histogram-only EM
-        pass, then a recompute inside the score stream) whenever a score
-        stream is going to happen and they fit host RAM: the kernels run
-        once instead of twice, and the downloads overlap the kernels either
-        way. EM-only jobs keep the histogram-only pass — no per-pair bytes
-        ever cross the link (what the bytes cost when they do is the
-        ``d2h_wait`` / ``mesh_gather`` / ``decode_pairs`` spans of a
-        ``chipbench`` run)."""
-        mode = self.settings.get("virtual_materialise_ids", "auto")
-        if mode == "on":
-            return True
-        if mode == "off":
-            return False
-        if not self._virtual_want_ids:
-            return False
-        from .gammas import pattern_ids_fit_uint16
-
-        small = pattern_ids_fit_uint16(self._ensure_pattern_program().n_patterns)
-        need = self._virtual.n_candidates * (10 if small else 12)
-        if need > _MAX_RESIDENT_ID_BYTES:
-            return False
-        # "fits host RAM" means the RAM actually free right now, not just
-        # the hard cap: claim at most half of it, else stream chunk-wise
-        try:
-            avail = os.sysconf("SC_AVPHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
-        except (ValueError, OSError, AttributeError):
-            return True  # no probe on this platform; the cap still bounds
-        return need <= avail // 2
-
-    def _ensure_pattern_ids(self):
+    def _ensure_pattern_ids(self, score_follows: bool = False):
         """(pattern_ids, counts, program): ONE device pass over the pair
         index computing gammas, pattern ids and their histogram. The gamma
         matrix itself never materialises — per-pair state is a uint16/int32
         id, and every later stage (EM, scoring, output columns) derives from
-        the ≤ prod(levels+1)-row pattern tables. This is also what keeps
-        host<->device traffic to a single pass over the pairs."""
+        the ≤ prod(levels+1)-row pattern tables. ``score_follows``: a score
+        stream follows the pass (the regime's ``ids_kept`` reads it)."""
         if self._P is None:
             table = self._ensure_encoded()
             if self._virtual_plan() is not None:
-                # device pair generation: pairs decode on device from the
-                # plan's unit structure; nothing is materialised or
-                # transferred per pair. Default is a histogram-ONLY pass
-                # (EM needs nothing else); when a score stream is known to
-                # follow, _virtual_ids_policy keeps the per-candidate ids
-                # and row pairs from this same pass so the stream is
-                # LUT-only.
+                # pairs decode on the device from the plan: nothing per pair
+                # is materialised unless the regime keeps the ids
                 if self._pattern_counts is not None:
                     return None, self._pattern_counts, self._pattern_program
                 from .pairgen import compute_virtual_pattern_ids
 
                 with self._stage("gammas_patterns") as st:
                     self._ensure_pattern_program()
-                    want_ids = self._virtual_ids_policy()
+                    want_ids = self._regime("ids_kept", score_follows=score_follows)
                     ids, self._pattern_counts, n_real = (
                         compute_virtual_pattern_ids(
                             self._pattern_program,
@@ -1396,7 +1240,7 @@ class Splink:
         from .term_frequencies import bayes_combine, term_frequency_columns
 
         tf_cols = list(term_frequency_columns(self.settings))
-        if not self._use_pattern_pipeline():
+        if not self._regime("patterns"):
             # resident regime: the one-frame path already exists
             df_e = self.get_scored_comparisons(compute_ll)
             yield self.make_term_frequency_adjustments(df_e)
@@ -1408,13 +1252,12 @@ class Splink:
             )
             yield from self.stream_scored_comparisons(compute_ll)
             return
-        self._virtual_want_ids = True
         # the try spans EVERYTHING from EM (which materialises the
         # potentially multi-GB per-candidate ids) onward: an exception in
         # the aggregation pass or a consumer abandoning/closing the
         # generator anywhere must not leak the ids
         try:
-            self._run_em_patterns(compute_ll)
+            self._run_em_patterns(compute_ll, score_follows=True)
             table = self._ensure_encoded()
             cols: dict[str, tuple[np.ndarray, int]] = {}
             for name in tf_cols:
@@ -1481,8 +1324,10 @@ class Splink:
             self._P_virtual = None
             self._obs.finish()
 
-    def _run_em_patterns(self, compute_ll: bool) -> None:
-        _, counts, program = self._ensure_pattern_ids()
+    def _run_em_patterns(self, compute_ll: bool, score_follows: bool = False) -> None:
+        """Fused EM on the weighted pattern matrix: one row a pattern seen,
+        its pair count as the weight."""
+        _, counts, program = self._ensure_pattern_ids(score_follows)
         if int(counts.sum()) == 0:
             warnings.warn(
                 "No candidate pairs to estimate from (blocking produced "
@@ -1495,13 +1340,16 @@ class Splink:
             "of %d", int(counts.sum()), len(seen.ids), program.n_patterns,
         )
         self._obs.gauge("gamma_patterns_distinct", len(seen.ids))
-        self._last_em_result = None  # same staleness guard as _run_em
         # always cheap here (one row a pattern seen); feeds telemetry AND
         # the EM diagnostics' level-support evidence
         hist = _gamma_histograms(self.settings, seen.rows, weights=seen.counts)
         if self._obs.enabled:
             self._obs.record("gamma_histogram", hist)
-        self._run_em_resident_weighted(seen.rows, seen.counts, compute_ll)
+        self._run_em_fused(
+            jnp.asarray(seen.rows),
+            jnp.asarray(seen.counts.astype(self._float_dtype)),
+            compute_ll, pairs=int(seen.counts.sum()),
+        )
         self._emit_em_diagnostics(hist)
 
     # ------------------------------------------------------------------
@@ -1512,7 +1360,7 @@ class Splink:
         """Score using the m/u values in the settings, without running EM
         (/root/reference/splink/__init__.py:111-119)."""
         with self._call("manually_apply_fellegi_sunter_weights") as call:
-            if self._use_pattern_pipeline():
+            if self._regime("patterns"):
                 df_e = self._score_patterns_frame()
             else:
                 G = self._ensure_gammas()
@@ -1570,50 +1418,33 @@ class Splink:
         return self.params
 
     def _estimate_parameters(self, compute_ll: bool) -> None:
-        if self._use_pattern_pipeline():
+        em = self._regime("em")
+        if em == "patterns":
             self._run_em_patterns(compute_ll)
-            return
-        pairs = self._ensure_pairs()
-        store = getattr(pairs, "spill_store", None)
-        # A store written under multi-controller emission holds only THIS
-        # process's shard subset, so the manifest-fed driver (whose
-        # cross-process stats reduction forms the global aggregate) is the
-        # ONLY correct EM path for it — and the branch must not depend on
-        # the LOCAL pair count, which differs per process and would split
-        # controllers across collective/non-collective regimes (deadlock)
-        # or train each on its own subset without reduction. process_count
-        # is identical in every per-process store's meta, so this decision
-        # is globally consistent.
-        if store is not None and (
-            self._multihost_spill_store(pairs) is not None
-            or pairs.n_pairs > int(self.settings["max_resident_pairs"])
-        ):
-            # spill-store-backed pairs past the resident cap: EM consumes
-            # the manifest directly — gammas per chunk on device, never
-            # rematerialised host-side
-            self._run_em_streamed_spill(pairs, compute_ll)
+        elif em == "spill":
+            batches = self._em_batches()
+            store = self._pairs.spill_store
+            logger.info(
+                "spill-fed streamed EM over %d pairs (%d manifest segments)",
+                store.total_pairs, len(store.segments),
+            )
+            self._run_em_streamed_driver(batches, compute_ll, int(store.total_pairs))
+            self._emit_em_diagnostics(None)
         else:
-            G = self._ensure_gammas()
-            self._run_em(G, compute_ll)
+            self._run_em(self._ensure_gammas(), compute_ll)
             self._G_dev = None
 
     def get_scored_comparisons(self, compute_ll: bool = False):
         """Estimate parameters by EM and return scored comparisons
         (/root/reference/splink/__init__.py:121-145).
 
-        When the candidate-pair count exceeds ``max_resident_pairs`` the
-        pipeline switches to the pattern-id regime: one device pass encodes
-        each pair's gamma vector as a mixed-radix pattern id and histograms
-        them, EM runs on the weighted pattern matrix, and scoring is a host
-        LUT gather — pair data crosses the host<->device link exactly once.
+        Past ``max_resident_pairs`` candidate pairs (or on the virtual pair
+        index) EM runs on the histogram of the pairs' gamma patterns and the
+        scores are taken from per-pattern tables (splink_tpu/regime.py).
         """
         with self._call("scored_comparisons") as call:
-            if self._use_pattern_pipeline():
-                # scoring follows EM here, so the virtual pass may keep its
-                # per-candidate ids and make the stream LUT-only (one kernel
-                # pass instead of two)
-                self._virtual_want_ids = True
-                self._run_em_patterns(compute_ll)
+            if self._regime("patterns"):
+                self._run_em_patterns(compute_ll, score_follows=True)
                 df_e = self._score_patterns_frame()
                 # the single-frame output is materialised — release the ids
                 # (same convention as _G_dev below); a later re-stream simply
@@ -1629,19 +1460,13 @@ class Splink:
         return df_e
 
     def _run_em(self, G: np.ndarray, compute_ll: bool) -> None:
-        """Dispatch EM to the resident or streamed regime by pair count.
-
-        A device OOM on the resident path (the gamma matrix plus EM
-        workspace outgrew HBM) degrades to the streamed regime — same
-        update math over host-batched uploads — instead of crashing the
-        run (docs/resilience.md degradation ladder)."""
+        """EM over the gamma matrix G, resident on the device or streamed
+        from the host (the regime's ``em_resident``). A device OOM on the
+        resident path degrades to the streamed one, same update math,
+        instead of crashing the run (docs/resilience.md)."""
         from .resilience import active_plan, is_oom
         from .utils.logging_utils import warn_degraded
 
-        # a stale result from an earlier call must not attach its
-        # trajectory to this run's diagnostics (the streamed/checkpointed
-        # paths replay history without going through _replay_history)
-        self._last_em_result = None
         # the gamma histogram doubles as the EM diagnostics' level-support
         # evidence (obs/quality.em_diagnostics) and as the quality
         # profile's raw material. Where the matrix is resident on the
@@ -1649,7 +1474,7 @@ class Splink:
         # after it; else the host counts it, but for the huge
         # streamed-with-telemetry-off case (diagnostics then omit support
         # counts)
-        resident = len(G) <= int(self.settings["max_resident_pairs"])
+        resident = self._regime("em_resident", n_pairs=len(G))
         hist = counts = None
         if self._G_dev is not None and G is self._G:
             levels = tuple(
@@ -1660,9 +1485,7 @@ class Splink:
                 dispatched("level_counts", counts, rows=len(G))
         elif self._obs.enabled or resident:
             hist = _gamma_histograms(self.settings, G)
-        if not resident:
-            self._run_em_streamed(G, compute_ll)
-        else:
+        if resident:
             # the resident attempt may replay completed updates into
             # self.params (checkpoint boundaries / save_state_fn) before it
             # OOMs; the fallback must restart from the PRE-attempt state or
@@ -1670,7 +1493,17 @@ class Splink:
             params_snapshot = copy.deepcopy(self.params)
             try:
                 active_plan(self.settings).fire("resident_em", pairs=len(G))
-                self._run_em_resident(G, compute_ll)
+                weights = None
+                mesh = mesh_from_settings(self.settings)
+                if mesh is not None:
+                    G_dev, weights = shard_pairs(mesh, G)
+                    weights = weights.astype(self._float_dtype)
+                elif self._G_dev is not None:
+                    G_dev = self._G_dev
+                else:
+                    with span("h2d_put", bytes=G.nbytes):
+                        G_dev = jnp.asarray(G)
+                self._run_em_fused(G_dev, weights, compute_ll, pairs=len(G))
             except Exception as e:  # noqa: BLE001 - is_oom() decides
                 if not is_oom(e):
                     raise
@@ -1679,33 +1512,27 @@ class Splink:
                     "resident_em", "streamed_em", f"{type(e).__name__}: {e}",
                     pairs=len(G),
                 )
-                self._run_em_streamed(G, compute_ll)
+                resident = False
+        if not resident:
+            self._run_em_streamed_driver(self._em_batches(G), compute_ll, len(G))
         if counts is not None:
             hist = _level_dict(self.settings, fetch(counts))
         if self._obs.enabled:
             self._obs.record("gamma_histogram", hist)
         self._emit_em_diagnostics(hist)
 
-    def _run_em_resident(self, G: np.ndarray, compute_ll: bool) -> None:
-        """Fused on-device EM with the gamma matrix resident in HBM."""
-        dtype = self._float_dtype
-        mesh = mesh_from_settings(self.settings)
-        weights = None
-        if mesh is not None:
-            G_dev, weights = shard_pairs(mesh, G)
-            weights = weights.astype(dtype)
-        else:
-            G_dev = self._G_dev
-            if G_dev is None:
-                with span("h2d_put", bytes=G.nbytes):
-                    G_dev = jnp.asarray(G)
-        self._run_em_fused(G_dev, weights, compute_ll, pairs=len(G))
-
     def _run_em_fused(self, G_dev, weights, compute_ll: bool, pairs: int) -> None:
-        """Shared fused-EM driver: whole-run while_loop normally, stepped one
-        update at a time when a save_state_fn checkpoint hook must run
-        between iterations (the restart semantics of
-        /root/reference/splink/iterate.py:54-55)."""
+        """Fused on-device EM over ``G_dev`` (the gamma matrix, or the
+        pattern matrix with its counts as ``weights``): one compiled
+        while_loop; with a checkpoint directory the same loop with a host
+        hook writing an atomic checkpoint every ``checkpoint_interval``
+        updates; stepped one update at a time when a save_state_fn hook must
+        run between them (the reference's ``splink/iterate.py:54-55``)."""
+        from .resilience import active_plan
+
+        # a stale result must not attach its trajectory to this run's
+        # diagnostics (only _replay_history sets it)
+        self._last_em_result = None
         dtype = self._float_dtype
         lam0, m0, u0, _ = self.params.to_arrays(dtype=dtype)
         init = FSParams(lam=jnp.asarray(lam0), m=jnp.asarray(m0), u=jnp.asarray(u0))
@@ -1726,10 +1553,54 @@ class Splink:
             if tel is not None:
                 tel.em_begin("fused", lam0, m0, u0)
             if ckpt_dir is not None:
-                converged = self._run_em_fused_checkpointed(
-                    G_dev, init, max_iterations, em_kwargs, ckpt_dir,
-                    resume, interval, compute_ll,
+                state_hash = self._em_state_hash()
+                ckpt = self._load_validated_checkpoint(
+                    ckpt_dir, state_hash, resume
                 )
+                if self.save_state_fn is not None:
+                    logger.warning(
+                        "checkpoint_dir moves save_state_fn onto the compiled "
+                        "loop's host-callback thread (called at checkpoint "
+                        "boundaries, mid-program): the hook must stay "
+                        "host-side work — dispatching jax computation from "
+                        "it can deadlock the running program."
+                    )
+                replayed = 0
+
+                def on_segment(done, hist, _converged):
+                    # history replays into the Params object at each
+                    # boundary, then save_state_fn sees it (both on the
+                    # callback thread)
+                    nonlocal replayed
+                    self._replay_em_history(
+                        hist["lam"], hist["m"], hist["u"], hist["ll"],
+                        replayed, done, compute_ll,
+                    )
+                    replayed = done
+                    if self.save_state_fn is not None:
+                        self.save_state_fn(self.params, self.settings)
+
+                result = run_em_checkpointed(
+                    G_dev, init, max_iterations=max_iterations,
+                    checkpoint_dir=ckpt_dir, state_hash=state_hash,
+                    checkpoint_every=interval, resume=resume,
+                    resume_checkpoint=ckpt,
+                    fault_plan=active_plan(self.settings),
+                    on_segment=on_segment, telemetry=tel, **em_kwargs,
+                )
+                # a resume that was already complete runs zero segments;
+                # catch up from the (checkpoint-restored) histories
+                n = int(result.n_updates)
+                self._replay_em_history(
+                    result.lam_history, result.m_history, result.u_history,
+                    result.ll_history, replayed, n, compute_ll,
+                )
+                if compute_ll and not np.isnan(result.ll_history[n]):
+                    self.params.params["log_likelihood"] = float(
+                        result.ll_history[n]
+                    )
+                    self.params.log_likelihood_exists = True
+                converged = bool(result.converged)
             elif self.save_state_fn is None:
                 if tel is not None:
                     # same compiled loop with the host-hook io_callback on:
@@ -1776,186 +1647,41 @@ class Splink:
         if converged:
             logger.info("EM algorithm has converged")
 
-    def _run_em_fused_checkpointed(
-        self, G_dev, init, max_iterations, em_kwargs, ckpt_dir, resume,
-        interval, compute_ll,
-    ) -> bool:
-        """Checkpointed resident EM: em.run_em_checkpointed runs the ONE
-        compiled while_loop with an in-loop host hook that writes an
-        atomic checkpoint every ``interval`` updates — bit-identical
-        trajectory, plus durable resume. History replays into the Params
-        object incrementally at each boundary (so save_state_fn sees the
-        same per-update cadence as the stepped driver, at boundary
-        granularity; both run on the callback thread and must stay
-        host-side) and resumed iterations replay from the checkpoint's
-        histories."""
-        from .resilience import active_plan
-
-        state_hash = self._em_state_hash()
-        ckpt = self._load_validated_checkpoint(ckpt_dir, state_hash, resume)
-        if self.save_state_fn is not None:
-            logger.warning(
-                "checkpoint_dir moves save_state_fn onto the compiled "
-                "loop's host-callback thread (called at checkpoint "
-                "boundaries, mid-program): the hook must stay host-side "
-                "work — dispatching jax computation from it can deadlock "
-                "the running program."
-            )
-        replayed = 0
-
-        def replay(done, hist):
-            nonlocal replayed
-            self._replay_em_history(
-                hist["lam"], hist["m"], hist["u"], hist["ll"],
-                replayed, done, compute_ll,
-            )
-            replayed = done
-
-        def on_segment(done, hist, _converged):
-            replay(done, hist)
-            if self.save_state_fn is not None:
-                self.save_state_fn(self.params, self.settings)
-
-        result = run_em_checkpointed(
-            G_dev,
-            init,
-            max_iterations=max_iterations,
-            checkpoint_dir=ckpt_dir,
-            state_hash=state_hash,
-            checkpoint_every=interval,
-            resume=resume,
-            resume_checkpoint=ckpt,
-            fault_plan=active_plan(self.settings),
-            on_segment=on_segment,
-            telemetry=self._obs if self._obs.enabled else None,
-            **em_kwargs,
-        )
-        # a resume that was already complete runs zero segments; catch up
-        # from the result's (checkpoint-restored) histories
-        n_updates = int(result.n_updates)
-        replay(
-            n_updates,
-            {
-                "lam": result.lam_history,
-                "m": result.m_history,
-                "u": result.u_history,
-                "ll": result.ll_history,
-            },
-        )
-        if compute_ll and not np.isnan(result.ll_history[n_updates]):
-            self.params.params["log_likelihood"] = float(
-                result.ll_history[n_updates]
-            )
-            self.params.log_likelihood_exists = True
-        return bool(result.converged)
-
-    def _run_em_streamed(self, G: np.ndarray, compute_ll: bool) -> None:
-        """Streaming EM over host-resident gamma micro-batches.
-
-        Reached only when the pattern-id pipeline declined the job (custom
-        kernels, or a pattern space whose dense device accumulators pass
-        their bytes, gammas.pattern_space_fits: 12 B a pattern, 1 GiB) —
-        otherwise large pair sets never materialise G at all
-        (_run_em_patterns)."""
-        self._run_em_streamed_stats(G, compute_ll)
-
-    def _run_em_resident_weighted(
-        self, G_pat: np.ndarray, weights: np.ndarray, compute_ll: bool
-    ) -> None:
-        """Fused EM on a weighted pattern matrix (counts as weights)."""
-        dtype = self._float_dtype
-        self._run_em_fused(
-            jnp.asarray(G_pat), jnp.asarray(weights.astype(dtype)), compute_ll,
-            pairs=int(weights.sum()),
-        )
-
-    def _run_em_streamed_stats(self, G: np.ndarray, compute_ll: bool) -> None:
-        """Streaming EM accumulating sufficient statistics per pass — the
-        fallback when the pattern space is too large for a dense histogram,
-        and the mesh path (stats psum across devices).
-
-        Under a multi-controller run (jax.process_count() > 1) each host
-        streams only its global_pair_slice of the pair set and the
-        per-pass sufficient statistics reduce across processes with
-        all_sum_stats (one allgather per pass — the path proven
-        bit-compatible with a single process by
-        tests/test_multiprocess_em.py), like every host's Spark executor
-        reading its own partitions."""
-        import jax
-
+    def _em_batches(self, G: np.ndarray | None = None):
+        """The batch factory streamed EM calls once a pass:
+        ``pair_batch_size`` slices of G or, without G, of the spill store,
+        gammas computed on the device a slice at a time
+        (``spill.iter_spill_gamma_batches``). Multi-controller hosts each
+        stream their ``global_pair_slice`` (which a per-process store
+        already is), and each pass's statistics are reduced across them."""
         from .parallel.distributed import global_pair_slice
 
-        pairs = len(G)
-        if jax.process_count() > 1:
-            G = G[global_pair_slice(len(G))]
         batch = int(self.settings["pair_batch_size"])
-
-        def batches():
-            for s in range(0, len(G), batch):
-                yield G[s : s + batch]
-
-        self._run_em_streamed_driver(batches, compute_ll, pairs)
-
-    def _run_em_streamed_spill(self, pairs: PairIndex, compute_ll: bool) -> None:
-        """Manifest-fed streamed EM: the spill store IS the pair stream.
-
-        Each EM pass walks the committed pair range of the store's memmaps
-        in ``pair_batch_size`` slices, computes that slice's gamma block on
-        device (GammaProgram.iter_gamma_chunks — same batching, padding
-        and overflow semantics as the resident paths) and feeds it to
-        run_em_streamed. The gamma matrix NEVER materialises on the host:
-        at billions of pairs even the int8 G is tens of GB, which is what
-        capped the old write path. Multi-controller runs stream only their
-        global_pair_slice of the manifest and reduce stats with
-        all_sum_stats, exactly like the materialised path. Trajectory is
-        bit-identical to a (hypothetical) resident streamed run over the
-        same pair order — batch boundaries match by construction."""
-        import jax
-
-        from .parallel.distributed import global_pair_slice
+        sliced = jax.process_count() > 1
+        if G is not None:
+            G = G[global_pair_slice(len(G))] if sliced else G
+            return lambda: (G[s : s + batch] for s in range(0, len(G), batch))
         from .spill import iter_spill_gamma_batches
 
-        store = pairs.spill_store
-        program = GammaProgram(
-            self.settings, self._ensure_encoded(),
-            float_dtype=self._float_dtype,
+        store = self._pairs.spill_store
+        program = GammaProgram(self.settings, self._ensure_encoded(), float_dtype=self._float_dtype)
+        sliced = sliced and not self._facts().multihost_store
+        pair_range = global_pair_slice(store.total_pairs) if sliced else None
+        return lambda: iter_spill_gamma_batches(
+            store, program, batch, pair_range=pair_range
         )
-        batch = int(self.settings["pair_batch_size"])
-        pair_range = None
-        if (
-            jax.process_count() > 1
-            and int(store.meta.get("process_count", 1) or 1) == 1
-        ):
-            # a SHARED single-writer store consumed by many controllers
-            # slices like a materialised G; a per-process store (written
-            # under multi-controller emission) already holds only this
-            # host's shard subset — streaming it whole IS the local slice
-            pair_range = global_pair_slice(store.total_pairs)
-
-        def batches():
-            return iter_spill_gamma_batches(
-                store, program, batch, pair_range=pair_range
-            )
-
-        self._last_em_result = None
-        logger.info(
-            "spill-fed streamed EM over %d pairs (%d manifest segments)",
-            store.total_pairs, len(store.segments),
-        )
-        self._run_em_streamed_driver(batches, compute_ll, int(store.total_pairs))
-        self._emit_em_diagnostics(None)
 
     def _run_em_streamed_driver(self, batches, compute_ll: bool, pairs: int) -> None:
-        """The shared streamed-EM driver: checkpoint/resume plumbing,
-        telemetry and the run_em_streamed call over any re-iterable batch
-        factory — the materialised G path and the spill-manifest path
-        differ ONLY in where their gamma batches come from."""
-        import jax
-
+        """Streamed EM, accumulating sufficient statistics a pass (stats
+        psum across a mesh's devices): checkpoint/resume plumbing, telemetry
+        and the run_em_streamed call over a batch factory — G's slices
+        (custom kernels, pattern spaces past their bytes, the resident OOM
+        fallback) or the spill manifest's (``_em_batches``)."""
         from .parallel.streaming import run_em_streamed
         from .resilience import RetryPolicy, active_plan
         from .resilience.checkpoint import EMCheckpointer
 
+        self._last_em_result = None  # as in _run_em_fused
         dtype = self._float_dtype
         lam0, m0, u0, _ = self.params.to_arrays(dtype=dtype)
         init = FSParams(lam=jnp.asarray(lam0), m=jnp.asarray(m0), u=jnp.asarray(u0))
@@ -2088,11 +1814,8 @@ class Splink:
         call.count(pairs=0, chunks=0, suspended_s=0.0)
         with call:
             try:
-                if self._use_pattern_pipeline():
-                    # scoring follows EM: let the virtual pass keep its ids
-                    # (the auto policy still bounds them against free RAM)
-                    self._virtual_want_ids = True
-                    self._run_em_patterns(compute_ll)
+                if self._regime("patterns"):
+                    self._run_em_patterns(compute_ll, score_follows=True)
                 else:
                     self._run_em(self._ensure_gammas(), compute_ll)
                 for chunk in self.stream_scored_comparisons_after_em():
@@ -2110,7 +1833,7 @@ class Splink:
         """Yield scored-comparison chunks using the current parameters
         (EM — or a loaded model — already applied); see
         stream_scored_comparisons."""
-        if self._use_pattern_pipeline():
+        if self._regime("patterns"):
             yield from self._stream_pattern_chunks()
             return
         G = self._ensure_gammas()

@@ -99,7 +99,7 @@ def _settings(**overrides):
 
 # Exact comparison as a CUSTOM kernel: a registered kernel disqualifies
 # the pattern-id pipeline (it could emit out-of-range gammas), which is
-# what routes estimate_parameters through _run_em_streamed_stats — the
+# what routes estimate_parameters through streamed EM over G — the
 # path carrying the batch_fetch/em_iteration fault sites and the
 # EMCheckpointer hook. Same gamma semantics as kind "exact".
 _CUSTOM_EXACT_REGISTRATION = """
@@ -327,7 +327,7 @@ def test_streamed_resume_matches_uninterrupted(tmp_path):
     excludes max_iterations: extending the cap is a legitimate resume)."""
     df = _df()
     part = Splink(_settings_streamed(max_iterations=3), df=df)
-    assert not part._use_pattern_pipeline()  # genuinely the streamed driver
+    assert not part._regime("patterns")  # genuinely streamed EM
     part.estimate_parameters(checkpoint_dir=tmp_path)
     assert os.path.exists(checkpoint_path(tmp_path))
 
@@ -423,7 +423,7 @@ def test_resident_oom_degrades_to_streamed():
     # max_resident_pairs through the pattern pipeline, a different path)
     streamed = Splink(_settings(), df=df)
     G = streamed._ensure_gammas()
-    streamed._run_em_streamed(G, False)
+    streamed._run_em_streamed_driver(streamed._em_batches(G), False, len(G))
     _assert_bit_identical(degraded, streamed)
     # ...and matching the resident run it replaced. Bounded near what was
     # measured, not at float32 resolution: under the installed XLA CPU
@@ -461,7 +461,7 @@ def test_resident_oom_mid_run_with_checkpointing_no_double_apply(tmp_path):
     assert any(issubclass(w.category, DegradationWarning) for w in caught)
     streamed = Splink(_settings(), df=df)
     G = streamed._ensure_gammas()
-    streamed._run_em_streamed(G, False)
+    streamed._run_em_streamed_driver(streamed._em_batches(G), False, len(G))
     _assert_bit_identical(degraded, streamed)
 
 

@@ -11,7 +11,7 @@ every score within the configuration's limits; the bfloat16 control has to
 fail them. Held beside it: every per-pattern table the host builds has one row
 a pattern SEEN (``linker._SeenPatterns``) and is bit-equal to the full-space
 table it replaces, on config 4's 1,296-pattern model and on a small space with
-the intermediates and the TF fold's logits; ``_pattern_capable`` on both sides
+the intermediates and the TF fold's logits; the regime's ``pattern_capable`` fact on both sides
 of the byte-derived bound; ``gammas.MAX_PATTERNS = 1`` still forcing the
 streamed-stats regime; the ``pattern_tables`` span's counts.
 """
@@ -98,7 +98,7 @@ def test_a_space_past_2_to_the_22_runs_on_the_normal_path(job):
     linker, frame, table = job
     program = linker._pattern_program
     assert program.n_patterns == SPACE > 1 << 22
-    assert linker._pattern_capable() and linker._virtual_plan() is not None
+    assert linker._facts().pattern_capable and linker._virtual_plan() is not None
     assert linker._G is None  # no per-pair gamma matrix on the host
     names = [s["name"] for s in table]
     assert "pairgen_plan" in names and "em" in names and "gammas" not in names
@@ -209,7 +209,7 @@ def test_pattern_capable_on_both_sides_of_the_byte_bound(config, people, monkeyp
     for bound, capable in ((SPACE, True), (SPACE - 1, False)):
         monkeypatch.setattr(gammas, "MAX_PATTERNS", bound)
         linker = Splink(_small(config["settings"]), df=people)
-        assert linker._pattern_capable() is capable
+        assert linker._facts().pattern_capable is capable
         # past the bound even "on" gives no virtual plan: the gamma-matrix paths
         assert (linker._virtual_plan() is not None) is capable
         refused = pytest.raises(ValueError, match="dense device accumulators")
@@ -224,13 +224,16 @@ def test_max_patterns_one_still_forces_the_streamed_stats_regime(monkeypatch):
     from splink_tpu.linker import Splink as Linker
 
     calls = []
-    streamed = Linker._run_em_streamed_stats
-    monkeypatch.setattr(Linker, "_run_em_streamed_stats",
-                        lambda self, G, ll: calls.append(len(G)) or streamed(self, G, ll))
+    streamed = Linker._run_em_streamed_driver
+    monkeypatch.setattr(
+        Linker, "_run_em_streamed_driver",
+        lambda self, batches, ll, pairs: calls.append(pairs)
+        or streamed(self, batches, ll, pairs),
+    )
     monkeypatch.setattr(gammas, "MAX_PATTERNS", 1)
     settings = _small(_config("baseline_c4")["settings"])
     linker = Splink(settings, df=datagen.make_people(rows=3000, seed=4))
-    assert not linker._pattern_capable() and not linker._use_pattern_pipeline()
+    assert not linker._facts().pattern_capable and not linker._regime("patterns")
     linker.estimate_parameters()
     assert calls == [linker._ensure_pairs().n_pairs]
     assert not [s for s in spans(run=linker.run_id) if s["name"] == "pattern_tables"]

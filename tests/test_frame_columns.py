@@ -274,7 +274,7 @@ def _frame_the_parents_way(linker):
     def take(lut, Pk):
         return None if lut is None else lut[Pk]
 
-    if linker._use_pattern_pipeline():
+    if linker._regime("patterns"):
         PM, *luts = linker._pattern_score_luts()
         chunks = [
             frame(PM[Pk], il, ir, *(take(lut, Pk) for lut in luts))
@@ -328,7 +328,7 @@ def test_one_frame_equals_the_per_chunk_frames_concatenated(case):
     assert (len(frame) == 0) == empty
     table = [s for s in spans(run=linker.run_id)]
     chunks = [s["counts"] for s in table if s["name"] == "assemble_frame"]
-    if linker._use_pattern_pipeline():
+    if linker._regime("patterns"):
         assert len(chunks) > 2 or empty
         if case == "every_position_masked":
             assert linker._virtual.n_candidates > 0
@@ -363,7 +363,7 @@ def test_streamed_chunks_concatenated_equal_the_one_frame(case):
     linker = _one_frame_linker(case)
     frame = linker.get_scored_comparisons()
     chunks = list(linker.stream_scored_comparisons_after_em())
-    if linker._use_pattern_pipeline():
+    if linker._regime("patterns"):
         assert len(chunks) > 2
     pd.testing.assert_frame_equal(
         pd.concat(chunks, ignore_index=True), frame, check_exact=True

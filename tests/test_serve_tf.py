@@ -232,7 +232,7 @@ def test_streamed_offline_path_matches_one_frame(trained):
     # scoring-only through the pattern-LUT regime
     linker.params = copy.deepcopy(linker0.params)
     streamed = linker.manually_apply_fellegi_sunter_weights()
-    assert linker._use_pattern_pipeline()
+    assert linker._regime("patterns")
     assert "tf_match_probability" in streamed.columns
     for col in ("match_probability", "tf_match_probability"):
         one = _score_map(df_e, col)

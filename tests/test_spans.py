@@ -335,6 +335,57 @@ def test_one_span_stack_and_no_profiler_session_code():
 # ---------------------------------------------------------------------------
 
 
+# the tree each job opens: every stage's and named span's parents, by name
+# (None: a root). The job's regime is asked where each decision is needed and
+# its questions open nothing of their own: the pair bound and the virtual
+# plan never open inside ``encode``, and nothing new opens inside ``em``
+_TREE = {
+    "dedupe": {
+        "init": {None}, "scored_comparisons": {None},
+        "encode": {"scored_comparisons"}, "pairgen_plan": {"scored_comparisons"},
+        "gammas_patterns": {"scored_comparisons"}, "em": {"scored_comparisons"},
+        "score_patterns": {"scored_comparisons"},
+        "pattern_tables": {"scored_comparisons"},
+        "gamma_histogram": {"scored_comparisons"},
+        "pack_table": {"gammas_patterns"},
+        "h2d_put": {"gammas_patterns", "pattern_tables"},
+        "d2h_wait": {"gammas_patterns", "em", "pattern_tables"},
+        "assemble_frame": {"score_patterns"}, "concat_frame": {"score_patterns"},
+        "decode_pairs": {"score_patterns"}, "lut_gather": {"assemble_frame"},
+    },
+    "link_tf": {
+        "init": {None}, "scored_comparisons": {None}, "tf": {None},
+        "encode": {"scored_comparisons"}, "pair_bound": {"scored_comparisons"},
+        "pack_table": {"scored_comparisons"}, "blocking": {"scored_comparisons"},
+        "gammas": {"scored_comparisons"}, "em": {"scored_comparisons"},
+        "score": {"scored_comparisons"},
+        "gamma_histogram": {"scored_comparisons"},
+        "assemble_frame": {"scored_comparisons"},
+        "concat_frame": {"scored_comparisons"},
+        "h2d_put": {"scored_comparisons", "gammas", "assemble_frame", "tf_device"},
+        "d2h_wait": {"scored_comparisons", "gammas", "em", "score",
+                     "assemble_frame", "tf_device"},
+        "tf_align_check": {"tf"}, "tf_token_ids": {"tf"}, "tf_frame": {"tf"},
+        "tf_device": {"tf_frame"},
+    },
+}
+
+
+def test_each_span_opens_under_the_parents_it_always_had(job):
+    name, _linker, table = job
+    by_id = {s["id"]: s for s in table}
+    tree: dict[str, set] = {}
+    for s in table:
+        if s["kind"] != "build":
+            parent = by_id.get(s["parent"])
+            tree.setdefault(s["name"], set()).add(parent and parent["name"])
+    assert tree == _TREE[name]
+    for decision in ("pair_bound", "pairgen_plan"):
+        assert "encode" not in tree.get(decision, ())
+    stages = [s["name"] for s in table if s["kind"] == "stage"]
+    assert len(stages) == len(set(stages))  # each stage opens once
+
+
 def test_stage_timings_hold_only_the_stage_names(job):
     name, linker, table = job
     timings = stage_timings(run=linker.run_id)

@@ -134,7 +134,7 @@ def test_pattern_pipeline_matches_resident_pipeline():
     resident = Splink({**base, "max_resident_pairs": 1 << 28}, df=df)
     df_res = resident.get_scored_comparisons()
     patterned = Splink({**base, "max_resident_pairs": 1024}, df=df)
-    assert patterned._use_pattern_pipeline()
+    assert patterned._regime("patterns")
     df_pat = patterned.get_scored_comparisons()
 
     assert list(df_res.columns) == list(df_pat.columns)
@@ -468,7 +468,7 @@ def test_all_null_chunk_and_empty_frame_carry_the_columns_dtypes(stream):
     assert isinstance(whole["note_l"].dtype, pd.StringDtype)
 
     linker = Splink({**settings, **_STREAMS[stream]}, df=df)
-    assert linker._use_pattern_pipeline() == (stream != "gamma_chunks")
+    assert linker._regime("patterns") == (stream != "gamma_chunks")
     chunks = list(linker.stream_scored_comparisons())
     assert len(chunks) > 4
     nulls = [c["note_l"].isna().all() and c["note_r"].isna().all() for c in chunks]
