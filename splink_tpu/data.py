@@ -4,9 +4,11 @@ The reference keeps data as Spark DataFrames and pushes strings through JVM
 UDFs per row. The TPU design instead encodes every compared column ONCE,
 host-side, into fixed-width device arrays (SURVEY.md section 7):
 
-  * string columns  -> (n, width) uint8 codepoint arrays + int32 lengths,
-                       plus factorised int32 token ids (for exact comparison
-                       and term-frequency adjustment) and a bool null mask
+  * string columns  -> (n, width) code arrays (uint8 bytes for an all-ASCII
+                       column, uint32 Unicode code points for any other) +
+                       int32 lengths, plus factorised int32 token ids (for
+                       exact comparison and term-frequency adjustment) and a
+                       bool null mask
   * numeric columns -> float64 values + bool null mask
 
 Candidate pairs are then just int32 index arrays into these columns; gathers
@@ -29,8 +31,8 @@ def _pad_width(n: int, multiple: int = 8) -> int:
 
 @dataclass
 class EncodedStringColumn:
-    bytes_: np.ndarray  # (n, width) uint8, zero padded
-    lengths: np.ndarray  # (n,) int32 byte lengths (post truncation)
+    bytes_: np.ndarray  # (n, width) uint8 bytes or uint32 code points, zero padded
+    lengths: np.ndarray  # (n,) int32 lengths in characters (post truncation)
     token_ids: np.ndarray  # (n,) int32 factorised codes, -1 for null
     null_mask: np.ndarray  # (n,) bool
     values: np.ndarray  # (n,) object: original strings (None for null)
@@ -251,16 +253,17 @@ def encode_string_column(values, width: int = DEFAULT_STRING_WIDTH) -> EncodedSt
         np.cumsum(ulens, out=offsets[1:])
         ubytes, ulengths = native.encode_fixed_width(flat, offsets, width)
     else:
-        ubytes = np.zeros((len(ustrs), width), dtype=np.uint32)
-        ulengths = np.zeros(len(ustrs), dtype=np.int32)
-        for i, v in enumerate(ustrs):
-            if not v:
-                continue
-            chars = v[:width]
-            ubytes[i, : len(chars)] = np.array(
-                [ord(c) for c in chars], dtype=np.uint32
-            )
-            ulengths[i] = len(chars)
+        # numpy's fixed-width unicode is UCS-4: a '<U{width}' array (which
+        # truncates each value to its first `width` characters) viewed as
+        # uint32 IS the zero-padded code points, an astral character one
+        # lane. Lengths come from len(), so a value's trailing NULs count
+        # as characters as they always did.
+        ubytes = (
+            np.asarray(ustrs, dtype=f"<U{width}")
+            .view(np.uint32)
+            .reshape(len(ustrs), width)
+        )
+        ulengths = np.minimum(ulens, width).astype(np.int32)
 
     if len(ubytes):
         bytes_ = ubytes[safe_codes]

@@ -855,6 +855,29 @@ def _string_evals(spec: dict) -> int:
     ))
 
 
+def _vector_string_evals(col_settings: dict, layout: dict, backend) -> int:
+    """Of :func:`_string_evals`, those a pair position takes through a
+    vmapped vector form rather than a Pallas kernel on ``backend``: a
+    Jaro-Winkler or Levenshtein evaluation whose width
+    ``strings_pallas.pallas_takes`` refuses (name inversion's cross pairs at
+    the wider of the two columns, as ``_spec_gamma`` pads them), and every
+    q-gram evaluation (no Pallas form)."""
+    from .ops.strings_pallas import pallas_takes
+
+    spec = col_settings["comparison"]
+    kind = spec["kind"]
+    if kind in ("qgram_jaccard", "qgram_cosine"):
+        return 1
+    if kind not in ("jaro_winkler", "levenshtein", "name_inversion"):
+        return 0
+    width = layout[_comparison_input_column(col_settings)].width
+    widths = [width] + [max(width, layout[other].width)
+                        for other in spec.get("other_columns", [])
+                        if kind == "name_inversion"]
+    # every packed kind hands the kernels integer codes (uint8 or uint32)
+    return sum(not pallas_takes(w, np.uint32, backend) for w in widths)
+
+
 # The fewest rows the device's table has on a TPU. XLA's TPU compiler stages a
 # gather operand of under ~300,000 rows row-major in its fast memory and then
 # writes every gathered row padded from its 12-30 words to 128 lanes: 2,070 B
@@ -945,17 +968,24 @@ class GammaProgram:
         probe = jnp.zeros(8, jnp.int32)
         log_jaxpr("gamma_program", self._gamma_batch, probe, probe)
 
-    def kernel_counts(self, positions: int) -> dict:
+    def kernel_counts(self, positions: int, backend: str | None = None) -> dict:
         """Which kernel forms the pass this program just made over
         ``positions`` pair positions was made of, for its stage span
         (docs/observability.md): ``string_evals`` — string-kernel evaluations
         issued, positions times the columns' sum (Jaro-Winkler, Levenshtein
         and q-gram columns 1 each, name inversion 1 + its other columns);
-        how many columns are Levenshtein and how many name inversion."""
-        specs = [c["comparison"] for c in self.settings["comparison_columns"]]
+        ``string_evals_vector`` — those of them the vmapped vector forms
+        made, not a Pallas kernel on ``backend`` (the default backend when
+        None): every one off a TPU, 0 on a TPU when every column is
+        Jaro-Winkler or Levenshtein within the kernels' width; how many
+        columns are Levenshtein and how many name inversion."""
+        cols = self.settings["comparison_columns"]
+        specs = [c["comparison"] for c in cols]
         kinds = [spec["kind"] for spec in specs]
+        vector = sum(_vector_string_evals(c, self._layout, backend) for c in cols)
         return {
             "string_evals": int(positions) * sum(map(_string_evals, specs)),
+            "string_evals_vector": int(positions) * vector,
             "levenshtein_columns": kinds.count("levenshtein"),
             "name_inversion_columns": kinds.count("name_inversion"),
         }

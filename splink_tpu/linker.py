@@ -636,7 +636,13 @@ class Splink:
                     self._table = encode_table(self.df, self.settings)
                 else:
                     self._table = concat_tables(self.df_l, self.df_r, self.settings)
-                st.count(rows=int(self._table.n_rows))
+                st.count(
+                    rows=int(self._table.n_rows),
+                    wide_columns=sum(  # encoded as uint32 code points
+                        c.bytes_.dtype != np.uint8
+                        for c in self._table.strings.values()
+                    ),
+                )
         return self._table
 
     def _ensure_pairs(self) -> PairIndex:

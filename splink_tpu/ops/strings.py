@@ -4,15 +4,27 @@ TPU-native replacements for the reference's JVM string UDFs
 (jars/scala-udf-similarity-0.0.6.jar, registered at
 /root/reference/tests/test_spark.py:44-56) and Spark's builtin
 ``levenshtein()`` (/root/reference/splink/case_statements.py:121). Strings are
-pre-encoded host-side into fixed-width uint8 codepoint arrays plus lengths
-(see splink_tpu/data.py), so every kernel here is shape-static, branch-free
-and vmappable: the batch axis maps onto VPU lanes and the per-string axis is a
-small fixed L (default 24/32 bytes).
+pre-encoded host-side into fixed-width code arrays plus lengths (see
+splink_tpu/data.py): uint8 bytes for an all-ASCII column, uint32 Unicode code
+points for any other, the width the column's ``max_string_length`` caps
+(default 24), so every kernel here is shape-static, branch-free and
+vmappable: the batch axis maps onto VPU lanes and the per-string axis is a
+fixed L. On a TPU the batched entry points (``jaro_winkler``,
+``levenshtein``, ``levenshtein_ratio``) take the Pallas kernels of
+strings_pallas.py up to its MAX_PALLAS_WIDTH (64) whatever the code dtype;
+the vmapped forms here serve wider columns and every other backend.
+
+Characters are Unicode CODE POINTS. The reference's Jaro-Winkler UDF runs on
+Java strings, i.e. UTF-16 code units: the two agree on every string of the
+Basic Multilingual Plane, and differ on a character above U+FFFF (one code
+point here, a surrogate pair of two units there: lengths, windows and
+matches count it once here, twice there; tests/test_music_deployment.py pins
+what this program does with one).
 
 Design notes:
   * jaro_winkler: the greedy character-matching pass is inherently sequential
     in the s1 index, so we run a fixed-trip-count ``lax.fori_loop`` over the L
-    positions with O(L) vectorised work per step (O(L^2) total, L small).
+    positions with O(L) vectorised work per step (O(L^2) total).
   * levenshtein: row-recurrence DP. The insertion chain within a row is a
     prefix-min, so each row update is fully vectorised via ``lax.cummin``
     (new[j] = j + cummin(t[j] - j)); ``lax.scan`` walks the L rows.
@@ -33,7 +45,7 @@ def _f(x):
 def jaro_winkler_single(
     s1, s2, l1, l2, prefix_scale: float = 0.1, boost_threshold: float = 0.7
 ):
-    """Jaro-Winkler similarity of two fixed-width byte strings, matching the
+    """Jaro-Winkler similarity of two fixed-width code arrays, matching the
     reference jar's JaroWinklerSimilarity UDF BIT-FOR-BIT in structure (the
     commons-text JaroWinklerDistance.apply the Scala wrapper delegates to,
     verified against its bytecode by scripts/jvm_mini.py; golden table
@@ -89,7 +101,12 @@ def jaro_winkler_single(
     def compact(s, matched):
         rank = jnp.cumsum(matched, dtype=jnp.int32) - 1
         ind = (rank[:, None] == idx[None, :]) & matched[:, None]  # (L, L)
-        return (s.astype(jnp.float32) * matched) @ ind.astype(jnp.float32)
+        # HIGHEST: a TPU's default f32 matmul rounds its operands to
+        # bfloat16, which would merge neighbouring code points above 256
+        return jnp.matmul(
+            s.astype(jnp.float32) * matched, ind.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        )
 
     seq1 = compact(a, matched_a)
     seq2 = compact(b, used_b)
@@ -203,7 +220,7 @@ def jaro_winkler_bitmask_single(
 
 
 def levenshtein_single(s1, s2, l1, l2):
-    """Levenshtein edit distance between two fixed-width byte strings.
+    """Levenshtein edit distance between two fixed-width code arrays.
 
     Row DP with the insertion chain solved as a prefix-min:
     row_i[j] = j + cummin_k<=j (min(row_{i-1}[k] + 1, row_{i-1}[k-1] + cost) - k).
@@ -267,8 +284,9 @@ exact_equal = jax.vmap(exact_equal_single)
 
 
 def levenshtein(s1, s2, l1, l2):
-    """Batched Levenshtein distance: Pallas lane-tile kernel on TPU for
-    ASCII fixed-width columns, vmapped row-DP elsewhere."""
+    """Batched Levenshtein distance: Pallas lane-tile kernel on TPU up to
+    width 64 (``strings_pallas.pallas_supported``), vmapped row-DP
+    elsewhere."""
     from .strings_pallas import levenshtein_pallas, pallas_supported
 
     if pallas_supported(s1):
@@ -288,8 +306,9 @@ def levenshtein_ratio(s1, s2, l1, l2):
 
 
 def jaro_winkler(s1, s2, l1, l2, prefix_scale=0.1, boost_threshold=0.7):
-    """Batched Jaro-Winkler: Pallas lane-tile kernel on TPU for ASCII
-    fixed-width columns, vmapped pure-JAX elsewhere (wide unicode, CPU)."""
+    """Batched Jaro-Winkler: Pallas lane-tile kernel on TPU up to width 64,
+    bytes or code points (``strings_pallas.pallas_supported``), vmapped
+    pure-JAX elsewhere (wider columns, CPU)."""
     from .strings_pallas import jaro_winkler_pallas, pallas_supported
 
     if pallas_supported(s1):

@@ -1,4 +1,5 @@
-"""Pallas TPU kernel for batched Jaro-Winkler similarity.
+"""Pallas TPU kernels for batched Jaro-Winkler similarity and Levenshtein
+distance.
 
 The pure-JAX implementation (splink_tpu/ops/strings.py) runs the greedy
 matching scan as vmapped (L,)-vector steps, which XLA executes as L
@@ -8,19 +9,28 @@ set of a lane-tile of pairs in VMEM/registers:
   * layout: the PAIR axis rides the 128 VPU lanes, the character axis rides
     sublanes — inputs arrive transposed as (L, B) float32 so one (L, T) tile
     holds T complete pairs;
-  * the greedy pass unrolls the L (static, <= 32) steps in-register;
+  * the greedy pass unrolls the L (static, <= MAX_PALLAS_WIDTH = 64) steps;
   * every prefix count ("first eligible partner", match ranks, common-prefix
     run) is a small lower-triangular (L, L) x (L, T) matmul on the MXU —
     no cumsum primitive, no scatters, no per-pair control flow;
   * transposition counting walks the L match ranks, selecting each side's
     k-th matched character with compare-and-mask sublane reductions.
 
+Character codes are exact end to end: a code (a byte, or a Unicode code
+point, at most 0x10FFFF < 2^24) is widened to float32, which holds it
+exactly, and only ever meets the VPU (equality tests, a one-hot masked
+sublane sum). Every MXU matmul multiplies 0/1 masks, whose products and
+prefix sums (<= L) a bfloat16 pass keeps exact, so no code is ever rounded
+to a neighbouring one.
+
 Semantics are identical to strings.jaro_winkler (jar-exact commons-text
 JaroWinklerDistance: shorter-over-longer matching, integer-halved
 transpositions, uncapped prefix with min(0.1, 1/maxlen) scaling, boost
 only at jaro >= 0.7), which the tests enforce against the jar bytecode's
-golden vectors. ASCII-width-<=32 columns dispatch here on TPU;
-wide-unicode or long columns fall back to the vmapped implementation.
+golden vectors. On a TPU every column of integer codes (uint8 bytes or
+uint32 code points) up to MAX_PALLAS_WIDTH characters wide dispatches here
+(``pallas_supported``); wider columns, and every other backend, take the
+vmapped forms of strings.py.
 """
 
 from __future__ import annotations
@@ -32,8 +42,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE_TILE = 512  # pairs per grid step
-MAX_PALLAS_WIDTH = 32
+LANE_TILE = 512  # pairs per grid step up to width 32
+MAX_PALLAS_WIDTH = 64
+
+
+def lane_tile(L: int) -> int:
+    """Pairs per grid step at width ``L``: LANE_TILE up to width 32, half of
+    it above, so one (L, T) float32 block stays at most 64 kB (16,384
+    codes) in VMEM at every width the kernels take."""
+    return LANE_TILE if L <= 32 else LANE_TILE // 2
 
 
 def _tril(L: int, strict: bool) -> jnp.ndarray:
@@ -124,8 +141,9 @@ def jaro_winkler_pallas(
 ):
     """Batched Jaro-Winkler via the Pallas lane-tile kernel.
 
-    Args: s1, s2 (B, L) integer character codes (<= 2^23 so float32 equality
-    is exact); l1, l2 (B,) lengths. Returns (B,) float32.
+    Args: s1, s2 (B, L) integer character codes (< 2^24, so float32 holds
+    each exactly; L <= MAX_PALLAS_WIDTH); l1, l2 (B,) lengths. Returns (B,)
+    float32.
     """
     B, L = s1.shape
     # jar semantics: the greedy match iterates the SHORTER string over the
@@ -137,7 +155,7 @@ def jaro_winkler_pallas(
         jnp.where(swap[:, None], s1, s2),
     )
     l1, l2 = jnp.minimum(l1, l2), jnp.maximum(l1, l2)
-    T = min(LANE_TILE, max(B, 1))
+    T = min(lane_tile(L), max(B, 1))
     pad = (-B) % T
     if pad:
         zf = lambda a, v=0: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
@@ -165,6 +183,7 @@ def jaro_winkler_pallas(
         out_specs=pl.BlockSpec((1, T), col, memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="jaro_winkler_pallas",
     )(s1T, s2T, l1r, l2r)
     return out[0, :B]
 
@@ -224,11 +243,11 @@ def _lev_kernel(s1_ref, s2p_ref, l1_ref, l2_ref, out_ref, *, L):
 def levenshtein_pallas(s1, s2, l1, l2, interpret=False):
     """Batched Levenshtein distance via the Pallas lane-tile kernel.
 
-    Args: s1, s2 (B, L) integer character codes; l1, l2 (B,) lengths.
-    Returns (B,) float32 distances.
+    Args: s1, s2 (B, L) integer character codes (< 2^24; L <=
+    MAX_PALLAS_WIDTH); l1, l2 (B,) lengths. Returns (B,) float32 distances.
     """
     B, L = s1.shape
-    T = min(LANE_TILE, max(B, 1))
+    T = min(lane_tile(L), max(B, 1))
     pad = (-B) % T
     if pad:
         zf = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
@@ -257,15 +276,25 @@ def levenshtein_pallas(s1, s2, l1, l2, interpret=False):
         out_specs=pl.BlockSpec((1, T), col, memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="levenshtein_pallas",
     )(s1T, s2pT, l1r, l2r)
     return out[0, :B]
 
 
+def pallas_takes(width: int, dtype, backend: str | None = None) -> bool:
+    """Whether a (batch, ``width``) array of ``dtype`` codes takes the Pallas
+    kernels on ``backend`` (the default backend when None): on a TPU, any
+    integer dtype (uint8 bytes, uint32 code points) up to MAX_PALLAS_WIDTH.
+    Wider columns take the vmapped vector forms, whose cost grows with the
+    width squared a pair and whose (L, L) intermediates a pair grow with it."""
+    backend = jax.default_backend() if backend is None else backend
+    return (
+        backend == "tpu"
+        and width <= MAX_PALLAS_WIDTH
+        and jnp.issubdtype(dtype, jnp.integer)
+    )
+
+
 def pallas_supported(s1) -> bool:
     """Whether the Pallas path handles this input on the current backend."""
-    return (
-        jax.default_backend() == "tpu"
-        and s1.ndim == 2
-        and s1.shape[1] <= MAX_PALLAS_WIDTH
-        and s1.dtype == jnp.uint8
-    )
+    return s1.ndim == 2 and pallas_takes(s1.shape[1], s1.dtype)

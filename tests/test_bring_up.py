@@ -63,9 +63,12 @@ def test_pallas_supported_only_on_the_tpu_backend(monkeypatch, backend, expected
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert pallas_supported(jnp.zeros((4, 8), jnp.uint8)) is expected
+    # code points and widths up to 64 take the kernels wherever bytes do
+    assert pallas_supported(jnp.zeros((4, 64), jnp.uint32)) is expected
+    assert pallas_supported(jnp.zeros((4, 40), jnp.uint8)) is expected
     # shape/dtype gates hold on the TPU too
-    assert not pallas_supported(jnp.zeros((4, 40), jnp.uint8))
-    assert not pallas_supported(jnp.zeros((4, 8), jnp.uint32))
+    assert not pallas_supported(jnp.zeros((4, 72), jnp.uint8))
+    assert not pallas_supported(jnp.zeros((4, 8), jnp.float32))
 
 
 def test_device_plan_build_error_propagates(monkeypatch, tmp_path):

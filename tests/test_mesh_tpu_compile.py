@@ -203,3 +203,21 @@ def test_small_table_gathers_rows_in_real_bytes(topo, uncached, monkeypatch):
 
     assert scratch_per_position(rows) > 1500
     assert scratch_per_position(program._packed.shape[0]) < 400
+
+
+@pytest.mark.parametrize("kernel", ["jaro_winkler_pallas", "levenshtein_pallas"])
+def test_string_kernels_compile_for_code_points_at_width_64(topo, uncached, kernel):
+    """Both Pallas string kernels at the song cell's widest columns (64), on
+    uint32 code points, through Mosaic for one described v5e chip: what the
+    chip's compiler would refuse, refused here."""
+    width = 64
+    from splink_tpu.ops import strings_pallas
+
+    one = SingleDeviceSharding(topo.devices[0])
+    codes = jax.ShapeDtypeStruct((1 << 16, width), jnp.uint32, sharding=one)
+    lens = jax.ShapeDtypeStruct((1 << 16,), jnp.int32, sharding=one)
+    with jax.enable_x64(False):
+        compiled = jax.jit(getattr(strings_pallas, kernel)).lower(
+            codes, codes, lens, lens).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and kernel in hlo

@@ -441,7 +441,7 @@ def test_counts_at_the_stage_boundaries(job):
     pairs = scored["counts"]["pairs"]
     assert pairs > 0
     assert by_name["init"][0]["counts"] == {"rows": 900}
-    assert by_name["encode"][0]["counts"] == {"rows": 900}
+    assert by_name["encode"][0]["counts"] == {"rows": 900, "wide_columns": 0}
     [em] = by_name["em"]
     assert em["counts"]["pairs"] == pairs
     assert 1 <= em["counts"]["iterations"] <= 3
@@ -460,10 +460,12 @@ def test_counts_at_the_stage_boundaries(job):
         assert sum(s["counts"]["rows"] for s in by_name["assemble_frame"]) == pairs
     else:
         assert by_name["blocking"][0]["counts"] == {"pairs": pairs}
-        # two default (Jaro-Winkler) columns, one evaluation each; the stage
-        # has no count of a pruned body, and pack_table says the row's words
+        # two default (Jaro-Winkler) columns, one evaluation each, every one
+        # a vector form off the chip; the stage has no count of a pruned
+        # body, and pack_table says the row's words
         assert by_name["gammas"][0]["counts"] == {
             "pairs": pairs, "batches": 1, "string_evals": 2 * pairs,
+            "string_evals_vector": 2 * pairs,
             "levenshtein_columns": 0, "name_inversion_columns": 0,
         }
         [pack] = by_name["pack_table"]

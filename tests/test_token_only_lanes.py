@@ -63,6 +63,7 @@ TOKEN_ONLY = {
     "baseline_c3": {"city"},
     "baseline_c2": set(),
     "febrl_ten_fields": set(),
+    "musicbrainz_songs": set(),
 }
 
 
